@@ -188,21 +188,18 @@ class TSDB:
 
     def _devwindow_devices(self):
         """The mesh device list the sharded hot set pins its shards to
-        (mesh_shape when set, else all local devices). Import failure
-        or an unbuildable mesh degrades to default placement — the
-        sharded path still runs, single-device."""
-        try:
-            import jax
+        (mesh_shape when set, else all local devices). An unbuildable
+        mesh raises: a sharded hot set that was asked for must not
+        land whole on device 0."""
+        import jax
 
-            if self.config.mesh_shape:
-                from opentsdb_tpu.parallel.plan import (
-                    build_mesh, flatten_series_mesh)
-                mesh = flatten_series_mesh(
-                    build_mesh(self.config.mesh_shape))
-                return list(mesh.devices.reshape(-1))
-            return list(jax.local_devices())
-        except Exception:
-            return [None]
+        if self.config.mesh_shape:
+            from opentsdb_tpu.parallel.plan import (
+                build_mesh, flatten_series_mesh)
+            mesh = flatten_series_mesh(
+                build_mesh(self.config.mesh_shape))
+            return list(mesh.devices.reshape(-1))
+        return list(jax.local_devices())
 
     def _warm_devwindow(self) -> None:
         """Mirror pre-existing storage (WAL-replayed memtable + sstable

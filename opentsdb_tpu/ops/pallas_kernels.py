@@ -1,4 +1,4 @@
-"""Pallas TPU kernel for segment reductions + the measured dispatch story.
+"""Pallas TPU kernel for segment reductions.
 
 The query hot loop (ops/kernels.py downsample_group) is a pair of segment
 reductions over a flat point stream — the vectorized replacement for the
@@ -13,23 +13,17 @@ indexing. It streams point chunks through VMEM with a 2-D grid
 chunks accumulate into it, so HBM traffic is one read of the points per
 segment tile plus one write of the bins.
 
-**Measured on a real v5e chip (2026-07, scripts/tpu_probe.py):** XLA's
-own lowering of a rank-1 f32 ``jax.ops.segment_sum`` is HBM-bound and
-excellent at every segment count — ~0.1 ms for N=10M points into 1.7M
-segments, and within noise of the Pallas kernel at small counts
-(N=1M points: pallas 0.03/0.08/0.09 ms vs XLA 0.05/0.07/0.08 ms at
-nseg=256/1024/4096). What IS slow on TPU is the shape, not the scatter:
-feature-stacked [N, K] scatters (~1000 ms for [10M, 3]) and
-segment_min/max (~240 ms) fall off the fast path. The production kernels
-therefore issue one rank-1 segment_sum per needed statistic
-(ops/kernels.py _segment_moments) and no longer route through a stacked
-feature matrix; the Pallas kernel is kept as a validated alternative (and
-the interpret-mode semantics oracle for tests), not as the default path.
+The production kernels do not call it: they issue one rank-1 XLA
+``jax.ops.segment_sum`` per needed statistic (ops/kernels.py
+_segment_moments). How the one-hot matmul, the rank-1 scatter, a
+feature-stacked [N, K] scatter and segment_min/max compare on a local
+chip is not measured (PERF.md, open questions); the kernel is kept as
+a validated alternative and the interpret-mode semantics oracle for
+tests.
 
 ``segment_sum_features`` remains the stacked-API entry point for callers
 that want K features reduced together; it unstacks into rank-1 XLA
-segment_sums, which beats both the stacked scatter and the one-hot matmul
-on hardware.
+segment_sums.
 """
 
 from __future__ import annotations
@@ -94,13 +88,9 @@ def pallas_segment_sum(feat: jnp.ndarray, seg: jnp.ndarray,
     n_tiles = nseg_pad // SEG_TILE
 
     # Under shard_map the out_shape needs the inputs' varying-manual-axes
-    # set, or tracing rejects the pallas_call (check_vma). Older jax
-    # (pre-typeof/vma) has no such check — a plain struct is correct.
-    try:
-        out_shape = jax.ShapeDtypeStruct((nseg_pad, k), jnp.float32,
-                                         vma=jax.typeof(feat).vma)
-    except (AttributeError, TypeError):
-        out_shape = jax.ShapeDtypeStruct((nseg_pad, k), jnp.float32)
+    # set, or tracing rejects the pallas_call (check_vma).
+    out_shape = jax.ShapeDtypeStruct((nseg_pad, k), jnp.float32,
+                                     vma=jax.typeof(feat).vma)
     out = pl.pallas_call(
         _seg_sum_kernel,
         grid=(n_tiles, n_chunks),
@@ -121,10 +111,9 @@ def pallas_segment_sum(feat: jnp.ndarray, seg: jnp.ndarray,
     return out[:num_segments]
 
 
-# Retained for callers that tune dispatch; at or below this count the
-# one-hot matmul matches XLA on hardware (see module docstring), above it
-# the nseg_pad FLOPs blow-up loses. The default path no longer consults
-# it — rank-1 XLA segment_sum won everywhere on the measured chip.
+# Retained for callers that tune dispatch: the one-hot matmul's FLOPs
+# grow with nseg_pad, so above this count it cannot win. The default
+# path does not consult it.
 PALLAS_MAX_SEGMENTS = 4096
 
 
@@ -132,10 +121,7 @@ def segment_sum_features(feat: jnp.ndarray, seg: jnp.ndarray,
                          num_segments: int):
     """Segment-sum K stacked features: K rank-1 XLA segment_sums.
 
-    Rank-1 f32 scatter-adds are the measured fast path on TPU (see
-    module docstring); the stacked [N, K] scatter this API used to issue
-    is ~1000x slower on hardware, and the Pallas one-hot matmul only ever
-    ties XLA. Semantics are identical to
+    Semantics are identical to
     ``jax.ops.segment_sum(feat, seg, num_segments)``.
     """
     return jnp.stack(

@@ -16,8 +16,7 @@ kernel the query/rollup/fused paths dispatch goes through
   program; GSPMD partitions it and inserts the collectives.
 - mesh + plan specs, style "shard_map": the fallback for map-style
   bodies with explicit collectives (psum/all_gather written out) —
-  ``shard_map`` over the mesh (via the PR-2 compat alias in
-  parallel/mesh.py, which this jax 0.4.37 needs) wrapped in one jit.
+  ``jax.shard_map`` over the mesh wrapped in one jit.
 
 Results cache per (fn, plan, mesh, statics) — repeat dashboards never
 rebuild a wrapper, and jax's own executable cache below keys on shapes
@@ -43,7 +42,6 @@ import jax
 from jax.sharding import NamedSharding
 
 from opentsdb_tpu.obs.registry import METRICS as _metrics
-from opentsdb_tpu.parallel.mesh import shard_map
 from opentsdb_tpu.parallel.plan import ExecPlan
 
 _M_COMPILE = _metrics.timer("mesh.compile")
@@ -150,9 +148,9 @@ def compile_with_plan(fn, plan: ExecPlan, mesh=None, statics: tuple = ()):
                                donate_argnums=plan.donate_argnums)
             wrapped = compiled if mesh is None else _MeshDispatch(compiled)
         elif plan.style == "pjit":
-            # Explicit shardings exist: prefer the pjit path (jax>=0.4
-            # spells it jax.jit with shardings) so the partitioner sees
-            # them; the body stays global-view.
+            # Explicit shardings exist: prefer the pjit path (jax.jit
+            # with shardings) so the partitioner sees them; the body
+            # stays global-view.
             compiled = jax.jit(
                 body,
                 in_shardings=_shardings(mesh, plan.in_specs),
@@ -163,8 +161,9 @@ def compile_with_plan(fn, plan: ExecPlan, mesh=None, statics: tuple = ()):
         else:
             # Map-style fallback: the body is written per-shard with
             # explicit collectives.
-            mapped = shard_map(body, mesh=mesh, in_specs=plan.in_specs,
-                               out_specs=plan.out_specs)
+            mapped = jax.shard_map(body, mesh=mesh,
+                                   in_specs=plan.in_specs,
+                                   out_specs=plan.out_specs)
             compiled = jax.jit(mapped,
                                static_argnames=static_names,
                                donate_argnums=plan.donate_argnums)

@@ -278,9 +278,12 @@ DASH_AGG_ID = {name: i for i, name in enumerate(DASH_AGGS)}
 
 def _finish_switch(agg_id, stats):
     """_finish with a traced aggregator: every statistic is already
-    computed; the switch selects the finishing arithmetic."""
-    branches = [lambda s, a=a: _finish(a, *s) for a in DASH_AGGS]
-    return jax.lax.switch(agg_id, branches, stats)
+    computed, so every finishing arithmetic is evaluated too
+    (elementwise, small next to the reductions) and the traced id
+    picks one — the same bits as the gated form. Deliberately not a
+    ``lax.switch``: XLA:TPU (libtpu 0.0.34, v5e) dies with SIGILL
+    compiling the group stage's switch over these branches (PR 21)."""
+    return jnp.stack([_finish(a, *stats) for a in DASH_AGGS])[agg_id]
 
 
 class DashPlan(NamedTuple):

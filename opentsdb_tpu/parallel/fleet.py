@@ -40,18 +40,6 @@ LOG = logging.getLogger("opentsdb.fleet")
 _PLANE: dict | None = None
 
 
-def gloo_available() -> bool:
-    """Capability probe for CPU cross-process collectives: without the
-    gloo TCP transport, ``jax.distributed`` CPU jobs fail with
-    "Multiprocess computations aren't implemented on the CPU backend".
-    Mirrors the skip guard in tests/test_mesh_plane.py."""
-    try:
-        from jax._src.lib import xla_extension
-        return hasattr(xla_extension, "make_gloo_tcp_collectives")
-    except Exception:
-        return False
-
-
 def init_plane(coordinator: str, num_processes: int,
                process_id: int) -> dict:
     """Join the serving mesh plane. Returns the plane-info dict (also
@@ -74,15 +62,9 @@ def init_plane(coordinator: str, num_processes: int,
     import jax
 
     if num_processes > 1:
-        # CPU fleets need the gloo TCP transport opted in BEFORE the
-        # backend initializes; TPU pods ignore the knob (they join over
-        # their native transport). Older/newer jax without the knob:
-        # initialize() itself decides, so failure stays loud.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation",
-                              "gloo")
-        except Exception:
-            pass
+        # CPU fleets ride the gloo TCP transport (jax's default
+        # jax_cpu_collectives_implementation); TPU pods join over
+        # their native transport.
         jax.distributed.initialize(coordinator_address=coordinator,
                                    num_processes=num_processes,
                                    process_id=process_id)

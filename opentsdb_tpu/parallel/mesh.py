@@ -5,15 +5,6 @@ from __future__ import annotations
 import jax
 from jax.sharding import Mesh
 
-# jax moved shard_map from jax.experimental to the top level around
-# 0.4.35 and removed the experimental path later; one alias here keeps
-# every mesh-sharded kernel working on both sides of the move.
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # older jax: experimental namespace only
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
-
 SERIES_AXIS = "series"  # data-parallel axis: series blocks across chips
 TIME_AXIS = "time"      # sequence-parallel axis: contiguous time tiles
 EXPERT_AXIS = "expert"  # expert axis: aggregator families across chips

@@ -513,8 +513,9 @@ def window_summaries_sharded(series, res: int, mesh):
 # fold with a bit-exactness contract against raw float64 scans. This
 # section moves that fold on-device behind the execution plane
 # (Config.rollup_device_fold): f64 accumulation where the backend
-# supports it (jax x64 — CPU yes, TPU no), else f32 with the contract
-# explicitly RELAXED. Either way the fold KIND is declared in the
+# really computes it under jax x64 (probed, not assumed: the CPU does,
+# and a v5e chip does too — tests/test_tpu_hardware.py), else f32 with
+# the contract explicitly RELAXED. Either way the fold KIND is declared in the
 # tier's state file ("fold": host-f64 | device-f64 | device-f32),
 # because even the f64 device fold is tolerance-level vs the host
 # pairwise sum: XLA's scatter-add reduction order is unspecified,
@@ -526,21 +527,18 @@ _DEVICE_F64: bool | None = None
 
 def device_f64_supported() -> bool:
     """Probe (once) whether the default jax backend really computes in
-    float64 under x64 mode — CPU does; TPU silently can't."""
+    float64 under x64 mode (a backend that silently computes in f32
+    returns 1.0 for the probe sum)."""
     global _DEVICE_F64
     if _DEVICE_F64 is None:
-        try:
-            import jax
-            import jax.numpy as jnp
-            from jax.experimental import enable_x64
+        import jax
+        import jax.numpy as jnp
 
-            with enable_x64():
-                x = jax.device_put(np.array([1.0, 2.0**-40]))
-                _DEVICE_F64 = bool(
-                    np.asarray(x).dtype == np.float64
-                    and float(jnp.sum(x)) != 1.0)
-        except Exception:
-            _DEVICE_F64 = False
+        with jax.enable_x64():
+            x = jax.device_put(np.array([1.0, 2.0**-40]))
+            _DEVICE_F64 = bool(
+                np.asarray(x).dtype == np.float64
+                and float(jnp.sum(x)) != 1.0)
     return _DEVICE_F64
 
 
@@ -596,10 +594,10 @@ def window_summaries_device(ts: np.ndarray, vals: np.ndarray,
     Same (window_bases, REC_DTYPE records) return; sums accumulate in
     f64 when the backend supports it (:func:`device_fold_kind`), and
     the result is tolerance-level — NOT byte-identical — vs the host
-    fold (XLA scatter order). Spans the int32 rebase can't carry (or a
-    missing/odd jax) fall back to the host fold silently: the caller's
-    declared kind stays honest because the contract it declares is
-    "at most this relaxed"."""
+    fold (XLA scatter order). Spans the int32 rebase can't carry take
+    the host fold: the caller's declared kind stays honest because the
+    contract it declares is "at most this relaxed". Any failure of the
+    device fold itself raises — it was asked for."""
     n = len(ts)
     if n == 0:
         return (np.empty(0, np.int64), np.empty(0, REC_DTYPE))
@@ -609,35 +607,30 @@ def window_summaries_device(ts: np.ndarray, vals: np.ndarray,
     if span > 2**31 - 1 or num_windows > 1 << 22:
         return window_summaries(ts, vals, res)
     global _DEVICE_FOLD
-    try:
-        import jax
+    import jax
 
-        if _DEVICE_FOLD is None:
-            _DEVICE_FOLD = _device_fold_fn()
-        f64 = device_f64_supported()
-        pad_n = 1 << max(int(n - 1).bit_length(), 10)
-        pad_w = 1 << max(int(num_windows - 1).bit_length(), 6)
-        rel = np.zeros(pad_n, np.int32)
-        rel[:n] = (np.asarray(ts, np.int64) - origin).astype(np.int32)
-        v = np.zeros(pad_n, np.float64 if f64 else np.float32)
-        v[:n] = vals
-        valid = np.zeros(pad_n, bool)
-        valid[:n] = True
+    if _DEVICE_FOLD is None:
+        _DEVICE_FOLD = _device_fold_fn()
+    f64 = device_f64_supported()
+    pad_n = 1 << max(int(n - 1).bit_length(), 10)
+    pad_w = 1 << max(int(num_windows - 1).bit_length(), 6)
+    rel = np.zeros(pad_n, np.int32)
+    rel[:n] = (np.asarray(ts, np.int64) - origin).astype(np.int32)
+    v = np.zeros(pad_n, np.float64 if f64 else np.float32)
+    v[:n] = vals
+    valid = np.zeros(pad_n, bool)
+    valid[:n] = True
 
-        def run():
-            return [np.asarray(g) for g in _DEVICE_FOLD(
-                jax.device_put(rel), jax.device_put(v),
-                jax.device_put(valid), num_windows=pad_w, res=res)]
+    def run():
+        return [np.asarray(g) for g in _DEVICE_FOLD(
+            jax.device_put(rel), jax.device_put(v),
+            jax.device_put(valid), num_windows=pad_w, res=res)]
 
-        if f64:
-            from jax.experimental import enable_x64
-
-            with enable_x64():
-                grids = run()
-        else:
+    if f64:
+        with jax.enable_x64():
             grids = run()
-    except Exception:
-        return window_summaries(ts, vals, res)
+    else:
+        grids = run()
     count, total, mn, mx, first, last, t_first, t_last = grids
     mask = count > 0
     w_idx = np.flatnonzero(mask)

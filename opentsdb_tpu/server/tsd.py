@@ -46,7 +46,7 @@ from opentsdb_tpu.query.executor import QueryExecutor, QuerySpec
 from opentsdb_tpu.query.grammar import parse_m
 from opentsdb_tpu.server import logbuffer
 from opentsdb_tpu.stats.collector import LatencyDigest, StatsCollector
-from opentsdb_tpu.utils import timeparse
+from opentsdb_tpu.utils import jaxenv, timeparse
 from typing import NamedTuple
 
 
@@ -928,6 +928,10 @@ class TSDServer:
             body["fenced_by_epoch"] = guard.fenced_epoch
         body["uptime_s"] = int(time.time()) - self.start_time
         body["inflight_queries"] = self.admission.inflight_queries
+        # Which device this daemon serves from, as jax reports it, and
+        # where its compiled programs persist.
+        body["device"] = jaxenv.device_info()
+        body["compile_cache_dir"] = jaxenv.compile_cache_dir()
         mesh = self._mesh_serving_info()
         if mesh is not None:
             # The router's fan-out weights series ownership by this
@@ -955,6 +959,10 @@ class TSDServer:
         if sharded:
             out["resident"] = {
                 "shards": dw.n_shards,
+                # Per shard: the device it is pinned to (None =
+                # default placement) and the points resident there.
+                "shard_devices": dw.shard_device_ids(),
+                "shard_points": dw.shard_resident_points(),
                 "points": dw.resident_points(),
                 "generation": dw.generation,
                 "reshards": dw.reshard_count,
@@ -1230,9 +1238,14 @@ class TSDServer:
         # The ingest fast path (wire decode + WAL group commit):
         # batches-per-fsync is the coalescing win, wait_ms p95 the
         # latency each acked batch paid for its covering fsync.
+        from opentsdb_tpu.server import wire
         ingest = {"group": {"batches": 0, "points": 0, "fsyncs": 0,
                             "waits": 0, "wait_ms_p95": 0.0},
-                  "parse": {"count": 0, "p95_ms": 0.0}}
+                  "parse": {"count": 0, "p95_ms": 0.0},
+                  # Which telnet decoder this process loaded: the
+                  # native .so is an untracked build product.
+                  "decoder": ("native" if wire.native_available()
+                              else "python")}
         for name, kind, tkey, obj in METRICS._snapshot():
             if name == "wal.group.batches":
                 ingest["group"]["batches"] += obj.value

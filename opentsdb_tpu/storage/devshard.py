@@ -350,15 +350,25 @@ class ShardedDeviceWindow:
 
     # -- observability -------------------------------------------------
 
-    def resident_points(self) -> int:
+    def shard_resident_points(self) -> list[int]:
         with self._lock:
             shards = list(self._shards)
-        total = 0
+        out = []
         for s in shards:
             with s._lock:
-                total += sum(mw.device_points
-                             for mw in s._metrics.values())
-        return total
+                out.append(sum(mw.device_points
+                               for mw in s._metrics.values()))
+        return out
+
+    def resident_points(self) -> int:
+        return sum(self.shard_resident_points())
+
+    def shard_device_ids(self) -> list:
+        """The jax device id each shard is pinned to (None = default
+        placement)."""
+        with self._lock:
+            return [None if s.device is None else int(s.device.id)
+                    for s in self._shards]
 
     def collect_stats(self, collector) -> None:
         with self._lock:

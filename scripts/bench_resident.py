@@ -48,8 +48,8 @@ def main() -> int:
     import jax
     if args.cpu:
         jax.config.update("jax_platforms", "cpu")
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.expanduser("~/.cache/jax_comp"))
+    from opentsdb_tpu.utils.jaxenv import setup_compile_cache
+    setup_compile_cache()
     dev = jax.devices()[0]
     log(f"device: {dev}")
 

@@ -47,6 +47,22 @@ def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
 
 
+def _jax_setup(args):
+    """Platform pin, compile cache and the device line, before the
+    first JAX use. The native decoder/extension are NOT built here:
+    which one a run took is recorded in its artifact (``native_ext`` /
+    ``native_decode_built``), decided by whoever ran ``make -C
+    native``, never by this script."""
+    import jax
+    if args.cpu:
+        jax.config.update("jax_platforms", "cpu")
+    from opentsdb_tpu.utils.jaxenv import setup_compile_cache
+    setup_compile_cache()
+    dev = jax.devices()[0]
+    log(f"device: {dev}")
+    return dev
+
+
 def rss_gb() -> float:
     with open("/proc/self/status") as f:
         for ln in f:
@@ -161,18 +177,7 @@ def run_codec_compare(args) -> int:
     fused (served plan, decode-plus-aggregate on the blocks) vs
     decode-then-reduce (sstable_fused_agg off -> classic scan), with a
     byte-identical answer check per spec."""
-    subprocess.run(["make", "-C", os.path.join(REPO, "native")],
-                   capture_output=True)
-    import jax
-    if args.cpu:
-        jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.expanduser("~/.cache/jax_comp"))
-    except Exception:
-        pass
-    dev = jax.devices()[0]
-    log(f"device: {dev}")
+    dev = _jax_setup(args)
 
     from opentsdb_tpu.core.tsdb import TSDB
     from opentsdb_tpu.query.executor import QueryExecutor, QuerySpec
@@ -513,20 +518,9 @@ def run_ingest_battery(args) -> int:
     fingerprinted — delta-fold legs must serve byte-identical answers
     to full-refold legs.
     """
-    subprocess.run(["make", "-C", os.path.join(REPO, "native")],
-                   capture_output=True)
     import hashlib
 
-    import jax
-    if args.cpu:
-        jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.expanduser("~/.cache/jax_comp"))
-    except Exception:
-        pass
-    dev = jax.devices()[0]
-    log(f"device: {dev}")
+    dev = _jax_setup(args)
 
     from opentsdb_tpu.core.tsdb import TSDB
     from opentsdb_tpu.obs.registry import METRICS
@@ -816,18 +810,7 @@ def run_sketch_serve(args) -> int:
     per-kind sketch bytes (the moment <= 25%-of-digest claim) and the
     Storyboard allocator's plan at three byte budgets over the real
     record densities."""
-    subprocess.run(["make", "-C", os.path.join(REPO, "native")],
-                   capture_output=True)
-    import jax
-    if args.cpu:
-        jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.expanduser("~/.cache/jax_comp"))
-    except Exception:
-        pass
-    dev = jax.devices()[0]
-    log(f"device: {dev}")
+    dev = _jax_setup(args)
 
     from opentsdb_tpu.core.tsdb import TSDB
     from opentsdb_tpu.query.executor import QueryExecutor, QuerySpec
@@ -1364,13 +1347,16 @@ def run_mesh_fleet_bench(args) -> int:
         log(f"fleet {nproc} does not divide mesh {shape}")
         return 1
     dpp = want_devs // nproc
+    # A gloo/CPU leg: the N children are told apart by a CPU flag
+    # (--xla_force_host_platform_device_count) and pin the CPU
+    # platform; N processes cannot share one host's chips, and the
+    # control below must run where the children ran.
+    if not args.cpu and os.environ.get("JAX_PLATFORMS") != "cpu":
+        log("--fleet is a gloo/CPU leg (N processes cannot share this "
+            "host's chips): pass --cpu")
+        return 2
     import jax
-    if args.cpu:
-        jax.config.update("jax_platforms", "cpu")
-    from opentsdb_tpu.parallel import fleet
-    if not fleet.gloo_available():
-        log("gloo cpu collectives unavailable; fleet leg skipped")
-        return 1
+    jax.config.update("jax_platforms", "cpu")
     from opentsdb_tpu.parallel.compile import set_mesh_devices
     from opentsdb_tpu.parallel.mesh import make_mesh
     from opentsdb_tpu.parallel.sharded import (pack_shards,
@@ -2014,20 +2000,7 @@ def main() -> int:
     if args.ingest_battery:
         return run_ingest_battery(args)
 
-    # Native hot loops (gitignored artifact) before any package import.
-    subprocess.run(["make", "-C", os.path.join(REPO, "native")],
-                   capture_output=True)
-
-    import jax
-    if args.cpu:
-        jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.expanduser("~/.cache/jax_comp"))
-    except Exception:
-        pass
-    dev = jax.devices()[0]
-    log(f"device: {dev}")
+    dev = _jax_setup(args)
 
     from opentsdb_tpu.core.tsdb import TSDB
     from opentsdb_tpu.query.executor import QueryExecutor, QuerySpec

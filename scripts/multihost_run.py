@@ -22,6 +22,14 @@ Cases:
 Run: python scripts/multihost_run.py    (parent forks both children)
 Writes MULTIHOST_PROC.json to the repo root from process 0.
 
+Every mode here is a gloo/CPU leg: the children are told apart by a CPU
+flag (--xla_force_host_platform_device_count), are started with
+JAX_PLATFORMS=cpu and pin the CPU platform before their first backend
+touch, so on a chip host they never claim a chip (N processes cannot
+share one host's chips — each would claim all of them and the second
+would fail or hang). Bringing the multi-process fleet onto chips is
+not what this script does.
+
 ``--serve`` runs the SERVED DEPLOYMENT MODE smoke (PR 18): the same
 two gloo processes join the plane through ``parallel/fleet.init_plane``
 (the exact bootstrap ``tsd --mesh-plane`` uses), each builds a TSDB
@@ -133,10 +141,6 @@ def child_plane(process_id: int, coordinator: str) -> int:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=N_PROC,
                                process_id=process_id)
@@ -248,15 +252,6 @@ def child(process_id: int, coordinator: str) -> int:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    # This jaxlib's CPU client defaults to NO cross-process collective
-    # transport ("Multiprocess computations aren't implemented on the
-    # CPU backend") — the gloo TCP transport must be opted into before
-    # the backend initializes. Builds without gloo are skipped by the
-    # capability probe in tests/test_multihost.py.
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass  # older/newer jax: no such knob; initialize() decides
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=N_PROC,
                                process_id=process_id)
@@ -549,6 +544,7 @@ def main() -> int:
         env_base.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={CHIPS_PER_PROC}"
     ).strip()
+    env_base["JAX_PLATFORMS"] = "cpu"      # gloo/CPU leg, by name
     env_base["MH_COORDINATOR"] = coord
     env_base["MH_MODE"] = mode
     procs = []

@@ -292,20 +292,6 @@ class TestShardedReductionBytes:
             gv1[gm1], np.asarray(ref["group_values"])[refm])
 
 
-def _cpu_collectives_available() -> bool:
-    try:
-        from jax._src.lib import xla_extension
-        return hasattr(xla_extension, "make_gloo_tcp_collectives")
-    except Exception:
-        return False
-
-
-@pytest.mark.skipif(
-    not _cpu_collectives_available(),
-    reason="this jaxlib's CPU client has no cross-process collectives "
-           "transport (no xla_extension.make_gloo_tcp_collectives; "
-           "'Multiprocess computations aren't implemented on the CPU "
-           "backend')")
 def test_two_process_plane_byte_parity():
     """The committed multi-process proof for the execution plane: two
     gloo-joined OS processes, a flat 8-device series mesh spanning the
@@ -371,10 +357,6 @@ class TestServerObservability:
         assert rc_bad == 2
 
 
-@pytest.mark.skipif(
-    not _cpu_collectives_available(),
-    reason="this jaxlib's CPU client has no cross-process collectives "
-           "transport (no xla_extension.make_gloo_tcp_collectives)")
 def test_two_process_served_deployment_mode():
     """The SERVED deployment-mode smoke across a real process boundary:
     two gloo-joined tsd-equivalent daemons (parallel/fleet.init_plane,
