@@ -29,6 +29,7 @@ from opentsdb_tpu.core.compaction import CompactionQueue
 from opentsdb_tpu.core.const import (MAX_TIMESPAN, TIMESTAMP_BYTES,
                                      UID_WIDTH)
 from opentsdb_tpu.core.errors import NoSuchUniqueName, PleaseThrottleError
+from opentsdb_tpu.obs import trace as obs_trace
 from opentsdb_tpu.storage.kv import KVStore
 from opentsdb_tpu.storage.sstable import series_hash
 from opentsdb_tpu.uid.uniqueid import UniqueId
@@ -1108,25 +1109,36 @@ class TSDB:
             # A replica owns neither the sketch snapshot nor the spill
             # tier; writing either would race the writer daemon.
             return 0
+        if not hasattr(self, "rollups"):
+            # The compaction thread's timer fired while __init__ is
+            # still running (refilling the device window can outlast a
+            # short checkpoint interval): ``rollups`` is the last thing
+            # it sets, and there is nothing to checkpoint before that.
+            return 0
         # One checkpoint at a time (see _checkpoint_lock): the rollup
         # bracketing below is only sound when THIS call's store spill is
         # the one between its begin_spill and fold_after_spill.
         with self._checkpoint_lock:
+            # The two snapshots run before the store's timed phases
+            # (checkpoint.phase); each is a checkpoint.snapshot timer
+            # and a profiler annotation of that name (obs/trace.py).
             path = self._sketch_path()
             if self.sketches is not None and path:
-                self.sketches.save(path)
+                with obs_trace.timed("checkpoint.snapshot", kind="sketch"):
+                    self.sketches.save(path)
             # Tenant accounting snapshot, same bracket position and
             # the same coverage argument: committed BEFORE the spill,
             # so a loaded TENANTS.json always covers the sstable tier
             # and boot only re-folds the replayed memtable's series.
             if self.tenants is not None:
-                self.tenants.save()
+                with obs_trace.timed("checkpoint.snapshot", kind="tenant"):
+                    self.tenants.save()
             # Rollup tier brackets the spill: mark the about-to-spill
             # windows in flight (and the tier pending on disk) BEFORE the
             # raw spill, fold the spilled keys into summary records after —
             # a crash in between leaves the pending marker and the next
             # open rebuilds (rollup/tier.py consistency contract).
-            rollups = getattr(self, "rollups", None)  # early-timer safety
+            rollups = self.rollups
             if rollups is not None:
                 rollups.begin_spill()
             ckpt = getattr(self.store, "checkpoint", None)

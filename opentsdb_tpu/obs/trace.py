@@ -9,10 +9,23 @@ query's execution (``activate``); the contextvar keeps concurrent
 queries' spans separate even though they share one executor and one
 thread pool.
 
-Span durations are wall-clock (``perf_counter``) milliseconds. The
-tree serializes as::
+Span durations are wall-clock (``perf_counter``) milliseconds; a
+span's start is one ``time.time_ns()`` reading taken at enter, so a
+tree can be laid beside a profiler trace or another process's tree.
+The tree serializes as::
 
-    {"name": ..., "ms": 12.3, "tags": {...}, "spans": [children]}
+    {"name": ..., "t0": 1790550860.123457, "ms": 12.3,
+     "tags": {...}, "spans": [children]}
+
+(``t0``: epoch seconds, microsecond precision.)
+
+While a span is open it is also a ``jax.profiler.TraceAnnotation`` of
+the same name carrying the trace's ``trace_id``, so a profiler session
+shows the program's spans on its host timeline, on the clock the
+device's operations are on. ``timed()`` does the same for threads that
+serve no request (the checkpoint timer, the event loop): it observes a
+registry timer and is an annotation of the timer's name. The profiler
+is imported at the first span or ``timed()`` block, never before.
 
 Storage fan-out gets ``timed_iter``: the sharded store's per-shard
 scan iterators are interleaved by the heap merge, so each shard's span
@@ -32,8 +45,11 @@ import threading
 import time
 from contextlib import contextmanager
 
+from opentsdb_tpu.obs.registry import METRICS
+
 _ACTIVE = 0                     # process-wide count of active traces
 _ACTIVE_LOCK = threading.Lock()
+_ANNOTATION = None              # jax.profiler.TraceAnnotation, once used
 
 
 def new_trace_id() -> str:
@@ -42,22 +58,44 @@ def new_trace_id() -> str:
     state and routers/replicas must never mint the same id)."""
     import os
     return os.urandom(8).hex()
+
+
 _CURRENT: contextvars.ContextVar = contextvars.ContextVar(
     "opentsdb_tpu_trace_span", default=None)
+_TRACE_ID: contextvars.ContextVar = contextvars.ContextVar(
+    "opentsdb_tpu_trace_id", default=None)
+
+
+def _annotation(name: str, **stats):
+    """A profiler annotation of this name: outside a profiler session
+    entering one checks a flag and no more. Imported at the first use,
+    so that a process that opens no span imports no profiler, and kept:
+    the import statement alone costs 14 us a time (jax.profiler
+    resolves its names lazily)."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION(name, **stats)
 
 
 class Span:
-    __slots__ = ("name", "tags", "t0", "ms", "children")
+    __slots__ = ("name", "tags", "t0", "wall_ns", "ms", "children")
 
     def __init__(self, name: str, tags: dict | None = None) -> None:
         self.name = name
         self.tags = tags if tags is not None else {}
-        self.t0 = time.perf_counter()
+        self.start()
         self.ms = 0.0
         self.children: list[Span] = []
 
+    def start(self) -> None:
+        self.wall_ns = time.time_ns()
+        self.t0 = time.perf_counter()
+
     def to_dict(self) -> dict:
-        d = {"name": self.name, "ms": round(self.ms, 3)}
+        d = {"name": self.name, "t0": round(self.wall_ns / 1e9, 6),
+             "ms": round(self.ms, 3)}
         if self.tags:
             d["tags"] = self.tags
         if self.children:
@@ -103,14 +141,16 @@ _NOOP = _NoopSpan()
 
 
 class _SpanCtx:
-    __slots__ = ("span", "_token")
+    __slots__ = ("span", "_token", "_ann")
 
     def __init__(self, name: str, tags: dict | None) -> None:
         self.span = Span(name, tags)
 
     def __enter__(self) -> Span:
+        self._ann = _annotation(self.span.name, trace_id=_TRACE_ID.get())
+        self._ann.__enter__()
         self._token = _CURRENT.set(self.span)
-        self.span.t0 = time.perf_counter()
+        self.span.start()
         return self.span
 
     def __exit__(self, *exc) -> None:
@@ -120,6 +160,7 @@ class _SpanCtx:
         parent = _CURRENT.get()
         if parent is not None:
             parent.children.append(sp)
+        self._ann.__exit__(*exc)
 
 
 def span(name: str, **tags):
@@ -128,6 +169,16 @@ def span(name: str, **tags):
     if not _ACTIVE or _CURRENT.get() is None:
         return _NOOP
     return _SpanCtx(name, tags or None)
+
+
+@contextmanager
+def timed(name: str, **tags):
+    """Context manager for a phase that belongs to no query: observes
+    the registry timer ``name`` (with these tags) and, for as long as
+    it runs, is a profiler annotation of that name."""
+    with _annotation(name, **tags), \
+            METRICS.timer(name, tags or None).time():
+        yield
 
 
 def current_span() -> Span | None:
@@ -145,11 +196,14 @@ def activate(trace: Trace):
     with _ACTIVE_LOCK:
         _ACTIVE += 1
     token = _CURRENT.set(trace.root)
-    trace.root.t0 = time.perf_counter()
+    id_token = _TRACE_ID.set(trace.trace_id)
+    trace.root.start()
     try:
-        yield trace
+        with _annotation(trace.root.name, trace_id=trace.trace_id):
+            yield trace
     finally:
         trace.root.ms = (time.perf_counter() - trace.root.t0) * 1000.0
+        _TRACE_ID.reset(id_token)
         _CURRENT.reset(token)
         with _ACTIVE_LOCK:
             _ACTIVE -= 1
@@ -162,6 +216,7 @@ def timed_iter(it, parent: Span, name: str, tags: dict | None = None):
     the heap merge interleaves shard iterators."""
     total = 0.0
     rows = 0
+    wall_ns = time.time_ns()
     try:
         while True:
             t0 = time.perf_counter()
@@ -176,5 +231,6 @@ def timed_iter(it, parent: Span, name: str, tags: dict | None = None):
     finally:
         sp = Span(name, dict(tags or ()))
         sp.tags["rows"] = rows
+        sp.wall_ns = wall_ns
         sp.ms = total * 1000.0
         parent.children.append(sp)

@@ -25,6 +25,11 @@ static kwargs through the wrapper: pass them as a hashable tuple of
 (name, value) pairs and they bind into the body before wrapping (and
 into the cache key).
 
+Every body compiles under ``jax.named_scope(plan.name)``: the plan's
+name is in each operation's metadata (``op_name`` in the HLO, the
+operation's name in a profiler trace), so a trace's device time can be
+summed by plan and a kernel keeps its name through a refactor.
+
 Observability: ``mesh.compile`` times wrapper builds AND any dispatch
 that triggered a fresh XLA compile (detected via the jitted callable's
 cache size growing); ``mesh.dispatch`` times every mesh-leg dispatch;
@@ -130,6 +135,11 @@ def compile_with_plan(fn, plan: ExecPlan, mesh=None, statics: tuple = ()):
     _C_MISS.inc()
     with _M_COMPILE.time():
         body = functools.partial(fn, **dict(statics)) if statics else fn
+        # The wrapper keeps the body's signature (functools.wraps, so
+        # jit still resolves static_argnames) and its name (a partial
+        # has none of its own), so the module is still jit_<fn>.
+        body = jax.named_scope(plan.name)(body)
+        body.__name__ = getattr(fn, "__name__", plan.name)
         # Statics bound through ``statics`` are no longer call-time
         # kwargs; keeping them in static_argnames would confuse jit's
         # signature inspection (and pjit rejects kwargs outright when

@@ -65,6 +65,14 @@ _metrics.gauge(
     lambda: (_C_FUSED_SERVED.value / _C_FUSED_ATTEMPT.value
              if _C_FUSED_ATTEMPT.value else 0.0))
 
+# The resident plan's stage cache (_dw_stage_cache): a miss builds a
+# stage, which is the device's whole cost of a resident sub-query;
+# evicted = stages dropped by hand (a dead data version, a device OOM),
+# not the LRU's own turnover at its cap.
+_C_STAGE_HIT = _metrics.counter("devwindow.stage.hit")
+_C_STAGE_MISS = _metrics.counter("devwindow.stage.miss")
+_C_STAGE_EVICTED = _metrics.counter("devwindow.stage.evicted")
+
 
 def _count_decline(reason: str) -> None:
     _metrics.counter("compress.fused.decline", {"reason": reason}).inc()
@@ -886,73 +894,89 @@ class QueryExecutor:
         # concatenated copy — the window can approach the whole HBM);
         # every moment family folds chunk-wise, dev included (Chan M2
         # combination, ops/kernels._chunk_fold).
-        cols = dw.chunk_columns(metric_uid, start, end)
+        # From here the resident.* spans are the children of
+        # planner.pick, in order and together tiling it (README,
+        # "Observability", says what each one times).
+        with obs_trace.span("resident.columns") as sp:
+            cols = dw.chunk_columns(metric_uid, start, end)
+            if sp is not None and cols is not None:
+                chunks = _dw_chunks(cols)
+                sp.tags["chunks"] = len(chunks)
+                sp.tags["points"] = sum(int(c[0].shape[0])
+                                        for c in chunks)
         if cols is None:
             return None
-        groups, named = self._devwindow_groups(
-            dw, metric_uid, cols, exact, group_bys)
-        if not groups:
-            return []
+        with obs_trace.span("resident.groups") as gsp:
+            groups, named, plan_hit = self._devwindow_groups(
+                dw, metric_uid, cols, exact, group_bys)
+            if not groups:
+                return []
 
-        # The shift (qbase - epoch) participates in arithmetic on device
-        # (rel_ts - shift in window_series_stage) — unlike lo/hi, which are
-        # comparison-only and clamp safely. If it doesn't fit in int32
-        # (e.g. an all-time query against a metric whose epoch is past
-        # 2^31), fall back to the scan path rather than silently
-        # mis-bucketing (devstore's exact-or-fall-back contract).
-        # Sharded windows carry one epoch PER shard; all must fit.
-        epochs = ([sc.epoch for sc in cols.shards if sc is not None]
-                  if sharded else [cols.epoch])
-        if not all(imin <= qbase - e <= imax for e in epochs):
-            return None
-        num_buckets = _pad_size(int((end - qbase) // interval + 1))
-        S_all = len(cols.series_keys)
-        S_pad = _pad_size(S_all)
-        if S_pad * num_buckets >= 2**31:
-            # The kernels' per-(series, bucket) segment ids are int32;
-            # a huge series-count x bucket-count product would wrap.
-            # Scan path handles it (per-group kernels, smaller grids).
-            return None
-        gkeys = sorted(groups)
-        G = _pad_size(len(gkeys))
-        # Device-resident include/gmap, cached per (window instance,
-        # plan, generation, padding): every fresh host array argument
-        # is its own transfer, so repeat dashboard queries should not
-        # re-upload masks that only change when the series directory
-        # grows (generation bump invalidates;
-        # instance_id guards against a replacement window whose counters
-        # restart at 0 — devstore's cache-keying contract).
-        mask_cache = self._dw_mask_cache
-        fk = _filter_key(exact, group_bys)
-        mkey = (dw.instance_id, metric_uid, fk)
-        hit = mask_cache.get(mkey)
-        if hit is not None and hit[0] == cols.generation:
-            include, gmap = hit[1], hit[2]
-        else:
-            include = np.zeros(S_pad, bool)
-            gmap = np.full(S_pad, G - 1, np.int32)
-            for gi, gkey in enumerate(gkeys):
-                for sid in groups[gkey]:
-                    include[sid] = True
-                    gmap[sid] = gi
-            # Sharded window: commit to the combine device (the first
-            # owning shard's) so the apply's inputs are colocated with
-            # the gathered stage grids.
-            tgt = None
-            if sharded:
-                for sc in cols.shards:
-                    if sc is not None and sc.chunks:
-                        try:
-                            tgt = next(iter(sc.chunks[0][0].devices()))
-                        except Exception:
-                            tgt = None
-                        break
-            include = jax.device_put(include, tgt)
-            gmap = jax.device_put(gmap, tgt)
-            # Generation lives in the VALUE (the _dw_plan_cache
-            # pattern): a directory growth overwrites in place, so dead
-            # generations never accumulate device arrays.
-            mask_cache.put(mkey, (cols.generation, include, gmap))
+            # The shift (qbase - epoch) participates in arithmetic on
+            # device (rel_ts - shift in window_series_stage) — unlike
+            # lo/hi, which are comparison-only and clamp safely. If it
+            # doesn't fit in int32 (e.g. an all-time query against a
+            # metric whose epoch is past 2^31), fall back to the scan
+            # path rather than silently mis-bucketing (devstore's
+            # exact-or-fall-back contract). Sharded windows carry one
+            # epoch PER shard; all must fit.
+            epochs = ([sc.epoch for sc in cols.shards if sc is not None]
+                      if sharded else [cols.epoch])
+            if not all(imin <= qbase - e <= imax for e in epochs):
+                return None
+            num_buckets = _pad_size(int((end - qbase) // interval + 1))
+            S_all = len(cols.series_keys)
+            S_pad = _pad_size(S_all)
+            if S_pad * num_buckets >= 2**31:
+                # The kernels' per-(series, bucket) segment ids are int32;
+                # a huge series-count x bucket-count product would wrap.
+                # Scan path handles it (per-group kernels, smaller grids).
+                return None
+            gkeys = sorted(groups)
+            G = _pad_size(len(gkeys))
+            # Device-resident include/gmap, cached per (window instance,
+            # plan, generation, padding): every fresh host array argument
+            # is its own transfer, so repeat dashboard queries should not
+            # re-upload masks that only change when the series directory
+            # grows (generation bump invalidates;
+            # instance_id guards against a replacement window whose counters
+            # restart at 0 — devstore's cache-keying contract).
+            mask_cache = self._dw_mask_cache
+            fk = _filter_key(exact, group_bys)
+            mkey = (dw.instance_id, metric_uid, fk)
+            hit = mask_cache.get(mkey)
+            if hit is not None and hit[0] == cols.generation:
+                include, gmap = hit[1], hit[2]
+            else:
+                include = np.zeros(S_pad, bool)
+                gmap = np.full(S_pad, G - 1, np.int32)
+                for gi, gkey in enumerate(gkeys):
+                    for sid in groups[gkey]:
+                        include[sid] = True
+                        gmap[sid] = gi
+                # Sharded window: commit to the combine device (the first
+                # owning shard's) so the apply's inputs are colocated with
+                # the gathered stage grids.
+                tgt = None
+                if sharded:
+                    for sc in cols.shards:
+                        if sc is not None and sc.chunks:
+                            try:
+                                tgt = next(iter(sc.chunks[0][0].devices()))
+                            except Exception:
+                                tgt = None
+                            break
+                include = jax.device_put(include, tgt)
+                gmap = jax.device_put(gmap, tgt)
+                # Generation lives in the VALUE (the _dw_plan_cache
+                # pattern): a directory growth overwrites in place, so dead
+                # generations never accumulate device arrays.
+                mask_cache.put(mkey, (cols.generation, include, gmap))
+            if gsp is not None:
+                gsp.tags.update(series=S_all, groups=len(gkeys),
+                                plan_hit=plan_hit,
+                                mask_hit=hit is not None
+                                and hit[0] == cols.generation)
         ngroups = 1 if len(gkeys) == 1 else G
         rate_kw = self._rate_kw(spec)
         # The heavy N-point half of ANY window query (range mask +
@@ -966,46 +990,52 @@ class QueryExecutor:
         skey = (dw.instance_id, metric_uid, cols.version, start, end,
                 interval, dsagg, tuple(sorted(rate_kw.items())))
         cache = self._dw_stage_cache
-        stage = cache.get(skey)
-        if stage is None:
-            try:
-                if sharded:
-                    grids = self._dw_sharded_stage(
-                        cols, start, end, qbase,
-                        num_buckets=num_buckets, S_pad=S_pad,
-                        interval=interval, dsagg=dsagg,
-                        rate_kw=rate_kw)
-                    if grids is None:
+        with obs_trace.span("resident.stage") as ssp:
+            stage = cache.get(skey)
+            (_C_STAGE_MISS if stage is None else _C_STAGE_HIT).inc()
+            if ssp is not None:
+                ssp.tags["hit"] = stage is not None
+                ssp.tags["chunks"] = len(_dw_chunks(cols))
+            if stage is None:
+                try:
+                    if sharded:
+                        grids = self._dw_sharded_stage(
+                            cols, start, end, qbase,
+                            num_buckets=num_buckets, S_pad=S_pad,
+                            interval=interval, dsagg=dsagg,
+                            rate_kw=rate_kw)
+                        if grids is None:
+                            return None
+                    else:
+                        lo32 = np.int32(
+                            min(max(start - cols.epoch, imin), imax))
+                        hi32 = np.int32(
+                            min(max(end - cols.epoch, imin), imax))
+                        shift32 = np.int32(qbase - cols.epoch)
+                        grids = kernels.window_series_stage_chunks(
+                            cols.chunks, lo32, hi32, shift32,
+                            num_series=S_pad, num_buckets=num_buckets,
+                            interval=interval, agg_down=dsagg, **rate_kw)
+                except Exception as e:
+                    # A near-HBM window can still OOM building the stage
+                    # grids; degrade to the storage scan (the
+                    # exact-or-fall-back contract) instead of erroring.
+                    if _is_device_oom(e):
                         return None
-                else:
-                    lo32 = np.int32(
-                        min(max(start - cols.epoch, imin), imax))
-                    hi32 = np.int32(
-                        min(max(end - cols.epoch, imin), imax))
-                    shift32 = np.int32(qbase - cols.epoch)
-                    grids = kernels.window_series_stage_chunks(
-                        cols.chunks, lo32, hi32, shift32,
-                        num_series=S_pad, num_buckets=num_buckets,
-                        interval=interval, agg_down=dsagg, **rate_kw)
-            except Exception as e:
-                # A near-HBM window can still OOM building the stage
-                # grids; degrade to the storage scan (the
-                # exact-or-fall-back contract) instead of erroring.
-                if _is_device_oom(e):
-                    return None
-                raise
-            # [5] fills with the host copy of presence on first fetch.
-            stage = list(grids) + [None]
-            # Stages of this metric's EARLIER data versions can never
-            # hit again (version is monotonic) but each pins [S, B]
-            # grids in HBM the devwindow's own budget can't see — drop
-            # them before the LRU cap so active ingest (a version bump
-            # per flush) doesn't strand dead grids on device.
-            for k in cache.keys():
-                if k[:2] == (dw.instance_id, metric_uid) \
-                        and k[2] != cols.version:
-                    cache.pop(k)
-            cache.put(skey, stage)
+                    raise
+                # [5] fills with the host copy of presence on first fetch.
+                stage = list(grids) + [None]
+                # Stages of this metric's EARLIER data versions can never
+                # hit again (version is monotonic) but each pins [S, B]
+                # grids in HBM the devwindow's own budget can't see — drop
+                # them before the LRU cap so active ingest (a version bump
+                # per flush) doesn't strand dead grids on device.
+                for k in cache.keys():
+                    if k[:2] == (dw.instance_id, metric_uid) \
+                            and k[2] != cols.version:
+                        cache.pop(k)
+                        _C_STAGE_EVICTED.inc()
+                cache.put(skey, stage)
         sv, sm, filled, in_range, presence_dev = stage[:5]
         # Shrink-wrap the fetch: clip to the live group/bucket counts
         # (64-quantized so statics don't churn recompiles) and bit-pack
@@ -1025,16 +1055,25 @@ class QueryExecutor:
         # the exact-or-fall-back contract breaks precisely in the
         # 1B-resident regime it exists for.
         try:
-            if agg.kind == "percentile":
-                gv, gm = kernels.window_quantile_apply(
-                    sm, filled, in_range, include, gmap,
-                    np.array([agg.quantile], np.float32),
-                    num_groups=ngroups, **shrink)
-            else:
-                gv, gm = kernels.window_moment_apply(
-                    sv, sm, filled, in_range, include, gmap,
-                    num_groups=ngroups, agg_group=spec.aggregator,
-                    **shrink)
+            with obs_trace.span("resident.apply", g_out=g_out,
+                                b_out=b_out):
+                if agg.kind == "percentile":
+                    gv, gm = kernels.window_quantile_apply(
+                        sm, filled, in_range, include, gmap,
+                        np.array([agg.quantile], np.float32),
+                        num_groups=ngroups, **shrink)
+                else:
+                    gv, gm = kernels.window_moment_apply(
+                        sv, sm, filled, in_range, include, gmap,
+                        num_groups=ngroups, agg_group=spec.aggregator,
+                        **shrink)
+            if obs_trace.current_span() is not None:
+                # Traced only: stage and apply above are dispatches
+                # (JAX returns before the device finishes), so without
+                # this sync the device's time would all land in the
+                # fetch. Untraced the path makes no such call.
+                with obs_trace.span("resident.wait"):
+                    jax.block_until_ready((gv, gm))
             # Series with no in-range points must not shape group labels
             # or emit empty groups — match the scan path, which never
             # sees them. (Pre-rate presence: computed from the raw
@@ -1042,35 +1081,43 @@ class QueryExecutor:
             # batched device_get — separate np.asarray fetches would
             # each pay a transport round trip; presence is fetched once
             # per stage.
-            if stage[5] is None:
-                gv, gm, stage[5] = jax.device_get((gv, gm, presence_dev))
-            else:
-                gv, gm = jax.device_get((gv, gm))
+            with obs_trace.span("resident.fetch") as sp:
+                if stage[5] is None:
+                    gv, gm, stage[5] = jax.device_get(
+                        (gv, gm, presence_dev))
+                else:
+                    gv, gm = jax.device_get((gv, gm))
+                if sp is not None:
+                    sp.tags["bytes"] = int(gv.nbytes + gm.nbytes)
         except Exception as e:
             if _is_device_oom(e):
                 # Drop the stage too: leaving it cached would pin its
                 # [S, B] grids in the very HBM that just ran out, and
                 # every later query of this panel would re-dispatch a
                 # doomed apply before falling back.
-                cache.pop(skey, None)
+                if cache.pop(skey, None) is not None:
+                    _C_STAGE_EVICTED.inc()
                 return None
             raise
-        has_points = stage[5]
-        gm = np.unpackbits(gm, axis=1, count=b_out).astype(bool)
-        results = []
-        for gi, gkey in enumerate(gkeys):
-            live = [sid for sid in groups[gkey] if has_points[sid]]
-            if not live:
-                continue
-            spans = [_Span(cols.series_keys[sid], named[sid], None, None)
-                     for sid in live]
-            tags, aggregated = self._group_tags(spans)
-            mask = gm[gi]
-            grid_ts = (np.flatnonzero(mask).astype(np.int64) * interval
-                       + qbase)
-            results.append(QueryResult(
-                spec.metric, tags, aggregated, grid_ts,
-                gv[gi][mask].astype(np.float64)))
+        with obs_trace.span("resident.results") as sp:
+            has_points = stage[5]
+            gm = np.unpackbits(gm, axis=1, count=b_out).astype(bool)
+            results = []
+            for gi, gkey in enumerate(gkeys):
+                live = [sid for sid in groups[gkey] if has_points[sid]]
+                if not live:
+                    continue
+                spans = [_Span(cols.series_keys[sid], named[sid], None, None)
+                         for sid in live]
+                tags, aggregated = self._group_tags(spans)
+                mask = gm[gi]
+                grid_ts = (np.flatnonzero(mask).astype(np.int64) * interval
+                           + qbase)
+                results.append(QueryResult(
+                    spec.metric, tags, aggregated, grid_ts,
+                    gv[gi][mask].astype(np.float64)))
+            if sp is not None:
+                sp.tags["results"] = len(results)
         return results
 
     def _dw_sharded_stage(self, cols, start: int, end: int, qbase: int,
@@ -1140,8 +1187,9 @@ class QueryExecutor:
                           group_bys):
         """Filter + group the window's series directory on host UIDs.
 
-        Returns ({group_key_tuple: [sid]}, {sid: named_tags}); cached per
-        (window instance, metric, filter) until the directory grows.
+        Returns ({group_key_tuple: [sid]}, {sid: named_tags}, whether
+        the plan cache held them); cached per (window instance, metric,
+        filter) until the directory grows.
         ``dw`` is the SAME window object ``cols`` came from (passed by
         the caller, not re-read from self.tsdb — a swap between capture
         and here must not cache the old window's plan under the new
@@ -1151,11 +1199,11 @@ class QueryExecutor:
         cache = self._dw_plan_cache
         hit = cache.get(fkey)
         if hit is not None and hit[0] == cols.generation:
-            return hit[1], hit[2]
+            return hit[1], hit[2], True
         groups, named = self._series_groups(cols.series_keys, exact,
                                             group_bys)
         cache.put(fkey, (cols.generation, groups, named))
-        return groups, named
+        return groups, named, False
 
     # -- fused decode-aggregate path (TSST4 blocks) --------------------
 
@@ -2213,6 +2261,15 @@ def _pad64(n: int) -> int:
     fine enough to cut padded-transfer waste, coarse enough to bound
     the distinct static shapes the apply kernels compile for."""
     return max((n + 63) // 64 * 64, 64)
+
+
+def _dw_chunks(cols) -> list:
+    """Every device chunk of a resident window's columns, the sharded
+    window's shard by shard (for a span's counts)."""
+    shards = getattr(cols, "shards", None)
+    if shards is None:
+        return cols.chunks
+    return [c for sc in shards if sc is not None for c in sc.chunks]
 
 
 def _is_device_oom(e: Exception) -> bool:

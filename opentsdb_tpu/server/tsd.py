@@ -75,6 +75,11 @@ MAX_BUFFER = 1 << 22  # pipelined-burst buffer bound for the bulk path
 # clients, oversized bodies, and shed load without parsing log text.
 _M_HTTP_ERRORS = METRICS.counter("http.errors")
 _M_TELNET_ERRORS = METRICS.counter("telnet.errors")
+# The /q JSON answer: its encode on the event-loop thread (the timer
+# is observed through obs_trace.timed; registered here so that /stats
+# lists it from boot) and the bytes of the bodies it made.
+METRICS.timer("http.q.encode")
+_M_Q_BYTES = METRICS.counter("http.q.bytes")
 
 # Test-only sabotage hook (scripts/servematrix.py --bug): names a
 # deliberate serve-tier bug the staleness-oracle gate must catch.
@@ -1527,6 +1532,14 @@ class TSDServer:
             sampled = self._trace_sample_seq % sample_n == 0
         do_trace = want_trace or sampled or (slow_ms > 0
                                              and not degrade)
+        # One trace_id a request: the router's fan-out id when this is
+        # a hop (trace_parent: the hop's records on this replica then
+        # carry the SAME id as the router's assembled tree, and
+        # /api/traces correlates across processes), else minted here,
+        # once — every sub-query's tree, ring record and profiler
+        # annotation of the request shares it.
+        trace_id = ((q.get("trace_parent") or obs_trace.new_trace_id())
+                    if do_trace else None)
         # The result tag for anything less than full service ("stale",
         # "rollup-only", or both): evaluated once per request, echoed
         # per-result in JSON and as X-Tsd-Degraded so the router can
@@ -1670,13 +1683,8 @@ class TSDServer:
             # Returned with the results: reading it back off the shared
             # executor after the pool hop could pick up a CONCURRENT
             # request's label.
-            # trace_parent: the router's fan-out id — hop traces on
-            # this replica carry the SAME trace_id as the router's
-            # assembled tree, so /api/traces correlates across
-            # processes.
-            trace = (obs_trace.Trace(
-                m, trace_id=q.get("trace_parent") or None)
-                if do_trace else None)
+            trace = (obs_trace.Trace(m, trace_id=trace_id)
+                     if do_trace else None)
             rs, plan, cached, ainfo = await loop.run_in_executor(
                 self._pool,
                 functools.partial(self.executor.run_approx,
@@ -1708,7 +1716,11 @@ class TSDServer:
             result_opts.extend([os_[mi] if mi < len(os_) else ""] * len(rs))
             result_plans.extend([plan] * len(rs))
             result_cached.extend([cached] * len(rs))
-            result_traces.extend([tdict] * len(rs))
+            # One tree a sub-query, on its first result: repeated on
+            # every result, a 4,000-group answer would carry (and the
+            # event loop encode) 4,000 copies of it.
+            result_traces.extend([tdict] + [None] * (len(rs) - 1)
+                                 if rs else [])
             result_approx.extend([ajson] * len(rs))
 
         extra: dict = {}
@@ -1730,13 +1742,17 @@ class TSDServer:
             body = self._ascii_output(results).encode()
             ctype = "text/plain"
         elif "json" in q:
-            body = json.dumps(
-                self._json_output(
-                    results, result_plans, result_cached,
-                    result_traces if want_trace else None,
-                    degraded=degraded,
-                    approx=result_approx,
-                    expert=expert_label)).encode()
+            # On the event-loop thread and outside every span: a wide
+            # answer's encode holds the GIL against the query workers.
+            with obs_trace.timed("http.q.encode"):
+                body = json.dumps(
+                    self._json_output(
+                        results, result_plans, result_cached,
+                        result_traces if want_trace else None,
+                        degraded=degraded,
+                        approx=result_approx,
+                        expert=expert_label)).encode()
+            _M_Q_BYTES.inc(len(body))
             ctype = "application/json"
         else:
             t0 = time.time()
