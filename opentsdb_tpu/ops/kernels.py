@@ -437,37 +437,80 @@ def _stage_tail(series_values, series_mask, presence, *, num_buckets,
 
 @jit_plan(ExecPlan(
     name="window.chunk_fold", axis="series",
-    static_argnames=("num_series", "num_buckets", "interval", "need"),
+    static_argnames=("num_series", "num_buckets", "interval", "need",
+                     "block"),
     donate_argnums=(4, 5, 6, 7, 8)))
 def _chunk_fold(rel_ts, vals, sid, valid, count, total, m2, mn, mx,
-                lo, hi, shift, *, num_series, num_buckets, interval,
-                need):
-    """Fold ONE resident chunk into the per-(series, bucket)
-    accumulators. Compiled once per chunk shape class (chunks are
-    pow2-padded, so there are only a handful); accumulators are donated
-    so the fold is in-place. The stage driver issues these
-    back-to-back ASYNC — dispatch does not wait for the device, so K
-    chunks cost ~K host-side submissions, not K round trips.
+                visit, *, num_series, num_buckets, interval, need, block):
+    """Fold the selected blocks of ONE resident chunk into the
+    per-(series, bucket) accumulators. ``visit`` is one int32 vector,
+    ``[lo, hi, shift, n, id_0 .. id_n-1, 0 ..]``: the range, the bucket
+    shift, and the ``n`` blocks of ``block`` slots to visit (the
+    devwindow's zone-map selection, DevChunks.blocks), padded to the
+    chunk's block count. All of it is DATA, so a range never seen before
+    runs the program already compiled: a ``fori_loop`` slices one block
+    a turn and scatters it into the chunk's partial statistics, and what
+    a turn costs follows the slots it is handed, not the points in
+    range. One vector and not five arguments because every host array
+    argument is its own host-to-device transfer at dispatch (measured
+    on a v5e, PERF.md §6: 0.35 ms a fold against 1.1 ms). Compiled once
+    per chunk shape class (chunks are pow2-padded, so there are only a
+    handful); accumulators are donated so the fold is in-place. The
+    stage driver issues these back-to-back ASYNC — dispatch does not
+    wait for the device, so K chunks cost ~K host-side submissions, not
+    K round trips.
 
-    ``m2`` accumulates the exact pairwise (Chan et al.) combination:
-    the chunk's M2 is centered on the CHUNK-local segment means, then
-    corrected by the mean shift against the running accumulator —
-    numerically sound where a naive E[x^2]-E[x]^2 merge cancels
-    catastrophically (same scheme as the sharded psum fan-in,
-    parallel/sharded.py)."""
+    ``m2`` accumulates the exact pairwise (Chan et al.) combination,
+    once a chunk: the chunk's M2 is centered on the CHUNK-local segment
+    means (a second turn over the same blocks), then corrected by the
+    mean shift against the running accumulator — numerically sound
+    where a naive E[x^2]-E[x]^2 merge cancels catastrophically (same
+    scheme as the sharded psum fan-in, parallel/sharded.py)."""
+    lo, hi, shift, n_blocks = visit[0], visit[1], visit[2], visit[3]
+    block_ids = visit[4:]
     nseg = num_series * num_buckets + 1
-    ok = valid & (rel_ts >= lo) & (rel_ts <= hi)
-    bucket = jnp.clip((rel_ts - shift) // interval, 0, num_buckets - 1)
-    seg = jnp.where(ok, sid * num_buckets + bucket, nseg - 1)
-    c_cnt = jax.ops.segment_sum(ok.astype(jnp.float32), seg, nseg)
-    c_tot = None
+
+    def block_of(i):
+        r, v, s, ok = (jax.lax.dynamic_slice_in_dim(
+            c, block_ids[i] * block, block)
+            for c in (rel_ts, vals, sid, valid))
+        ok = ok & (r >= lo) & (r <= hi)
+        bucket = jnp.clip((r - shift) // interval, 0, num_buckets - 1)
+        return v, ok, jnp.where(ok, s * num_buckets + bucket, nseg - 1)
+
+    def moments(i, part):
+        v, ok, seg = block_of(i)
+        part = dict(part)
+        part["count"] = part["count"].at[seg].add(ok.astype(jnp.float32))
+        if "sum" in part:
+            part["sum"] = part["sum"].at[seg].add(jnp.where(ok, v, 0.0))
+        if "min" in part:
+            part["min"] = part["min"].at[seg].min(
+                jnp.where(ok, v, _POS_INF))
+        if "max" in part:
+            part["max"] = part["max"].at[seg].max(
+                jnp.where(ok, v, _NEG_INF))
+        return part
+
+    zeros = jnp.zeros(nseg, jnp.float32)
+    part = {"count": zeros}
     if "sum" in need or "m2" in need:
-        c_tot = jax.ops.segment_sum(jnp.where(ok, vals, 0.0), seg,
-                                    nseg)
+        part["sum"] = zeros
+    if "min" in need:
+        part["min"] = jnp.full(nseg, _POS_INF, jnp.float32)
+    if "max" in need:
+        part["max"] = jnp.full(nseg, _NEG_INF, jnp.float32)
+    part = jax.lax.fori_loop(0, n_blocks, moments, part)
+    c_cnt, c_tot = part["count"], part.get("sum")
     if "m2" in need:
         c_mean = c_tot / jnp.maximum(c_cnt, 1.0)
-        centered = jnp.where(ok, vals - c_mean[seg], 0.0)
-        c_m2 = jax.ops.segment_sum(centered * centered, seg, nseg)
+
+        def centered(i, c_m2):
+            v, ok, seg = block_of(i)
+            d = jnp.where(ok, v - c_mean[seg], 0.0)
+            return c_m2.at[seg].add(d * d)
+
+        c_m2 = jax.lax.fori_loop(0, n_blocks, centered, zeros)
         # Chan combine with the running (count, total, m2): the
         # mean-shift correction uses the PRE-update accumulator.
         a_cnt = count
@@ -482,11 +525,9 @@ def _chunk_fold(rel_ts, vals, sid, valid, count, total, m2, mn, mx,
     if c_tot is not None:
         total = total + c_tot
     if "min" in need:
-        mn = jnp.minimum(mn, jax.ops.segment_min(
-            jnp.where(ok, vals, _POS_INF), seg, nseg))
+        mn = jnp.minimum(mn, part["min"])
     if "max" in need:
-        mx = jnp.maximum(mx, jax.ops.segment_max(
-            jnp.where(ok, vals, _NEG_INF), seg, nseg))
+        mx = jnp.maximum(mx, part["max"])
     return count, total, m2, mn, mx
 
 
@@ -518,6 +559,7 @@ def _chunk_stage_finish(count, total, m2, mn, mx, *, num_series,
 
 def window_series_stage_chunks(chunks, lo, hi, shift, *, num_series,
                                num_buckets, interval, agg_down,
+                               blocks=None, block=None,
                                rate=False, counter_max=0.0,
                                reset_value=0.0, counter=False,
                                drop_resets=False):
@@ -538,6 +580,14 @@ def window_series_stage_chunks(chunks, lo, hi, shift, *, num_series,
     centered M2 + Chan mean-shift correction — see _chunk_fold).
 
     ``chunks``: iterable of (rel_ts, values, sid, valid) tuples.
+    ``blocks`` / ``block``: the devwindow's zone-map selection for
+    [lo, hi] (DevChunks.blocks / .block) — per chunk, the ids of the
+    ``block``-slot blocks whose recorded [min, max] timestamp meets the
+    range. A chunk with none is not dispatched at all, and of the
+    others only the listed blocks are visited; a block left out holds
+    nothing but slots the fold would have sent to its dump segment, so
+    the grids are the same for any order of data. Without ``blocks``
+    every chunk is folded whole, as one block.
     Returns the window_series_stage contract: (series_values,
     series_mask, filled, in_range, presence)."""
     need = _needs(agg_down)
@@ -550,11 +600,24 @@ def window_series_stage_chunks(chunks, lo, hi, shift, *, num_series,
     m2 = jnp.zeros(nseg, jnp.float32)
     mn = jnp.full(nseg, _POS_INF, jnp.float32)
     mx = jnp.full(nseg, _NEG_INF, jnp.float32)
-    for rel_ts, vals, sid, valid in chunks:
+    for i, (rel_ts, vals, sid, valid) in enumerate(chunks):
+        slots = rel_ts.shape[0]
+        if blocks is None:
+            blk, picked = slots, (0,)
+        else:
+            blk, picked = min(block, slots), blocks[i]
+        if not len(picked):
+            continue
+        # The vector's length follows the chunk's block count, whatever
+        # was picked: the shape, and so the program, follows the chunk's
+        # shape class alone.
+        visit = np.zeros(4 + slots // blk, np.int32)
+        visit[:4] = lo, hi, shift, len(picked)
+        visit[4:4 + len(picked)] = picked
         count, total, m2, mn, mx = _chunk_fold(
-            rel_ts, vals, sid, valid, count, total, m2, mn, mx,
-            lo, hi, shift, num_series=num_series,
-            num_buckets=num_buckets, interval=interval, need=need)
+            rel_ts, vals, sid, valid, count, total, m2, mn, mx, visit,
+            num_series=num_series, num_buckets=num_buckets,
+            interval=interval, need=need, block=blk)
     return _chunk_stage_finish(
         count, total, m2, mn, mx, num_series=num_series,
         num_buckets=num_buckets, interval=interval, agg_down=agg_down,

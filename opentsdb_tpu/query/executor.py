@@ -72,6 +72,12 @@ _metrics.gauge(
 _C_STAGE_HIT = _metrics.counter("devwindow.stage.hit")
 _C_STAGE_MISS = _metrics.counter("devwindow.stage.miss")
 _C_STAGE_EVICTED = _metrics.counter("devwindow.stage.evicted")
+# What the stages built were handed, in slots of the resident chunks
+# (padding included): visited = the blocks the zone maps let through to
+# window.chunk_fold, skipped = the rest. Together they are the slots
+# resident a stage built.
+_C_FOLD_VISITED = _metrics.counter("devwindow.fold.slots.visited")
+_C_FOLD_SKIPPED = _metrics.counter("devwindow.fold.slots.skipped")
 
 
 def _count_decline(reason: str) -> None:
@@ -997,6 +1003,12 @@ class QueryExecutor:
                 ssp.tags["hit"] = stage is not None
                 ssp.tags["chunks"] = len(_dw_chunks(cols))
             if stage is None:
+                picked, of, visited, resident = _dw_fold_extent(cols)
+                _C_FOLD_VISITED.inc(visited)
+                _C_FOLD_SKIPPED.inc(resident - visited)
+                if ssp is not None:
+                    ssp.tags["blocks"] = picked
+                    ssp.tags["blocks_total"] = of
                 try:
                     if sharded:
                         grids = self._dw_sharded_stage(
@@ -1015,7 +1027,9 @@ class QueryExecutor:
                         grids = kernels.window_series_stage_chunks(
                             cols.chunks, lo32, hi32, shift32,
                             num_series=S_pad, num_buckets=num_buckets,
-                            interval=interval, agg_down=dsagg, **rate_kw)
+                            interval=interval, agg_down=dsagg,
+                            blocks=cols.blocks, block=cols.block,
+                            **rate_kw)
                 except Exception as e:
                     # A near-HBM window can still OOM building the stage
                     # grids; degrade to the storage scan (the
@@ -1159,7 +1173,8 @@ class QueryExecutor:
                 np.int32(min(max(end - sc.epoch, imin), imax)),
                 np.int32(qbase - sc.epoch),
                 num_series=_pad_size(S_i), num_buckets=num_buckets,
-                interval=interval, agg_down=dsagg, **rate_kw)
+                interval=interval, agg_down=dsagg,
+                blocks=sc.blocks, block=sc.block, **rate_kw)
             parts.append((S_i, grids))
         if not parts:
             return None
@@ -2270,6 +2285,15 @@ def _dw_chunks(cols) -> list:
     if shards is None:
         return cols.chunks
     return [c for sc in shards if sc is not None for c in sc.chunks]
+
+
+def _dw_fold_extent(cols) -> tuple[int, ...]:
+    """DevChunks.fold_extent() of a resident window's columns, summed
+    over the sharded window's shards: (blocks picked, blocks in all,
+    slots picked, slots in all)."""
+    shards = getattr(cols, "shards", None)
+    parts = [cols] if shards is None else filter(None, shards)
+    return tuple(map(sum, zip(*(p.fold_extent() for p in parts))))
 
 
 def _is_device_oom(e: Exception) -> bool:

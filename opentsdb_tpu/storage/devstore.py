@@ -20,6 +20,11 @@ Design:
   chunks upload in ``staging_points``-sized batches, padded to powers of
   two so jit shapes repeat. One upload per ~million points amortizes the
   slow host link at ingest time, once, instead of per query.
+- **Zone maps.** A query's device cost follows the slots its fold is
+  handed, not the points in its range. Each chunk records, per block of
+  ``ZONE_BLOCK`` slots, the least and greatest timestamp of the block's
+  valid slots; ``chunk_columns`` lists the blocks a range can hit and
+  the chunked stage visits only those (see ``DevChunks``).
 - **Exactness, not cache-maybe.** The window only serves a query when its
   answer is guaranteed byte-identical to the storage scan path:
   - per-series timestamps must be strictly monotone across appends (the
@@ -58,6 +63,13 @@ def _pad_pow2(n: int, lo: int = 1024) -> int:
     return size
 
 
+# Slots per zone-map block (a power of two, so it divides every padded
+# chunk at least as long; a shorter chunk is one block). One constant:
+# the map is built with it at upload and DevChunks.block hands it to
+# the fold, which slices by it.
+ZONE_BLOCK = 1 << 16
+
+
 class DevColumns(NamedTuple):
     """One metric's resident window, ready for the fused kernels."""
     rel_ts: object          # [N] int32 device, seconds since ``epoch``
@@ -77,12 +89,57 @@ class DevChunks(NamedTuple):
     window_series_stage_chunks) folds these into [S, B] grids with
     per-chunk transients, so a window can approach the chip's whole
     HBM: the concat view costs a second full copy of the columns plus
-    N-sized kernel transients, which caps it near half the HBM."""
+    N-sized kernel transients, which caps it near half the HBM.
+
+    ``blocks`` is the zone-map selection for the range the caller asked
+    about. Every chunk keeps, for each block of ``block`` slots, the
+    least and greatest timestamp of the block's valid slots (a min/max
+    zone map, recorded from the host arrays at upload; a block that is
+    all padding has no entry). ``blocks[i]`` lists the blocks of
+    ``chunks[i]`` whose [min, max] meets [start, end], ascending; it is
+    empty for a chunk the range cannot hit. The selection is exact for
+    any order of data: a block left out holds only slots that are
+    padding or out of range, which the fold would have sent to its dump
+    segment. It saves work wherever data is clustered in time (the
+    refill's [metric][hour][series] order, live ingest's time-major
+    slices). ``chunks`` is still every chunk, whole, for a caller that
+    wants that."""
     chunks: list            # [(rel_ts, values, sid, valid) device arrays]
     epoch: int
     series_keys: list
     generation: int
     version: int
+    blocks: list            # per chunk: int32 ids of the blocks in range
+    block: int              # slots a block id stands for (ZONE_BLOCK)
+
+    def fold_extent(self) -> tuple[int, int, int, int]:
+        """What the selection hands the fold: (blocks picked, blocks in
+        all, slots picked, slots in all). Slots count the chunks'
+        padding too: a skipped slot is skipped whatever it held."""
+        picked = of = visited = resident = 0
+        for chunk, ids in zip(self.chunks, self.blocks):
+            slots = int(chunk[0].shape[0])
+            blk = min(self.block, slots)
+            picked += len(ids)
+            of += slots // blk
+            visited += len(ids) * blk
+            resident += slots
+        return picked, of, visited, resident
+
+
+def _zone_map(ts: np.ndarray, pad: int) -> tuple[np.ndarray, np.ndarray]:
+    """Least and greatest of ``ts`` (a chunk's valid slots, which are
+    its first len(ts)) over blocks of min(ZONE_BLOCK, pad) slots. One
+    entry a block that holds a valid slot: the all-padding blocks at a
+    chunk's end have none, so no range selects them."""
+    starts = np.arange(0, len(ts), min(ZONE_BLOCK, pad))
+    return np.minimum.reduceat(ts, starts), np.maximum.reduceat(ts, starts)
+
+
+def _blocks_in_range(zmin: np.ndarray, zmax: np.ndarray, start: int,
+                     end: int) -> np.ndarray:
+    """Ids of the blocks whose [min, max] meets [start, end]."""
+    return np.flatnonzero((zmax >= start) & (zmin <= end)).astype(np.int32)
 
 
 class _MetricWindow:
@@ -98,6 +155,7 @@ class _MetricWindow:
         self.last_ts: list[int] = []
         self.epoch: int | None = None
         self.chunks: list[dict] = []      # ts/vals/sid device + n/max_ts
+        #                                   + the zone map zmin/zmax
         self.staged_ts: list[np.ndarray] = []
         self.staged_vals: list[np.ndarray] = []
         self.staged_sid: list[np.ndarray] = []
@@ -373,13 +431,15 @@ class DeviceWindow:
             sid = np.pad(sid, (0, pad - n))
         valid = np.arange(pad) < n
         dev = self.device
+        zmin, zmax = _zone_map(ts, pad)
         chunk = {
             "ts": jax.device_put(rel, dev),
             "vals": jax.device_put(vals, dev),
             "sid": jax.device_put(sid, dev),
             "valid": jax.device_put(valid, dev),
             "n": n, "pad": pad, "seq": seq,
-            "min_ts": int(ts.min()), "max_ts": int(ts.max()),
+            "min_ts": int(zmin.min()), "max_ts": int(zmax.max()),
+            "zmin": zmin, "zmax": zmax,
         }
         with self._lock:
             if mw.dirty:  # marked dirty while we were copying
@@ -646,7 +706,13 @@ class DeviceWindow:
         """Like columns(), but returns the raw chunk list without
         building (or caching) the concatenated view — the chunked query
         stage folds it without a second full copy of the columns. Same
-        availability contract: None means scan-path fallback."""
+        availability contract: None means scan-path fallback.
+
+        Beside every chunk goes the zone-map selection for [start, end]
+        (DevChunks.blocks): the blocks whose recorded [min, max]
+        timestamp meets the range. It decides what the fold visits,
+        never what is available, and never leaves out a slot in range
+        whatever order the data came in."""
         with self._ready_window(metric_uid, start) as mw:
             if mw is None:
                 return None
@@ -655,7 +721,10 @@ class DeviceWindow:
                 chunks=[(c["ts"], c["vals"], c["sid"], c["valid"])
                         for c in mw.chunks],
                 epoch=mw.epoch, series_keys=list(mw.keys),
-                generation=mw.generation, version=mw.version)
+                generation=mw.generation, version=mw.version,
+                blocks=[_blocks_in_range(c["zmin"], c["zmax"], start, end)
+                        for c in mw.chunks],
+                block=ZONE_BLOCK)
 
     # -- observability -------------------------------------------------
 

@@ -320,8 +320,9 @@ class TestKernelsNamedByTheirPlan:
         fold = kernels._chunk_fold.lower(
             jnp.zeros(n, jnp.int32), jnp.zeros(n, jnp.float32),
             jnp.zeros(n, jnp.int32), jnp.ones(n, bool), *acc,
-            np.int32(0), np.int32(100), np.int32(0), num_series=s,
-            num_buckets=b, interval=10, need=kernels._needs("max"))
+            np.array([0, 100, 0, 1, 0], np.int32), num_series=s,
+            num_buckets=b, interval=10, need=kernels._needs("max"),
+            block=n)
         text = fold.as_text(debug_info=True)
         assert "/window.chunk_fold/" in text
         assert "jit__chunk_fold" in text
