@@ -989,7 +989,7 @@ class TSDB:
     def scan_series(self, start_key: bytes, stop_key: bytes,
                     key_regexp: bytes | None = None,
                     batch_cells: int = 1 << 18,
-                    series_hint=None):
+                    series_hint=None, counts: dict | None = None):
         """Whole-range columnar scan regrouped BY SERIES in vectorized
         passes: returns (series_keys, per_series Columns dict) with one
         global (series, timestamp) lexsort + one vectorized dedup pass
@@ -1000,8 +1000,10 @@ class TSDB:
         decode itself; here both collapse into a handful of
         whole-range numpy ops. Duplicate (series, ts) points collapse
         when value-equal and raise IllegalDataError otherwise —
-        sort_dedup's rule (reference complexCompact :600-679)."""
+        sort_dedup's rule (reference complexCompact :600-679).
+        ``counts``, when given, has the rows read added under "rows"."""
         from opentsdb_tpu.core.errors import IllegalDataError
+        rows = 0
         quals: list[bytes] = []
         vals: list[bytes] = []
         bases: list[int] = []
@@ -1021,6 +1023,7 @@ class TSDB:
                 self.table, start_key, stop_key,
                 family=FAMILY, key_regexp=key_regexp,
                 series_hint=series_hint):
+            rows += 1
             base = codec.key_base_time(key)
             skey = codec.series_key(key)
             si = skey_index.get(skey)
@@ -1038,6 +1041,8 @@ class TSDB:
                 decode_batch()
         if quals:
             decode_batch()
+        if counts is not None:
+            counts["rows"] = counts.get("rows", 0) + rows
         if not parts:
             return skeys, {}
         ts = np.concatenate([p[0] for p in parts])
@@ -1045,7 +1050,16 @@ class TSDB:
         i = np.concatenate([p[2] for p in parts])
         isf = np.concatenate([p[3] for p in parts])
         sid = np.concatenate([p[4] for p in parts])
-        order = np.lexsort((ts, sid))
+        # Rows come in key order, so a series' cells already arrive
+        # in time order and one stable sort by series regroups them;
+        # where some series' do not (overlapping cells of several
+        # tiers), the two-key sort gives the order. Either way the
+        # result is sorted by (series, timestamp), ties in scan order.
+        order = np.argsort(sid, kind="stable")
+        if len(ts) > 1:
+            st, ss = ts[order], sid[order]
+            if ((ss[1:] == ss[:-1]) & (st[1:] < st[:-1])).any():
+                order = np.lexsort((ts, sid))
         ts, f, i, isf, sid = (ts[order], f[order], i[order], isf[order],
                               sid[order])
         if len(ts) > 1:

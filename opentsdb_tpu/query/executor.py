@@ -80,6 +80,13 @@ _C_FOLD_VISITED = _metrics.counter("devwindow.fold.slots.visited")
 _C_FOLD_SKIPPED = _metrics.counter("devwindow.fold.slots.skipped")
 
 
+# What the raw plan read from storage and handed to its kernels: rows
+# decoded by the scans of raw sub-queries (a fragment-cache hit decodes
+# none) and the points in range that went on to the aggregate stage.
+_C_RAW_ROWS = _metrics.counter("query.raw.rows")
+_C_RAW_POINTS = _metrics.counter("query.raw.points")
+
+
 def _count_decline(reason: str) -> None:
     _metrics.counter("compress.fused.decline", {"reason": reason}).inc()
 
@@ -184,7 +191,7 @@ class QueryExecutor:
         self._frag_cache = _shared_frag_cache(
             tsdb.store,
             int(getattr(cfg, "qcache_fragments", 1024)),
-            int(getattr(cfg, "qcache_points", 1 << 24)))
+            int(getattr(cfg, "qcache_points", 1 << 25)))
         # Candidate-series hint per (metric, filter): identity hashes
         # from the sketch directory, revalidated on the metric's
         # directory growth; cost-bounded in total cached hashes (an
@@ -274,7 +281,9 @@ class QueryExecutor:
         """Scan matching rows into per-series columnar spans, grouped by
         the distinct combinations of group-by tag values. ``info``, when
         given, receives {"cached": bool} — True iff every fragment of
-        the range served from the warm cache."""
+        the range served from the warm cache — and what was read:
+        "rows" (storage rows decoded; a cache hit decodes none) and
+        "points" (points in range, handed on to the group stage)."""
         metric_uid = self.tsdb.metrics.get_id(spec.metric)
         exact, group_bys = self._tag_filters(spec.tags)
         group_by_keys = sorted(k for k, _ in group_bys)
@@ -283,17 +292,32 @@ class QueryExecutor:
         per_series = self._scan_selector(metric_uid, exact, group_bys,
                                          regexp, start, end, info)
         groups: dict[tuple, list[_Span]] = {}
-        for skey, cat in per_series.items():
-            m = (cat.timestamps >= start) & (cat.timestamps <= end)
-            if not m.any():
-                continue
-            tag_uids = codec.series_tag_uids(skey)
-            named = {
-                self.tsdb.tagk.get_name(k): self.tsdb.tagv.get_name(v)
-                for k, v in tag_uids.items()}
-            gkey = tuple(tag_uids.get(k, b"") for k in group_by_keys)
-            groups.setdefault(gkey, []).append(_Span(
-                skey, named, cat.timestamps[m], cat.values[m]))
+        points = 0
+        # What is left of a scan after its chunk.decode children: every
+        # series cut to the exact bounds and filed under its group.
+        with obs_trace.span("scan.group") as sp:
+            for skey, cat in per_series.items():
+                # A series' timestamps ascend (scan_series sorts by
+                # series and time; chunks stitch in time order), so the
+                # bounds are two binary searches and the cut a view: no
+                # per-series mask or copy, whose every numpy call could
+                # hand the GIL to another busy thread.
+                lo = int(np.searchsorted(cat.timestamps, start, "left"))
+                hi = int(np.searchsorted(cat.timestamps, end, "right"))
+                if hi <= lo:
+                    continue
+                tag_uids = codec.series_tag_uids(skey)
+                named = {
+                    self.tsdb.tagk.get_name(k): self.tsdb.tagv.get_name(v)
+                    for k, v in tag_uids.items()}
+                gkey = tuple(tag_uids.get(k, b"") for k in group_by_keys)
+                points += hi - lo
+                groups.setdefault(gkey, []).append(_Span(
+                    skey, named, cat.timestamps[lo:hi], cat.values[lo:hi]))
+            if sp is not None:
+                sp.tags.update(series=len(per_series), groups=len(groups))
+        if info is not None:
+            info["points"] = points
         return groups
 
     # -- fragment cache (the query fast path) --------------------------
@@ -333,14 +357,14 @@ class QueryExecutor:
         return hint
 
     def _scan_chunk(self, metric_uid: bytes, regexp, hint,
-                    c_lo: int, c_hi: int) -> dict:
+                    c_lo: int, c_hi: int, info: dict | None) -> dict:
         """Scan + decode one [c_lo, c_hi) base-time chunk into a
         per-series Columns dict (the cacheable fragment unit)."""
         start_key = metric_uid + _u32(c_lo)
         stop_key = metric_uid + _u32(min(c_hi, 0xFFFFFFFF))
         return self.tsdb.scan_series(start_key, stop_key,
                                      key_regexp=regexp,
-                                     series_hint=hint)[1]
+                                     series_hint=hint, counts=info)[1]
 
     def _scan_selector(self, metric_uid: bytes, exact, group_bys,
                        regexp, start: int, end: int,
@@ -380,7 +404,7 @@ class QueryExecutor:
             with obs_trace.span("chunk.decode", outcome="unchunked"):
                 return tsdb.scan_series(start_key, stop_key,
                                         key_regexp=regexp,
-                                        series_hint=hint)[1]
+                                        series_hint=hint, counts=info)[1]
 
         chunk_s = int(getattr(cfg, "qcache_chunk_s", 0) or 0)
         chunk_s -= chunk_s % MAX_TIMESPAN
@@ -429,7 +453,7 @@ class QueryExecutor:
                 with obs_trace.span("chunk.decode", outcome="bypass",
                                     base=int(c)):
                     frag = self._scan_chunk(metric_uid, regexp, hint,
-                                            c, c + chunk_s)
+                                            c, c + chunk_s, info)
             else:
                 ent = self._frag_cache.get(key)
                 if ent is not None and all(
@@ -445,7 +469,7 @@ class QueryExecutor:
                     with obs_trace.span("chunk.decode", outcome="miss",
                                         base=int(c)):
                         frag = self._scan_chunk(metric_uid, regexp,
-                                                hint, c, c + chunk_s)
+                                                hint, c, c + chunk_s, info)
                     cost = sum(len(cols.timestamps)
                                for cols in frag.values())
                     self._frag_cache.put(key, (seqs, frag),
@@ -464,16 +488,23 @@ class QueryExecutor:
             t["qcache_hit"] = t.get("qcache_hit", 0) + n_hit
             t["qcache_miss"] = t.get("qcache_miss", 0) + n_miss
             t["qcache_bypass"] = t.get("qcache_bypass", 0) + n_byp
-        out: dict[bytes, codec.Columns] = {}
-        for skey, lst in parts.items():
-            if len(lst) == 1:
-                out[skey] = lst[0]
-            else:
-                out[skey] = codec.Columns(
-                    np.concatenate([c.timestamps for c in lst]),
-                    np.concatenate([c.values for c in lst]),
-                    np.concatenate([c.int_values for c in lst]),
-                    np.concatenate([c.is_float for c in lst]))
+        # Stitch the series that span several chunks with ONE
+        # concatenation a column and hand each its slice: a wide
+        # selector has thousands of series, and a concatenation a
+        # series and column is as many chances to lose the GIL to
+        # another busy thread for a switch interval.
+        out: dict[bytes, codec.Columns] = {
+            skey: lst[0] for skey, lst in parts.items()}
+        flat = [c for lst in parts.values() if len(lst) > 1 for c in lst]
+        if flat:
+            whole = codec.columns_concat(flat)
+            at = 0
+            for skey, lst in parts.items():
+                if len(lst) > 1:
+                    n = sum(len(c.timestamps) for c in lst)
+                    out[skey] = codec.Columns(
+                        *(col[at:at + n] for col in whole))
+                    at += n
         return out
 
     @staticmethod
@@ -795,7 +826,11 @@ class QueryExecutor:
         with obs_trace.span("scan") as sp:
             groups = self._find_spans(spec, start, end, info)
             if sp is not None:
-                sp.tags["cached"] = bool(info.get("cached"))
+                sp.tags.update(cached=bool(info.get("cached")),
+                               rows=info.get("rows", 0),
+                               points=info["points"])
+        _C_RAW_ROWS.inc(info.get("rows", 0))
+        _C_RAW_POINTS.inc(info["points"])
         self.scan_latency.add((_time.time() - t0) * 1000)
         with obs_trace.span("aggregate"):
             results = self._execute_groups(spec, groups, start, end)
@@ -837,25 +872,23 @@ class QueryExecutor:
         # Wide group-bys on the TPU backend batch into ONE kernel call
         # (two segment reductions for all groups — or the grouped radix
         # select for percentiles) instead of G calls.
+        # The fused downsample kernels' callers open the children of
+        # the enclosing "aggregate" span (README, "Observability"):
+        # aggregate.pack / .dispatch / .wait / .fetch, then .results.
         if (not use_cpu and len(gkeys) > 1 and spec.downsample
                 and agg.kind in ("moment", "percentile")):
             per_group = self._run_tpu_multigroup(
                 spec, [groups[k] for k in gkeys], start, end)
+        elif use_cpu:
+            per_group = [self._run_cpu(spec, groups[k], start)
+                         for k in gkeys]
         else:
-            per_group = None
-        results = []
-        for gi, gkey in enumerate(gkeys):
-            spans = groups[gkey]
-            tags, aggregated = self._group_tags(spans)
-            if per_group is not None:
-                ts, vals = per_group[gi]
-            elif use_cpu:
-                ts, vals = self._run_cpu(spec, spans, start)
-            else:
-                ts, vals = self._run_tpu(spec, spans, start, end)
-            results.append(QueryResult(
-                spec.metric, tags, aggregated, ts, vals))
-        return results
+            per_group = [self._run_tpu(spec, groups[k], start, end)
+                         for k in gkeys]
+        with obs_trace.span("aggregate.results", results=len(gkeys)):
+            return [QueryResult(spec.metric,
+                                *self._group_tags(groups[gkey]), ts, vals)
+                    for gkey, (ts, vals) in zip(gkeys, per_group)]
 
     # -- device-resident window path ----------------------------------
 
@@ -911,6 +944,11 @@ class QueryExecutor:
                 sp.tags["points"] = sum(int(c[0].shape[0])
                                         for c in chunks)
         if cols is None:
+            # On planner.pick, the span open around this call: why the
+            # window declined a request of a kind it serves.
+            sp = obs_trace.current_span()
+            if sp is not None:
+                sp.tags["miss"] = dw.last_miss()
             return None
         with obs_trace.span("resident.groups") as gsp:
             groups, named, plan_hit = self._devwindow_groups(
@@ -1709,29 +1747,54 @@ class QueryExecutor:
                 spec, spans, qbase, interval, dsagg, num_buckets)
             if sharded is not None:
                 return sharded
-        rel, vals, sid, valid = self._flatten_spans(spans, qbase)
-        out = kernels.downsample_group(
-            rel, vals, sid, valid, num_series=_pad_size(len(spans)),
-            num_buckets=num_buckets, interval=interval,
-            agg_down=dsagg,
-            agg_group=spec.aggregator if agg.kind == "moment" else "count",
-            **self._rate_kw(spec))
-        gmask = np.asarray(out["group_mask"])
-        if agg.kind == "percentile":
-            # series_values/series_mask are the post-rate per-bucket
-            # signal when spec.rate; rates step-hold, plain values lerp.
-            fill = kernels.step_fill if spec.rate else kernels.gap_fill
-            filled, in_range = fill(
-                out["series_values"], out["series_mask"],
-                int(num_buckets))
-            vals_g = kernels.masked_quantile_axis0(
-                filled, in_range, np.array([agg.quantile], np.float32))[0]
-            values = np.asarray(vals_g)[gmask]
-        else:
-            values = np.asarray(out["group_values"])[gmask]
-        # Epoch-aligned bucket-start timestamps (see module docstring).
-        grid_ts = np.flatnonzero(gmask).astype(np.int64) * interval + qbase
-        return grid_ts, values.astype(np.float64)
+        with obs_trace.span("aggregate.pack") as sp:
+            rel, vals, sid, valid = self._flatten_spans(spans, qbase,
+                                                        pad=True)
+            if sp is not None:
+                sp.tags.update(series=len(spans), slots=len(rel))
+        with obs_trace.span("aggregate.dispatch"):
+            out = kernels.downsample_group(
+                rel, vals, sid, valid, num_series=_pad_size(len(spans)),
+                num_buckets=num_buckets, interval=interval,
+                agg_down=dsagg,
+                agg_group=(spec.aggregator if agg.kind == "moment"
+                           else "count"),
+                **self._rate_kw(spec))
+            gmask, values = out["group_mask"], out["group_values"]
+            if agg.kind == "percentile":
+                # series_values/series_mask are the post-rate per-bucket
+                # signal when spec.rate; rates step-hold, plain values
+                # lerp.
+                fill = kernels.step_fill if spec.rate else kernels.gap_fill
+                filled, in_range = fill(
+                    out["series_values"], out["series_mask"],
+                    int(num_buckets))
+                values = kernels.masked_quantile_axis0(
+                    filled, in_range,
+                    np.array([agg.quantile], np.float32))[0]
+        gmask, values = self._aggregate_fetch(gmask, values)
+        with obs_trace.span("aggregate.results"):
+            # Epoch-aligned bucket-start timestamps (module docstring).
+            grid_ts = (np.flatnonzero(gmask).astype(np.int64) * interval
+                       + qbase)
+            return grid_ts, values[gmask].astype(np.float64)
+
+    @staticmethod
+    def _aggregate_fetch(gmask, values):
+        """The device's answer brought to the host, as the two spans
+        after aggregate.dispatch. The wait is issued ONLY in a traced
+        request: the kernels above are dispatches (JAX returns before
+        the device finishes), so without it the h2d copy and the
+        device's time would all land in the fetch. Untraced the path
+        makes no such call."""
+        if obs_trace.current_span() is not None:
+            with obs_trace.span("aggregate.wait"):
+                jax.block_until_ready((gmask, values))
+        with obs_trace.span("aggregate.fetch") as sp:
+            gmask, values = jax.device_get((gmask, values))
+            if sp is not None:
+                sp.tags["bytes"] = int(gmask.nbytes + values.nbytes)
+        return gmask, values
 
     def _tpu_downsample_sharded(self, spec: QuerySpec, spans: list[_Span],
                                 qbase: int, interval: int, dsagg: str,
@@ -1800,16 +1863,31 @@ class QueryExecutor:
         return grid_ts, np.asarray(gv)[gm].astype(np.float64)
 
     @staticmethod
-    def _flatten_spans(spans: list[_Span], qbase: int):
-        """Spans -> one flat (rel_ts, vals, sid, valid) point stream."""
+    def _flatten_spans(spans: list[_Span], qbase: int, pad: bool = False):
+        """Spans -> one flat (rel_ts, vals, sid, valid) point stream.
+        Whole-array operations, not a loop of per-series copies: every
+        numpy call may hand the GIL to another busy thread for a whole
+        switch interval, and a wide request has thousands of series.
+
+        ``pad`` appends invalid slots up to the quarter-octave ladder
+        (``pad_fine``), for the fused downsample kernels: the stream's
+        length is a static shape of theirs, and a window that starts
+        one second later holds one point a series more or less, which
+        unpadded is a new program for every such length (a 12 h window
+        of 10 s data: 4,320 or 4,321 points a series)."""
         ts = np.concatenate([sp.timestamps for sp in spans])
-        vals = np.concatenate(
-            [sp.values for sp in spans]).astype(np.float32)
-        sid = np.concatenate([
-            np.full(len(sp.timestamps), i, np.int32)
-            for i, sp in enumerate(spans)])
-        rel = (ts - qbase).astype(np.int32)
-        return rel, vals, sid, np.ones(len(rel), bool)
+        n = len(ts)
+        size = _pad_fine(n) if pad else n
+        rel = np.zeros(size, np.int32)
+        rel[:n] = ts - qbase
+        vals = np.zeros(size, np.float32)
+        vals[:n] = np.concatenate([sp.values for sp in spans])
+        sid = np.zeros(size, np.int32)
+        sid[:n] = np.repeat(
+            np.arange(len(spans), dtype=np.int32),
+            np.fromiter((len(sp.timestamps) for sp in spans), np.int64,
+                        len(spans)))
+        return rel, vals, sid, np.arange(size) < n
 
     def _run_tpu_multigroup(self, spec: QuerySpec,
                             span_groups: list[list[_Span]],
@@ -1839,38 +1917,46 @@ class QueryExecutor:
                 spec, all_spans, group_of_sid, G, qbase, interval, dsagg,
                 num_buckets, D)
         else:
-            rel, vals, sid, valid = self._flatten_spans(all_spans, qbase)
-            # Shapes padded to power-of-two buckets (see
-            # _tpu_downsample_group). Padded series are assigned group
-            # G-1 (possibly a REAL group when the count is already a
-            # power of two) — safe solely because padded series carry no
-            # points, so they contribute nothing wherever they land.
-            S = _pad_size(len(all_spans))
-            gmap = np.zeros(S, np.int32)
-            gmap[:len(group_of_sid)] = group_of_sid
-            gmap[len(group_of_sid):] = G - 1
-            if agg.kind == "percentile":
-                out = kernels.downsample_multigroup_quantile(
-                    rel, vals, sid, valid, gmap,
-                    np.array([agg.quantile], np.float32),
-                    num_series=S, num_groups=G, num_buckets=num_buckets,
-                    interval=interval, agg_down=dsagg,
-                    **self._rate_kw(spec))
-            else:
-                out = kernels.downsample_multigroup(
-                    rel, vals, sid, valid, gmap,
-                    num_series=S, num_groups=G,
-                    num_buckets=num_buckets, interval=interval,
-                    agg_down=dsagg, agg_group=spec.aggregator,
-                    **self._rate_kw(spec))
-            gv = np.asarray(out["group_values"])
-            gm = np.asarray(out["group_mask"])
-        results = []
-        for gi in range(len(span_groups)):
-            mask = gm[gi]
-            grid_ts = (np.flatnonzero(mask).astype(np.int64) * interval
-                       + qbase)
-            results.append((grid_ts, gv[gi][mask].astype(np.float64)))
+            with obs_trace.span("aggregate.pack") as sp:
+                rel, vals, sid, valid = self._flatten_spans(
+                    all_spans, qbase, pad=True)
+                # Shapes padded to power-of-two buckets (see
+                # _tpu_downsample_group). Padded series are assigned
+                # group G-1 (possibly a REAL group when the count is
+                # already a power of two) — safe solely because padded
+                # series carry no points, so they contribute nothing
+                # wherever they land.
+                S = _pad_size(len(all_spans))
+                gmap = np.zeros(S, np.int32)
+                gmap[:len(group_of_sid)] = group_of_sid
+                gmap[len(group_of_sid):] = G - 1
+                if sp is not None:
+                    sp.tags.update(series=len(all_spans), slots=len(rel))
+            with obs_trace.span("aggregate.dispatch"):
+                if agg.kind == "percentile":
+                    out = kernels.downsample_multigroup_quantile(
+                        rel, vals, sid, valid, gmap,
+                        np.array([agg.quantile], np.float32),
+                        num_series=S, num_groups=G,
+                        num_buckets=num_buckets,
+                        interval=interval, agg_down=dsagg,
+                        **self._rate_kw(spec))
+                else:
+                    out = kernels.downsample_multigroup(
+                        rel, vals, sid, valid, gmap,
+                        num_series=S, num_groups=G,
+                        num_buckets=num_buckets, interval=interval,
+                        agg_down=dsagg, agg_group=spec.aggregator,
+                        **self._rate_kw(spec))
+            gm, gv = self._aggregate_fetch(out["group_mask"],
+                                           out["group_values"])
+        with obs_trace.span("aggregate.results"):
+            results = []
+            for gi in range(len(span_groups)):
+                mask = gm[gi]
+                grid_ts = (np.flatnonzero(mask).astype(np.int64) * interval
+                           + qbase)
+                results.append((grid_ts, gv[gi][mask].astype(np.float64)))
         return results
 
     def _multigroup_sharded(self, spec: QuerySpec, all_spans: list[_Span],

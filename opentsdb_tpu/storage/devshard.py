@@ -114,6 +114,8 @@ class ShardedDeviceWindow:
         self.dirty_fallbacks = 0
         self.window_hits = 0
         self.window_misses = 0
+        self.horizon_misses = 0
+        self._why = threading.local()   # DeviceWindow.last_miss's twin
 
     def _build_shards(self, n_shards: int, devices) -> list[DeviceWindow]:
         per = max(self.max_points // max(n_shards, 1), 1)
@@ -182,20 +184,24 @@ class ShardedDeviceWindow:
         Snapshot-consistent under reshard: the shard list is captured
         once, so a concurrent swap leaves this query on the complete
         pre-swap set, never a mix."""
+        self._why.reason = None
         with self._lock:
             if metric_uid in self._dirty_metrics:
                 self.dirty_fallbacks += 1
+                self._why.reason = "dirty"
                 return None
             shards = list(self._shards)
             gen = self.generation
             owners = sorted(self._metric_shards.get(metric_uid, ()))
         if not owners:
             self.window_misses += 1
+            self._why.reason = "absent"
             return None
         per = [None] * len(shards)
         for i in owners:
             if i >= len(shards):     # mapping raced a shrink; decline
                 self.window_misses += 1
+                self._why.reason = "absent"
                 return None
             cols = shards[i].chunk_columns(metric_uid, start, end)
             if cols is None:
@@ -203,6 +209,8 @@ class ShardedDeviceWindow:
                 # slow upload): a partial union would be WRONG, so the
                 # whole window falls back to the scan path.
                 self.window_misses += 1
+                why = self._why.reason = shards[i].last_miss()
+                self.horizon_misses += why == "horizon"
                 return None
             per[i] = cols
         starts, keys = [], []
@@ -370,6 +378,11 @@ class ShardedDeviceWindow:
             return [None if s.device is None else int(s.device.id)
                     for s in self._shards]
 
+    def last_miss(self) -> str | None:
+        """Why this thread's last chunk_columns() declined (the owning
+        shard's reason: see DeviceWindow.last_miss)."""
+        return getattr(self._why, "reason", None)
+
     def collect_stats(self, collector) -> None:
         with self._lock:
             shards = list(self._shards)
@@ -393,8 +406,17 @@ class ShardedDeviceWindow:
             collector.record(name, value)
         collector.record("devwindow.hits", self.window_hits)
         collector.record("devwindow.misses", self.window_misses)
+        collector.record("devwindow.misses.horizon", self.horizon_misses)
         collector.record("devwindow.dirty_fallbacks",
                          self.dirty_fallbacks)
+        collector.record("devwindow.points.budget", self.max_points)
+        # A shard evicts alone, so the fleet's horizons are the extremes
+        # over the shards that hold data.
+        spans = [h for h in (s.horizons() for s in shards) if h]
+        collector.record("devwindow.horizon.min",
+                         min((h[0] for h in spans), default=0))
+        collector.record("devwindow.horizon.max",
+                         max((h[1] for h in spans), default=0))
         collector.record("mesh.resident.points",
                          agg["devwindow.points.resident"])
         collector.record("mesh.resident.shards", len(shards))

@@ -31,8 +31,13 @@ Design:
     overwhelmingly common collector pattern); an out-of-order or rewritten
     timestamp marks the metric dirty and queries fall back to the scan
     path (``dirty_fallbacks`` counts them);
-  - evicting old chunks advances ``complete_from``; queries reaching
-    before it fall back.
+  - evicting old chunks advances ``complete_from`` (the metric's
+    *horizon*); queries reaching before it fall back. The budget is
+    the chip's, so the victim is always the chunk holding the OLDEST
+    data fleet-wide (least ``max_ts``), whatever order it arrived in:
+    live ingest is time-major, so there arrival order is age, but a
+    boot refill scans metric by metric, and eviction by arrival would
+    leave the first metrics scanned with nothing and the last whole.
   - deletes/fsck rewrites call ``invalidate``.
 - **Sizing.** ~12 B/point device-side: the 1B-point north-star workload is
   ~12 GB — within one v5e chip's 16 GB HBM, which is exactly the design
@@ -224,9 +229,10 @@ class DeviceWindow:
         # global queue couples query latency to unrelated ingest bursts).
         self._cond = threading.Condition(self._lock)
         # Global residency accounting: max_points caps the SUM across
-        # metrics (the HBM budget is per chip, not per metric); chunks
-        # carry an upload sequence number so eviction picks the oldest
-        # chunk fleet-wide.
+        # metrics (the HBM budget is per chip, not per metric). Chunks
+        # carry an upload sequence number, which orders a metric's own
+        # chunks; eviction picks the chunk with the oldest DATA
+        # fleet-wide (_evict_over_budget).
         self._total_points = 0
         self._seq = 0
         # Liveness signal: bumps on EVERY upload completion (success or
@@ -243,6 +249,11 @@ class DeviceWindow:
         self.upload_stalls = 0
         self.window_hits = 0
         self.window_misses = 0
+        self.horizon_misses = 0
+        # Why this thread's last columns() / chunk_columns() declined
+        # (last_miss()): a query thread reads its own verdict, never a
+        # neighbour's.
+        self._why = threading.local()
 
     # -- ingest side ---------------------------------------------------
 
@@ -458,26 +469,37 @@ class DeviceWindow:
             self._total_points += n
             mw.concat = None
             mw.version += 1
-            # Evict the globally-oldest chunks past the (per-chip, NOT
-            # per-metric) budget. complete_from of the owning metric
-            # advances past everything the evicted chunk could cover.
-            while self._total_points > self.max_points:
-                victim = min(
-                    (m for m in self._metrics.values() if m.chunks),
-                    key=lambda m: m.chunks[0]["seq"], default=None)
-                if victim is None or (victim is mw
-                                      and len(mw.chunks) == 1):
-                    break  # never evict the chunk just added
-                old = victim.chunks.pop(0)
-                victim.device_points -= old["n"]
-                self._total_points -= old["n"]
-                self.evicted_points += old["n"]
-                victim.concat = None
-                victim.version += 1
-                nxt = old["max_ts"] + 1
-                if (victim.complete_from is None
-                        or nxt > victim.complete_from):
-                    victim.complete_from = nxt
+            self._evict_over_budget(mw)
+
+    def _evict_over_budget(self, mw: _MetricWindow) -> None:
+        """Drop chunks until the (per-chip, NOT per-metric) budget
+        holds; caller holds _lock and has just given ``mw`` a chunk.
+
+        The victim is the metric whose oldest chunk holds the oldest
+        data (least ``max_ts``; the upload sequence breaks ties, so
+        time-major live ingest evicts in the order it always did). Its
+        ``complete_from`` advances past everything the chunk could
+        cover. By the data's age and not by arrival, so that a boot
+        refill, which scans metric by metric, leaves what live ingest
+        of the same points would have left: the newest chunks of every
+        metric, their horizons within one chunk of each other."""
+        while self._total_points > self.max_points:
+            victim = min(
+                (m for m in self._metrics.values() if m.chunks),
+                key=lambda m: (m.chunks[0]["max_ts"], m.chunks[0]["seq"]),
+                default=None)
+            if victim is None or (victim is mw and len(mw.chunks) == 1):
+                break  # never evict the chunk just added
+            old = victim.chunks.pop(0)
+            victim.device_points -= old["n"]
+            self._total_points -= old["n"]
+            self.evicted_points += old["n"]
+            victim.concat = None
+            victim.version += 1
+            nxt = old["max_ts"] + 1
+            if (victim.complete_from is None
+                    or nxt > victim.complete_from):
+                victim.complete_from = nxt
 
     def flush(self) -> None:
         """Upload every metric's staged points and wait for the
@@ -636,10 +658,12 @@ class DeviceWindow:
         path — the old hand-off-a-held-lock contract deadlocked if any
         future early return forgot the release), or None for scan-path
         fallback."""
+        self._why.reason = None
         with self._lock:
             mw = self._metrics.get(metric_uid)
             if mw is None:
                 self.window_misses += 1
+                self._why.reason = "absent"
                 yield None
                 return
             work = self._take_staged(mw)
@@ -664,18 +688,44 @@ class DeviceWindow:
         if self._wait_quiet(mw) == "slow":
             with self._lock:       # counters mutate under the lock only
                 self.window_misses += 1
+            self._why.reason = "slow"
             yield None
             return
         with self._lock:
             if mw.dirty:
                 self.dirty_fallbacks += 1
+                self._why.reason = "dirty"
                 yield None
             elif (mw.complete_from is not None
-                    and start < mw.complete_from) or not mw.chunks:
+                    and start < mw.complete_from):
                 self.window_misses += 1
+                self.horizon_misses += 1
+                self._why.reason = "horizon"
+                yield None
+            elif not mw.chunks:
+                self.window_misses += 1
+                self._why.reason = "absent"
                 yield None
             else:
                 yield mw
+
+    def last_miss(self) -> str | None:
+        """Why this thread's last columns() / chunk_columns() declined:
+        ``horizon`` (the range starts before the metric's
+        ``complete_from``), ``dirty``, ``absent`` (no window, or none
+        of its chunks left) or ``slow`` (uploads still in flight after
+        the wait); None after a hit."""
+        return getattr(self._why, "reason", None)
+
+    def horizons(self) -> tuple[int, int] | None:
+        """Least and greatest ``complete_from`` over the metrics that
+        hold data: how far apart eviction has left them. A metric that
+        has lost nothing counts as 0, so (0, 0) where nothing was
+        evicted; None where no metric holds data."""
+        with self._lock:
+            froms = [mw.complete_from or 0
+                     for mw in self._metrics.values() if mw.chunks]
+        return (min(froms), max(froms)) if froms else None
 
     def columns(self, metric_uid: bytes, start: int,
                 end: int) -> DevColumns | None:
@@ -733,8 +783,13 @@ class DeviceWindow:
         collector.record("devwindow.points.evicted", self.evicted_points)
         collector.record("devwindow.hits", self.window_hits)
         collector.record("devwindow.misses", self.window_misses)
+        collector.record("devwindow.misses.horizon", self.horizon_misses)
         collector.record("devwindow.dirty_fallbacks", self.dirty_fallbacks)
         collector.record("devwindow.upload_stalls", self.upload_stalls)
+        collector.record("devwindow.points.budget", self.max_points)
+        lo, hi = self.horizons() or (0, 0)
+        collector.record("devwindow.horizon.min", lo)
+        collector.record("devwindow.horizon.max", hi)
         with self._lock:
             collector.record("devwindow.metrics", len(self._metrics))
             collector.record(

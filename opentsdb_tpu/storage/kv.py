@@ -236,6 +236,11 @@ def _merge_unique(a: list[bytes], b: list[bytes]) -> list[bytes]:
     return out
 
 
+class _TiersMoved(FileNotFoundError):
+    """A replica's load saw the writer rotate or commit between its
+    reads of the manifest, <wal>.old and the WAL."""
+
+
 class _Table:
     """Row storage + an incremental sorted key index.
 
@@ -599,14 +604,19 @@ class MemKVStore(KVStore):
                 self._lockfd = None
             raise
 
+    # A load beside a writer that checkpoints back to back is redone
+    # whenever a rotation or a commit fell inside it (_TiersMoved).
+    _OPEN_TRIES = 32
+
     def _open_tiers_retrying(self, wal_path: str | None) -> None:
         """_open_tiers for replicas, retrying on FileNotFoundError: a
         live writer's merge can unlink a dropped generation between
         the replica's manifest read and the file open (found by the
-        replica-vs-writer stress test). The manifest converges, so a
-        bounded re-read wins the race; skipping the missing file
+        replica-vs-writer stress test), or rotate and commit between
+        the reads of one load (_TiersMoved). The manifest converges,
+        so a bounded re-read wins the race; skipping the missing file
         instead would silently drop its rows."""
-        for _ in range(8):
+        for _ in range(self._OPEN_TRIES):
             for sst in self._ssts:
                 sst.close()
             self._tables = {}
@@ -618,15 +628,18 @@ class MemKVStore(KVStore):
                 continue
         raise FileNotFoundError(
             f"generation set for {wal_path!r} kept changing mid-open "
-            f"(writer merging continuously?); gave up after 8 tries")
+            f"(writer merging continuously?); gave up after "
+            f"{self._OPEN_TRIES} tries")
 
     def _open_tiers(self, wal_path: str | None) -> None:
         """Load sstable generations, replay the WAL(s), open for append
         (the recovery tail of __init__; caller owns lock-fd cleanup on
         failure)."""
         self._replay_epoch = 0
+        gen_paths = self._generation_paths() if self._sst_path else []
+        old_seen = self._stat_old() if wal_path and self.read_only else None
         if self._sst_path:
-            for path in self._generation_paths():
+            for path in gen_paths:
                 sst = SSTable(path)
                 self._ssts.append(sst)
                 for name in sst.tables():
@@ -659,8 +672,21 @@ class MemKVStore(KVStore):
                     with open(wal_path, "r+b") as f:
                         f.truncate(valid_bytes)
             if self.read_only:
+                # The manifest, <wal>.old and the WAL were read one
+                # after another beside a live writer. A rotation after
+                # the .old check leaves a view without the records
+                # that just moved into .old; a commit after the
+                # manifest read leaves one without the generation that
+                # took .old's records in. Either shows as a key going
+                # backwards (the replica-vs-writer stress test, once in
+                # ~20 runs on a loaded machine). Both change the
+                # manifest or .old, so read them again and start over.
+                if self._stat_old() != old_seen or (
+                        self._sst_path
+                        and self._generation_paths() != gen_paths):
+                    raise _TiersMoved(wal_path)
                 self._ro_state = {"wal": (ino, valid_bytes),
-                                  "old": self._stat_old()}
+                                  "old": old_seen}
             else:
                 self._wal = open(wal_path, "ab")
                 self._stamp_epoch_header()

@@ -178,6 +178,16 @@ def make_tsdb(args, start_thread: bool = False) -> TSDB:
         cfg.mesh_plane_procs = getattr(args, "mesh_plane_procs", 1)
         cfg.mesh_plane_id = getattr(args, "mesh_plane_id", 0)
         cfg.devwindow_shards = getattr(args, "devwindow_shards", 0)
+        budget = getattr(args, "device_window_points", 0)
+        if budget < 0:
+            raise SystemExit("--device-window-points must not be negative")
+        if budget:
+            cfg.device_window_points = budget
+            # A chunk is what eviction drops at a time: keep the budget
+            # at 64 chunks, as the defaults have it (1 << 26 over
+            # 1 << 20), and never a larger upload than the default's.
+            cfg.device_window_staging = min(cfg.device_window_staging,
+                                            max(budget // 64, 1024))
         cfg.rollup_device_fold = getattr(args, "rollup_device_fold",
                                          False)
         if cfg.mesh_plane:
@@ -986,6 +996,16 @@ def main(argv: list[str] | None = None) -> int:
                         "(/api/mesh/reshard). 0 = one resident window "
                         "(defaulted to the local device count under "
                         "--mesh-plane)")
+    p.add_argument("--device-window-points", type=int, default=0,
+                   metavar="N",
+                   help="budget of the device-resident hot window, in "
+                        "points summed over metrics (~12 B a point of "
+                        "HBM). Past it the chunks holding the oldest "
+                        "data are evicted and a request that starts "
+                        "before its metric's horizon is served from "
+                        "storage. 0 = the default, 67,108,864 "
+                        "(1 << 26); /stats has it as "
+                        "tsd.devwindow.points.budget")
     p.add_argument("--rollup-device-fold", action="store_true",
                    help="run the rollup checkpoint fold on-device "
                         "behind the mesh plane (f64 accumulation where "

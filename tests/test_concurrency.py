@@ -326,3 +326,50 @@ class TestReplicaRacesWriter:
             got = int(final.get(T, k)[0].value)
             assert got == v, (k, got, v)
         final.close()
+
+    @pytest.mark.parametrize("moved", ["rotation", "commit"])
+    def test_replica_load_beside_a_checkpoint_starts_over(self, tmp_path,
+                                                          moved):
+        """The race behind 'went backwards', made to happen: the writer
+        rotates its WAL (or commits a whole checkpoint) after the
+        replica's load has looked for <wal>.old and before it replays
+        the WAL. The load must notice and start over, not serve the
+        older generation without the records that moved."""
+        from opentsdb_tpu.fault import faultpoints as fp
+        wal = str(tmp_path / "wal")
+        writer = MemKVStore(wal_path=wal)
+        writer.put(T, b"k", F, b"q", b"000001")
+        assert writer.checkpoint() == 1
+        writer.put(T, b"k", F, b"q", b"000002")
+        replica = MemKVStore(wal_path=wal, read_only=True)
+        assert int(replica.get(T, b"k")[0].value) == 2
+        replay, fired = replica._replay, []
+
+        def racing(path, start=0):
+            if not path.endswith(".old") and not fired:
+                fired.append(path)
+                if moved == "rotation":
+                    fp.arm("kv.checkpoint.freeze", "raise")
+                    try:
+                        with pytest.raises(fp.FaultInjected):
+                            writer.checkpoint()
+                    finally:
+                        fp.clear()
+                else:
+                    assert writer.checkpoint() == 1
+            return replay(path, start)
+
+        replica._replay = racing
+        try:
+            with replica._lock:
+                replica._rebuild_locked()
+            assert fired
+            assert int(replica.get(T, b"k")[0].value) == 2
+            # What the load recorded is what refresh() polls against:
+            # nothing has moved since, so nothing is rebuilt again.
+            rebuilds = replica.rebuilds
+            assert replica.refresh() is False
+            assert replica.rebuilds == rebuilds
+        finally:
+            replica.close()
+            writer.close()
