@@ -78,6 +78,11 @@ _C_STAGE_EVICTED = _metrics.counter("devwindow.stage.evicted")
 # resident a stage built.
 _C_FOLD_VISITED = _metrics.counter("devwindow.fold.slots.visited")
 _C_FOLD_SKIPPED = _metrics.counter("devwindow.fold.slots.skipped")
+# Stages built with and without a cut by the matched series (their sum
+# is devwindow.stage.miss): how often the series dimension of the zone
+# maps engages.
+_C_FOLD_NARROWED = _metrics.counter("devwindow.fold.stages.narrowed")
+_C_FOLD_WHOLE = _metrics.counter("devwindow.fold.stages.whole")
 
 
 # What the raw plan read from storage and handed to its kernels: rows
@@ -990,7 +995,7 @@ class QueryExecutor:
             mkey = (dw.instance_id, metric_uid, fk)
             hit = mask_cache.get(mkey)
             if hit is not None and hit[0] == cols.generation:
-                include, gmap = hit[1], hit[2]
+                include, gmap, sids = hit[1:]
             else:
                 include = np.zeros(S_pad, bool)
                 gmap = np.full(S_pad, G - 1, np.int32)
@@ -1010,12 +1015,16 @@ class QueryExecutor:
                             except Exception:
                                 tgt = None
                             break
+                # The matched series ids, sorted: what the stage's block
+                # selection is narrowed by.
+                sids = np.flatnonzero(include)
                 include = jax.device_put(include, tgt)
                 gmap = jax.device_put(gmap, tgt)
                 # Generation lives in the VALUE (the _dw_plan_cache
                 # pattern): a directory growth overwrites in place, so dead
                 # generations never accumulate device arrays.
-                mask_cache.put(mkey, (cols.generation, include, gmap))
+                mask_cache.put(mkey,
+                               (cols.generation, include, gmap, sids))
             if gsp is not None:
                 gsp.tags.update(series=S_all, groups=len(gkeys),
                                 plan_hit=plan_hit,
@@ -1023,24 +1032,35 @@ class QueryExecutor:
                                 and hit[0] == cols.generation)
         ngroups = 1 if len(gkeys) == 1 else G
         rate_kw = self._rate_kw(spec)
-        # The heavy N-point half of ANY window query (range mask +
-        # per-series downsample [+ rate]) is FILTER-INDEPENDENT, so it
-        # caches per (window instance, metric, data version, range,
-        # interval, downsample, rate) and stays device-resident: every
-        # dashboard panel over the same range — any tag filter, any
-        # group-by, moments and p50/p95/p99 alike — reuses one stage
-        # and pays only the [S, B]-sized apply + one dispatch (the
-        # saving on a local chip: not measured).
-        skey = (dw.instance_id, metric_uid, cols.version, start, end,
-                interval, dsagg, tuple(sorted(rate_kw.items())))
+        # The heavy N-point half of a window query (range mask +
+        # per-series downsample [+ rate]) caches per (window instance,
+        # metric, data version, range, interval, downsample, rate, the
+        # filter its blocks were narrowed by) and stays device-resident.
+        # A request whose matched series cut no block out (it matched
+        # every series, or every block in range holds one of them)
+        # folds the blocks of its range whole: that stage is good for
+        # any tag filter, any group-by, moments and p50/p95/p99 alike,
+        # which then pay only the [S, B]-sized apply + one dispatch.
+        # Any other folds only the blocks its series can lie in
+        # (DevChunks.narrowed): its grids are whole for the rows its
+        # own include mask keeps and partial for the others, so its
+        # stage answers that filter alone.
         cache = self._dw_stage_cache
         with obs_trace.span("resident.stage") as ssp:
+            whole, cols = cols, cols.narrowed(sids, start, end)
+            narrowed = cols is not whole
+            skey = (dw.instance_id, metric_uid, cols.version, start, end,
+                    interval, dsagg, tuple(sorted(rate_kw.items())),
+                    fk if narrowed else None)
             stage = cache.get(skey)
             (_C_STAGE_MISS if stage is None else _C_STAGE_HIT).inc()
             if ssp is not None:
                 ssp.tags["hit"] = stage is not None
                 ssp.tags["chunks"] = len(_dw_chunks(cols))
+                ssp.tags["narrowed"] = narrowed
+                ssp.tags["series"] = len(sids)
             if stage is None:
+                (_C_FOLD_NARROWED if narrowed else _C_FOLD_WHOLE).inc()
                 picked, of, visited, resident = _dw_fold_extent(cols)
                 _C_FOLD_VISITED.inc(visited)
                 _C_FOLD_SKIPPED.inc(resident - visited)

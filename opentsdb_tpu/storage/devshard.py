@@ -73,6 +73,21 @@ class ShardedDevChunks(NamedTuple):
     generation: tuple       # (reshard_gen, per-shard generations)
     version: tuple          # (reshard_gen, per-shard (instance, version))
 
+    def narrowed(self, sids: np.ndarray, start: int,
+                 end: int) -> "ShardedDevChunks":
+        """DevChunks.narrowed, shard by shard: every shard's selection
+        cut to those of the sorted combined ``sids`` that are its own
+        rows; ``self`` where no shard's selection is narrowed by it."""
+        cuts = np.searchsorted(
+            sids, self.shard_starts + [len(self.series_keys)])
+        shards = [None if sc is None
+                  else sc.narrowed(sids[lo:hi] - first, start, end)
+                  for sc, first, lo, hi in zip(
+                      self.shards, self.shard_starts, cuts, cuts[1:])]
+        if all(a is b for a, b in zip(shards, self.shards)):
+            return self
+        return self._replace(shards=shards)
+
 
 class ShardedDeviceWindow:
     """Series-hash-sharded fleet of device-pinned ``DeviceWindow``s."""

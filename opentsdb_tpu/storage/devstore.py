@@ -22,9 +22,11 @@ Design:
   slow host link at ingest time, once, instead of per query.
 - **Zone maps.** A query's device cost follows the slots its fold is
   handed, not the points in its range. Each chunk records, per block of
-  ``ZONE_BLOCK`` slots, the least and greatest timestamp of the block's
-  valid slots; ``chunk_columns`` lists the blocks a range can hit and
-  the chunked stage visits only those (see ``DevChunks``).
+  ``ZONE_BLOCK`` slots, the least and greatest timestamp and the least
+  and greatest series id of the block's valid slots; ``chunk_columns``
+  lists the blocks a range can hit, ``DevChunks.narrowed`` those of
+  them that can hold a series the request matched, and the chunked
+  stage visits only those (see ``DevChunks``).
 - **Exactness, not cache-maybe.** The window only serves a query when its
   answer is guaranteed byte-identical to the storage scan path:
   - per-series timestamps must be strictly monotone across appends (the
@@ -88,6 +90,37 @@ class DevColumns(NamedTuple):
     #                         chunks) — derived-result cache key
 
 
+class ZoneMap(NamedTuple):
+    """One chunk's map: an entry a block that holds a valid slot (the
+    all-padding blocks at a chunk's end have none, so nothing selects
+    them), each the least and greatest timestamp and the least and
+    greatest series id of the block's valid slots."""
+    tmin: np.ndarray
+    tmax: np.ndarray
+    smin: np.ndarray
+    smax: np.ndarray
+
+    def select(self, start: int, end: int, sids=None) -> np.ndarray:
+        """Ids of the blocks whose [tmin, tmax] meets [start, end] and,
+        given the sorted ``sids``, whose [smin, smax] holds one of
+        them."""
+        hit = (self.tmax >= start) & (self.tmin <= end)
+        if sids is not None:
+            hit &= (np.searchsorted(sids, self.smin, "left")
+                    < np.searchsorted(sids, self.smax, "right"))
+        return np.flatnonzero(hit).astype(np.int32)
+
+
+def _zone_map(ts: np.ndarray, sid: np.ndarray, pad: int) -> ZoneMap:
+    """The map of a chunk whose valid slots (its first len(ts)) hold
+    ``ts`` and ``sid``: blocks of min(ZONE_BLOCK, pad) slots."""
+    starts = np.arange(0, len(ts), min(ZONE_BLOCK, pad))
+    return ZoneMap(np.minimum.reduceat(ts, starts),
+                   np.maximum.reduceat(ts, starts),
+                   np.minimum.reduceat(sid, starts),
+                   np.maximum.reduceat(sid, starts))
+
+
 class DevChunks(NamedTuple):
     """One metric's resident window as its RAW device chunk list — no
     concatenation. The chunked query stage (ops/kernels
@@ -97,25 +130,51 @@ class DevChunks(NamedTuple):
     N-sized kernel transients, which caps it near half the HBM.
 
     ``blocks`` is the zone-map selection for the range the caller asked
-    about. Every chunk keeps, for each block of ``block`` slots, the
-    least and greatest timestamp of the block's valid slots (a min/max
-    zone map, recorded from the host arrays at upload; a block that is
-    all padding has no entry). ``blocks[i]`` lists the blocks of
-    ``chunks[i]`` whose [min, max] meets [start, end], ascending; it is
-    empty for a chunk the range cannot hit. The selection is exact for
-    any order of data: a block left out holds only slots that are
-    padding or out of range, which the fold would have sent to its dump
-    segment. It saves work wherever data is clustered in time (the
-    refill's [metric][hour][series] order, live ingest's time-major
-    slices). ``chunks`` is still every chunk, whole, for a caller that
-    wants that."""
+    about, and for the series it matched if it said which. Every chunk
+    keeps, for each block of slots, the least and greatest timestamp
+    and series id of the block's valid slots (``ZoneMap``, recorded
+    from the host arrays at upload). ``blocks[i]`` lists, ascending,
+    the blocks of ``chunks[i]`` whose [tmin, tmax] meets [start, end];
+    it is empty for a chunk the range cannot hit. ``narrowed``
+    cuts the selection further, to the blocks that can also hold one of
+    the matched series. Both are exact for any order of data: a block
+    left out holds only slots that are padding, out of range or of a
+    series nobody asked about. The time cut saves work wherever data is
+    clustered in time (the refill's [metric][hour][series] order, live
+    ingest's time-major slices), the series cut wherever a series'
+    slots lie together and a row-hour of all the series spans several
+    blocks (a run of 360 a series a row-hour in the refill's order: one
+    host of 4,000 lies in 1 block of a row-hour's 22).
+
+    The stage of a selection on time alone is whole: good for any
+    filter. The grids of a narrowed one are whole for the matched
+    series ONLY: it must never serve another filter. ``chunks`` is
+    still every chunk, whole, for a caller that wants that."""
     chunks: list            # [(rel_ts, values, sid, valid) device arrays]
     epoch: int
     series_keys: list
     generation: int
     version: int
-    blocks: list            # per chunk: int32 ids of the blocks in range
+    blocks: list            # per chunk: int32 ids of the blocks picked
     block: int              # slots a block id stands for (ZONE_BLOCK)
+    zones: list             # per chunk: its ZoneMap
+
+    def narrowed(self, sids: np.ndarray, start: int,
+                 end: int) -> "DevChunks":
+        """This selection (made on time alone, for [start, end]) cut to
+        the sorted series ids ``sids``: the blocks in range that can
+        hold one of them. ``self`` where they cut no block out: every
+        series matched, or data in which every block in range holds
+        some matched series (a row-hour of all the series shorter than
+        a block, or slots in no order of series)."""
+        if len(sids) >= len(self.series_keys):
+            return self
+        # Only the chunks the range can hit at all are looked at.
+        blocks = [zone.select(start, end, sids) if len(ids) else ids
+                  for zone, ids in zip(self.zones, self.blocks)]
+        if sum(map(len, blocks)) == sum(map(len, self.blocks)):
+            return self
+        return self._replace(blocks=blocks)
 
     def fold_extent(self) -> tuple[int, int, int, int]:
         """What the selection hands the fold: (blocks picked, blocks in
@@ -132,21 +191,6 @@ class DevChunks(NamedTuple):
         return picked, of, visited, resident
 
 
-def _zone_map(ts: np.ndarray, pad: int) -> tuple[np.ndarray, np.ndarray]:
-    """Least and greatest of ``ts`` (a chunk's valid slots, which are
-    its first len(ts)) over blocks of min(ZONE_BLOCK, pad) slots. One
-    entry a block that holds a valid slot: the all-padding blocks at a
-    chunk's end have none, so no range selects them."""
-    starts = np.arange(0, len(ts), min(ZONE_BLOCK, pad))
-    return np.minimum.reduceat(ts, starts), np.maximum.reduceat(ts, starts)
-
-
-def _blocks_in_range(zmin: np.ndarray, zmax: np.ndarray, start: int,
-                     end: int) -> np.ndarray:
-    """Ids of the blocks whose [min, max] meets [start, end]."""
-    return np.flatnonzero((zmax >= start) & (zmin <= end)).astype(np.int32)
-
-
 class _MetricWindow:
     __slots__ = ("sids", "keys", "last_ts", "epoch", "chunks",
                  "staged_ts", "staged_vals", "staged_sid", "staged_n",
@@ -160,7 +204,7 @@ class _MetricWindow:
         self.last_ts: list[int] = []
         self.epoch: int | None = None
         self.chunks: list[dict] = []      # ts/vals/sid device + n/max_ts
-        #                                   + the zone map zmin/zmax
+        #                                   + the zone map
         self.staged_ts: list[np.ndarray] = []
         self.staged_vals: list[np.ndarray] = []
         self.staged_sid: list[np.ndarray] = []
@@ -436,21 +480,21 @@ class DeviceWindow:
         sid = np.concatenate(staged_sid)
         n = len(rel)
         pad = _pad_pow2(n)
+        zone = _zone_map(ts, sid, pad)
         if pad != n:
             rel = np.pad(rel, (0, pad - n))
             vals = np.pad(vals, (0, pad - n))
             sid = np.pad(sid, (0, pad - n))
         valid = np.arange(pad) < n
         dev = self.device
-        zmin, zmax = _zone_map(ts, pad)
         chunk = {
             "ts": jax.device_put(rel, dev),
             "vals": jax.device_put(vals, dev),
             "sid": jax.device_put(sid, dev),
             "valid": jax.device_put(valid, dev),
             "n": n, "pad": pad, "seq": seq,
-            "min_ts": int(zmin.min()), "max_ts": int(zmax.max()),
-            "zmin": zmin, "zmax": zmax,
+            "min_ts": int(zone.tmin.min()), "max_ts": int(zone.tmax.max()),
+            "zone": zone,
         }
         with self._lock:
             if mw.dirty:  # marked dirty while we were copying
@@ -760,9 +804,11 @@ class DeviceWindow:
 
         Beside every chunk goes the zone-map selection for [start, end]
         (DevChunks.blocks): the blocks whose recorded [min, max]
-        timestamp meets the range. It decides what the fold visits,
-        never what is available, and never leaves out a slot in range
-        whatever order the data came in."""
+        timestamp meets the range. A caller that then learns which
+        series the request matched (from the directory this returns)
+        cuts it further with ``DevChunks.narrowed``. It decides what
+        the fold visits, never what is available, and never leaves out
+        a slot in range whatever order the data came in."""
         with self._ready_window(metric_uid, start) as mw:
             if mw is None:
                 return None
@@ -772,9 +818,8 @@ class DeviceWindow:
                         for c in mw.chunks],
                 epoch=mw.epoch, series_keys=list(mw.keys),
                 generation=mw.generation, version=mw.version,
-                blocks=[_blocks_in_range(c["zmin"], c["zmax"], start, end)
-                        for c in mw.chunks],
-                block=ZONE_BLOCK)
+                blocks=[c["zone"].select(start, end) for c in mw.chunks],
+                block=ZONE_BLOCK, zones=[c["zone"] for c in mw.chunks])
 
     # -- observability -------------------------------------------------
 
