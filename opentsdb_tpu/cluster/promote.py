@@ -54,8 +54,7 @@ class PromotionManager:
 
     def __init__(self, router) -> None:
         self.router = router
-        self.grace_ms = float(getattr(router.config,
-                                      "writer_grace_ms", 0.0) or 0.0)
+        self.grace_ms = float(router.config.writer_grace_ms or 0.0)
         self.dead_since: float | None = None
         self.promoting = False
         self.demoting = False

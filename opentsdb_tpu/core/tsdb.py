@@ -59,7 +59,7 @@ class TSDB:
         # the configured format. Only a non-default config value
         # overrides a store the embedder configured directly; replicas
         # never spill, so the read side stays format-sniffed per file.
-        codec = getattr(self.config, "sstable_codec", "none") or "none"
+        codec = self.config.sstable_codec or "none"
         if codec != "none":
             if codec != "tsst4":
                 raise ValueError(
@@ -69,7 +69,7 @@ class TSDB:
                 store.sstable_codec = codec
         # WAL group commit (storage/kv.py): pushed onto the store the
         # same way; replicas never append so the knob is writer-only.
-        group_ms = float(getattr(self.config, "wal_group_ms", 0.0) or 0.0)
+        group_ms = float(self.config.wal_group_ms or 0.0)
         if group_ms > 0 and hasattr(store, "wal_group_ms") \
                 and not getattr(store, "read_only", False):
             store.wal_group_ms = group_ms
@@ -77,7 +77,7 @@ class TSDB:
         # the writer pool is shared across stores/shards).
         from opentsdb_tpu.storage import sstable as _sstable_mod
         _sstable_mod.set_encode_workers(
-            int(getattr(self.config, "spill_encode_workers", 0) or 0))
+            int(self.config.spill_encode_workers or 0))
         self._lock = threading.Lock()
         # Serializes checkpoint() end to end so the rollup tier's spill
         # bracketing (begin_spill ... fold_after_spill) pairs 1:1 with
@@ -483,11 +483,10 @@ class TSDB:
 
         cfg = self.config
         self.tenant_limits = TenantLimiter(
-            max_series=getattr(cfg, "tenant_max_series", 0),
-            global_max=getattr(cfg, "tenant_global_max_series", 0),
-            mode=getattr(cfg, "tenant_limit_mode", "enforce"),
-            overrides=parse_overrides(
-                getattr(cfg, "tenant_overrides", ())))
+            max_series=cfg.tenant_max_series,
+            global_max=cfg.tenant_global_max_series,
+            mode=cfg.tenant_limit_mode,
+            overrides=parse_overrides(cfg.tenant_overrides))
         path = self._tenants_path()
         acct = None
         if path and os.path.exists(path):

@@ -364,7 +364,7 @@ def _shrink_wrap(gv, gm, g_out, b_out, wire_bf16=False):
     """Clip apply outputs to the (64-quantized) live group/bucket counts
     and bit-pack the mask before the device->host fetch, so a wide
     group-by does not fetch its PADDED [G, B] grids (what the fetch
-    costs on a local chip: not measured). g_out/b_out are static
+    costs is the ledger's fetch_ms). g_out/b_out are static
     (bounded recompiles: 64 quantization).
 
     ``wire_bf16`` additionally halves the [G, B] value payload by
@@ -645,37 +645,6 @@ window_quantile_apply = compile_with_plan(_quantile_apply,
                                           WINDOW_QUANTILE_APPLY_PLAN)
 
 
-@jit_plan(ExecPlan(
-    name="window.query", axis="series",
-    static_argnames=("num_series", "num_groups", "num_buckets",
-                     "interval", "agg_down", "agg_group")
-    + _RATE_STATICS))
-def window_query(rel_ts: jnp.ndarray, vals: jnp.ndarray, sid: jnp.ndarray,
-                 valid_in: jnp.ndarray, include: jnp.ndarray,
-                 gmap: jnp.ndarray, lo, hi, shift, *, num_series: int,
-                 num_groups: int, num_buckets: int, interval: int,
-                 agg_down: str, agg_group: str,
-                 rate: bool = False, counter_max: float = 0.0,
-                 reset_value: float = 0.0, counter: bool = False,
-                 drop_resets: bool = False):
-    """The whole resident-window MOMENT query in ONE jit — the
-    single-shot composition of window_series_stage + window_moment_apply
-    (one dispatch instead of two; results are identical, so the
-    executor's cached-stage path and this path are interchangeable).
-
-    Returns (group_values [G, B], group_mask [G, B], presence [S]).
-    """
-    sv, sm, filled, in_range, presence = _window_series_stage(
-        rel_ts, vals, sid, valid_in, lo, hi, shift,
-        num_series=num_series, num_buckets=num_buckets,
-        interval=interval, agg_down=agg_down, rate=rate,
-        counter_max=counter_max, reset_value=reset_value,
-        counter=counter, drop_resets=drop_resets)
-    gv, gm = _moment_apply(sv, sm, filled, in_range, include, gmap,
-                           num_groups=num_groups, agg_group=agg_group)
-    return gv, gm, presence
-
-
 # ---------------------------------------------------------------------------
 # Fused downsample + group-by (the hot query kernel)
 # ---------------------------------------------------------------------------
@@ -686,13 +655,13 @@ def _series_stage(ts, vals, sid, valid, *, num_series, num_buckets,
     reduction producing series_values/series_mask [S, B] (and, when
     ``with_ts``, per-bucket integer-mean member timestamps).
 
-    Negative result, measured r03: a scatter-free formulation for
+    Negative result from before PR 1 (its record is gone with the
+    transport it was taken through): a scatter-free formulation for
     (sid, ts)-sorted columns — int32/fixed-point-int64 prefix sums +
-    searchsorted of the [S*B] grid — LOST to the XLA scatter on both
-    TPU (1248 vs 598 ms at N=20M) and CPU (179 vs 56 ms): the grid-
-    side searchsorted (820 ms default 'scan', 305 ms 'sort' method on
-    TPU) costs more than the scatter it replaces. The scatter path
-    stays; don't re-derive without beating those numbers."""
+    searchsorted of the [S*B] grid — lost to the XLA scatter on TPU and
+    CPU alike, because the grid-side searchsorted costs more than the
+    scatter it replaces. The scatter path stays; a second attempt has
+    to win in the benchmark's cells."""
     bucket = jnp.clip(ts // interval, 0, num_buckets - 1)
     seg = jnp.where(valid, sid * num_buckets + bucket,
                     num_series * num_buckets)

@@ -55,7 +55,7 @@ _C_HIT = _metrics.counter("mesh.cache.hit")
 _C_MISS = _metrics.counter("mesh.cache.miss")
 
 # Process-wide mesh width for the /stats + /metrics gauge: 1 until a
-# server/bench configures a mesh (set_mesh_devices). Gauges re-read on
+# server configures a mesh (set_mesh_devices). Gauges re-read on
 # every scrape, so role changes show up live.
 _MESH_DEVICES = 1
 _metrics.gauge("mesh.devices", lambda: _MESH_DEVICES)

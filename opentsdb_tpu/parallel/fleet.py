@@ -18,12 +18,11 @@ weights series ownership by each backend's advertised mesh width
 (serve/router.py). The plane join buys the fleet:
 
 - one coordinated device namespace (process_index/device ids are
-  globally consistent — the reshard journal and BENCH_MESH legs key on
-  them);
+  globally consistent — the reshard journal keys on them);
 - boot-time membership checks (a misconfigured process fails loudly at
   join instead of silently serving an undersized hot set);
-- the collective transport for offline legs (bench folds, rollup
-  rebuild fan-out) that DO run one program fleet-wide.
+- the collective transport for offline legs (rollup rebuild fan-out)
+  that DO run one program fleet-wide.
 
 ``init_plane`` is idempotent per process and must run BEFORE the first
 jax backend touch — the CPU collectives implementation is latched at

@@ -195,8 +195,8 @@ class QueryExecutor:
         # _shared_frag_cache), not per executor.
         self._frag_cache = _shared_frag_cache(
             tsdb.store,
-            int(getattr(cfg, "qcache_fragments", 1024)),
-            int(getattr(cfg, "qcache_points", 1 << 25)))
+            int(cfg.qcache_fragments),
+            int(cfg.qcache_points))
         # Candidate-series hint per (metric, filter): identity hashes
         # from the sketch directory, revalidated on the metric's
         # directory growth; cost-bounded in total cached hashes (an
@@ -218,7 +218,7 @@ class QueryExecutor:
         # per-block query-independent columns stay resident on device,
         # bounded by total cached points. Keyed by SSTable OBJECT +
         # block index (entries pin their generation against id reuse).
-        dbp = int(getattr(cfg, "devblock_points", 0))
+        dbp = int(cfg.devblock_points)
         self._devcache = None
         if dbp > 0 and self.backend != "cpu":
             from opentsdb_tpu.compress.devcache import DeviceBlockCache
@@ -411,15 +411,15 @@ class QueryExecutor:
                                         key_regexp=regexp,
                                         series_hint=hint, counts=info)[1]
 
-        chunk_s = int(getattr(cfg, "qcache_chunk_s", 0) or 0)
+        chunk_s = int(cfg.qcache_chunk_s or 0)
         chunk_s -= chunk_s % MAX_TIMESPAN
         state_fn = getattr(store, "chunk_state", None)
-        if (not getattr(cfg, "qcache", True) or state_fn is None
+        if (not cfg.qcache or state_fn is None
                 or chunk_s <= 0 or b_hi < b_lo):
             return full_scan()
         c0 = b_lo - b_lo % chunk_s
         nchunks = (b_hi - c0) // chunk_s + 1
-        if nchunks > int(getattr(cfg, "qcache_max_chunks", 512)):
+        if nchunks > int(cfg.qcache_max_chunks):
             # All-time-style ranges: per-chunk scan setup would cost
             # more than it saves, and caching them would flush the
             # dashboard working set.
@@ -1112,14 +1112,13 @@ class QueryExecutor:
         # Shrink-wrap the fetch: clip to the live group/bucket counts
         # (64-quantized so statics don't churn recompiles) and bit-pack
         # the mask on device, so wide group-by queries do not fetch
-        # padded [G, B] grids (fetch cost on a local chip: not
-        # measured).
+        # padded [G, B] grids (what the fetch costs is the ledger's
+        # fetch_ms).
         b_live = int((end - qbase) // interval + 1)
         g_out = min(ngroups, _pad64(len(gkeys)))
         b_out = min(num_buckets, _pad64(b_live))
         shrink = dict(g_out=g_out, b_out=b_out,
-                      wire_bf16=bool(getattr(self.tsdb.config,
-                                            "wire_bf16", False)))
+                      wire_bf16=bool(self.tsdb.config.wire_bf16))
         # The applies allocate fresh [S,B]/[G,B] buffers on a device the
         # resident window may have filled to within a few hundred MB of
         # HBM — an OOM here (or in the fetch's staging buffer) must
@@ -1341,7 +1340,7 @@ class QueryExecutor:
                 or not spec.downsample
                 or agg.kind not in ("moment", "percentile")
                 or Aggregators.get(spec.downsample[1]).kind != "moment"
-                or not getattr(cfg, "sstable_fused_agg", True)):
+                or not cfg.sstable_fused_agg):
             return None
         store = tsdb.store
         if getattr(store, "encoded_range", None) is None \
@@ -1599,8 +1598,7 @@ class QueryExecutor:
         g_out = min(ngroups, _pad64(len(gkeys)))
         b_out = min(num_buckets, _pad64(b_live))
         shrink = dict(g_out=g_out, b_out=b_out,
-                      wire_bf16=bool(getattr(tsdb.config, "wire_bf16",
-                                             False)))
+                      wire_bf16=bool(tsdb.config.wire_bf16))
         if agg.kind == "percentile":
             gv, gm = kernels.window_quantile_apply(
                 sm, filled, in_range, include, gmap,

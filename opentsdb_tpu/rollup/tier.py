@@ -198,12 +198,11 @@ class _MapBuffer:
 class RollupTier:
     def __init__(self, tsdb, config) -> None:
         self._init_layout(tsdb, config)
-        if bool(getattr(config, "rollup_delta_fold", True)):
+        if bool(config.rollup_delta_fold):
             from opentsdb_tpu.rollup.delta import DeltaFolds
             self.delta = DeltaFolds(
                 coarse=self.resolutions[-1],
-                cap_points=int(getattr(config, "rollup_delta_points",
-                                       1 << 22)))
+                cap_points=int(config.rollup_delta_points))
         store = tsdb.store
         st = self._read_state()
         rebuild = self._needs_rebuild(st)
@@ -225,8 +224,7 @@ class RollupTier:
                     # entry bytes — the block-direct read fast path in
                     # scan_records serves off them without inflating
                     # whole rows).
-                    s.sstable_codec = getattr(config, "sstable_codec",
-                                              "none")
+                    s.sstable_codec = config.sstable_codec
                     s.ensure_table(self.table)
                     self.stores[r].append(s)
         except BaseException:
@@ -247,7 +245,7 @@ class RollupTier:
             self._write_state(pending=True, inflight=windows)
             if windows is not None:
                 self._inflight = frozenset(windows)
-            mode = getattr(config, "rollup_catchup", "background")
+            mode = config.rollup_catchup
             if mode == "sync":
                 self._rebuilding = True
                 self._rebuild(windows=windows)
@@ -294,11 +292,9 @@ class RollupTier:
         self.digest_k = int(config.rollup_digest_k)
         self.hll_p = int(config.rollup_hll_p)
         self.sketch_min_res = int(config.rollup_sketch_min_res)
-        self.moment_k = int(getattr(config, "rollup_moment_k", 0))
-        self.moment_min_res = int(getattr(config,
-                                          "rollup_moment_min_res", 0))
-        self.sketch_byte_budget = int(getattr(config,
-                                              "sketch_byte_budget", 0))
+        self.moment_k = int(config.rollup_moment_k)
+        self.moment_min_res = int(config.rollup_moment_min_res)
+        self.sketch_byte_budget = int(config.sketch_byte_budget)
 
         # Checkpoint fold backend. Default is the host NumPy f64
         # pairwise fold (bit-exact across chunkings); Config.
@@ -309,7 +305,7 @@ class RollupTier:
         # accumulation orders inside the same stored rows, so a kind
         # change rebuilds like any layout change (but a legacy state
         # file with no "fold" key means host-f64 — see _needs_rebuild).
-        if bool(getattr(config, "rollup_device_fold", False)):
+        if bool(config.rollup_device_fold):
             self.fold_kind = summary.device_fold_kind()
             self._fold_fn = summary.window_summaries_device
         else:
@@ -372,8 +368,7 @@ class RollupTier:
         # at quiescent instants — the two derivations are separate
         # lock acquisitions, so concurrent ingest between them is a
         # benign difference, and tests quiesce before comparing.
-        self.sweep_check = bool(getattr(config, "rollup_sweep_check",
-                                        False))
+        self.sweep_check = bool(config.rollup_sweep_check)
 
         self._dirs: dict[int, list[str]] = {}
         for r in res:
@@ -394,8 +389,8 @@ class RollupTier:
         self.sketch_alloc = self._compute_alloc()
         # Cumulative sketch-column bytes written per (resolution,
         # kind) — process lifetime; /stats `sketch.bytes{kind=}` sums
-        # across resolutions, the bench reads the per-res split (the
-        # moment-vs-digest size story differs by window density).
+        # across resolutions (the moment-vs-digest size story differs
+        # by window density).
         self.sketch_bytes_res: dict[int, dict[str, int]] = {}
 
     @property
@@ -572,8 +567,7 @@ class RollupTier:
         if st.get("pending", True):
             wins = st.get("inflight")
             if (config_ok and isinstance(wins, list)
-                    and getattr(self.tsdb.config,
-                                "rollup_incremental_catchup", True)):
+                    and self.tsdb.config.rollup_incremental_catchup):
                 self._incr_windows = [int(b) for b in wins]
                 return "incr"
             return "full"

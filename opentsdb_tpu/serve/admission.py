@@ -120,7 +120,7 @@ class AdmissionController:
         ``ingest_done``); > 0 is the Retry-After in seconds, and NO
         slot was taken."""
         cfg = self.config
-        cap = int(getattr(cfg, "ingest_queue_points", 0) or 0)
+        cap = int(cfg.ingest_queue_points or 0)
         if cap:
             # Check-and-reserve under ONE lock acquisition: a check
             # now and an increment later would let two concurrent
@@ -133,7 +133,7 @@ class AdmissionController:
                     # honest hint (the caller can't see the drain rate).
                     return 0.5
                 self.inflight_ingest_points += points
-        rate = float(getattr(cfg, "ingest_rate", 0) or 0)
+        rate = float(cfg.ingest_rate or 0)
         if rate > 0:
             b = self._bucket(self._ingest_buckets, tenant, rate,
                              rate * float(cfg.ingest_burst_s))
@@ -148,7 +148,7 @@ class AdmissionController:
         return 0.0
 
     def ingest_done(self, points: int) -> None:
-        if int(getattr(self.config, "ingest_queue_points", 0) or 0):
+        if int(self.config.ingest_queue_points or 0):
             with self._lock:
                 self.inflight_ingest_points = max(
                     0, self.inflight_ingest_points - points)
@@ -160,7 +160,7 @@ class AdmissionController:
         in-flight slot — the caller MUST pair them with
         ``query_done()``; shed verdicts don't."""
         cfg = self.config
-        rate = float(getattr(cfg, "query_rate", 0) or 0)
+        rate = float(cfg.query_rate or 0)
         if rate > 0:
             b = self._bucket(self._query_buckets, tenant, rate,
                              float(cfg.query_burst))
@@ -168,7 +168,7 @@ class AdmissionController:
             if wait > 0:
                 self.query_shed_quota += 1
                 return SHED_QUOTA, max(wait, 0.05)
-        n = int(getattr(cfg, "query_max_inflight", 0) or 0)
+        n = int(cfg.query_max_inflight or 0)
         if n <= 0:
             with self._lock:
                 self.inflight_queries += 1

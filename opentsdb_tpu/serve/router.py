@@ -164,8 +164,8 @@ class RouterServer:
         # owner history and the answers merge). The map is loaded from
         # Config.cluster_map when the file exists, else built as an
         # equal split and persisted there.
-        writers = list(getattr(config, "router_writers", ()) or ())
-        self.cluster_map_path = getattr(config, "cluster_map", None)
+        writers = list(config.router_writers or ())
+        self.cluster_map_path = config.cluster_map
         self.ownership: OwnershipMap | None = None
         if self.cluster_map_path and \
                 os.path.exists(self.cluster_map_path):
@@ -180,24 +180,24 @@ class RouterServer:
         elif len(writers) > 1:
             self.ownership = OwnershipMap(
                 writers,
-                slots=int(getattr(config, "cluster_slots", 64) or 64))
+                slots=int(config.cluster_slots or 64))
             if self.cluster_map_path:
                 self.ownership.save(self.cluster_map_path)
         self.writer_backends = [Backend(u) for u in
                                 (self.ownership.writers
                                  if self.ownership else writers)]
-        backends = list(getattr(config, "router_backends", ()) or ())
+        backends = list(config.router_backends or ())
         if not backends:
             if self.writer_backends:
                 # Writer-serves-reads topology: the writers ARE the
-                # read backends (the bench_serve --writers shape).
+                # read backends.
                 backends = [b.url for b in self.writer_backends]
             else:
                 raise ValueError("router role needs --backends "
                                  "(comma-separated replica URLs) or "
                                  "--writers")
         self.backends = [Backend(u) for u in backends]
-        self.writer_url = getattr(config, "writer_url", None)
+        self.writer_url = config.writer_url
         if not self.writer_url and len(writers) == 1:
             # A lone --writers entry is just the writer (ingest
             # forwards there; no ownership map needed).
@@ -212,17 +212,16 @@ class RouterServer:
         self.promotion = PromotionManager(self) if self._writer \
             else None
         self.admission = AdmissionController(config)
-        self.trace_ring = TraceRing(getattr(config, "trace_ring", 256))
+        self.trace_ring = TraceRing(config.trace_ring)
         # Bounded result cache (the fragment-cache stamp discipline at
         # the router): full-service JSON answers keyed by (normalized
         # query, ownership-map epoch, staleness bound). Repeat
         # dashboard fan-ins stop re-hitting replicas every poll; an
         # ownership handoff bumps the map epoch and orphans every
         # entry computed under the old layout.
-        n_rcache = int(getattr(config, "router_rcache", 0) or 0)
+        n_rcache = int(config.router_rcache or 0)
         self.rcache = LRUCache(n_rcache) if n_rcache > 0 else None
-        self.rcache_ms = float(getattr(config, "router_rcache_ms",
-                                       1000.0) or 1000.0)
+        self.rcache_ms = float(config.router_rcache_ms or 1000.0)
         self._server: asyncio.AbstractServer | None = None
         self._shutdown = asyncio.Event()
         self._probe_task: asyncio.Task | None = None
@@ -272,7 +271,7 @@ class RouterServer:
     # ------------------------------------------------------------------
 
     async def _probe_loop(self) -> None:
-        interval = float(getattr(self.config, "probe_interval_s", 1.0))
+        interval = float(self.config.probe_interval_s)
         while True:
             probes = [self._probe_one(b) for b in self.backends]
             if self.promotion is not None:
@@ -305,8 +304,7 @@ class RouterServer:
 
     def _note_failure(self, b: Backend) -> None:
         b.consecutive_fails += 1
-        eject_after = int(getattr(self.config, "router_eject_after",
-                                  3) or 3)
+        eject_after = int(self.config.router_eject_after or 3)
         if b.healthy and b.consecutive_fails >= eject_after:
             b.healthy = False
             _M_EJECTED.inc()
@@ -938,7 +936,7 @@ class RouterServer:
         want_trace = q.get("trace", "0") not in ("", "0")
         trace_id = obs_trace.new_trace_id()
         deadline = time.monotonic() + float(
-            getattr(self.config, "router_deadline_ms", 10_000)) / 1000.0
+            self.config.router_deadline_ms) / 1000.0
         want_json = "json" in q or want_trace
         png = not ("json" in q or "ascii" in q)
 
@@ -1231,9 +1229,8 @@ class RouterServer:
         5xx handling as the replica hop, but NO alternate candidates
         and no hedging — writers are not interchangeable (each owns
         its slice), so retries go to the same writer."""
-        retries = int(getattr(self.config, "router_retries", 2) or 0)
-        backoff = float(getattr(self.config, "router_backoff_ms",
-                                50.0)) / 1000.0
+        retries = int(self.config.router_retries or 0)
+        backoff = float(self.config.router_backoff_ms) / 1000.0
         spans: list[dict] = []
         last_err: Exception | None = None
         for attempt in range(retries + 1):
@@ -1288,9 +1285,8 @@ class RouterServer:
         exponential backoff between retries, and a hedged duplicate
         when the leader is slower than the hedge delay. Returns
         (status, ctype, body, extra_headers, hop_spans)."""
-        retries = int(getattr(self.config, "router_retries", 2) or 0)
-        backoff = float(getattr(self.config, "router_backoff_ms",
-                                50.0)) / 1000.0
+        retries = int(self.config.router_retries or 0)
+        backoff = float(self.config.router_backoff_ms) / 1000.0
         cands = self._candidates(owner)
         spans: list[dict] = []
         last_err: Exception | None = None
@@ -1324,7 +1320,7 @@ class RouterServer:
 
     def _hedge_delay_s(self, b: Backend, remaining: float) -> float | None:
         """None disables hedging for this hop."""
-        cfg_ms = float(getattr(self.config, "router_hedge_ms", 0.0))
+        cfg_ms = float(self.config.router_hedge_ms)
         if cfg_ms < 0 or len(self.backends) < 2:
             return None
         # Hedging is a TAIL-LATENCY tool, not an overload tool: a
@@ -1333,7 +1329,7 @@ class RouterServer:
         # every request), which is how hedged routers melt down under
         # load. At or beyond the admission ladder's first step, every
         # hop flies solo.
-        n = int(getattr(self.config, "query_max_inflight", 0) or 0)
+        n = int(self.config.query_max_inflight or 0)
         if n and self.admission.inflight_queries >= n:
             return None
         if cfg_ms > 0:

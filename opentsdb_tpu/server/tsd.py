@@ -167,7 +167,7 @@ class TSDServer:
         self.tsdb = tsdb
         if executor is None:
             mesh = None
-            shape = getattr(tsdb.config, "mesh_shape", "") or ""
+            shape = tsdb.config.mesh_shape or ""
             if shape:
                 from opentsdb_tpu.parallel.plan import build_mesh
 
@@ -188,8 +188,7 @@ class TSDServer:
         # still DECLARES the decline (plan: "expert-decline",
         # mesh.expert.decline{reason=no-mesh}) instead of silently
         # serving serially, so a misconfigured fleet is visible.
-        self.expert_enabled = bool(
-            getattr(self.config, "expert_parallel", False))
+        self.expert_enabled = bool(self.config.expert_parallel)
         if self.config.cachedir:
             # The /q disk cache writes <hash>.txt.tmp files here; create
             # the directory up front so a fresh --cachedir works without
@@ -222,8 +221,7 @@ class TSDServer:
         # ingests the /stats snapshot into the store itself as tsd.*
         # series every selfmon_interval_s (0 = off — constructed
         # anyway so tests can run_once() deterministically).
-        self.trace_ring = TraceRing(
-            getattr(self.config, "trace_ring", 256))
+        self.trace_ring = TraceRing(self.config.trace_ring)
         # 1-in-N ambient trace sampling counter (Config.trace_sample_n).
         self._trace_sample_seq = 0
         # Per-plan serve counters (raw / resident / fused / rollup /
@@ -232,8 +230,7 @@ class TSDServer:
         self.plan_counts: dict[str, int] = {}
         from opentsdb_tpu.obs.selfmon import SelfMonitor
         self.selfmon = SelfMonitor(
-            tsdb, self._collect_stats,
-            getattr(self.config, "selfmon_interval_s", 0.0))
+            tsdb, self._collect_stats, self.config.selfmon_interval_s)
         # Serve tier (opentsdb_tpu/serve/): admission control runs on
         # every daemon (all knobs default off); the WAL tailer is
         # attached by the CLI for --role replica daemons and owns the
@@ -915,7 +912,7 @@ class TSDServer:
             body = self.tailer.health()
         else:
             body = {
-                "role": getattr(self.config, "role", "writer"),
+                "role": self.config.role,
                 "ok": True,
                 "read_only": bool(getattr(self.tsdb.store, "read_only",
                                           False)),
@@ -1059,14 +1056,13 @@ class TSDServer:
             # it was — still tailing. The bump is durable; crash
             # after it leaves an epoch with no acting writer, and the
             # next promotion attempt bumps past it.
-            owner = (getattr(self.config, "cluster_owner", None)
+            owner = (self.config.cluster_owner
                      or f"{self.config.bind}:{self.config.port}")
             new = _ep.bump_epoch(path, owner=owner, expect=expect)
             _fault("cluster.promote.bumped", path)
             guard = _ep.EpochGuard(
                 path, new,
-                interval_s=getattr(self.config,
-                                   "epoch_check_interval_s", 0.05))
+                interval_s=self.config.epoch_check_interval_s)
             tailer, self.tailer = self.tailer, None
             if tailer is not None:
                 # The tailer is the replica's only refresh driver; it
@@ -1089,7 +1085,7 @@ class TSDServer:
             # configured with (0 = manual/shutdown checkpoints only,
             # the plain-writer default).
             self.tsdb.compactionq.checkpoint_interval = \
-                getattr(self.config, "checkpoint_interval", 0.0) or 0.0
+                self.config.checkpoint_interval or 0.0
             LOG.warning("promoted to writer at epoch %d", new)
             return new
 
@@ -1124,7 +1120,7 @@ class TSDServer:
                 return  # a concurrent demote won the race; idempotent
             self.tsdb.demote()
             self.config.role = "replica"
-            if not getattr(self.config, "max_staleness_ms", 0.0):
+            if not self.config.max_staleness_ms:
                 # The staleness contract defaults ON for replicas (the
                 # cmd_tsd replica-role default) — a demoted daemon
                 # serves under the same promise as a born replica.
@@ -1320,7 +1316,7 @@ class TSDServer:
         acct = getattr(self.tsdb, "tenants", None)
         if acct is None:
             body = {"enabled": False,
-                    "role": getattr(self.config, "role", "writer")}
+                    "role": self.config.role}
             return (200, "application/json",
                     json.dumps(body).encode(), {})
         body = acct.snapshot_info(
@@ -1518,14 +1514,14 @@ class TSDServer:
         # bookkeeping is pure overhead when the goal is staying up.
         want_trace = (q.get("trace", "0") not in ("", "0")
                       and not degrade)
-        slow_ms = float(getattr(self.config, "slow_query_ms", 0) or 0)
+        slow_ms = float(self.config.slow_query_ms or 0)
         # Ambient 1-in-N trace sampling (Config.trace_sample_n): every
         # Nth query is traced into the ring even when nobody asked and
         # nothing is slow, so the traces BETWEEN incidents exist when
         # a slow-query record needs a baseline to compare against.
         # Sampled traces keep normal caching (a disk-cache hit simply
         # isn't traced — the baseline is of executed queries).
-        sample_n = int(getattr(self.config, "trace_sample_n", 0) or 0)
+        sample_n = int(self.config.trace_sample_n or 0)
         sampled = False
         if sample_n > 0 and not degrade and not want_trace:
             self._trace_sample_seq += 1
@@ -1559,8 +1555,7 @@ class TSDServer:
         approx_on = (q.get("approx", "0") not in ("", "0")
                      or max_error is not None)
         if degrade and max_error is None:
-            cfg_budget = float(getattr(self.config,
-                                       "degrade_max_error", 0) or 0)
+            cfg_budget = float(self.config.degrade_max_error or 0)
             max_error = cfg_budget if cfg_budget > 0 else None
         aspec = ApproxSpec(approx_on, max_error)
         # An explicitly traced request bypasses the /q disk cache both
@@ -1795,7 +1790,7 @@ class TSDServer:
         else:
             max_age = 300
         if (self.tailer is not None
-                and getattr(self.config, "max_staleness_ms", 0) > 0):
+                and self.config.max_staleness_ms > 0):
             # Staleness-contract replicas: a disk-cache hit adds its
             # age to the answer's staleness, so cap it at the contract
             # bound — the cache can never make a fresh replica serve
@@ -1928,7 +1923,7 @@ class TSDServer:
             # The streaming estimate is an HLL — declare it under the
             # error contract like every other approximate answer.
             from opentsdb_tpu.sketch.bounds import hll_error
-            err = hll_error(getattr(self.config, "sketch_hll_p", 12), n)
+            err = hll_error(self.config.sketch_hll_p, n)
             body = json.dumps({
                 "metric": q["metric"], "tagk": q["tagk"], "distinct": n,
                 "source": "stream",

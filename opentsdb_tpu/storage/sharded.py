@@ -5,10 +5,9 @@ partitioning on the metric-first row key (reference
 src/core/IncomingDataPoints.java); this engine funneled every write
 through one ``MemKVStore`` — one memtable lock, one WAL, one sstable
 generation tier — so at the 1B+ scale the checkpoint spill/merge of the
-WHOLE history became the single largest ingest stall
-(``BENCH_SCALE_2000M.json``: 807 s of a 1207 s wall in
-checkpoint.spill + checkpoint.wait + kv.put_batch, with single 177 s
-pauses when a tiered collapse landed).
+WHOLE history is one stall that every writer waits behind
+(checkpoint.spill + checkpoint.wait + kv.put_batch, and one long pause
+whenever a tiered collapse lands).
 
 ``ShardedKVStore`` partitions rows by a stable hash of the row key's
 SERIES identity (metric UID + tag UID pairs — the base-time bytes are

@@ -1,11 +1,10 @@
 """Garbage-collector tuning for sustained ingest.
 
-Measured motivation (10M-point sustained-ingest attribution run, r04):
-the memtable holds millions of long-lived container objects (one dict
-per row-hour plus key bytes), and CPython's generational collector
-rescans them on every gen2 pass — 8.5 s of a 22 s / 10M-point run,
-turning 740k dps into 454k. None of it is reclaimable: the memtable is
-alive by design until a checkpoint spills it.
+Motivation: the memtable holds millions of long-lived container
+objects (one dict per row-hour plus key bytes), and CPython's
+generational collector rescans them on every gen2 pass. None of it is
+reclaimable: the memtable is alive by design until a checkpoint spills
+it.
 
 ``tune_for_ingest`` moves the current heap (the replayed WAL + loaded
 sstable index + interpreter) into the permanent generation and pushes
@@ -19,7 +18,7 @@ gen2 passes far out. This is safe for this workload shape:
 - a higher gen0 threshold trades a little young-object latency for
   far fewer passes over the (large) old heap's remembered sets.
 
-Call it once at daemon/bench startup after the stores are initialised
+Call it once at daemon startup after the stores are initialised
 (so the replayed state lands in the permanent generation). Idempotent;
 calling again after a large load (e.g. WAL replay) re-freezes the
 survivors.

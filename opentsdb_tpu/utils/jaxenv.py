@@ -1,6 +1,6 @@
 """Where JAX runs and where it keeps compiled programs — decided once.
 
-Two facts every entry point (the daemon, bench.py, the scripts) must
+Two facts every entry point (the daemon, the scripts) must
 settle before its first JAX call, kept here so they are settled the
 same way everywhere:
 

@@ -1,10 +1,10 @@
 #!/usr/bin/env python
 """Hostile-workload harness: adversarial scenario legs with correctness
-gates, feeding BENCH_HOSTILE.json.
+gates; the report goes to ``--json``.
 
-Every perf number in this repo is benched on uniform synthetic series;
-these legs are the other half of the story — the workloads a hostile
-(or merely broken) tenant actually sends:
+The benchmark's cells send uniform synthetic series; these legs are
+the other half of the story — the workloads a hostile (or merely
+broken) tenant actually sends:
 
   cardinality  millions of DISTINCT series: directory / UID / bloom /
                sketch-slot pressure, per-tenant accounting parity

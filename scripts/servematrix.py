@@ -782,21 +782,77 @@ def scenario_promote_crash(dep: Deployment, seed: int) -> dict:
     return {"problems": problems, "fingerprint_parts": []}
 
 
-def _wait_stats_value(port: int, name: str, want: float,
-                      timeout: float = 60.0) -> bool:
+def _stats(port: int) -> dict[str, float]:
+    """One ``/stats`` snapshot as {name: value} (a name exported under
+    several tags keeps its last line)."""
+    _, _, body = http_get(port, "/stats", timeout=5)
+    out: dict[str, float] = {}
+    for ln in body.decode("utf-8", "replace").splitlines():
+        parts = ln.split()
+        if len(parts) >= 3:
+            try:
+                out[parts[0]] = float(parts[2])
+            except ValueError:
+                pass
+    return out
+
+
+def _wait_stats(port: int, pred, timeout: float = 60.0) -> bool:
+    """Poll ``/stats`` until ``pred(snapshot)`` holds."""
     deadline = time.time() + timeout
     while time.time() < deadline:
         try:
-            _, _, body = http_get(port, "/stats", timeout=5)
-            for ln in body.decode("utf-8", "replace").splitlines():
-                parts = ln.split()
-                if len(parts) >= 3 and parts[0] == name:
-                    if float(parts[2]) == want:
-                        return True
+            if pred(_stats(port)):
+                return True
         except Exception:
             pass
-        time.sleep(0.2)
+        time.sleep(0.1)
     return False
+
+
+def _folded(s: dict[str, float]) -> bool:
+    return (s.get("tsd.dirty_set.size") == 0
+            and s.get("tsd.rollup.ready") == 1)
+
+
+def _wait_replica_folded(port: int, timeout: float = 60.0) -> bool:
+    """Wait until the replica at ``port`` serves the writer's folded
+    state. Call it once the writer reads ``_folded``: the rows have
+    left its memtable and their fold is durable or about to be (a
+    replica that reads the state file before it is finds the tier
+    pending, and is not ready). Two things must have happened since:
+
+    - a tail cycle that STARTED after that moment has ended (two more
+      completed cycles than the count read now: the first of them may
+      have been under way already). Before it the replica may not
+      have replayed the ingest at all, and then it reads ``_folded``
+      too: no rows, and the empty tier it adopted at boot;
+    - a cycle has ended with the raw view clean and the tier ready
+      from its first moment on. ``refresh_replica`` refreshes the raw
+      store first and the tier after it, so a snapshot taken between
+      the two shows the new raw view over the tier's old capture.
+
+    Until both hold the replica sheds a rollup-only pNN query with
+    503, which is the declared ladder and not what this scenario is
+    about."""
+    # Completed tail cycles: at the first look, and at the look that
+    # first found the replica folded since it last was not.
+    start = seen = None
+
+    def caught_up(s: dict[str, float]) -> bool:
+        nonlocal start, seen
+        n = s["tsd.replica.refreshes"]
+        if start is None:
+            start = n
+        if not _folded(s):
+            seen = None
+        elif seen is None:
+            seen = n
+        else:
+            return n > seen and n >= start + 2
+        return False
+
+    return _wait_stats(port, caught_up, timeout)
 
 
 def scenario_degraded_approx(dep: Deployment, seed: int) -> dict:
@@ -809,18 +865,16 @@ def scenario_degraded_approx(dep: Deployment, seed: int) -> dict:
     metric = "deg.p95.m"
     n = 360  # six 1h windows of minutely points
     dep.ingest_acked(metric, n, BT, seed % 89)
-    # Quiesce: the writer's 2 s checkpoint timer folds the tier, the
-    # replicas adopt it read-only via the tailer.
-    if not _wait_stats_value(dep.ports["writer"],
-                             "tsd.rollup.ready", 1):
-        problems.append("writer rollup tier never became ready")
-    if not _wait_stats_value(dep.ports["writer"],
-                             "tsd.dirty_set.size", 0):
-        problems.append("writer never quiesced (dirty windows left)")
-    for rep in ("replica-a", "replica-b"):
-        if not _wait_stats_value(dep.ports[rep],
-                                 "tsd.rollup.ready", 1):
-            problems.append(f"{rep} rollup tier never became ready")
+    # Quiesce: the writer's 2 s checkpoint timer spills the rows and
+    # folds the tier; each replica then has to tail that state.
+    if not _wait_stats(dep.ports["writer"], _folded):
+        problems.append("writer never quiesced (dirty windows left, "
+                        "or its rollup tier not ready)")
+    else:
+        for rep in ("replica-a", "replica-b"):
+            if not _wait_replica_folded(dep.ports[rep]):
+                problems.append(f"{rep} never tailed the writer's "
+                                f"fold")
     if problems:
         return {"problems": problems, "fingerprint_parts": []}
     m = f"max:1h-p95:{metric}"

@@ -124,20 +124,29 @@ def test_masked_quantile_radix_parity():
     _assert_tree_close(got, want)
 
 
-def test_window_query_parity():
-    """The whole resident-window fused query — the devwindow hot path —
-    in one jit on the chip vs CPU."""
+def _window_stage_apply(ts, vals, sid, valid, include, gmap):
+    half = len(ts) // 2
+    chunks = [tuple(jnp.asarray(c[s]) for c in (ts, vals, sid, valid))
+              for s in (slice(0, half), slice(half, None))]
+    grids = kernels.window_series_stage_chunks(
+        chunks, np.int32(0), np.int32(48 * 600), np.int32(0),
+        num_series=64, num_buckets=48, interval=600, agg_down="avg")
+    gv, gm = kernels.window_moment_apply(
+        *grids[:4], include, gmap, num_groups=4, agg_group="sum")
+    return gv, gm, grids[4]
+
+
+def test_window_stage_apply_parity():
+    """The resident-window query as it is served: the chunked stage
+    (two chunks, each folded whole) and the moment apply, on the chip
+    vs CPU."""
     ts, vals, sid, valid = _flat(5, n=50_000)
     include = np.ones(64, bool)
     include[60:] = False
     gmap = (np.arange(64, dtype=np.int32) % 3)
-    kw = dict(num_series=64, num_groups=4, num_buckets=48, interval=600,
-              agg_down="avg", agg_group="sum")
-    args = (ts, vals, sid, valid, include, gmap,
-            np.int32(0), np.int32(48 * 600), np.int32(0))
-    got = _tpu(kernels.window_query, *args, **kw)
-    want = _cpu(kernels.window_query, *args, **kw)
-    _assert_tree_close(got, want)
+    args = (ts, vals, sid, valid, include, gmap)
+    _assert_tree_close(_tpu(_window_stage_apply, *args),
+                       _cpu(_window_stage_apply, *args))
 
 
 def test_flat_rate_counter_wrap_parity():
