@@ -988,7 +988,8 @@ class TSDB:
     def scan_series(self, start_key: bytes, stop_key: bytes,
                     key_regexp: bytes | None = None,
                     batch_cells: int = 1 << 18,
-                    series_hint=None, counts: dict | None = None):
+                    series_hint=None, counts: dict | None = None,
+                    series_keys=None):
         """Whole-range columnar scan regrouped BY SERIES in vectorized
         passes: returns (series_keys, per_series Columns dict) with one
         global (series, timestamp) lexsort + one vectorized dedup pass
@@ -1000,7 +1001,8 @@ class TSDB:
         whole-range numpy ops. Duplicate (series, ts) points collapse
         when value-equal and raise IllegalDataError otherwise —
         sort_dedup's rule (reference complexCompact :600-679).
-        ``counts``, when given, has the rows read added under "rows"."""
+        ``counts``, when given, has the rows read added under "rows".
+        ``series_hint`` / ``series_keys``: see KVStore.scan_raw."""
         from opentsdb_tpu.core.errors import IllegalDataError
         rows = 0
         quals: list[bytes] = []
@@ -1021,7 +1023,7 @@ class TSDB:
         for key, items in self.store.scan_raw(
                 self.table, start_key, stop_key,
                 family=FAMILY, key_regexp=key_regexp,
-                series_hint=series_hint):
+                series_hint=series_hint, series_keys=series_keys):
             rows += 1
             base = codec.key_base_time(key)
             skey = codec.series_key(key)

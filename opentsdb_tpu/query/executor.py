@@ -198,10 +198,10 @@ class QueryExecutor:
             int(cfg.qcache_fragments),
             int(cfg.qcache_points))
         # Candidate-series hint per (metric, filter): identity hashes
-        # from the sketch directory, revalidated on the metric's
-        # directory growth; cost-bounded in total cached hashes (an
-        # unfiltered hint for a high-cardinality metric is a multi-MB
-        # array).
+        # and the series keys they were made from, out of the sketch
+        # directory, revalidated on the metric's directory growth;
+        # cost-bounded in total cached series (an unfiltered hint for
+        # a high-cardinality metric is a multi-MB array and key list).
         self._ident_cache = LRUCache(256, max_cost=1 << 21)
         # Devwindow caches (previously ad-hoc dicts with wholesale
         # clear-at-cap eviction).
@@ -328,22 +328,27 @@ class QueryExecutor:
     # -- fragment cache (the query fast path) --------------------------
 
     def _series_hint(self, metric_uid: bytes, exact, group_bys,
-                     ) -> np.ndarray | None:
-        """uint64 identity hashes of every KNOWN series matching the
-        selector — a pruning hint for the storage fan-out (shard
-        routing + per-generation series blooms). Sourced from the
-        streaming-sketch slot directory, which the WRITER's ingest
+                     ) -> dict:
+        """Every KNOWN series matching the selector, as the keyword
+        arguments scan_series hands the store: ``series_hint``, their
+        uint64 identity hashes, which prune the storage fan-out (shard
+        routing + per-generation series blooms), and ``series_keys``,
+        which let a selective scan seek its rows by point lookup in
+        place of listing the range (MemKVStore.scan_raw). Sourced from
+        the streaming-sketch slot directory, which the WRITER's ingest
         path keeps a complete superset of series with stored data
         (TSDB.add_batch/add_point register via note_series BEFORE the
-        put, so no query can observe stored rows the directory lacks).
-        None — absence of a hint never prunes — when sketches are
-        disabled, nothing matches, or the store is a read-only
-        replica: a replica's directory reloads only on checkpoint
-        rebuilds, so it can lag WAL-suffix-replayed new series by a
-        whole checkpoint interval."""
+        put, so no query can observe stored rows the directory lacks):
+        the pruning and the seek both rest on that and on nothing
+        weaker. Empty — absence of a hint never prunes, and the
+        scan walks — when sketches are disabled, nothing matches,
+        or the store is a read-only replica: a replica's directory
+        reloads only on checkpoint rebuilds, so it can lag
+        WAL-suffix-replayed new series by a whole checkpoint
+        interval."""
         sk = getattr(self.tsdb, "sketches", None)
         if sk is None or getattr(self.tsdb.store, "read_only", False):
-            return None
+            return {}
         fkey = (metric_uid, _filter_key(exact, group_bys))
         # Revalidate on THIS metric's directory size (monotonic): a new
         # series under another metric leaves the cached hint valid, and
@@ -354,11 +359,13 @@ class QueryExecutor:
             return ent[1]
         regexp = self._build_regexp(exact, group_bys, prefix=UID_WIDTH)
         pattern = re.compile(regexp, re.S) if regexp else None
-        hashes = [series_hash(k) for k in sk.metric_series_keys(metric_uid)
-                  if pattern is None or pattern.match(k)]
-        hint = np.asarray(hashes, np.uint64) if hashes else None
+        keys = [k for k in sk.metric_series_keys(metric_uid)
+                if pattern is None or pattern.match(k)]
+        hint = {"series_hint": np.asarray(
+                    [series_hash(k) for k in keys], np.uint64),
+                "series_keys": keys} if keys else {}
         self._ident_cache.put(fkey, (count, hint),
-                              cost=max(len(hashes), 1))
+                              cost=max(len(keys), 1))
         return hint
 
     def _scan_chunk(self, metric_uid: bytes, regexp, hint,
@@ -368,8 +375,8 @@ class QueryExecutor:
         start_key = metric_uid + _u32(c_lo)
         stop_key = metric_uid + _u32(min(c_hi, 0xFFFFFFFF))
         return self.tsdb.scan_series(start_key, stop_key,
-                                     key_regexp=regexp,
-                                     series_hint=hint, counts=info)[1]
+                                     key_regexp=regexp, counts=info,
+                                     **hint)[1]
 
     def _scan_selector(self, metric_uid: bytes, exact, group_bys,
                        regexp, start: int, end: int,
@@ -408,8 +415,8 @@ class QueryExecutor:
                 min(b_hi + MAX_TIMESPAN, 0xFFFFFFFF))
             with obs_trace.span("chunk.decode", outcome="unchunked"):
                 return tsdb.scan_series(start_key, stop_key,
-                                        key_regexp=regexp,
-                                        series_hint=hint, counts=info)[1]
+                                        key_regexp=regexp, counts=info,
+                                        **hint)[1]
 
         chunk_s = int(cfg.qcache_chunk_s or 0)
         chunk_s -= chunk_s % MAX_TIMESPAN

@@ -53,7 +53,8 @@ from typing import Iterator, NamedTuple
 
 import numpy as np
 
-from opentsdb_tpu.core.const import TIMESTAMP_BYTES, UID_WIDTH
+from opentsdb_tpu.core.const import (MAX_TIMESPAN, TIMESTAMP_BYTES,
+                                     UID_WIDTH)
 from opentsdb_tpu.core.errors import (PleaseThrottleError,
                                        ReadOnlyStoreError)
 from opentsdb_tpu.fault.faultpoints import fire as _fault
@@ -80,6 +81,11 @@ _M_GRP_BATCHES = _metrics.counter("wal.group.batches")
 _M_GRP_POINTS = _metrics.counter("wal.group.points")
 _M_GRP_FSYNCS = _metrics.counter("wal.group.fsyncs")
 _M_GRP_WAIT = _metrics.timer("wal.group.wait_ms")
+# Selective scans (scan_raw with a key regexp) by how they found their
+# rows: point lookups of the candidate keys, or the walk over every key
+# of the range. Their sum is the count of such scans.
+_M_SCAN_SEEK = _metrics.counter("scan.seek")
+_M_SCAN_WALK = _metrics.counter("scan.walk")
 # Observed through _trace.timed (a timer and a profiler annotation of
 # the same name); registered here so that /stats lists them from boot.
 for _ph in ("freeze", "spill", "commit"):
@@ -93,6 +99,13 @@ for _ph in ("freeze", "spill", "commit"):
 # simply not indexed — matching the sweep's own filter.
 _BASE_LO = UID_WIDTH
 _BASE_HI = UID_WIDTH + TIMESTAMP_BYTES
+# A selective scan seeks where its probes (candidate keys x tiers to
+# bisect) times this margin are no more than the keys the walk would
+# list. A probe that finds nothing costs about what a listed key does (a
+# bisect against a few membership tests and a regexp match), so with
+# the margin a seek is the cheaper way even if every probe misses, and
+# a selector that names most of the range (host=*) stays on the walk.
+_SEEK_MARGIN = 4
 
 
 class Cell(NamedTuple):
@@ -175,6 +188,7 @@ class KVStore:
                  family: bytes | None = None,
                  key_regexp: bytes | None = None,
                  series_hint: "np.ndarray | None" = None,
+                 series_keys: "list[bytes] | None" = None,
                  ) -> Iterator[tuple[bytes, list[tuple[bytes, bytes]]]]:
         """Scan for bulk decode: (key, [(qualifier, value), ...]) rows,
         qualifiers sorted — no Cell objects. Default adapts scan();
@@ -186,7 +200,14 @@ class KVStore:
         hashes (sstable.series_hash) that is a SUPERSET of the series
         the caller will keep — a pure pruning hint. Stores may use it
         to skip sstable generations (bloom prefilter) or whole shards
-        (routing); ignoring it is always correct."""
+        (routing); ignoring it is always correct.
+
+        ``series_keys``: optional, with a ``key_regexp`` over a
+        metric's base hours: the series keys (core/codec.series_key)
+        the regexp matches, again a SUPERSET of those with stored rows
+        in the range. A store may read their rows by point lookup in
+        place of listing and filtering the range; ignoring it is
+        always correct, and the rows that come back are the same."""
         for cells in self.scan(table, start, stop, family=family,
                                key_regexp=key_regexp):
             yield cells[0].key, [(c.qualifier, c.value) for c in cells]
@@ -379,6 +400,17 @@ class _Table:
             if k in rows:
                 out.append(k)
         return out
+
+    def range_count(self, start: bytes, stop: bytes | None) -> int:
+        """Upper bound on len(range_keys(start, stop)) from the same
+        bisects, listing nothing (stale keys, cross-duplicates and the
+        unsorted pending inserts all count). Caller holds the store
+        lock."""
+        n = len(self.pending)
+        for run in (self.base, self.delta):
+            hi = bisect_left(run, stop) if stop else len(run)
+            n += hi - bisect_left(run, start)
+        return n
 
 
 # WAL opcodes
@@ -2739,10 +2771,50 @@ class MemKVStore(KVStore):
             if cells:
                 yield cells
 
+    def _seek_keys(self, table: str, start: bytes, stop: bytes,
+                   series_keys: "list[bytes]",
+                   skip_paths: "set[str] | None",
+                   ) -> list[bytes] | None:
+        """The row keys a selective scan of [start, stop) can find, in
+        key order, formed without listing the range: each of
+        ``series_keys`` under each base hour of the range (a row key is
+        metric + u32(base hour) + tags, core/codec.row_key). None where
+        the walk is the cheaper way or the bounds are not a metric's
+        base hours. Caller holds the lock.
+
+        Which way is decided by what the tiers say of themselves: two
+        bisects a tier count the keys the walk would list, and a
+        candidate costs a bisect in every tier it is probed in."""
+        if not (len(start) == len(stop) == _BASE_HI
+                and start[:_BASE_LO] == stop[:_BASE_LO]):
+            return None
+        metric = start[:_BASE_LO]
+        lo = int.from_bytes(start[_BASE_LO:], "big")
+        hi = int.from_bytes(stop[_BASE_LO:], "big")
+        bases = range(lo + -lo % MAX_TIMESPAN, hi, MAX_TIMESPAN)
+        listed = self._table(table).range_count(start, stop)
+        tiers = 1
+        ft = self._frozen.get(table) if self._frozen else None
+        if ft is not None:
+            listed += ft.range_count(start, stop)
+            tiers += 1
+        for sst in self._ssts:
+            if not (skip_paths and sst.path in skip_paths):
+                listed += sst.range_count(table, start, stop)
+                tiers += 1
+        if len(bases) * len(series_keys) * tiers * _SEEK_MARGIN > listed:
+            return None
+        tails = sorted({k[_BASE_LO:] for k in series_keys
+                        if k[:_BASE_LO] == metric})
+        heads = [metric + b.to_bytes(TIMESTAMP_BYTES, "big")
+                 for b in bases]
+        return [h + t for h in heads for t in tails]
+
     def scan_raw(self, table: str, start: bytes, stop: bytes,
                  family: bytes | None = None,
                  key_regexp: bytes | None = None, chunk: int = 1024,
                  series_hint: "np.ndarray | None" = None,
+                 series_keys: "list[bytes] | None" = None,
                  ) -> Iterator[tuple[bytes, list[tuple[bytes, bytes]]]]:
         """Batched form of scan() for the columnar decode path: rows as
         (key, sorted [(qualifier, value), ...]), the lock taken once per
@@ -2752,16 +2824,28 @@ class MemKVStore(KVStore):
         the single largest host cost of the cold query path (profiled:
         ~16 us/row, more than the vectorized decode itself).
 
-        ``series_hint`` (see KVStore.scan_raw) prunes generations whose
-        series bloom excludes every candidate — on a high-file-count
-        store most generations hold disjoint time ranges OF THE SAME
-        series, but tag-filtered dashboards and sparse metrics leave
-        whole generations with nothing to say. Skips are decided ONCE
-        per scan against the then-current generation set and matched
-        by path thereafter: a generation swapped in mid-scan is simply
-        not skipped (conservative), and one dropped mid-scan vanishes
-        from self._ssts like any other scan."""
-        pattern = re.compile(key_regexp, re.S) if key_regexp else None
+        ``series_hint`` and ``series_keys`` (see KVStore.scan_raw) both
+        rest on the caller's list being a superset of the series with
+        stored rows that ``key_regexp`` matches, which the writer's
+        series directory gives and a replica's does not.
+
+        The hint prunes generations whose series bloom excludes every
+        candidate — on a high-file-count store most generations hold
+        disjoint time ranges OF THE SAME series, but tag-filtered
+        dashboards and sparse metrics leave whole generations with
+        nothing to say. Skips are decided ONCE per scan against the
+        then-current generation set and matched by path thereafter: a
+        generation swapped in mid-scan is simply not skipped
+        (conservative), and one dropped mid-scan vanishes from
+        self._ssts like any other scan.
+
+        The keys select the rows: where they are few beside the keys
+        of the range (_seek_keys), the scan probes each series under
+        each base hour through the tier merge and neither lists the
+        range nor applies the regexp; a candidate no tier holds reads
+        as a deleted row does. Otherwise, and always without them, it
+        walks the range and filters. Either way the same rows come
+        back in the same order."""
         with self._lock:
             skip_paths: set[str] | None = None
             if series_hint is not None and len(series_hint) \
@@ -2773,9 +2857,27 @@ class MemKVStore(KVStore):
                         self.bloom_files_skipped += 1
                 if not skip_paths:
                     skip_paths = None
-            keys = self._snapshot_keys(table, start, stop, skip_paths)
-        if pattern is not None:
-            keys = [k for k in keys if pattern.match(k)]
+            keys = None
+            if key_regexp and series_keys is not None:
+                keys = self._seek_keys(table, start, stop, series_keys,
+                                       skip_paths)
+            seek = keys is not None
+            if not seek:
+                keys = self._snapshot_keys(table, start, stop,
+                                           skip_paths)
+        if key_regexp:
+            (_M_SCAN_SEEK if seek else _M_SCAN_WALK).inc()
+            sp = _trace.current_span()
+            if sp is not None:
+                # Summed, because a sharded store's fan-out lands on
+                # one span; 0/1 and one scan's candidates otherwise.
+                t = sp.tags
+                t["seek"] = t.get("seek", 0) + seek
+                t["probes"] = t.get("probes", 0) + (len(keys) if seek
+                                                    else 0)
+            if not seek:
+                pattern = re.compile(key_regexp, re.S)
+                keys = [k for k in keys if pattern.match(k)]
         for i in range(0, len(keys), chunk):
             out = []
             with self._lock:
@@ -2798,7 +2900,7 @@ class MemKVStore(KVStore):
                         if items:
                             items.sort()
                             out.append((key, items))
-                elif pattern is not None:
+                elif key_regexp:
                     # Selective regexp scans touch few rows: per-key
                     # merged reads beat extracting whole key ranges
                     # that the filter would then discard.

@@ -61,6 +61,7 @@ from opentsdb_tpu.fault import faultpoints as _fp
 from opentsdb_tpu.obs import trace as _trace
 from opentsdb_tpu.obs.registry import METRICS as _metrics
 from opentsdb_tpu.storage.kv import Cell, KVStore, MemKVStore
+from opentsdb_tpu.storage.sstable import series_hash
 
 MANIFEST_NAME = "SHARDS.json"
 
@@ -385,39 +386,41 @@ class ShardedKVStore(KVStore):
     def scan_raw(self, table: str, start: bytes, stop: bytes,
                  family: bytes | None = None,
                  key_regexp: bytes | None = None,
-                 series_hint=None,
+                 series_hint=None, series_keys=None,
                  ) -> Iterator[tuple[bytes, list[tuple[bytes, bytes]]]]:
         """Fan-in scan; with a ``series_hint`` (uint64 series-identity
         hashes, a superset of the series the caller keeps) the fan-out
         first drops shards no candidate routes to — the routing hash
         IS the identity hash (sstable.series_hash), so ``h % N`` is
         exact, not probabilistic — then each shard's own series blooms
-        prune generations."""
-        shards = self.shards
-        if (series_hint is not None and len(series_hint)
-                and table == self.data_table and self.shard_count > 1):
-            live = np.unique(series_hint
-                             % np.uint64(self.shard_count)).tolist()
-            if len(live) < self.shard_count:
-                self.bloom_shards_skipped += \
-                    self.shard_count - len(live)
-                shards = [self.shards[int(i)] for i in live]
-        its = [s.scan_raw(table, start, stop, family=family,
-                          key_regexp=key_regexp,
-                          series_hint=series_hint) for s in shards]
+        prune generations. ``series_keys`` go to the shards by the
+        same routing: each seeks, or walks, its own rows."""
+        n = self.shard_count
+        live = list(range(n))
+        keys_of = [series_keys] * n
+        if table == self.data_table and n > 1:
+            if series_hint is not None and len(series_hint):
+                live = np.unique(series_hint % np.uint64(n)).tolist()
+                self.bloom_shards_skipped += n - len(live)
+            if series_keys is not None:
+                keys_of = [[] for _ in range(n)]
+                for k in series_keys:
+                    keys_of[series_hash(k) % n].append(k)
+        its = [self.shards[i].scan_raw(
+            table, start, stop, family=family, key_regexp=key_regexp,
+            series_hint=series_hint, series_keys=keys_of[i])
+            for i in live]
         parent = _trace.current_span()
         if parent is not None:
             # Per-shard fan-out spans: each shard's span accumulates
             # only the time spent pulling from THAT shard's iterator
             # (the heap merge interleaves them), attached to the span
             # current at fan-out time when its iterator is exhausted.
-            idx_of = {id(s): i for i, s in enumerate(self.shards)}
             its = [_trace.timed_iter(it, parent, "shard.scan",
-                                     {"shard": idx_of[id(s)]})
-                   for it, s in zip(its, shards)]
-            if len(shards) < self.shard_count:
-                parent.tags["shards_skipped"] = (
-                    self.shard_count - len(shards))
+                                     {"shard": i})
+                   for it, i in zip(its, live)]
+            if len(live) < n:
+                parent.tags["shards_skipped"] = n - len(live)
         return heapq.merge(*its, key=lambda row: row[0])
 
     # -- memtable introspection (sketch recovery re-fold) ------------------

@@ -1162,6 +1162,16 @@ class SSTable:
         hi = bisect_left(keys, stop) if stop else len(keys)
         return keys[lo:hi]
 
+    def range_count(self, table: str, start: bytes,
+                    stop: bytes | None) -> int:
+        """len(scan_keys(table, start, stop)) without the slice."""
+        idx = self._index.get(table)
+        if not idx:
+            return 0
+        keys, _ = idx
+        hi = bisect_left(keys, stop) if stop else len(keys)
+        return hi - bisect_left(keys, start)
+
     def record_extents(self, table: str) -> tuple[
             "list[bytes]", "np.ndarray", "np.ndarray"]:
         """(sorted keys, record starts, record ends) for one table.
