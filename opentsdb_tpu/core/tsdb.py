@@ -115,6 +115,9 @@ class TSDB:
         # ingest mirrors into HBM so queries skip the host->device
         # upload. CPU-oracle deployments skip it (nothing to upload to).
         self.devwindow = None
+        # The boot refill's span (_warm_devwindow), as a dict: what a
+        # restart spent loading the window, and how much it loaded.
+        self.devwindow_refill: dict | None = None
         # A replica never ingests, so nothing would keep the window
         # (or its completeness bookkeeping) in sync with the writer's
         # appends arriving via store.refresh() — a boot-warmed window
@@ -212,17 +215,28 @@ class TSDB:
         window would claim coverage it doesn't have, and fsck must be
         able to run against exactly this data."""
         from opentsdb_tpu.core.errors import IllegalDataError
+        from opentsdb_tpu.storage import devstore
 
+        dw, points = self.devwindow, 0
+        sp = obs_trace.Span("devwindow.refill")
         try:
             for key, cols in self.scan_columns(b"", b"\xff" * 64):
                 if len(cols.timestamps) == 0:
                     continue
                 pr = codec.parse_row_key(key)
-                self.devwindow.append(pr.metric_uid,
-                                      codec.series_key(key),
-                                      cols.timestamps, cols.values)
+                dw.append(pr.metric_uid, codec.series_key(key),
+                          cols.timestamps, cols.values)
+                points += len(cols.timestamps)
         except IllegalDataError:
             self.devwindow = None
+        sp.ms = (time.perf_counter() - sp.t0) * 1000.0
+        # Chunks cut so far: the last of a metric's points may still be
+        # staged (a query of the metric uploads them).
+        sp.tags.update(points=points, chunks=devstore.chunks_cut(dw),
+                       seconds=round(sp.ms / 1000.0, 3))
+        self.devwindow_refill = sp.to_dict()
+        LOG.info("device window refilled: %d points in %d chunks, "
+                 "%.1f s", points, sp.tags["chunks"], sp.ms / 1000.0)
 
     # ------------------------------------------------------------------
     # Streaming sketches
@@ -1298,5 +1312,8 @@ class TSDB:
             self.tenants.collect_stats(collector)
         if self.devwindow is not None:
             self.devwindow.collect_stats(collector)
+        if self.devwindow_refill is not None:
+            collector.record("devwindow.refill.ms",
+                             self.devwindow_refill["ms"])
         if self.rollups is not None:
             self.rollups.collect_stats(collector)

@@ -557,6 +557,23 @@ def _chunk_stage_finish(count, total, m2, mn, mx, *, num_series,
                        num_buckets=num_buckets, rate=rate)
 
 
+# The largest grid (series x buckets, both as the executor pads them)
+# a resident stage is built for: 4,096 series at 4,096 buckets, or
+# 65,536 at 256. The executor's resident plan declines a request above
+# it (the scan path serves that one in per-group grids), and the
+# daemon's boot check keeps room for one such stage beside the window,
+# so what the check reserved for is what is served.
+STAGE_GRID_MAX = 1 << 24
+
+
+def stage_accumulator_bytes(cells: int = STAGE_GRID_MAX) -> int:
+    """What the chunked stage's accumulators hold on the device while
+    it folds a grid of ``cells``: the five float32 vectors
+    window_series_stage_chunks allocates (count, total, m2, min, max),
+    one segment a cell and the dump segment."""
+    return 5 * 4 * (cells + 1)
+
+
 def window_series_stage_chunks(chunks, lo, hi, shift, *, num_series,
                                num_buckets, interval, agg_down,
                                blocks=None, block=None,

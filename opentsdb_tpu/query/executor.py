@@ -83,6 +83,11 @@ _C_FOLD_SKIPPED = _metrics.counter("devwindow.fold.slots.skipped")
 # maps engages.
 _C_FOLD_NARROWED = _metrics.counter("devwindow.fold.stages.narrowed")
 _C_FOLD_WHOLE = _metrics.counter("devwindow.fold.stages.whole")
+# window.chunk_fold calls: a stage built dispatches one for every chunk
+# its selection picked a block of, so dispatches / devwindow.stage.miss
+# is the host's dispatches a stage, which grow with the span of the
+# range where the slots visited need not.
+_C_FOLD_DISPATCHES = _metrics.counter("devwindow.fold.dispatches")
 
 
 # What the raw plan read from storage and handed to its kernels: rows
@@ -983,10 +988,12 @@ class QueryExecutor:
             num_buckets = _pad_size(int((end - qbase) // interval + 1))
             S_all = len(cols.series_keys)
             S_pad = _pad_size(S_all)
-            if S_pad * num_buckets >= 2**31:
-                # The kernels' per-(series, bucket) segment ids are int32;
-                # a huge series-count x bucket-count product would wrap.
-                # Scan path handles it (per-group kernels, smaller grids).
+            if S_pad * num_buckets > kernels.STAGE_GRID_MAX:
+                # The largest grid the daemon kept room for beside the
+                # window at boot (tools/cli.py), and far under where the
+                # kernels' int32 per-(series, bucket) segment ids would
+                # wrap. Scan path handles it (per-group kernels, smaller
+                # grids).
                 return None
             gkeys = sorted(groups)
             G = _pad_size(len(gkeys))
@@ -1063,15 +1070,17 @@ class QueryExecutor:
             (_C_STAGE_MISS if stage is None else _C_STAGE_HIT).inc()
             if ssp is not None:
                 ssp.tags["hit"] = stage is not None
-                ssp.tags["chunks"] = len(_dw_chunks(cols))
                 ssp.tags["narrowed"] = narrowed
                 ssp.tags["series"] = len(sids)
             if stage is None:
                 (_C_FOLD_NARROWED if narrowed else _C_FOLD_WHOLE).inc()
-                picked, of, visited, resident = _dw_fold_extent(cols)
+                picked, of, visited, resident, dispatched = \
+                    _dw_fold_extent(cols)
                 _C_FOLD_VISITED.inc(visited)
                 _C_FOLD_SKIPPED.inc(resident - visited)
+                _C_FOLD_DISPATCHES.inc(dispatched)
                 if ssp is not None:
+                    ssp.tags["chunks"] = dispatched
                     ssp.tags["blocks"] = picked
                     ssp.tags["blocks_total"] = of
                 try:
@@ -2401,7 +2410,7 @@ def _dw_chunks(cols) -> list:
 def _dw_fold_extent(cols) -> tuple[int, ...]:
     """DevChunks.fold_extent() of a resident window's columns, summed
     over the sharded window's shards: (blocks picked, blocks in all,
-    slots picked, slots in all)."""
+    slots picked, slots in all, chunks dispatched)."""
     shards = getattr(cols, "shards", None)
     parts = [cols] if shards is None else filter(None, shards)
     return tuple(map(sum, zip(*(p.fold_extent() for p in parts))))

@@ -58,7 +58,7 @@ from typing import NamedTuple
 import numpy as np
 
 from ..fault import faultpoints
-from .devstore import DeviceWindow
+from .devstore import DeviceWindow, record_device_memory
 from .sstable import series_hash
 
 
@@ -415,10 +415,17 @@ class ShardedDeviceWindow:
                 if name in agg:
                     agg[name] += value
         sink = _Sink()
+        # A device runs out alone: devwindow.bytes is what the shards
+        # of the fullest device hold, and device.* are that device's.
+        held: dict = {}
         for s in shards:
-            s.collect_stats(sink)
+            s.collect_stats(sink, device=False)
+            held[s.device] = held.get(s.device, 0) + s._total_bytes
         for name, value in agg.items():
             collector.record(name, value)
+        fullest = max(held, key=held.get)
+        collector.record("devwindow.bytes", held[fullest])
+        record_device_memory(collector, fullest)
         collector.record("devwindow.hits", self.window_hits)
         collector.record("devwindow.misses", self.window_misses)
         collector.record("devwindow.misses.horizon", self.horizon_misses)

@@ -41,9 +41,17 @@ Design:
     boot refill scans metric by metric, and eviction by arrival would
     leave the first metrics scanned with nothing and the last whole.
   - deletes/fsck rewrites call ``invalidate``.
-- **Sizing.** ~12 B/point device-side: the 1B-point north-star workload is
-  ~12 GB — within one v5e chip's 16 GB HBM, which is exactly the design
-  point (BASELINE.json: 1B points, single chip serving).
+- **Sizing.** A slot is ``SLOT_BYTES`` = 13 B on the device (int32
+  time, float32 value, int32 series id, a validity byte) and a chunk's
+  columns are padded to a power of two. A chunk is cut when the staged
+  points reach ``staging_points``, a power of two itself, so it holds a
+  little more than that and pads to twice it: a resident point costs
+  ``POINT_BYTES`` = 26 B, half of it padding (the default budget of
+  1 << 26 points is 1.74 GB, 1 << 28 is 6.98 GB of a v5e chip's 16).
+  The window accounts what it holds (``devwindow.bytes`` in /stats) and
+  the daemon checks the budget against the device at boot
+  (``require_fits``). The zone maps and the series directory stay on
+  the host and cost the device nothing.
 
 No reference analog: HBase scans are the reference's only read path
 (src/core/TsdbQuery.java:240-285); this is the TPU-era replacement for
@@ -75,6 +83,63 @@ def _pad_pow2(n: int, lo: int = 1024) -> int:
 # the map is built with it at upload and DevChunks.block hands it to
 # the fold, which slices by it.
 ZONE_BLOCK = 1 << 16
+
+# What one slot of a chunk holds on the device: the four columns of
+# _upload (int32 rel_ts, float32 value, int32 sid, bool valid).
+SLOT_BYTES = 4 + 4 + 4 + 1
+# What one resident point can cost: _pad_pow2 pads a chunk of 2^k + 1
+# points to 2^(k+1) slots, and a chunk cut at ``staging_points`` = 2^k
+# staged points is such a chunk (the refill's are 1,048,680 points in
+# 2,097,152 slots).
+POINT_BYTES = 2 * SLOT_BYTES
+
+
+def window_bytes(max_points: int, staging_points: int) -> int:
+    """The most a window of this budget holds on the device: the budget
+    and, for the moment between an upload and the eviction that follows
+    it, one chunk more, with one further batch in the uploader's
+    hands, every point at ``POINT_BYTES``."""
+    return (max_points + 2 * staging_points) * POINT_BYTES
+
+
+def require_fits(max_points: int, staging_points: int, stage_bytes: int,
+                 bytes_limit: int | None) -> None:
+    """Refuse a budget the device cannot hold: ``window_bytes`` of it
+    beside ``stage_bytes`` (what a query's stage allocates next to the
+    window) against ``bytes_limit``, the device's own statement of its
+    memory. No statement (a CPU backend makes none), no check: an
+    allocation there is the host's to page."""
+    if bytes_limit is None:
+        return
+    need = window_bytes(max_points, staging_points) + stage_bytes
+    if need > bytes_limit:
+        fits = max((bytes_limit - stage_bytes) // POINT_BYTES
+                   - 2 * staging_points, 0)
+        raise ValueError(
+            f"a device window of {max_points:,} points needs "
+            f"{need:,} bytes on the device ({POINT_BYTES} B a resident "
+            f"point, chunks of {staging_points:,} points, "
+            f"{stage_bytes:,} B for a query's stage beside it) and the "
+            f"device has {bytes_limit:,}; the largest budget that fits "
+            f"is {fits:,} points")
+
+
+def record_device_memory(collector, device) -> None:
+    """The gauges ``device.bytes_limit`` / ``.bytes_in_use`` /
+    ``.peak_bytes_in_use``: what the device a window's chunks live on
+    says of its memory, asked now (beside ``devwindow.bytes``: the
+    window's share of it). A device that states no limit, as a CPU
+    backend's, records none."""
+    from opentsdb_tpu.utils import jaxenv
+
+    for name, value in (jaxenv.device_memory(device) or {}).items():
+        collector.record("device." + name, value)
+
+
+def chunks_cut(window) -> int:
+    """Chunks cut from staged points since ``window`` (plain or
+    sharded) began: uploaded or on their way, evicted ones included."""
+    return sum(s._seq for s in getattr(window, "_shards", (window,)))
 
 
 class DevColumns(NamedTuple):
@@ -176,11 +241,13 @@ class DevChunks(NamedTuple):
             return self
         return self._replace(blocks=blocks)
 
-    def fold_extent(self) -> tuple[int, int, int, int]:
+    def fold_extent(self) -> tuple[int, int, int, int, int]:
         """What the selection hands the fold: (blocks picked, blocks in
-        all, slots picked, slots in all). Slots count the chunks'
-        padding too: a skipped slot is skipped whatever it held."""
-        picked = of = visited = resident = 0
+        all, slots picked, slots in all, chunks with a block picked:
+        the stage dispatches one fold for each of those and none for
+        the others). Slots count the chunks' padding too: a skipped
+        slot is skipped whatever it held."""
+        picked = of = visited = resident = dispatched = 0
         for chunk, ids in zip(self.chunks, self.blocks):
             slots = int(chunk[0].shape[0])
             blk = min(self.block, slots)
@@ -188,14 +255,15 @@ class DevChunks(NamedTuple):
             of += slots // blk
             visited += len(ids) * blk
             resident += slots
-        return picked, of, visited, resident
+            dispatched += len(ids) > 0
+        return picked, of, visited, resident, dispatched
 
 
 class _MetricWindow:
     __slots__ = ("sids", "keys", "last_ts", "epoch", "chunks",
                  "staged_ts", "staged_vals", "staged_sid", "staged_n",
                  "dirty", "complete_from", "concat", "generation",
-                 "version", "device_points", "inflight",
+                 "version", "device_points", "device_bytes", "inflight",
                  "inflight_since")
 
     def __init__(self) -> None:
@@ -217,6 +285,7 @@ class _MetricWindow:
         #                           appended/evicted, invalidate) —
         #                           derived-result cache key
         self.device_points = 0
+        self.device_bytes = 0           # of its chunks' device columns
         self.inflight = 0               # taken-but-not-uploaded batches
         # Monotonic time of THIS metric's last upload progress while it
         # has in-flight batches (None = quiescent): the per-metric
@@ -278,6 +347,7 @@ class DeviceWindow:
         # chunks; eviction picks the chunk with the oldest DATA
         # fleet-wide (_evict_over_budget).
         self._total_points = 0
+        self._total_bytes = 0           # of the resident chunks' columns
         self._seq = 0
         # Liveness signal: bumps on EVERY upload completion (success or
         # failure). Stall handling keys off this, not off elapsed time
@@ -492,7 +562,7 @@ class DeviceWindow:
             "vals": jax.device_put(vals, dev),
             "sid": jax.device_put(sid, dev),
             "valid": jax.device_put(valid, dev),
-            "n": n, "pad": pad, "seq": seq,
+            "n": n, "pad": pad, "seq": seq, "bytes": pad * SLOT_BYTES,
             "min_ts": int(zone.tmin.min()), "max_ts": int(zone.tmax.max()),
             "zone": zone,
         }
@@ -510,7 +580,9 @@ class DeviceWindow:
                 pos -= 1
             mw.chunks.insert(pos, chunk)
             mw.device_points += n
+            mw.device_bytes += chunk["bytes"]
             self._total_points += n
+            self._total_bytes += chunk["bytes"]
             mw.concat = None
             mw.version += 1
             self._evict_over_budget(mw)
@@ -536,7 +608,9 @@ class DeviceWindow:
                 break  # never evict the chunk just added
             old = victim.chunks.pop(0)
             victim.device_points -= old["n"]
+            victim.device_bytes -= old["bytes"]
             self._total_points -= old["n"]
+            self._total_bytes -= old["bytes"]
             self.evicted_points += old["n"]
             victim.concat = None
             victim.version += 1
@@ -634,7 +708,8 @@ class DeviceWindow:
         mw.staged_sid.clear()
         mw.staged_n = 0
         self._total_points -= mw.device_points
-        mw.device_points = 0
+        self._total_bytes -= mw.device_bytes
+        mw.device_points = mw.device_bytes = 0
 
     # -- query side ----------------------------------------------------
 
@@ -823,7 +898,7 @@ class DeviceWindow:
 
     # -- observability -------------------------------------------------
 
-    def collect_stats(self, collector) -> None:
+    def collect_stats(self, collector, device: bool = True) -> None:
         collector.record("devwindow.points.appended", self.appended_points)
         collector.record("devwindow.points.evicted", self.evicted_points)
         collector.record("devwindow.hits", self.window_hits)
@@ -840,3 +915,8 @@ class DeviceWindow:
             collector.record(
                 "devwindow.points.resident",
                 sum(mw.device_points for mw in self._metrics.values()))
+            # What the resident chunks' columns hold on the device,
+            # padding included.
+            collector.record("devwindow.bytes", self._total_bytes)
+        if device:
+            record_device_memory(collector, self.device)

@@ -108,6 +108,36 @@ def _open_list() -> list:
     return lst
 
 
+def _require_window_fits(cfg: Config) -> None:
+    """Refuse to boot with a device-window budget the serving device
+    cannot hold beside one query's stage at the largest grid the
+    resident plan serves (storage/devstore.py ``require_fits``): found
+    here, in a sentence with both numbers, and not by an allocation
+    failing in the middle of the refill. A sharded window
+    (--devwindow-shards) is checked by the share of the budget its
+    fullest device holds. A device that states no limit (a CPU) is not
+    checked."""
+    import jax
+
+    from opentsdb_tpu.ops import kernels
+    from opentsdb_tpu.storage import devstore
+    from opentsdb_tpu.utils import jaxenv
+
+    points = cfg.device_window_points
+    if cfg.devwindow_shards > 0:
+        devices = min(jax.local_device_count(), cfg.devwindow_shards)
+        points = (points // cfg.devwindow_shards
+                  * -(-cfg.devwindow_shards // devices))
+    mem = jaxenv.device_memory()
+    try:
+        devstore.require_fits(
+            points, cfg.device_window_staging,
+            kernels.stage_accumulator_bytes(),
+            mem and mem["bytes_limit"])
+    except ValueError as e:
+        raise SystemExit(f"tsd: --device-window-points: {e}") from None
+
+
 def make_tsdb(args, start_thread: bool = False) -> TSDB:
     if getattr(args, "backend", None) == "cpu":
         # Pin the JAX platform BEFORE anything initializes the default
@@ -210,6 +240,9 @@ def make_tsdb(args, start_thread: bool = False) -> TSDB:
         from opentsdb_tpu.utils import jaxenv
         LOG.info("jax compile cache: %s", jaxenv.setup_compile_cache())
         jaxenv.require_serving_device(cfg.backend)
+        if cfg.backend != "cpu" and not getattr(args, "read_only", False):
+            # (A read-only daemon keeps no device window: core/tsdb.py.)
+            _require_window_fits(cfg)
         cfg.slow_query_ms = getattr(args, "slow_query_ms", 0.0)
         cfg.selfmon_interval_s = getattr(args, "selfmon_interval", 0.0)
         cfg.trace_sample_n = getattr(args, "trace_sample_n", 0)
@@ -999,13 +1032,16 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument("--device-window-points", type=int, default=0,
                    metavar="N",
                    help="budget of the device-resident hot window, in "
-                        "points summed over metrics (~12 B a point of "
-                        "HBM). Past it the chunks holding the oldest "
-                        "data are evicted and a request that starts "
-                        "before its metric's horizon is served from "
-                        "storage. 0 = the default, 67,108,864 "
-                        "(1 << 26); /stats has it as "
-                        "tsd.devwindow.points.budget")
+                        "points summed over metrics (26 B a point of "
+                        "HBM, half of it the chunks' padding). Past it "
+                        "the chunks holding the oldest data are "
+                        "evicted and a request that starts before its "
+                        "metric's horizon is served from storage. The "
+                        "daemon checks the budget against the device's "
+                        "memory at boot and refuses one that does not "
+                        "fit. 0 = the default, 67,108,864 (1 << 26); "
+                        "/stats has it as tsd.devwindow.points.budget "
+                        "and what it holds as tsd.devwindow.bytes")
     p.add_argument("--rollup-device-fold", action="store_true",
                    help="run the rollup checkpoint fold on-device "
                         "behind the mesh plane (f64 accumulation where "

@@ -172,7 +172,9 @@ class Config:
     # host->device upload (the measured query bottleneck on real TPU)
     device_window: bool = True
     device_window_staging: int = 1 << 20   # points per upload chunk
-    device_window_points: int = 1 << 26    # resident budget (~12 B/point)
+    device_window_points: int = 1 << 26    # resident budget (26 B/point:
+    #                                        13 B a slot, chunks padded
+    #                                        to twice their points)
     # Mesh-sharded hot set (storage/devshard.py): shard the resident
     # window over the mesh devices on the series axis so capacity and
     # dashboard throughput scale with mesh width. 0 = off (single

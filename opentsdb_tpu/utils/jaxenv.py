@@ -62,6 +62,23 @@ def device_info() -> dict:
             "count": len(devs)}
 
 
+def device_memory(device=None) -> dict | None:
+    """What a device says of its memory: ``bytes_limit``,
+    ``bytes_in_use`` and ``peak_bytes_in_use`` of its
+    ``memory_stats()``, or None where it states no limit (a CPU backend
+    states none: its arrays are the host's to page). ``device`` None is
+    this process's first device (under a mesh plane ``jax.devices()[0]``
+    may be another process's, which cannot be asked)."""
+    import jax
+
+    st = (device or jax.local_devices()[0]).memory_stats()
+    if not st or not st.get("bytes_limit"):
+        return None
+    return {name: int(st.get(name, 0))
+            for name in ("bytes_limit", "bytes_in_use",
+                         "peak_bytes_in_use")}
+
+
 def require_serving_device(backend: str) -> dict:
     """Resolve the devices once at daemon boot, log them, and refuse to
     serve ``--backend tpu`` from anything but a TPU unless the CPU was
