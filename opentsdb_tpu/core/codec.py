@@ -405,3 +405,76 @@ def columns_concat(parts: list[Columns]) -> Columns:
         np.concatenate([p.int_values for p in parts]),
         np.concatenate([p.is_float for p in parts]),
     )
+
+
+class SeriesBlock(NamedTuple):
+    """The points of a key range as ONE flat block in (series, time)
+    order: what TSDB.scan_block decodes and the unit the query fragment
+    cache holds. Series ``i`` owns rows ``bounds[i]:bounds[i + 1]`` of
+    every column; a consumer that wants per-series Columns takes views
+    (``per_series``), one that wants the points reads the columns whole.
+    """
+    series_keys: list[bytes]    # in order of first appearance in the scan
+    bounds: np.ndarray          # int64 (len(series_keys) + 1,)
+    cols: Columns               # the flat columns
+
+    def per_series(self) -> dict[bytes, Columns]:
+        """Views of the block a series with a point, in block order."""
+        return {
+            skey: Columns(*(col[a:b] for col in self.cols))
+            for skey, a, b in zip(self.series_keys, self.bounds[:-1].tolist(),
+                                  self.bounds[1:].tolist())
+            if b > a}
+
+    def cut(self, start: int, end: int) -> tuple[np.ndarray, np.ndarray]:
+        """Rows ``[lo[i], hi[i])`` of each series with a timestamp in
+        [start, end]: a binary search a bound over all the series at a
+        step (a series' timestamps ascend), so a fleet-wide block costs
+        a few dozen small whole-array operations and no Python turn a
+        series. Only a series that begins before ``start`` (ends after
+        ``end``) is searched: none of a block the range covers whole.
+        """
+        ts = self.cols.timestamps
+        lo, hi = self.bounds[:-1].copy(), self.bounds[1:].copy()
+        held = np.flatnonzero(hi > lo)
+        early = held[ts[lo[held]] < start]
+        late = held[ts[hi[held] - 1] > end]
+        lo[early], hi[late] = (
+            self._first_not(ts, lo[early], hi[early], lambda v: v < start),
+            self._first_not(ts, lo[late], hi[late], lambda v: v <= end))
+        return lo, np.maximum(lo, hi)
+
+    @staticmethod
+    def _first_not(ts, lo, hi, below) -> np.ndarray:
+        """Per segment ``[lo, hi)`` of ascending ``ts``, the first row
+        that is not ``below`` (``hi`` where all are); ``lo`` and ``hi``
+        are consumed."""
+        todo = np.flatnonzero(lo < hi)
+        while len(todo):
+            mid = (lo[todo] + hi[todo]) >> 1
+            under = below(ts[mid])
+            lo[todo[under]] = mid[under] + 1
+            hi[todo[~under]] = mid[~under]
+            todo = todo[lo[todo] < hi[todo]]
+        return lo
+
+    @classmethod
+    def merged(cls, blocks: "list[SeriesBlock]") -> "SeriesBlock":
+        """Blocks of consecutive time ranges as one block: the series
+        numbered over their union in order of first appearance, the rows
+        regrouped by one stable sort on the series number (a series'
+        rows keep the blocks' order, which is time order)."""
+        if len(blocks) == 1:
+            return blocks[0]
+        index: dict[bytes, int] = {}
+        sid = np.concatenate([
+            np.repeat(np.fromiter((index.setdefault(k, len(index))
+                                   for k in b.series_keys), np.int64,
+                                  len(b.series_keys)), np.diff(b.bounds))
+            for b in blocks])
+        order = np.argsort(sid, kind="stable")
+        bounds = np.zeros(len(index) + 1, np.int64)
+        np.cumsum(np.bincount(sid, minlength=len(index)), out=bounds[1:])
+        return cls(list(index), bounds, Columns(
+            *(np.concatenate(cols)[order]
+              for cols in zip(*(b.cols for b in blocks)))))

@@ -1000,21 +1000,33 @@ class TSDB:
             yield from decode_batch()
 
     def scan_series(self, start_key: bytes, stop_key: bytes,
-                    key_regexp: bytes | None = None,
-                    batch_cells: int = 1 << 18,
-                    series_hint=None, counts: dict | None = None,
-                    series_keys=None):
+                    **scan_kw):
+        """``scan_block`` as (series_keys, per-series Columns dict):
+        every series the scan met, and views of the block for those
+        with a point."""
+        block = self.scan_block(start_key, stop_key, **scan_kw)
+        return block.series_keys, block.per_series()
+
+    def scan_block(self, start_key: bytes, stop_key: bytes,
+                   key_regexp: bytes | None = None,
+                   batch_cells: int = 1 << 18,
+                   series_hint=None, counts: dict | None = None,
+                   series_keys=None) -> codec.SeriesBlock:
         """Whole-range columnar scan regrouped BY SERIES in vectorized
-        passes: returns (series_keys, per_series Columns dict) with one
-        global (series, timestamp) lexsort + one vectorized dedup pass
+        passes: one codec.SeriesBlock, the range's points as four flat
+        columns in (series, timestamp) order with each series' row
+        bounds, from one global sort + one vectorized dedup pass
         instead of per-row Columns objects and per-series
         re-concatenation. Profiled on the cold query path (the row-hour
         layout means ~10 points/row): per-row namedtuple construction +
         columns_concat of ~168 hour-parts per series cost more than the
         decode itself; here both collapse into a handful of
-        whole-range numpy ops. Duplicate (series, ts) points collapse
-        when value-equal and raise IllegalDataError otherwise —
-        sort_dedup's rule (reference complexCompact :600-679).
+        whole-range numpy ops, and nothing downstream has to undo
+        them: the block is what the query fragment cache keeps and
+        what a fused request packs its point stream from. Duplicate
+        (series, ts) points collapse when value-equal and raise
+        IllegalDataError otherwise — sort_dedup's rule (reference
+        complexCompact :600-679).
         ``counts``, when given, has the rows read added under "rows".
         ``series_hint`` / ``series_keys``: see KVStore.scan_raw."""
         from opentsdb_tpu.core.errors import IllegalDataError
@@ -1059,7 +1071,9 @@ class TSDB:
         if counts is not None:
             counts["rows"] = counts.get("rows", 0) + rows
         if not parts:
-            return skeys, {}
+            return codec.SeriesBlock(
+                skeys, np.zeros(len(skeys) + 1, np.int64),
+                codec.columns_concat([]))
         ts = np.concatenate([p[0] for p in parts])
         f = np.concatenate([p[1] for p in parts])
         i = np.concatenate([p[2] for p in parts])
@@ -1092,11 +1106,8 @@ class TSDB:
                 ts, f, i, isf, sid = (ts[keep], f[keep], i[keep],
                                       isf[keep], sid[keep])
         bounds = np.searchsorted(sid, np.arange(len(skeys) + 1))
-        per_series = {
-            skeys[s]: codec.Columns(ts[a:b], f[a:b], i[a:b], isf[a:b])
-            for s, (a, b) in enumerate(zip(bounds[:-1], bounds[1:]))
-            if b > a}
-        return skeys, per_series
+        return codec.SeriesBlock(skeys, bounds,
+                                 codec.Columns(ts, f, i, isf))
 
     # ------------------------------------------------------------------
     # Suggest / admin / lifecycle

@@ -28,6 +28,7 @@ Un-downsampled queries keep the exact 1.1 union-grid semantics.
 
 from __future__ import annotations
 
+import itertools
 import re
 import threading
 import weakref
@@ -95,6 +96,12 @@ _C_FOLD_DISPATCHES = _metrics.counter("devwindow.fold.dispatches")
 # none) and the points in range that went on to the aggregate stage.
 _C_RAW_ROWS = _metrics.counter("query.raw.rows")
 _C_RAW_POINTS = _metrics.counter("query.raw.points")
+# Points packed into a stream for the fused downsample kernels, by
+# where they were read from: the flat blocks of a raw scan (cached
+# fragments and fresh chunk scans alike), or a list of spans (the
+# rollup planner's per-bucket records, an expert batch's groups).
+_C_PACK_FLAT = _metrics.counter("query.pack.flat_points")
+_C_PACK_SPANS = _metrics.counter("query.pack.span_points")
 
 
 def _count_decline(reason: str) -> None:
@@ -159,6 +166,162 @@ class _Span(NamedTuple):
     values: np.ndarray
 
 
+class _Scan:
+    """What a sub-query's scan found, before a point of it is copied:
+    the flat blocks (codec.SeriesBlock: a fragment of the range each,
+    in time order), the rows of each block's series that lie in the
+    range, and the series that have one, numbered in order of first
+    appearance, named, and filed under their groups.
+
+    The fused downsample kernels take it as one point ``stream``,
+    written once and straight from the blocks; whatever works a series
+    at a time (the float64 oracle, union-grid interpolation, the mesh
+    packers) asks for ``spans``, views of the blocks as one."""
+
+    def __init__(self, blocks: "list[codec.SeriesBlock]",
+                 start: int | None = None,
+                 end: int | None = None) -> None:
+        self.blocks = blocks
+        # No range: every row of the blocks (``of_spans``).
+        self.range = (start, end)
+        self.cuts = [self._cut(blk) for blk in blocks]
+        index: dict[bytes, int] = {}
+        # Per block, its series -> the request's (-1: no row in range).
+        self.sids = []
+        for blk, (lo, hi) in zip(blocks, self.cuts):
+            live = np.flatnonzero(hi > lo)
+            sid = np.full(len(blk.series_keys), -1, np.int32)
+            sid[live] = [index.setdefault(blk.series_keys[i], len(index))
+                         for i in live.tolist()]
+            self.sids.append(sid)
+        self.keys = list(index)
+        self.points = sum(int((hi - lo).sum()) for lo, hi in self.cuts)
+        self.tags: list[dict[str, str]] = []    # by request series
+        self.groups: dict[tuple, list[int]] = {}
+
+    def _cut(self, blk: "codec.SeriesBlock"):
+        if self.range[0] is None:
+            return blk.bounds[:-1], blk.bounds[1:]
+        return blk.cut(*self.range)
+
+    @classmethod
+    def of_spans(cls, groups: "dict[tuple, list[_Span]]") -> "_Scan":
+        """Span lists (every row of each in range) as a scan of one
+        block: one concatenation a column."""
+        spans = [sp for members in groups.values() for sp in members]
+        bounds = np.zeros(len(spans) + 1, np.int64)
+        np.cumsum([len(sp.timestamps) for sp in spans], out=bounds[1:])
+        scan = cls([codec.SeriesBlock(
+            [sp.series_key for sp in spans], bounds, codec.Columns(
+                np.concatenate([sp.timestamps for sp in spans]),
+                np.concatenate([sp.values for sp in spans]), None, None)
+        )] if spans else [])
+        scan.tags = [sp.tags for sp in spans]
+        at = 0
+        for gkey, members in groups.items():
+            scan.groups[gkey] = list(range(at, at + len(members)))
+            at += len(members)
+        return scan
+
+    def spans(self) -> "dict[tuple, list[_Span]]":
+        """The groups as lists of per-series views of one block: the
+        blocks merged first where the range took several."""
+        if not self.blocks:
+            return {}
+        blk = codec.SeriesBlock.merged(self.blocks)
+        lo, hi = self.cuts[0] if len(self.blocks) == 1 else self._cut(blk)
+        row = dict(zip(blk.series_keys, zip(lo.tolist(), hi.tolist())))
+        ts, vals = blk.cols.timestamps, blk.cols.values
+        out = {}
+        for gkey, members in self.groups.items():
+            out[gkey] = group = []
+            for i in members:
+                a, b = row[self.keys[i]]
+                group.append(_Span(self.keys[i], self.tags[i],
+                                   ts[a:b], vals[a:b]))
+        return out
+
+    def stream(self, qbase: int, pad: bool = False):
+        """The fused kernels' flat (rel_ts, vals, sid, valid) point
+        stream, each array allocated once and filled block by block
+        with whole-array operations: a fleet-wide request moves its
+        points once, and the stream is all the large memory it touches
+        for the first time (a fresh page costs more than the copy into
+        it; and every numpy call may hand the GIL to another busy
+        thread for a whole switch interval, where a wide request has
+        thousands of series). Of a block, the rows in range are read as
+        they lie where they are one run (a block wholly in range, one
+        series) or a grid (series sampled in step, all cut alike), and
+        gathered by one index vector otherwise.
+
+        The stream is block-major: a series' points ascend in time
+        (blocks are in time order, each in (series, time) order) but
+        lie in as many runs as blocks hold it. The kernels reduce by
+        (sid, bucket) and read no order.
+
+        ``pad`` appends invalid slots up to the quarter-octave ladder
+        (``pad_fine``), for the single-device kernels: the stream's
+        length is a static shape of theirs, and a window that starts
+        one second later holds one point a series more or less, which
+        unpadded is a new program for every such length (a 12 h window
+        of 10 s data: 4,320 or 4,321 points a series)."""
+        n = self.points
+        size = _pad_fine(n) if pad else n
+        rel = np.empty(size, np.int32)
+        vals = np.empty(size, np.float32)
+        sid = np.empty(size, np.int32)
+        scratch = np.empty((2, 0), np.int64)    # the gathers', grown once
+        at = 0
+        for blk, (lo, hi), series in zip(self.blocks, self.cuts,
+                                         self.sids):
+            live = np.flatnonzero(hi > lo)
+            if not len(live):
+                continue
+            lo, count, series = lo[live], (hi - lo)[live], series[live]
+            first = np.cumsum(count) - count
+            m = int(first[-1] + count[-1])
+            sid[at:at + m] = np.repeat(series, count)
+            ts, v = blk.cols.timestamps, blk.cols.values
+            a, c, shape, rows = int(lo[0]), int(count[0]), (m,), None
+            if int(lo[-1] + count[-1]) - a == m:
+                ts, v = ts[a:a + m], v[a:a + m]
+            elif (count == c).all() and not np.diff(lo, 2).any():
+                # As many rows of every series, as far apart (the cut of
+                # series sampled in step): a 2-D view, read in place.
+                shape = (len(lo), c)
+                ts, v = (np.lib.stride_tricks.as_strided(
+                    col[a:], shape,
+                    (int(lo[1] - a) * col.itemsize, col.itemsize),
+                    writeable=False) for col in (ts, v))
+            else:
+                if scratch.shape[1] < m:
+                    scratch = np.empty((2, m), np.int64)
+                # The rows of run k are lo[k], lo[k] + 1, ...: a step
+                # of one, each run's jump from the last at its first
+                # slot, summed in place (no temporary of m, as a repeat
+                # and an arange each would make).
+                rows = scratch[0, :m]
+                rows[:] = 1
+                rows[first] = lo - np.concatenate(
+                    ([0], lo[:-1] + count[:-1] - 1))
+                np.cumsum(rows, out=rows)
+                # (clip: an `out` is buffered otherwise; every row is
+                # in bounds.)
+                ts = np.take(ts, rows, out=scratch[1, :m], mode="clip")
+            np.subtract(ts, qbase, out=rel[at:at + m].reshape(shape),
+                        casting="unsafe")
+            if rows is not None:
+                v = np.take(v, rows, out=ts.view(np.float64), mode="clip")
+            vals[at:at + m].reshape(shape)[...] = v
+            at += m
+        for column in (rel, vals, sid):
+            column[n:] = 0
+        valid = np.zeros(size, bool)
+        valid[:n] = True
+        (_C_PACK_SPANS if self.range[0] is None else _C_PACK_FLAT).inc(n)
+        return rel, vals, sid, valid
+
+
 class QueryExecutor:
     def __init__(self, tsdb, backend: str | None = None,
                  mesh=None) -> None:
@@ -193,8 +356,9 @@ class QueryExecutor:
         self.last_plan = "raw"
         cfg = tsdb.config
         # Fragment cache (the query fast path): decoded per-(selector,
-        # aligned time-chunk) columnar spans, validated against the
-        # store's content epochs + dirty-base set (_scan_selector).
+        # aligned time-chunk) flat blocks (codec.SeriesBlock), validated
+        # against the store's content epochs + dirty-base set
+        # (_scan_blocks).
         # Bounded by cached POINTS, not entries — fragments range from
         # bytes to megabytes. ONE cache per store process-wide (see
         # _shared_frag_cache), not per executor.
@@ -286,49 +450,52 @@ class QueryExecutor:
                 exact.append((k, self.tsdb.tagv.get_id(value)))
         return exact, group_bys
 
-    def _find_spans(self, spec: QuerySpec, start: int, end: int,
-                    info: dict | None = None):
-        """Scan matching rows into per-series columnar spans, grouped by
-        the distinct combinations of group-by tag values. ``info``, when
-        given, receives {"cached": bool} — True iff every fragment of
-        the range served from the warm cache — and what was read:
-        "rows" (storage rows decoded; a cache hit decodes none) and
-        "points" (points in range, handed on to the group stage)."""
+    def _find_series(self, spec: QuerySpec, start: int, end: int,
+                     info: dict | None = None) -> _Scan:
+        """Scan the matching rows into flat blocks and file the series
+        with a point in [start, end] under the distinct combinations of
+        their group-by tag values: a _Scan, of which no point has been
+        copied yet. ``info``, when given, receives {"cached": bool} —
+        True iff every fragment of the range served from the warm
+        cache — and what was read: "rows" (storage rows decoded; a
+        cache hit decodes none) and "points" (points in range, handed
+        on to the group stage)."""
         metric_uid = self.tsdb.metrics.get_id(spec.metric)
         exact, group_bys = self._tag_filters(spec.tags)
         group_by_keys = sorted(k for k, _ in group_bys)
         regexp = self._build_regexp(exact, group_bys)
 
-        per_series = self._scan_selector(metric_uid, exact, group_bys,
-                                         regexp, start, end, info)
-        groups: dict[tuple, list[_Span]] = {}
-        points = 0
+        blocks = self._scan_blocks(metric_uid, exact, group_bys,
+                                   regexp, start, end, info)
         # What is left of a scan after its chunk.decode children: every
-        # series cut to the exact bounds and filed under its group.
+        # block's series cut to the exact bounds (two binary searches
+        # over all of a block's series at once: codec.SeriesBlock.cut),
+        # and those with a point named and filed under their group, from
+        # the series keys alone.
         with obs_trace.span("scan.group") as sp:
-            for skey, cat in per_series.items():
-                # A series' timestamps ascend (scan_series sorts by
-                # series and time; chunks stitch in time order), so the
-                # bounds are two binary searches and the cut a view: no
-                # per-series mask or copy, whose every numpy call could
-                # hand the GIL to another busy thread.
-                lo = int(np.searchsorted(cat.timestamps, start, "left"))
-                hi = int(np.searchsorted(cat.timestamps, end, "right"))
-                if hi <= lo:
-                    continue
+            scan = _Scan(blocks, start, end)
+            for i, skey in enumerate(scan.keys):
                 tag_uids = codec.series_tag_uids(skey)
-                named = {
+                scan.tags.append({
                     self.tsdb.tagk.get_name(k): self.tsdb.tagv.get_name(v)
-                    for k, v in tag_uids.items()}
+                    for k, v in tag_uids.items()})
                 gkey = tuple(tag_uids.get(k, b"") for k in group_by_keys)
-                points += hi - lo
-                groups.setdefault(gkey, []).append(_Span(
-                    skey, named, cat.timestamps[lo:hi], cat.values[lo:hi]))
+                scan.groups.setdefault(gkey, []).append(i)
             if sp is not None:
-                sp.tags.update(series=len(per_series), groups=len(groups))
+                found = set()   # series with a stored point
+                for blk in blocks:
+                    found.update(itertools.compress(
+                        blk.series_keys, np.diff(blk.bounds) > 0))
+                sp.tags.update(series=len(found), groups=len(scan.groups))
         if info is not None:
-            info["points"] = points
-        return groups
+            info["points"] = scan.points
+        return scan
+
+    def _find_spans(self, spec: QuerySpec, start: int, end: int,
+                    info: dict | None = None) -> dict[tuple, list[_Span]]:
+        """``_find_series`` as per-series columnar spans by group, for
+        the callers that work a series at a time."""
+        return self._find_series(spec, start, end, info).spans()
 
     # -- fragment cache (the query fast path) --------------------------
 
@@ -374,20 +541,32 @@ class QueryExecutor:
         return hint
 
     def _scan_chunk(self, metric_uid: bytes, regexp, hint,
-                    c_lo: int, c_hi: int, info: dict | None) -> dict:
-        """Scan + decode one [c_lo, c_hi) base-time chunk into a
-        per-series Columns dict (the cacheable fragment unit)."""
+                    c_lo: int, c_hi: int,
+                    info: dict | None) -> codec.SeriesBlock:
+        """Scan + decode one [c_lo, c_hi) base-time chunk into one flat
+        block (the cacheable fragment unit)."""
         start_key = metric_uid + _u32(c_lo)
         stop_key = metric_uid + _u32(min(c_hi, 0xFFFFFFFF))
-        return self.tsdb.scan_series(start_key, stop_key,
-                                     key_regexp=regexp, counts=info,
-                                     **hint)[1]
+        return self.tsdb.scan_block(start_key, stop_key,
+                                    key_regexp=regexp, counts=info,
+                                    **hint)
 
     def _scan_selector(self, metric_uid: bytes, exact, group_bys,
                        regexp, start: int, end: int,
                        info: dict | None = None) -> dict:
-        """Per-series columns for a selector over [start, end] (full
-        covering row range — the caller masks to the exact bounds).
+        """``_scan_blocks`` as per-series Columns: views of the range's
+        one block, or of its several merged."""
+        return codec.SeriesBlock.merged(self._scan_blocks(
+            metric_uid, exact, group_bys, regexp, start, end,
+            info)).per_series()
+
+    def _scan_blocks(self, metric_uid: bytes, exact, group_bys,
+                     regexp, start: int, end: int,
+                     info: dict | None = None,
+                     ) -> list[codec.SeriesBlock]:
+        """A selector's points over [start, end] as flat blocks in time
+        order, one a fragment (full covering row range — the caller
+        cuts to the exact bounds).
 
         The range splits into row-span-aligned chunks; each chunk
         serves from the fragment cache when (a) no shard has
@@ -400,8 +579,12 @@ class QueryExecutor:
         BYPASS the cache both ways — scanned fresh, never stored — so
         a live-ingest tail is re-read every time while frozen history
         hits RAM, and answers stay bit-identical to a cold scan:
-        chunks align to the row span, so per-chunk decode + concat
-        reproduces the whole-range decode order exactly."""
+        chunks align to the row span, so the chunks' blocks in turn
+        hold every series' points in the whole-range decode's order.
+        A fragment IS its block and nothing here copies it: the fused
+        kernels' stream is written from the blocks as they lie
+        (_Scan.stream), and only a consumer of per-series columns over
+        several chunks pays for a merge (codec.SeriesBlock.merged)."""
         tsdb = self.tsdb
         cfg = tsdb.config
         store = tsdb.store
@@ -414,14 +597,14 @@ class QueryExecutor:
         b_lo = codec.base_time(max(start, 0))
         b_hi = min(codec.base_time(min(end, 0xFFFFFFFF)), 0xFFFFFFFF)
 
-        def full_scan() -> dict:
+        def full_scan() -> list[codec.SeriesBlock]:
             start_key = metric_uid + _u32(b_lo)
             stop_key = metric_uid + _u32(
                 min(b_hi + MAX_TIMESPAN, 0xFFFFFFFF))
             with obs_trace.span("chunk.decode", outcome="unchunked"):
-                return tsdb.scan_series(start_key, stop_key,
+                return [tsdb.scan_block(start_key, stop_key,
                                         key_regexp=regexp, counts=info,
-                                        **hint)[1]
+                                        **hint)]
 
         chunk_s = int(cfg.qcache_chunk_s or 0)
         chunk_s -= chunk_s % MAX_TIMESPAN
@@ -458,7 +641,7 @@ class QueryExecutor:
                 sp.tags["qcache_bypass"] = (
                     sp.tags.get("qcache_bypass", 0) + nchunks)
             return full_scan()
-        parts: dict[bytes, list] = {}
+        blocks = []
         all_hit = True
         n_hit = n_miss = n_byp = 0
         for c, (seqs, floors, stamps, dirty) in zip(chunks, states):
@@ -487,12 +670,10 @@ class QueryExecutor:
                                         base=int(c)):
                         frag = self._scan_chunk(metric_uid, regexp,
                                                 hint, c, c + chunk_s, info)
-                    cost = sum(len(cols.timestamps)
-                               for cols in frag.values())
-                    self._frag_cache.put(key, (seqs, frag),
-                                         cost=max(cost, 1))
-            for skey, cols in frag.items():
-                parts.setdefault(skey, []).append(cols)
+                    self._frag_cache.put(
+                        key, (seqs, frag),
+                        cost=max(len(frag.cols.timestamps), 1))
+            blocks.append(frag)
         if info is not None:
             info["cached"] = all_hit
         # Fragment-cache outcome on the enclosing span (scan /
@@ -505,40 +686,24 @@ class QueryExecutor:
             t["qcache_hit"] = t.get("qcache_hit", 0) + n_hit
             t["qcache_miss"] = t.get("qcache_miss", 0) + n_miss
             t["qcache_bypass"] = t.get("qcache_bypass", 0) + n_byp
-        # Stitch the series that span several chunks with ONE
-        # concatenation a column and hand each its slice: a wide
-        # selector has thousands of series, and a concatenation a
-        # series and column is as many chances to lose the GIL to
-        # another busy thread for a switch interval.
-        out: dict[bytes, codec.Columns] = {
-            skey: lst[0] for skey, lst in parts.items()}
-        flat = [c for lst in parts.values() if len(lst) > 1 for c in lst]
-        if flat:
-            whole = codec.columns_concat(flat)
-            at = 0
-            for skey, lst in parts.items():
-                if len(lst) > 1:
-                    n = sum(len(c.timestamps) for c in lst)
-                    out[skey] = codec.Columns(
-                        *(col[at:at + n] for col in whole))
-                    at += n
-        return out
+        return blocks
 
     @staticmethod
-    def _group_tags(spans: list[_Span]):
-        """Intersection tags + aggregated (differing) tag names.
+    def _group_tags(members: list[dict[str, str]]):
+        """Intersection tags + aggregated (differing) tag names of a
+        group, from its series' named tags.
 
         Parity: reference SpanGroup.computeTags (:149-173)."""
-        common = dict(spans[0].tags)
-        keys = set(spans[0].tags)
-        for sp in spans[1:]:
-            keys &= set(sp.tags)
+        common = dict(members[0])
+        keys = set(members[0])
+        for tags in members[1:]:
+            keys &= set(tags)
             for k in list(common):
-                if sp.tags.get(k) != common[k]:
+                if tags.get(k) != common[k]:
                     del common[k]
         common = {k: v for k, v in common.items() if k in keys}
         aggregated = sorted(
-            {k for sp in spans for k in sp.tags} - set(common))
+            {k for tags in members for k in tags} - set(common))
         return common, aggregated
 
     # ------------------------------------------------------------------
@@ -767,7 +932,8 @@ class QueryExecutor:
                 queries, self.mesh, num_series=S,
                 num_buckets=num_buckets, interval=interval)
         for (si, spans), (gv, gm) in zip(refs, got):
-            tags, aggregated = self._group_tags(spans)
+            tags, aggregated = self._group_tags(
+                [sp.tags for sp in spans])
             mask = np.asarray(gm)
             grid_ts = (np.flatnonzero(mask).astype(np.int64) * interval
                        + qbase)
@@ -833,7 +999,8 @@ class QueryExecutor:
             groups, spec2, res = planned
             from opentsdb_tpu.rollup.tier import res_label
             with obs_trace.span("aggregate"):
-                results = self._execute_groups(spec2, groups, start, end)
+                results = self._execute_groups(
+                    spec2, _Scan.of_spans(groups), start, end)
             return results, res_label(res), False
         if fusedr is not None:
             return fusedr, "fused", False
@@ -841,7 +1008,7 @@ class QueryExecutor:
         t0 = _time.time()
         info: dict = {}
         with obs_trace.span("scan") as sp:
-            groups = self._find_spans(spec, start, end, info)
+            scan = self._find_series(spec, start, end, info)
             if sp is not None:
                 sp.tags.update(cached=bool(info.get("cached")),
                                rows=info.get("rows", 0),
@@ -850,7 +1017,7 @@ class QueryExecutor:
         _C_RAW_POINTS.inc(info["points"])
         self.scan_latency.add((_time.time() - t0) * 1000)
         with obs_trace.span("aggregate"):
-            results = self._execute_groups(spec, groups, start, end)
+            results = self._execute_groups(spec, scan, start, end)
         return results, "raw", bool(info.get("cached"))
 
     def _plan_rollup(self, spec: QuerySpec, start: int, end: int,
@@ -863,13 +1030,12 @@ class QueryExecutor:
                             rollup_only=rollup_only,
                             meta_out=meta_out)
 
-    def _execute_groups(self, spec: QuerySpec, groups: dict,
+    def _execute_groups(self, spec: QuerySpec, scan: _Scan,
                         start: int, end: int) -> list[QueryResult]:
         """Group-stage execution shared by the raw-scan and rollup
         paths (identical inputs => identical answers, the golden-parity
         contract of tests/test_rollup.py)."""
-        agg = Aggregators.get(spec.aggregator)
-        gkeys = sorted(groups)
+        gkeys = sorted(scan.groups)
         # Ranges wider than int32 seconds (>68 years, e.g. start=0
         # "all-time" against year-2106 timestamps) would wrap the int32
         # rel-timestamp offsets the kernels use; the float64 oracle
@@ -886,25 +1052,33 @@ class QueryExecutor:
             qbase = (start - start % spec.downsample[0]
                      if spec.downsample else start)
             use_cpu = end - qbase > 2**31 - 1
-        # Wide group-bys on the TPU backend batch into ONE kernel call
-        # (two segment reductions for all groups — or the grouped radix
-        # select for percentiles) instead of G calls.
-        # The fused downsample kernels' callers open the children of
-        # the enclosing "aggregate" span (README, "Observability"):
-        # aggregate.pack / .dispatch / .wait / .fetch, then .results.
-        if (not use_cpu and len(gkeys) > 1 and spec.downsample
-                and agg.kind in ("moment", "percentile")):
-            per_group = self._run_tpu_multigroup(
-                spec, [groups[k] for k in gkeys], start, end)
-        elif use_cpu:
-            per_group = [self._run_cpu(spec, groups[k], start)
-                         for k in gkeys]
+        # A downsampled request on the TPU backend is ONE fused kernel
+        # call over the scan's whole point stream, however many groups
+        # (two segment reductions for all of them — or the grouped
+        # radix select for percentiles) instead of G calls. Its callers
+        # open the children of the enclosing "aggregate" span (README,
+        # "Observability"): aggregate.pack / .dispatch / .wait /
+        # .fetch, then .results.
+        if not use_cpu and spec.downsample:
+            if len(gkeys) > 1:
+                per_group = self._run_tpu_multigroup(spec, scan, gkeys,
+                                                     start, end)
+            else:
+                per_group = [self._tpu_downsample_group(spec, scan,
+                                                        start, end)
+                             for _ in gkeys]
         else:
-            per_group = [self._run_tpu(spec, groups[k], start, end)
-                         for k in gkeys]
+            # A series at a time: the float64 oracle, or union-grid
+            # interpolation on the device.
+            spans = scan.spans()
+            run = self._run_cpu if use_cpu else self._run_tpu
+            per_group = [run(spec, spans[k], start) for k in gkeys]
         with obs_trace.span("aggregate.results", results=len(gkeys)):
-            return [QueryResult(spec.metric,
-                                *self._group_tags(groups[gkey]), ts, vals)
+            return [QueryResult(
+                        spec.metric,
+                        *self._group_tags([scan.tags[i]
+                                           for i in scan.groups[gkey]]),
+                        ts, vals)
                     for gkey, (ts, vals) in zip(gkeys, per_group)]
 
     # -- device-resident window path ----------------------------------
@@ -1194,9 +1368,8 @@ class QueryExecutor:
                 live = [sid for sid in groups[gkey] if has_points[sid]]
                 if not live:
                     continue
-                spans = [_Span(cols.series_keys[sid], named[sid], None, None)
-                         for sid in live]
-                tags, aggregated = self._group_tags(spans)
+                tags, aggregated = self._group_tags(
+                    [named[sid] for sid in live])
                 mask = gm[gi]
                 grid_ts = (np.flatnonzero(mask).astype(np.int64) * interval
                            + qbase)
@@ -1636,9 +1809,8 @@ class QueryExecutor:
             live = [sid for sid in groups[gkey] if has_points[sid]]
             if not live:
                 continue
-            spans_ = [_Span(src_keys[sid], named[sid], None, None)
-                      for sid in live]
-            tags, aggregated = self._group_tags(spans_)
+            tags, aggregated = self._group_tags(
+                [named[sid] for sid in live])
             mask = gm[gi]
             grid_ts = (np.flatnonzero(mask).astype(np.int64) * interval
                        + qbase)
@@ -1682,14 +1854,10 @@ class QueryExecutor:
 
     # -- TPU kernel backend -------------------------------------------
 
-    def _run_tpu(self, spec: QuerySpec, spans: list[_Span], start: int,
-                 end: int):
-        if spec.downsample:
-            # Fused path covers rate too: the rate stage rides the same
-            # kernel on the shared bucket grid (no per-span host loops).
-            return self._tpu_downsample_group(spec, spans, start, end)
-        # General (un-downsampled) path: optional rate, then union-grid
-        # interpolation, all on device.
+    def _run_tpu(self, spec: QuerySpec, spans: list[_Span], start: int):
+        """One group of an un-downsampled request: optional rate, then
+        union-grid interpolation, all on device. (A downsampled one is
+        fused, rate included: _execute_groups.)"""
         series = [(sp.timestamps, sp.values) for sp in spans]
         if spec.rate:
             series = self._tpu_rate(series, spec)
@@ -1764,10 +1932,10 @@ class QueryExecutor:
             counter=spec.counter,
             drop_resets=spec.reset_value is not None)
 
-    def _tpu_downsample_group(self, spec: QuerySpec, spans: list[_Span],
+    def _tpu_downsample_group(self, spec: QuerySpec, scan: _Scan,
                               start: int, end: int):
-        """The fused fast path: flat downsample [+ rate] + cross-series
-        group, one kernel call."""
+        """The fused fast path for a scan of one group: flat downsample
+        [+ rate] + cross-series group, one kernel call."""
         interval, dsagg = spec.downsample
         qbase = start - start % interval
         # Pad the static kernel shapes to power-of-two buckets: padded
@@ -1777,18 +1945,19 @@ class QueryExecutor:
         num_buckets = _pad_size(int((end - qbase) // interval + 1))
         agg = Aggregators.get(spec.aggregator)
         if self.mesh is not None and agg.kind in ("moment", "percentile"):
+            (spans,) = scan.spans().values()
             sharded = self._tpu_downsample_sharded(
                 spec, spans, qbase, interval, dsagg, num_buckets)
             if sharded is not None:
                 return sharded
         with obs_trace.span("aggregate.pack") as sp:
-            rel, vals, sid, valid = self._flatten_spans(spans, qbase,
-                                                        pad=True)
+            rel, vals, sid, valid = scan.stream(qbase, pad=True)
             if sp is not None:
-                sp.tags.update(series=len(spans), slots=len(rel))
+                sp.tags.update(series=len(scan.keys), slots=len(rel))
         with obs_trace.span("aggregate.dispatch"):
             out = kernels.downsample_group(
-                rel, vals, sid, valid, num_series=_pad_size(len(spans)),
+                rel, vals, sid, valid,
+                num_series=_pad_size(len(scan.keys)),
                 num_buckets=num_buckets, interval=interval,
                 agg_down=dsagg,
                 agg_group=(spec.aggregator if agg.kind == "moment"
@@ -1897,75 +2066,52 @@ class QueryExecutor:
         return grid_ts, np.asarray(gv)[gm].astype(np.float64)
 
     @staticmethod
-    def _flatten_spans(spans: list[_Span], qbase: int, pad: bool = False):
-        """Spans -> one flat (rel_ts, vals, sid, valid) point stream.
-        Whole-array operations, not a loop of per-series copies: every
-        numpy call may hand the GIL to another busy thread for a whole
-        switch interval, and a wide request has thousands of series.
+    def _flatten_spans(spans: list[_Span], qbase: int):
+        """Spans -> one flat unpadded (rel_ts, vals, sid, valid) point
+        stream, sid = position in ``spans``: what the mesh packers of
+        one group take."""
+        return _Scan.of_spans({(): spans}).stream(qbase)
 
-        ``pad`` appends invalid slots up to the quarter-octave ladder
-        (``pad_fine``), for the fused downsample kernels: the stream's
-        length is a static shape of theirs, and a window that starts
-        one second later holds one point a series more or less, which
-        unpadded is a new program for every such length (a 12 h window
-        of 10 s data: 4,320 or 4,321 points a series)."""
-        ts = np.concatenate([sp.timestamps for sp in spans])
-        n = len(ts)
-        size = _pad_fine(n) if pad else n
-        rel = np.zeros(size, np.int32)
-        rel[:n] = ts - qbase
-        vals = np.zeros(size, np.float32)
-        vals[:n] = np.concatenate([sp.values for sp in spans])
-        sid = np.zeros(size, np.int32)
-        sid[:n] = np.repeat(
-            np.arange(len(spans), dtype=np.int32),
-            np.fromiter((len(sp.timestamps) for sp in spans), np.int64,
-                        len(spans)))
-        return rel, vals, sid, np.arange(size) < n
-
-    def _run_tpu_multigroup(self, spec: QuerySpec,
-                            span_groups: list[list[_Span]],
-                            start: int, end: int):
+    def _run_tpu_multigroup(self, spec: QuerySpec, scan: _Scan,
+                            gkeys: list[tuple], start: int, end: int):
         """All group-by buckets in one fused kernel call.
 
-        Flattens every group's spans into one point stream with a
-        series->group map; downsample_multigroup runs the per-series and
-        per-group reductions for all G groups at once. Returns
-        [(grid_ts, values)] aligned with span_groups.
+        The scan's one point stream with a series->group map;
+        downsample_multigroup runs the per-series and per-group
+        reductions for all G groups at once. Returns
+        [(grid_ts, values)] aligned with ``gkeys``.
         """
         interval, dsagg = spec.downsample
         qbase = start - start % interval
         num_buckets = _pad_size(int((end - qbase) // interval + 1))
 
-        all_spans: list[_Span] = []
-        group_of_sid: list[int] = []
-        for gi, spans in enumerate(span_groups):
-            for sp in spans:
-                all_spans.append(sp)
-                group_of_sid.append(gi)
-        G = _pad_size(len(span_groups))
+        G = _pad_size(len(gkeys))
         agg = Aggregators.get(spec.aggregator)
         D = int(self.mesh.devices.size) if self.mesh is not None else 0
-        if D and len(all_spans) >= D:
+        if D and len(scan.keys) >= D:
+            spans = scan.spans()
             gv, gm = self._multigroup_sharded(
-                spec, all_spans, group_of_sid, G, qbase, interval, dsagg,
-                num_buckets, D)
+                spec, [sp for k in gkeys for sp in spans[k]],
+                [gi for gi, k in enumerate(gkeys) for _ in spans[k]],
+                G, qbase, interval, dsagg, num_buckets, D)
         else:
             with obs_trace.span("aggregate.pack") as sp:
-                rel, vals, sid, valid = self._flatten_spans(
-                    all_spans, qbase, pad=True)
+                rel, vals, sid, valid = scan.stream(qbase, pad=True)
                 # Shapes padded to power-of-two buckets (see
                 # _tpu_downsample_group). Padded series are assigned
                 # group G-1 (possibly a REAL group when the count is
                 # already a power of two) — safe solely because padded
                 # series carry no points, so they contribute nothing
                 # wherever they land.
-                S = _pad_size(len(all_spans))
-                gmap = np.zeros(S, np.int32)
-                gmap[:len(group_of_sid)] = group_of_sid
-                gmap[len(group_of_sid):] = G - 1
+                S = _pad_size(len(scan.keys))
+                group_of = [0] * len(scan.keys)
+                for gi, k in enumerate(gkeys):
+                    for i in scan.groups[k]:
+                        group_of[i] = gi
+                gmap = np.full(S, G - 1, np.int32)
+                gmap[:len(group_of)] = group_of
                 if sp is not None:
-                    sp.tags.update(series=len(all_spans), slots=len(rel))
+                    sp.tags.update(series=len(scan.keys), slots=len(rel))
             with obs_trace.span("aggregate.dispatch"):
                 if agg.kind == "percentile":
                     out = kernels.downsample_multigroup_quantile(
@@ -1986,7 +2132,7 @@ class QueryExecutor:
                                            out["group_values"])
         with obs_trace.span("aggregate.results"):
             results = []
-            for gi in range(len(span_groups)):
+            for gi in range(len(gkeys)):
                 mask = gm[gi]
                 grid_ts = (np.flatnonzero(mask).astype(np.int64) * interval
                            + qbase)

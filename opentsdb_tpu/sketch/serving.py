@@ -408,7 +408,7 @@ def _group_stage(executor, spec, spans):
     applied to the est/lo/hi rails separately. Monotone aggregators
     only (callers gate), so the rails stay a sound enclosure.
     Returns ([QueryResult], max_abs_err, max_rel_err)."""
-    from opentsdb_tpu.query.executor import QueryResult, _Span
+    from opentsdb_tpu.query.executor import QueryResult
 
     tsdb = executor.tsdb
     group_by_keys = sorted(
@@ -445,8 +445,8 @@ def _group_stage(executor, spec, spans):
             est_g = _agg_reduce_cols(E, agg)
             lo_g = _agg_reduce_cols(Lo, agg)
             hi_g = _agg_reduce_cols(Hi, agg)
-        sps = [_Span(s, named_spans[s], None, None) for s in skeys]
-        tags, aggregated = executor._group_tags(sps)
+        tags, aggregated = executor._group_tags(
+            [named_spans[s] for s in skeys])
         ts_out = grid[mask]
         est_out = est_g[mask]
         err = np.maximum(hi_g[mask] - est_out, est_out - lo_g[mask])
