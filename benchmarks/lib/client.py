@@ -8,6 +8,11 @@ collectors' mix (``kind: load``) and sends telnet ``put`` lines over a
 few connections with disjoint hosts, as ``tsbs_load`` does. Both draw
 everything from the seed and import neither jax nor the program.
 
+``kind: live`` is the two in one window: the collectors send a step at a
+time on a schedule (``IngestLoad.start_paced``) and keep the
+acknowledged edge (``Edge``); a request type with ``"anchor": "edge"``
+ends at the edge as it stands when the request is drawn.
+
 A request is timed from just before it is written to the socket until
 the last byte of the body is read, on ``time.perf_counter``.
 """
@@ -47,14 +52,15 @@ class Request:
     the answer (the parsed sub-queries and the window)."""
 
     __slots__ = ("type", "target", "ms", "start", "end", "groups",
-                 "series_steps")
+                 "series_steps", "edge")
 
     def __init__(self, type_, target, ms, start, end, groups,
-                 series_steps):
+                 series_steps, edge=None):
         self.type, self.target, self.ms = type_, target, ms
         self.start, self.end = start, end
         self.groups = groups                # results the answer must hold
         self.series_steps = series_steps    # sum of series x steps needed
+        self.edge = edge        # the edge step an anchored draw ended at
 
 
 class Done:
@@ -88,21 +94,72 @@ def first_traces(body: bytes, metrics: list[str]) -> list[dict]:
     return out
 
 
+class Edge:
+    """The acknowledged edge of a deployment that is being written to:
+    the newest step (counted from the first step after the loaded span;
+    0 = nothing sent yet) whose barrier every collector has had
+    answered, kept with the wall time it moved. Every point with a
+    timestamp up to ``ts(step)`` was acknowledged before ``moved``."""
+
+    def __init__(self, collectors: int, first_ts: int, interval_s: int):
+        self.first_ts, self.interval_s = first_ts, interval_s
+        self._acked = [0] * collectors
+        self._lock = threading.Lock()
+        self.step = 0
+        self.moved: list[tuple[int, float]] = [(0, time.time())]
+
+    def ts(self, step: int) -> int:
+        """Timestamp of edge ``step``; step 0 is the loaded span's last."""
+        return self.first_ts + self.interval_s * (step - 1)
+
+    def acknowledged(self, collector: int, steps: int) -> None:
+        """Collector ``collector`` has had ``steps`` steps acknowledged."""
+        with self._lock:
+            self._acked[collector] = steps
+            low = min(self._acked)
+            if low > self.step:
+                self.step = low
+                self.moved.append((low, time.time()))
+
+    def read(self) -> tuple[int, int]:
+        with self._lock:
+            return self.step, self.ts(self.step)
+
+    def moved_at(self, step: int) -> float:
+        """Wall time at which the edge reached ``step`` or passed it."""
+        with self._lock:
+            return next(t for s, t in self.moved if s >= step)
+
+
 def draw_request(cfg: dict, qtype: dict, rng: np.random.Generator,
-                 extra: str = "", metrics: list[str] | None = None
-                 ) -> Request:
+                 extra: str = "", metrics: list[str] | None = None,
+                 edge: Edge | None = None) -> Request:
     """Draw one request of ``qtype``: ``metrics`` first metrics (TSBS
     takes the first N of its list), ``hosts`` hosts at random (0 = every
     host, ``tag=*``), a window of ``window_s`` starting at a random
-    second of the loaded span."""
+    second of the loaded span; a type with ``"anchor": "edge"`` ends at
+    the acknowledged edge as it stands now instead (nothing is drawn
+    for its window)."""
     step, t0 = int(cfg["interval_s"]), int(cfg["t0"])
     span_end = t0 + step * (tsbs.loaded_steps(cfg) - 1)
     window = int(qtype["window_s"])
     if window > span_end - t0:
         raise ValueError(f"type {qtype['name']}: window {window} s is "
                          f"longer than the loaded span")
-    start = t0 + int(rng.integers(0, span_end - t0 - window + 1))
-    end = start + window
+    anchor = qtype.get("anchor")
+    at = None
+    if anchor == "edge":
+        if edge is None:
+            raise ValueError(f"type {qtype['name']} is anchored to the "
+                             f"edge, and this kind of traffic keeps none")
+        at, end = edge.read()
+        start = end - window
+    elif anchor is not None:
+        raise ValueError(f"type {qtype['name']}: unknown anchor "
+                         f"{anchor!r}")
+    else:
+        start = t0 + int(rng.integers(0, span_end - t0 - window + 1))
+        end = start + window
     nhosts = int(qtype["hosts"])
     if nhosts:
         picks = rng.choice(int(cfg["hosts"]), nhosts, replace=False)
@@ -119,14 +176,15 @@ def draw_request(cfg: dict, qtype: dict, rng: np.random.Generator,
     series = (nhosts or int(cfg["hosts"])) * len(ms)
     steps = (end - max(start, t0)) // step + 1
     return Request(qtype["name"], target, ms, start, end, series,
-                   series * steps)
+                   series * steps, at)
 
 
 class QueryLoad:
     def __init__(self, cfg: dict, traffic: dict, seed: int, port: int,
-                 traced: bool):
+                 traced: bool, edge: Edge | None = None):
         self.cfg, self.traffic, self.seed, self.port = (
             cfg, traffic, seed, port)
+        self.edge = edge
         self.types = traffic["types"]
         self.workers = int(traffic["workers"])
         # A traced run's requests carry trace=1 (the span tree comes
@@ -134,6 +192,9 @@ class QueryLoad:
         # hit share is read in the untraced shape of the request only.
         self.extra = "&trace=1" if traced else ""
         self.done: list[Done] = []
+        # Whole cycles each worker has finished, and who is told of one.
+        self.cycles = [0] * self.workers
+        self.after_cycle = None
         self._lock = threading.Lock()
 
     def warm(self) -> list[Done]:
@@ -151,7 +212,8 @@ class QueryLoad:
                 names = self.cfg["metrics"][:int(qtype["metrics"])]
                 fresh = [n for n in names if n not in touched] or names[:1]
                 touched.update(fresh)
-                req = draw_request(self.cfg, qtype, rng, self.extra, fresh)
+                req = draw_request(self.cfg, qtype, rng, self.extra, fresh,
+                                   self.edge)
                 out.append(self._one(conn, req, -1, keep=False))
         finally:
             conn.close()
@@ -187,23 +249,33 @@ class QueryLoad:
                 body, self.cfg["metrics"][:len(req.ms)])
         return done
 
+    def cycle(self, index: int, rng: np.random.Generator):
+        """One whole cycle of the mix as worker ``index`` draws it, in
+        the file's order (worker i starts i/workers of the way in):
+        every seed and every run issues the same sizes in the same
+        order, and only hosts and windows are drawn. A request is drawn
+        when the one before it has been answered."""
+        n = len(self.types)
+        for k in range(n):
+            qtype = self.types[(index * n // self.workers + k) % n]
+            yield draw_request(self.cfg, qtype, rng, self.extra,
+                               edge=self.edge)
+
     def _worker(self, index: int, t_end: float) -> None:
         rng = tsbs.rng(self.seed, 100 + index)
         conn = http.client.HTTPConnection("127.0.0.1", self.port,
                                           timeout=HTTP_TIMEOUT_S)
         mine = []
-        # Whole cycles of the mix, in the file's order (worker i starts
-        # i types in): every seed and every run issues the same sizes in
-        # the same order, and only hosts and windows are drawn. A cycle
-        # begun before the window's end is finished, so the statistics
-        # never depend on where the clock cut a cycle.
-        n = len(self.types)
+        # Whole cycles: a cycle begun before the window's end is
+        # finished, so the statistics never depend on where the clock
+        # cut a cycle.
         try:
             while time.perf_counter() < t_end:
-                for k in range(n):
-                    qtype = self.types[(index * n // self.workers + k) % n]
-                    req = draw_request(self.cfg, qtype, rng, self.extra)
+                for req in self.cycle(index, rng):
                     mine.append(self._one(conn, req, index))
+                self.cycles[index] += 1
+                if self.after_cycle is not None:
+                    self.after_cycle()
         finally:
             conn.close()
             with self._lock:
@@ -329,8 +401,11 @@ class IngestLoad:
     def __init__(self, cfg: dict, traffic: dict, seed: int,
                  max_seconds: float):
         self.cfg, self.traffic, self.seed = cfg, traffic, seed
-        self.workers = int(traffic["workers"])
-        self.slice_steps = int(traffic["slice_steps"])
+        # A live mix's ``workers`` are its dashboards.
+        self.workers = int(traffic["collectors"] if "collectors" in traffic
+                           else traffic["workers"])
+        self.slice_steps = int(traffic.get("slice_steps", 1))
+        self.pace = float(traffic["pace"]) if "pace" in traffic else None
         self.warm_steps = int(traffic["warm_steps"])
         self.hosts_per_send = int(traffic["hosts_per_send"])
         self.tail_steps = int(traffic["tail_steps"])
@@ -338,10 +413,14 @@ class IngestLoad:
         step, t0 = int(cfg["interval_s"]), int(cfg["t0"])
         self.loaded = tsbs.loaded_steps(cfg)
         series = int(cfg["hosts"]) * len(cfg["metrics"])
-        # Enough steps for the longest window at a rate the daemon
-        # cannot reach.
-        room = int(max_seconds * float(traffic["max_points_per_s"])
-                   / series)
+        # Enough steps for the longest window: on the schedule of a
+        # paced mix, or at a rate the daemon cannot reach.
+        if self.pace is not None:
+            self.period_s = step / self.pace
+            room = int(max_seconds / self.period_s) + 1
+        else:
+            room = int(max_seconds * float(traffic["max_points_per_s"])
+                       / series)
         self.extra = (self.warm_steps + self.tail_steps
                       + (room // self.slice_steps + 2) * self.slice_steps)
         self.values = [
@@ -355,13 +434,20 @@ class IngestLoad:
         self.suffix = [(" " + " ".join(f"{k}={v}" for k, v in t.items())
                         + "\n").encode() for t in tags]
         self.collectors = [Collector(self, i) for i in range(self.workers)]
+        self.edge = Edge(self.workers, self.first_ts, step)
+        self._stop = threading.Event()
+        self._threads: list[threading.Thread] = []
+        self.t0_wall = self.t_stop_wall = self.t_end_wall = 0.0
 
-    def _all(self, fn) -> None:
+    def _start(self, fn) -> list[threading.Thread]:
         threads = [threading.Thread(target=fn, args=(c,), daemon=True)
                    for c in self.collectors]
         for t in threads:
             t.start()
-        for t in threads:
+        return threads
+
+    def _all(self, fn) -> None:
+        for t in self._start(fn):
             t.join()
 
     def warm(self, port: int) -> bool:
@@ -371,7 +457,8 @@ class IngestLoad:
 
         def go(c: Collector) -> None:
             c.send_steps(0, self.warm_steps, None)
-            c.barrier()
+            if c.barrier():
+                self.edge.acknowledged(c.index, self.warm_steps)
         self._all(go)
         return not any(c.lost for c in self.collectors)
 
@@ -395,6 +482,58 @@ class IngestLoad:
             c.barrier()
         self._all(go)
         return time.perf_counter() - t0
+
+    def start_paced(self) -> None:
+        """Open loop by schedule: step k after the warm-up is due at
+        now + k x ``period_s`` (``interval_s`` / ``pace``). A collector
+        sends its hosts' step for every metric once it is due and the
+        step before is acknowledged, then its barrier, and tells the
+        edge. Returns at once; ``finish_paced`` ends it."""
+        self.t0_wall = time.time()
+
+        def go(c: Collector) -> None:
+            last = self.extra - self.tail_steps
+            k = 0
+            while not self._stop.wait(
+                    max(self.t0_wall + k * self.period_s - time.time(), 0)):
+                s = self.warm_steps + k
+                if s >= last:
+                    c.lost = "ran out of steps before the window's end"
+                    return
+                c.send_steps(s, s + 1)
+                if not c.barrier():
+                    return
+                self.edge.acknowledged(c.index, s + 1)
+                k += 1
+        self._threads = self._start(go)
+
+    def finish_paced(self) -> float:
+        """The window has ended: no collector begins another step; each
+        finishes the one it is in and takes its barrier. Returns window
+        start -> last barrier reply, in seconds."""
+        self.t_stop_wall = time.time()
+        self._stop.set()
+        for t in self._threads:
+            t.join()
+        self.t_end_wall = time.time()
+        return self.t_end_wall - self.t0_wall
+
+    def late_steps(self) -> tuple[int, float]:
+        """Steps of the paced window acknowledged (by every collector)
+        more than one period after they were due, and the worst
+        lateness in seconds. A step that was due before the window's
+        end and never sent is late by what it had waited when the last
+        barrier came back."""
+        late, worst = 0, 0.0
+        due_steps = -int(-(self.t_stop_wall - self.t0_wall) // self.period_s)
+        for k in range(due_steps):
+            due = self.t0_wall + k * self.period_s
+            at = self.warm_steps + k + 1
+            by = (self.edge.moved_at(at) if self.edge.step >= at
+                  else self.t_end_wall)
+            worst = max(worst, by - due)
+            late += by - due > self.period_s
+        return late, worst
 
     def tail(self) -> bool:
         """One more step from the first collector, acknowledged, for the

@@ -5,20 +5,29 @@ one reader here and its arguments. The harness evaluates the metrics
 ``BENCHMARK.json`` lists for the cell; a reader that finds nothing to
 read returns None and the metric is left out of the line.
 
-What a reader is given (``ctx``): ``done`` (the window's requests, each
-with ``ms``, ``type``, ``spans``, ``results``, ``resident``, ``cached``,
-``t_wall_end``, ``series_steps``), ``before`` / ``after`` (``/stats`` as
-read at the window's ends), ``window_s``,
-``points`` (points acknowledged in the window), ``compiles`` (programs
-the compile cache gained in the window), ``trace`` (the reduced
-profiler trace and its wall-clock bounds), ``device_kind``.
+What a reader is given (``ctx``): ``kind`` (the traffic's), ``done``
+(the window's requests, each with ``ms``, ``type``, ``spans``,
+``results``, ``resident``, ``cached``, ``t_wall_end``,
+``series_steps``), ``before`` / ``after`` (``/stats`` as read at the
+window's ends), ``window_s``, ``points`` (points acknowledged in the
+window), ``compiles`` (programs the compile cache gained in the
+window), ``trace`` (the reduced profiler trace and the wall-clock
+bounds of the span it was recorded over), ``device_kind``. The readers
+of requests take those answered inside the traced span, the readers of
+``/stats`` the whole window.
 """
 
 from __future__ import annotations
 
 import statistics
 
-from benchmarks.lib import roofline, stats
+from benchmarks.lib import roofline, stats, tsbs
+
+# What a window of a kind of traffic gives a reader, where that is more
+# than its own kind's: a live window holds requests and acknowledged
+# points, so the metrics of both kinds read in it. A layer file's
+# ``kinds`` never names ``live``.
+READS_AS = {"live": ("queries", "load")}
 
 
 def _span_ms(tree: dict, name: str) -> float | None:
@@ -39,9 +48,17 @@ def _request_span_ms(done, name: str) -> float | None:
 
 
 def _typed(ctx, args):
+    """The window's answered requests of the reader's types: those
+    answered before the profiler stopped. The profile is written beside
+    the rest of the window and slows its requests (cpu100.dash-12h:
+    q_p50_ms 9% up, PERF.md section 5), which is the method's cost and
+    not the program's."""
     prefix = args.get("type_prefix", "")
-    return [d for d in ctx.get("done", ()) if d.ok
+    done = [d for d in ctx.get("done", ()) if d.ok
             and d.req.type.startswith(prefix)]
+    stop = (ctx.get("trace") or {}).get("t_stop")
+    return done if stop is None else [d for d in done
+                                      if d.t_wall_end <= stop]
 
 
 def client_median(ctx, args):
@@ -159,11 +176,26 @@ READERS = {f.__name__: f for f in (
     trace_hbm_share)}
 
 
+def find(bench_root: str, name: str) -> str:
+    """The file of a per-layer metric: ``layers/<name>.json``. A quantity
+    whose cells report different end-to-end metrics is split in
+    ``BENCHMARK.json`` (``parse_ms_per_kpt.live`` moves ``q_mean_ms``
+    where ``parse_ms_per_kpt`` moves ``ingest_points_per_s``); a split
+    name with no file of its own is read by its quantity's."""
+    try:
+        return tsbs.find_file(bench_root, "layers", name)
+    except FileNotFoundError:
+        if "." not in name:
+            raise
+        return tsbs.find_file(bench_root, "layers", name.rsplit(".", 1)[0])
+
+
 def evaluate(layer: dict, ctx: dict):
     reader = READERS.get(layer["reader"])
     if reader is None:
         raise KeyError(f"layer metric {layer['name']!r} names reader "
                        f"{layer['reader']!r}; known: {sorted(READERS)}")
-    if ctx["kind"] not in layer["kinds"]:
+    if not set(READS_AS.get(ctx["kind"], (ctx["kind"],))) \
+            & set(layer["kinds"]):
         return None
     return reader(ctx, layer.get("args", {}))

@@ -35,9 +35,10 @@ def build(cfg: dict, seed: int, out_dir: str) -> dict:
     os.makedirs(out_dir)
     wal = os.path.join(out_dir, "wal")
     # As tools/cli.py opens a store for an offline tool: CPU backend, no
-    # device window (nothing here queries), everything else default.
+    # device window (nothing here queries), everything else default but
+    # what the config's ``store`` object says (tsbs.STORE_KEYS).
     conf = Config(wal_path=wal, backend="cpu", auto_create_metrics=True,
-                  device_window=False)
+                  device_window=False, **cfg.get("store", {}))
     t0 = time.monotonic()
     db = TSDB(MemKVStore(wal_path=wal), conf, start_compaction_thread=False)
     steps = tsbs.loaded_steps(cfg)

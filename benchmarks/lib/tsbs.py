@@ -35,6 +35,9 @@ TEAMS = ("SF", "NYC", "LON", "CHI")
 WALK_SIGMA = 100.0              # one percent a step, in hundredths
 # Salts that keep the random streams of one seed apart.
 _TAG_STREAM, _VALUE_STREAM = 1, 2
+# What a config's optional ``store`` object may say of how its store is
+# built (``lib/store.py`` hands it to the program's ``Config``).
+STORE_KEYS = ("sstable_codec",)
 
 
 def rng(seed: int, *stream: int) -> np.random.Generator:
@@ -49,6 +52,10 @@ def load_config(path: str) -> dict:
     for key in ("hosts", "interval_s", "hours", "t0", "metrics", "tags"):
         if key not in cfg:
             raise ValueError(f"{path}: config lacks {key!r}")
+    unknown = sorted(set(cfg.get("store", ())) - set(STORE_KEYS))
+    if unknown:
+        raise ValueError(f"{path}: 'store' has {unknown}; the builder "
+                         f"knows {list(STORE_KEYS)}")
     return cfg
 
 

@@ -1,7 +1,10 @@
 """Reduce a profiler trace (``*.xplane.pb``) to the device figures.
 
 Run as a child with ``JAX_PLATFORMS=cpu`` once the daemon is gone
-(``python -m benchmarks.lib.xplane <trace dir>``): reading the file
+(``python -m benchmarks.lib.xplane <trace dir> [<from s> <to s>]``, the
+bounds on the profile's own clock: only what ran between them counts.
+The profiler records for a second or so after it is told to stop, and
+a span that ends amid traffic would count that as busy): reading the file
 needs ``jax.profiler.ProfileData``, and the harness's parent never
 imports jax. Prints one JSON object:
 
@@ -60,10 +63,11 @@ def union(intervals: list[tuple[int, int, str]]):
     return busy, gaps
 
 
-def reduce_planes(planes) -> dict:
+def reduce_planes(planes, clip: tuple[int, int] | None = None) -> dict:
     """``planes``: iterable of (plane name, [(line name, [(name,
     start_ns, duration_ns)])]) — what ``ProfileData`` holds, as plain
-    tuples so that a test can hand-make one."""
+    tuples so that a test can hand-make one. ``clip``: (from_ns, to_ns)
+    on the events' own clock; an operation counts by its part inside."""
     per_plane, ops, gaps_all = [], {}, []
     first, last = None, None
     for pname, lines in planes:
@@ -72,6 +76,9 @@ def reduce_planes(planes) -> dict:
         chosen = [ln for ln in lines if ln[0] == OPS_LINE] or lines
         iv = sorted((s, s + d, short_name(n)) for _ln, evs in chosen
                     for n, s, d in evs if d > 0)
+        if clip is not None:
+            iv = [(max(s, clip[0]), min(e, clip[1]), n) for s, e, n in iv
+                  if min(e, clip[1]) > max(s, clip[0])]
         for s, e, n in iv:
             ops[n] = ops.get(n, 0) + (e - s)
         busy, gaps = union(iv)
@@ -107,7 +114,9 @@ def main(argv: list[str]) -> int:
     path = argv[0]
     if os.path.isdir(path):
         path = find_xplane(path)
-    out = reduce_planes(read_planes(path))
+    clip = ((int(float(argv[1]) * 1e9), int(float(argv[2]) * 1e9))
+            if len(argv) > 2 else None)
+    out = reduce_planes(read_planes(path), clip)
     out["file_bytes"] = os.path.getsize(path)
     print(json.dumps(out))
     return 0

@@ -11,8 +11,11 @@ process that owns the chip is the daemon under test. A run
    copy of the store (a run never dirties the cache);
 3. checks the device and that the device window took in every stored
    point, and warms one request of every type of the cell's traffic
-   (a load cell: sends and has acknowledged the first steps);
-4. measures for ``--seconds``;
+   (a load or live cell: sends and has acknowledged the first steps);
+4. measures for ``--seconds``; a ``--trace 1`` run records the profiler
+   from the window's start until every worker has finished two whole
+   cycles of its mix, 20 s at the least, and not the whole window
+   (``TRACE_MIN_S``, ``TRACE_CYCLES``);
 5. checks the answers, outside the window, against the numpy reference
    of ``benchmarks/lib/tsbs.py``;
 6. prints each number compared beside its limit, then the result line.
@@ -51,6 +54,13 @@ OUT = os.path.join(BENCH, "out")
 # first. A store is ~450 MB on disk.
 MAX_STORES = 6
 READY_TIMEOUT_S = 900.0
+# A traced run records the profiler from the window's start until every
+# worker has finished TRACE_CYCLES whole cycles of its mix (a fair share
+# of every type, in the file's order), TRACE_MIN_S at the least, and
+# never past the window's end. A whole window's profile took
+# cpu100.dash-12h 98-114 s to write (PERF.md section 5).
+TRACE_MIN_S = 20.0
+TRACE_CYCLES = 2
 
 
 class RunFailure(Exception):
@@ -75,8 +85,11 @@ def child_env(cpu: bool) -> dict:
 # ---------------------------------------------------------------------------
 
 def store_key(cfg: dict, seed: int) -> str:
-    shape = json.dumps([cfg[k] for k in ("hosts", "interval_s", "hours",
-                                         "t0", "metrics", "tags")])
+    shape = [cfg[k] for k in ("hosts", "interval_s", "hours", "t0",
+                              "metrics", "tags")]
+    if "store" in cfg:      # how the store is built, where a config says
+        shape.append(cfg["store"])
+    shape = json.dumps(shape, sort_keys=True)
     return (f"{cfg['name']}-{seed}-"
             + hashlib.sha256(shape.encode()).hexdigest()[:8])
 
@@ -138,9 +151,18 @@ class Checks:
 
 
 def check_answers(cfg: dict, traffic: dict, seed: int,
-                  done: list, checks: Checks, rtol: float) -> None:
+                  done: list, checks: Checks, rtol: float,
+                  edge: client.Edge | None = None) -> None:
     """A sample of the window's answers, drawn from the seed, with the
-    longest in it, against the reference."""
+    longest in it, against the reference. With an ``edge`` (a store
+    written to in the window) the reference is taken over the loaded
+    steps plus those sent up to the edge's last stand: every point up
+    to a request's ``end`` was acknowledged before the request was
+    written, so its answer is determined; and the sample holds, of each
+    type, the first request written after each move of the edge, the
+    soonest after its move first: the answers that a point which
+    becomes visible late (a stage or ``/q`` cache not yet invalidated,
+    a staged batch not yet drained) would be missing from."""
     kept = [d for d in done if d.ok and d.body is not None]
     if not kept:
         checks.add("answers_compared_missing", 1, 0)
@@ -150,16 +172,43 @@ def check_answers(cfg: dict, traffic: dict, seed: int,
     longest = max(range(len(kept)), key=lambda i: len(kept[i].body))
     order = [longest] + [int(i) for i in rng.permutation(len(kept))
                          if i != longest]
-    # Every type of the mix first, then by the draw.
-    seen, pick = set(), []
+    steps = tsbs.loaded_steps(cfg)
+    pick, lags = [longest], []
+    if edge is not None:
+        steps += edge.step
+        first: dict[tuple[int, str], tuple[float, int]] = {}
+        for i, d in enumerate(kept):
+            if d.req.edge is None:
+                continue
+            lag = (d.t_wall_end - d.ms / 1000.0
+                   - edge.moved_at(d.req.edge))
+            key = (d.req.edge, d.req.type)
+            if key not in first or lag < first[key][0]:
+                first[key] = (lag, i)
+        lags = sorted(first.values())
+        pick += [i for _lag, i in lags if i != longest]
+    # Every type of the mix next, then by the draw.
+    seen = {kept[i].req.type for i in pick}
     for i in order:
         if kept[i].req.type not in seen:
             seen.add(kept[i].req.type)
             pick.append(i)
     pick += [i for i in order if i not in pick]
     pick = pick[:int(traffic["check_max"])]
+    near = [lag for lag, i in lags if i in pick]
+    if near:
+        log(f"of the requests compared {len(near)} are the first of their "
+            f"type after a move of the edge, {sum(x < 1.0 for x in near)} "
+            f"written within 1 s of it, the soonest "
+            f"{min(near) * 1000.0:.0f} ms after")
+    if edge is not None:
+        # An anchored request ends at an edge that had been
+        # acknowledged when it was written.
+        checks.add("answers_ahead_of_edge", sum(
+            d.req.end != edge.ts(d.req.edge)
+            or edge.moved_at(d.req.edge) > d.t_wall_end - d.ms / 1000.0
+            for d in (kept[i] for i in pick) if d.req.edge is not None), 0)
     tags = tsbs.host_tag_table(cfg, seed)
-    steps = tsbs.loaded_steps(cfg)
     values: dict[int, np.ndarray] = {}
     worst_f32, exact_bad, shape_bad, compared, tokens = 0.0, 0, 0, 0, 0
     for i in pick:
@@ -204,47 +253,53 @@ def check_answers(cfg: dict, traffic: dict, seed: int,
 
 
 # ---------------------------------------------------------------------------
-# The two kinds of traffic
+# The kinds of traffic
 # ---------------------------------------------------------------------------
 
 def percentile(xs: list[float], q: float) -> float:
     return float(np.percentile(np.asarray(xs, dtype=np.float64), q))
 
 
-def run_queries(ctx: dict, daemon: Daemon, checks: Checks) -> dict:
-    cfg, traffic, args = ctx["cfg"], ctx["traffic"], ctx["args"]
-    load = client.QueryLoad(cfg, traffic, args.seed, daemon.port,
-                            traced=bool(args.trace))
+def warm_queries(load: client.QueryLoad) -> None:
     for d in load.warm():
         if not d.ok:
             raise RunFailure(f"warm-up request {d.req.type} failed: "
                              f"{d.why}")
         log(f"warm {d.req.type}: {d.ms:.1f} ms")
-    ctx["begin_window"]()
-    if args.trace:
-        daemon.start_trace()
-    ctx["window_s"] = load.run(args.seconds)
-    if args.trace:
-        daemon.stop_trace()
-    ctx["end_window"]()
+
+
+def query_metrics(ctx: dict, load: client.QueryLoad) -> dict:
+    """The window's requests into ``ctx``, the log and the two
+    end-to-end metrics: over every request, and all of the window."""
     ctx["done"] = load.done
     ms = [d.ms for d in load.done]
     ctx["attempted"] = len(load.done)
     ctx["failed"] = sum(not d.ok for d in load.done)
     for d in [d for d in load.done if not d.ok][:5]:
         log(f"failed {d.req.type}: {d.why}")
-    for qtype in traffic["types"]:
+    for qtype in ctx["traffic"]["types"]:
         mine = [d.ms for d in load.done if d.req.type == qtype["name"]]
         if mine:
             log(f"{qtype['name']}: {len(mine)} requests, median "
                 f"{percentile(mine, 50):.0f} ms, max {max(mine):.0f} ms")
     log(f"window {ctx['window_s']:.1f} s, {len(ms)} requests, "
         f"{sum(len(d.req.ms) for d in load.done)} sub-queries")
+    return {"q_mean_ms": float(np.mean(np.asarray(ms, dtype=np.float64))),
+            "queries_per_s": len(ms) / ctx["window_s"]}
+
+
+def run_queries(ctx: dict, daemon: Daemon, checks: Checks) -> dict:
+    cfg, traffic, args = ctx["cfg"], ctx["traffic"], ctx["args"]
+    load = client.QueryLoad(cfg, traffic, args.seed, daemon.port,
+                            traced=bool(args.trace))
+    warm_queries(load)
+    ctx["begin_window"](load)
+    ctx["window_s"] = load.run(args.seconds)
+    ctx["end_window"]()
     ctx["after_kill"] = lambda: check_answers(
         cfg, traffic, args.seed, load.done, checks,
         float(cfg["guarantees"]["f32_rtol"]))
-    return {"q_mean_ms": float(np.mean(np.asarray(ms, dtype=np.float64))),
-            "queries_per_s": len(ms) / ctx["window_s"]}
+    return query_metrics(ctx, load)
 
 
 class CycleClock:
@@ -323,80 +378,48 @@ class CycleClock:
             self._thread.join()
 
 
-def run_load(ctx: dict, daemon: Daemon, checks: Checks) -> dict:
+def count_back(ctx: dict, daemon: Daemon, checks: Checks,
+               gen: client.IngestLoad) -> int:
+    """Every acknowledged point counted back: by the live daemon, then
+    a seeded sample of the new series value by value, then one more
+    acknowledged step, the SIGKILL, and a recount from the files alone.
+    Ends the daemon (its device figures are read first). Returns the
+    points and put lines that missed."""
     cfg, traffic, args = ctx["cfg"], ctx["traffic"], ctx["args"]
-    gen: client.IngestLoad = ctx["ingest"]
-    try:
-        if not gen.warm(daemon.port):
-            raise RunFailure("a collector lost its connection in warm-up")
-        warm_points = gen.points_sent()
-        clock = CycleClock(daemon.port, traffic, args.seconds)
-        ctx["begin_window"]()
-        if args.trace:
-            daemon.start_trace()
-        clock.start()
-        try:
-            elapsed = gen.run(clock.deadline)
-        finally:
-            clock.stop()
-        ctx["window_s"] = elapsed
-        ctx["end_window"]()
-        lost = [c.lost for c in gen.collectors if c.lost]
-        if lost:
-            raise RunFailure(f"a collector stopped: {lost[0]}")
-        sent = gen.points_sent() - warm_points
-        ctx["points"] = sent
-        log(f"sent {sent:,} points in {elapsed:.2f} s; cycles ended at "
-            + ", ".join(f"+{m - clock.t0:.1f}" for m in clock.marks)
-            + f" s; window of {clock.cycles} cycles")
-        if clock.capped and not ctx["rehearsal"]:
-            raise RunFailure(
-                f"the window reached {clock.cap_s:.0f} s before "
-                f"{clock.min_cycles} cycles of {clock.stat} were seen")
-
-        # -- every acknowledged point counted back, by the live daemon --
-        step, t0 = int(cfg["interval_s"]), int(cfg["t0"])
-        last_ts = gen.first_ts + step * (gen.extra - 1)
-        target = (f"/q?start={t0}&end={last_ts}"
-                  + "".join(f"&m=sum:1h-count:{name}"
-                            for name in cfg["metrics"]) + "&json&nocache")
-        counted = sum(sum(r["dps"].values())
-                      for r in stats.get_json(daemon.port, target, 600.0))
-        want = ctx["store_points"] + gen.points_sent()
-        checks.add("count_minus_acknowledged", counted - want, 0)
-        # -- a seeded sample of the new series, value by value ----------
-        rng = tsbs.rng(args.seed, 66)
-        bad = 0
-        for _ in range(int(traffic["check_series"])):
-            mi = int(rng.integers(len(cfg["metrics"])))
-            h = int(rng.integers(int(cfg["hosts"])))
-            idx = gen.series_sent(mi, h)
-            if idx.size == 0:
-                continue
-            res = stats.get_json(daemon.port, (
-                f"/q?start={gen.first_ts}&end={last_ts}&m=sum:"
-                f"{cfg['metrics'][mi]}%7Bhost=host_{h}%7D&json&nocache"),
-                600.0)
-            want_v = tsbs.stored(gen.values[mi][idx, h])
-            ok = (len(res) == 1 and tsbs.compare(
-                res[0]["dps"], gen.first_ts + step * idx, want_v, 0.0)
-                == 0.0)
-            bad += not ok
-        checks.add("sampled_series_unequal", bad, 0)
-        if args.trace:
-            daemon.stop_trace()
-            ctx["trace_marks"] = daemon.trace_result(120.0)
-        ctx["memory"] = daemon.memory()
-
-        # -- one more acknowledged step, the kill, the files alone ------
-        if not gen.tail():
-            raise RunFailure("the tail's barrier did not come back")
-        daemon.kill()
-        errors = sum(c.error_lines for c in gen.collectors)
-        checks.add("put_error_lines", errors, 0)
-        new_points = gen.points_sent()
-    finally:
-        gen.close()
+    step, t0 = int(cfg["interval_s"]), int(cfg["t0"])
+    last_ts = gen.first_ts + step * (gen.extra - 1)
+    target = (f"/q?start={t0}&end={last_ts}"
+              + "".join(f"&m=sum:1h-count:{name}"
+                        for name in cfg["metrics"]) + "&json&nocache")
+    counted = sum(sum(r["dps"].values())
+                  for r in stats.get_json(daemon.port, target, 600.0))
+    want = ctx["store_points"] + gen.points_sent()
+    checks.add("count_minus_acknowledged", counted - want, 0)
+    rng = tsbs.rng(args.seed, 66)
+    bad = 0
+    for _ in range(int(traffic["check_series"])):
+        mi = int(rng.integers(len(cfg["metrics"])))
+        h = int(rng.integers(int(cfg["hosts"])))
+        idx = gen.series_sent(mi, h)
+        if idx.size == 0:
+            continue
+        res = stats.get_json(daemon.port, (
+            f"/q?start={gen.first_ts}&end={last_ts}&m=sum:"
+            f"{cfg['metrics'][mi]}%7Bhost=host_{h}%7D&json&nocache"),
+            600.0)
+        want_v = tsbs.stored(gen.values[mi][idx, h])
+        ok = (len(res) == 1 and tsbs.compare(
+            res[0]["dps"], gen.first_ts + step * idx, want_v, 0.0)
+            == 0.0)
+        bad += not ok
+    checks.add("sampled_series_unequal", bad, 0)
+    ctx["read_device"]()
+    if not gen.tail():
+        raise RunFailure("the tail's barrier did not come back")
+    daemon.kill()
+    errors = sum(c.error_lines for c in gen.collectors)
+    checks.add("put_error_lines", errors, 0)
+    new_points = gen.points_sent()
     res = subprocess.run(
         [sys.executable, "-m", "benchmarks.lib.store", "count",
          os.path.join(ctx["work"], "store"), str(gen.first_ts),
@@ -409,13 +432,96 @@ def run_load(ctx: dict, daemon: Daemon, checks: Checks) -> dict:
     recount = json.loads(res.stdout.strip().splitlines()[-1])["points"]
     checks.add("recount_after_kill_minus_acknowledged",
                recount - new_points, 0)
-    ctx["attempted"] = sent
-    ctx["failed"] = int(abs(counted - want) + abs(recount - new_points)
-                        + errors)
+    return int(abs(counted - want) + abs(recount - new_points) + errors)
+
+
+def collectors_sound(gen: client.IngestLoad) -> None:
+    lost = [c.lost for c in gen.collectors if c.lost]
+    if lost:
+        raise RunFailure(f"a collector stopped: {lost[0]}")
+
+
+def run_load(ctx: dict, daemon: Daemon, checks: Checks) -> dict:
+    traffic, args = ctx["traffic"], ctx["args"]
+    gen: client.IngestLoad = ctx["ingest"]
+    try:
+        gen.warm(daemon.port)
+        collectors_sound(gen)
+        warm_points = gen.points_sent()
+        clock = CycleClock(daemon.port, traffic, args.seconds)
+        ctx["begin_window"]()
+        clock.start()
+        try:
+            elapsed = gen.run(clock.deadline)
+        finally:
+            clock.stop()
+        ctx["window_s"] = elapsed
+        ctx["end_window"]()
+        collectors_sound(gen)
+        sent = gen.points_sent() - warm_points
+        ctx["points"] = sent
+        log(f"sent {sent:,} points in {elapsed:.2f} s; cycles ended at "
+            + ", ".join(f"+{m - clock.t0:.1f}" for m in clock.marks)
+            + f" s; window of {clock.cycles} cycles")
+        if clock.capped and not ctx["rehearsal"]:
+            raise RunFailure(
+                f"the window reached {clock.cap_s:.0f} s before "
+                f"{clock.min_cycles} cycles of {clock.stat} were seen")
+        ctx["attempted"] = sent
+        ctx["failed"] = count_back(ctx, daemon, checks, gen)
+    finally:
+        gen.close()
     return {"ingest_points_per_s": sent / elapsed}
 
 
-KINDS = {"queries": run_queries, "load": run_load}
+def run_live(ctx: dict, daemon: Daemon, checks: Checks) -> dict:
+    """A deployment that is written to while it is read: the paced
+    collectors of ``IngestLoad`` beside the closed-loop workers of
+    ``QueryLoad``, joined by the acknowledged edge. The window and the
+    end-to-end metrics are ``run_queries``'s, the count-back
+    ``run_load``'s; the answers are checked over loaded + sent steps."""
+    cfg, traffic, args = ctx["cfg"], ctx["traffic"], ctx["args"]
+    gen: client.IngestLoad = ctx["ingest"]
+    try:
+        gen.warm(daemon.port)
+        collectors_sound(gen)
+        warm_points = gen.points_sent()
+        load = client.QueryLoad(cfg, traffic, args.seed, daemon.port,
+                                traced=bool(args.trace), edge=gen.edge)
+        warm_queries(load)
+        ctx["begin_window"](load)
+        gen.start_paced()
+        try:
+            ctx["window_s"] = load.run(args.seconds)
+        finally:
+            elapsed = gen.finish_paced()
+        ctx["end_window"]()
+        collectors_sound(gen)
+        metrics = query_metrics(ctx, load)
+        ctx["points"] = gen.points_sent() - warm_points
+        late, worst = gen.late_steps()
+        log(f"collectors: {ctx['points']:,} points acknowledged in "
+            f"{elapsed:.2f} s, a step every {gen.period_s:g} s, edge "
+            f"{gen.warm_steps} -> {gen.edge.step} (moved at "
+            + ", ".join(f"+{t - gen.t0_wall:.1f}" for s, t in gen.edge.moved
+                        if s > gen.warm_steps)
+            + f" s); {late} steps late, the worst acknowledged "
+            f"{worst:.2f} s after it was due")
+        # A collector that falls behind is a deployment that cannot
+        # take the rate its mix states: not ``correct``, at any pace.
+        checks.add("collector_late_steps", late, 0)
+        ctx["after_kill"] = lambda: check_answers(
+            cfg, traffic, args.seed, load.done, checks,
+            float(cfg["guarantees"]["f32_rtol"]), gen.edge)
+        ctx["failed"] += count_back(ctx, daemon, checks, gen)
+    finally:
+        gen.close()
+    return metrics
+
+
+KINDS = {"queries": run_queries, "load": run_load, "live": run_live}
+# The kinds whose collectors' data is made while the daemon boots.
+WRITING = ("load", "live")
 
 
 # ---------------------------------------------------------------------------
@@ -428,7 +534,8 @@ def reduce_trace(ctx: dict, daemon: Daemon) -> dict | None:
         return None
     res = subprocess.run(
         [sys.executable, "-m", "benchmarks.lib.xplane",
-         os.path.join(daemon.sig_dir, "trace")],
+         os.path.join(daemon.sig_dir, "trace"), repr(marks["lead_s"]),
+         repr(marks["lead_s"] + marks["t_stop"] - marks["t_start"])],
         cwd=REPO, env=child_env(cpu=True), stdout=subprocess.PIPE,
         stderr=subprocess.PIPE, text=True)
     if res.returncode != 0:
@@ -448,7 +555,10 @@ def run(args, bench: dict, rehearsal: bool) -> dict:
                          f"{args.benchmark_json}")
     conf = next(c for c in bench["configs"] if c["name"] == cell["config"])
     cfg_path = os.path.join(REPO, conf["file"])
-    cfg = tsbs.load_config(cfg_path)
+    try:
+        cfg = tsbs.load_config(cfg_path)
+    except ValueError as e:
+        raise RunFailure(str(e)) from e
     if rehearsal and not cfg.get("rehearsal"):
         raise RunFailure(
             "JAX_PLATFORMS=cpu asks for a CPU rehearsal, and a rehearsal "
@@ -476,8 +586,7 @@ def run(args, bench: dict, rehearsal: bool) -> dict:
                  "store_points": int(meta["points"])}
     try:
         daemon.start()
-        if traffic["kind"] == "load":
-            # The collectors' data, made while the daemon boots.
+        if traffic["kind"] in WRITING:
             ctx["ingest"] = client.IngestLoad(
                 cfg, traffic, args.seed, args.seconds
                 * float(traffic.get("max_window_factor", 1)))
@@ -498,27 +607,57 @@ def run(args, bench: dict, rehearsal: bool) -> dict:
                    - meta["points"], 0)
         cache_dir = hz.get("compile_cache_dir")
 
-        def begin_window():
+        def begin_window(load: client.QueryLoad | None = None):
             ctx["before"] = stats.read_stats(port) if traced else None
             ctx["cache_before"] = cache_files(cache_dir)
             ctx["setup_s"] = time.monotonic() - T_START
             log(f"window starts; setup_s = {ctx['setup_s']:.2f}")
+            if not traced:
+                return
+            daemon.start_trace()
+            t0 = time.monotonic()
+
+            def span_over():
+                """Ends the traced span once it has lasted TRACE_MIN_S
+                and every worker has finished TRACE_CYCLES cycles: asked
+                by a timer at the one and by each worker at the end of
+                a cycle (a window of collectors alone has no worker)."""
+                if time.monotonic() - t0 >= TRACE_MIN_S and all(
+                        n >= TRACE_CYCLES for n in
+                        (load.cycles if load is not None else ())):
+                    daemon.stop_trace()
+            if load is not None:
+                load.after_cycle = span_over
+            ctx["trace_timer"] = threading.Timer(TRACE_MIN_S, span_over)
+            ctx["trace_timer"].daemon = True
+            ctx["trace_timer"].start()
 
         def end_window():
             ctx["compiles"] = cache_files(cache_dir) - ctx["cache_before"]
             if traced:
+                ctx["trace_timer"].cancel()
+                daemon.stop_trace()
                 ctx["after"] = stats.read_stats(port)
                 if args.keep:
                     with open(os.path.join(work, "stats.json"), "w") as f:
                         json.dump({"before": ctx["before"],
                                    "after": ctx["after"]}, f)
 
+        def read_device():
+            """What only the live daemon can say, once the window is
+            over: the traced span's bounds and the device's memory."""
+            if traced:
+                marks = ctx["trace_marks"] = daemon.trace_result(120.0)
+                log(f"traced {marks['t_stop'] - marks['t_start']:.1f} s from "
+                    f"{marks['lead_s']:.3f} s into the profile; written "
+                    f"in {marks['written_s']:.1f} s")
+            ctx["memory"] = daemon.memory()
+
         ctx["begin_window"], ctx["end_window"] = begin_window, end_window
+        ctx["read_device"] = read_device
         metrics = KINDS[traffic["kind"]](ctx, daemon, checks)
         if "memory" not in ctx:
-            if traced:
-                ctx["trace_marks"] = daemon.trace_result(120.0)
-            ctx["memory"] = daemon.memory()
+            read_device()
         daemon.kill()
         if "after_kill" in ctx:
             ctx["after_kill"]()
@@ -554,7 +693,7 @@ def run(args, bench: dict, rehearsal: bool) -> dict:
         for m in bench["per_layer"]:
             if "workloads" in m and args.workload not in m["workloads"]:
                 continue
-            with open(tsbs.find_file(BENCH, "layers", m["name"])) as f:
+            with open(layers.find(BENCH, m["name"])) as f:
                 layer = json.load(f)
             if rehearsal and layer["source"] == "device_trace":
                 continue

@@ -11,7 +11,8 @@ harness sends their signal:
   measured window.
 - SIGUSR2 starts a helper thread that records a ``jax.profiler`` trace
   into ``D/trace`` until ``D/trace.stop`` appears, then writes
-  ``D/trace.json`` with the wall-clock bounds. Sent only in a
+  ``D/trace.json`` with the wall-clock bounds and how long after the
+  profile's own start the first of them lies. Sent only in a
   ``--trace 1`` run.
 
 Nothing else is here: what breaks a guarantee to show ``correct`` come
@@ -57,6 +58,7 @@ def _trace(sig_dir: str) -> None:
     opts.python_tracer_level = 0
     opts.host_tracer_level = 1
     stop = os.path.join(sig_dir, "trace.stop")
+    t_call = time.time()
     jax.profiler.start_trace(os.path.join(sig_dir, "trace"),
                              profiler_options=opts)
     t0 = time.time()
@@ -65,8 +67,12 @@ def _trace(sig_dir: str) -> None:
         time.sleep(0.05)
     t1 = time.time()
     jax.profiler.stop_trace()
+    # The profile counts its time from the session's start, inside the
+    # call above, and goes on recording for a while after the call
+    # below: ``lead_s`` places the bounds on the profile's own clock.
     _write(os.path.join(sig_dir, "trace.json"),
-           {"t_start": t0, "t_stop": t1, "written_s": time.time() - t1})
+           {"t_start": t0, "t_stop": t1, "lead_s": t0 - t_call,
+            "written_s": time.time() - t1})
 
 
 def main(argv: list[str]) -> int:
