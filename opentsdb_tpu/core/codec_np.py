@@ -155,44 +155,83 @@ def decode_cell(qual: bytes, value: bytes, base_ts: int) -> Columns:
 
 
 
+def sort_dedup_multi(group: np.ndarray | None, deltas: np.ndarray,
+                     float_values: np.ndarray, int_values: np.ndarray,
+                     is_float: np.ndarray):
+    """Sort points by (group, delta) and drop duplicate deltas within a
+    group; ``group`` None is one group (one row, or one series).
+
+    Equal (delta, type, value) duplicates collapse silently. A group
+    holding conflicting values at one delta is dropped WHOLE and named
+    in the returned ``bad`` dict (group -> its first conflicting delta):
+    the tombstone-or-fsck rule of the compaction merge (reference
+    complexCompact :600-679) applied per group, so one series' conflict
+    costs a multi-series chunk that series alone. Last-writer order
+    within the input is irrelevant because conflicts are errors, not
+    overwrites. Returns (group, deltas, floats, ints, is_float, bad);
+    arrays come back by reference where nothing had to move.
+    """
+    d = np.asarray(deltas)
+    f = np.asarray(float_values)
+    i = np.asarray(int_values)
+    isf = np.asarray(is_float)
+    g = None if group is None else np.asarray(group)
+    bad: dict[int, int] = {}
+    if len(d) <= 1:
+        return g, d, f, i, isf, bad
+    # The collector pattern: batches arrive sorted, and one O(n)
+    # monotonicity check beats the O(n log n) sort + gathers it
+    # replaces (~8% of sustained batch ingest).
+    if g is None:
+        same_g = True
+        inorder = d[1:] >= d[:-1]
+    else:
+        same_g = g[1:] == g[:-1]
+        inorder = (g[1:] > g[:-1]) | (same_g & (d[1:] >= d[:-1]))
+    if not inorder.all():
+        order = (np.argsort(d, kind="stable") if g is None
+                 else np.lexsort((d, g)))
+        d, f, i, isf = d[order], f[order], i[order], isf[order]
+        if g is not None:
+            g = g[order]
+            same_g = g[1:] == g[:-1]
+    dup = (d[1:] == d[:-1]) & same_g
+    if dup.any():
+        same_type = isf[1:] == isf[:-1]
+        same_val = np.where(isf[1:], f[1:] == f[:-1], i[1:] == i[:-1])
+        conflict = dup & ~(same_type & same_val)
+        keep = np.concatenate(([True], ~dup))
+        if conflict.any():
+            at = np.flatnonzero(conflict) + 1
+            if g is None:
+                bad[0] = int(d[at[0]])
+                keep[:] = False
+            else:
+                for gi, di in zip(g[at].tolist(), d[at].tolist()):
+                    bad.setdefault(gi, di)
+                keep &= ~np.isin(g, list(bad))
+        d, f, i, isf = d[keep], f[keep], i[keep], isf[keep]
+        if g is not None:
+            g = g[keep]
+    return g, d, f, i, isf, bad
+
+
+def duplicate_data_error(delta: int) -> IllegalDataError:
+    return IllegalDataError(
+        f"Found out of order or duplicate data: delta={delta}"
+        " -- run an fsck.")
+
+
 def sort_dedup(deltas: np.ndarray, float_values: np.ndarray,
                int_values: np.ndarray, is_float: np.ndarray,
                ) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-    """Sort one row's points by delta and drop duplicate deltas.
-
-    Equal (delta, type, value) duplicates collapse silently; conflicting
-    values at one delta raise IllegalDataError — the same tombstone-or-fsck
-    rule as the compaction merge (reference complexCompact :600-679).
-    Last-writer order within the input is irrelevant because conflicts are
-    errors, not overwrites.
-    """
-    deltas = np.asarray(deltas)
-    if len(deltas) > 1 and (deltas[1:] >= deltas[:-1]).all():
-        # The collector pattern: batches arrive time-sorted, and one
-        # O(n) monotonicity check beats the O(n log n) argsort + four
-        # gathers it replaces (~8% of sustained batch ingest).
-        d = deltas
-        f = np.asarray(float_values)
-        i = np.asarray(int_values)
-        isf = np.asarray(is_float)
-    else:
-        order = np.argsort(deltas, kind="stable")
-        d = deltas[order]
-        f = np.asarray(float_values)[order]
-        i = np.asarray(int_values)[order]
-        isf = np.asarray(is_float)[order]
-    if len(d) > 1:
-        dup = d[1:] == d[:-1]
-        if dup.any():
-            same_type = isf[1:] == isf[:-1]
-            same_val = np.where(isf[1:], f[1:] == f[:-1], i[1:] == i[:-1])
-            if (dup & ~(same_type & same_val)).any():
-                bad = int(d[1:][dup & ~(same_type & same_val)][0])
-                raise IllegalDataError(
-                    f"Found out of order or duplicate data: delta={bad}"
-                    " -- run an fsck.")
-            keep = np.concatenate(([True], ~dup))
-            d, f, i, isf = d[keep], f[keep], i[keep], isf[keep]
+    """Sort one row's points by delta and drop duplicate deltas: the
+    one-group case of ``sort_dedup_multi``, raising IllegalDataError
+    where that names the group bad."""
+    _, d, f, i, isf, bad = sort_dedup_multi(
+        None, deltas, float_values, int_values, is_float)
+    if bad:
+        raise duplicate_data_error(bad[0])
     return d, f, i, isf
 
 

@@ -67,6 +67,12 @@ class CompactionQueue:
         with self._lock:
             self._queue[row_key] = base_ts
 
+    def add_many(self, row_keys: list[bytes], base_times) -> None:
+        """``add`` for many rows whose base times the caller holds, in
+        one lock turn."""
+        with self._lock:
+            self._queue.update(zip(row_keys, base_times))
+
     def flush(self, cutoff: int | None = None,
               max_flushes: int | None = None) -> int:
         """Compact every queued row with base_time <= cutoff; returns count.

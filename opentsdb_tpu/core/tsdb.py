@@ -6,10 +6,12 @@ write path builds row keys, encodes values on their smallest width, and
 schedules rows for compaction (:327-352).
 
 TPU-first departures:
-- ``add_batch`` is the real ingest path: a columnar batch for one series is
-  sorted/deduped/encoded into one *pre-compacted* cell per row-hour before
-  it ever hits storage, eliminating the reference's write-then-compact
-  amplification (one put per point + one rewrite per row per hour).
+- ``add_chunk`` is the real ingest path: a decoded wire chunk of many
+  series (``add_batch``: one series' columns, its one-series case) is
+  sorted/deduped/encoded into one *pre-compacted* cell per (series,
+  row-hour) and written as ONE put before it ever hits storage,
+  eliminating the reference's write-then-compact amplification (one put
+  per point + one rewrite per row per hour).
 - ``read_row`` decodes cells straight into columnar arrays (codec_np), so
   queries never iterate cells point by point.
 """
@@ -30,6 +32,7 @@ from opentsdb_tpu.core.const import (MAX_TIMESPAN, TIMESTAMP_BYTES,
                                      UID_WIDTH)
 from opentsdb_tpu.core.errors import NoSuchUniqueName, PleaseThrottleError
 from opentsdb_tpu.obs import trace as obs_trace
+from opentsdb_tpu.obs.registry import METRICS as _metrics
 from opentsdb_tpu.storage.kv import KVStore
 from opentsdb_tpu.storage.sstable import series_hash
 from opentsdb_tpu.uid.uniqueid import UniqueId
@@ -38,6 +41,46 @@ from opentsdb_tpu.utils.config import Config
 LOG = logging.getLogger(__name__)
 
 FAMILY = b"t"
+
+# The write path's phases, a chunk at a time (add_chunk): timers whose
+# sum_ms /stats carries; wal.append / wal.fsync run inside ingest.put.
+_M_RESOLVE = _metrics.timer("ingest.resolve")
+_M_ENCODE = _metrics.timer("ingest.encode")
+_M_PUT = _metrics.timer("ingest.put")
+_M_WINDOW = _metrics.timer("ingest.window")
+_M_SKETCH = _metrics.timer("ingest.sketch")
+_M_CHUNKS = _metrics.counter("ingest.chunks")
+_M_CHUNK_SERIES = _metrics.counter("ingest.chunk.series")
+_M_SERIES_COLD = _metrics.counter("ingest.series.cold")
+
+
+class _Series:
+    """A series as the write path resolved it (TSDB._resolve_series)."""
+
+    __slots__ = ("metric", "tag_map", "metric_uid", "tmpl", "skey",
+                 "tag_uids", "observed", "_label")
+
+    def __init__(self, metric: str, tag_map: dict[str, str],
+                 metric_uid: bytes, tmpl: bytes, skey: bytes,
+                 tag_uids: list[tuple[bytes, bytes, bytes]]) -> None:
+        self.metric = metric
+        self.tag_map = tag_map
+        self.metric_uid = metric_uid
+        self.tmpl = tmpl            # row key at base time 0
+        self.skey = skey
+        self.tag_uids = tag_uids    # (metric, tagk, tagv) UIDs: the HLLs'
+        self.observed = False       # tag_uids folded into the sketches
+        self._label: str | None = None
+
+    def label(self) -> str:
+        """"metric{k=v,...}", the tenant accounts' name of the series."""
+        if self._label is None:
+            self._label = self.metric
+            if self.tag_map:
+                self._label += "{" + ",".join(
+                    f"{k}={v}"
+                    for k, v in sorted(self.tag_map.items())) + "}"
+        return self._label
 
 
 class TSDB:
@@ -105,6 +148,9 @@ class TSDB:
         self._deregister = None
         # ingest stats
         self.datapoints_added = 0
+        # (metric, tags) -> _Series: what the write path resolved
+        # (_resolve_series).
+        self._series_cache: dict[tuple, _Series] = {}
         # Streaming sketch state (stats/livesketch.py): loaded from the
         # checkpoint snapshot when one exists (then re-folding only the
         # WAL-replayed memtable), else rebuilt from a full storage scan.
@@ -162,13 +208,6 @@ class TSDB:
         # Writers only — a replica neither admits nor snapshots.
         self.tenants = None
         self.tenant_limits = None
-        # skey -> "metric{k=v,...}" memo for the heavy-hitter summary:
-        # the label is invariant per series, so the per-point ingest
-        # path must not rebuild (sort + join) it every point. Cleared
-        # wholesale at the cap — churn past it is the hostile regime
-        # where the rebuild cost is the attacker's, not the steady
-        # workload's.
-        self._series_labels: dict[bytes, str] = {}
         if (self.config.tenant_accounting
                 and not getattr(store, "read_only", False)):
             self._init_tenants()
@@ -248,6 +287,8 @@ class TSDB:
 
     def _init_sketches(self) -> None:
         import os as _os
+
+        self._series_cache.clear()
 
         from opentsdb_tpu.stats.livesketch import LiveSketches
 
@@ -453,16 +494,6 @@ class TSDB:
                 [(pr.metric_uid, k, v) for k, v in pr.tag_uids])
         self.sketches.flush()
 
-    def _observe(self, series_key: bytes, metric_uid: bytes,
-                 pairs: list[tuple[bytes, bytes]],
-                 values: np.ndarray) -> None:
-        """Ingest-side sketch fold; callers pass the UIDs they already
-        resolved (no row-key re-parse on the hot path)."""
-        if self.sketches is None:
-            return
-        self.sketches.observe(
-            series_key, values, [(metric_uid, k, v) for k, v in pairs])
-
     # ------------------------------------------------------------------
     # Tenant cardinality control plane (opentsdb_tpu/tenant/)
     # ------------------------------------------------------------------
@@ -495,6 +526,7 @@ class TSDB:
         from opentsdb_tpu.tenant.limits import (TenantLimiter,
                                                 parse_overrides)
 
+        self._series_cache.clear()
         cfg = self.config
         self.tenant_limits = TenantLimiter(
             max_series=cfg.tenant_max_series,
@@ -582,23 +614,6 @@ class TSDB:
             return
         self.tenant_limits.admit_new_series(acct, tenant)
         acct.note_new_series(tenant, h, metric)
-
-    _SERIES_LABEL_CAP = 65536
-
-    def _account_points(self, tenant: str, metric: str,
-                        tag_map: dict, n: int, skey: bytes) -> None:
-        if self.tenants is None or n <= 0:
-            return
-        label = self._series_labels.get(skey)
-        if label is None:
-            label = metric
-            if tag_map:
-                label += "{" + ",".join(
-                    f"{k}={v}" for k, v in sorted(tag_map.items())) + "}"
-            if len(self._series_labels) >= self._SERIES_LABEL_CAP:
-                self._series_labels.clear()
-            self._series_labels[skey] = label
-        self.tenants.note_points(tenant, label, n)
 
     # ------------------------------------------------------------------
     # Row-key construction
@@ -688,32 +703,28 @@ class TSDB:
         else:
             buf, flags = codec.encode_long(value)
         base_ts = codec.base_time(timestamp)
-        metric_uid, pairs = self._row_parts_admitted(tenant, metric,
-                                                     tag_map)
-        row = codec.row_key(metric_uid, base_ts, pairs)
+        # Resolved, admitted and registered in the sketch directory
+        # before the put, as a chunk's series are (_resolve_series).
+        s = self._resolve_series(tenant, metric, tag_map)
+        row = (s.tmpl[:UID_WIDTH] + base_ts.to_bytes(TIMESTAMP_BYTES, "big")
+               + s.tmpl[UID_WIDTH + TIMESTAMP_BYTES:])
         qual = codec.encode_qualifier(timestamp - base_ts, flags)
-        skey = codec.series_key(row)
-        # Tenant admission first (a refused NEW series must leave no
-        # trace — _row_parts_admitted already gated UID creation the
-        # same way), then directory registration, then the put (see
-        # add_batch for the ordering argument).
-        self._admit_series(tenant, skey, metric)
-        if self.sketches is not None:
-            self.sketches.note_series(skey)
         self.store.put(self.table, row, FAMILY, qual, buf, durable=durable)
-        # Scalar puts bypass the delta-fold feed (add_batch): their
+        # Scalar puts bypass the delta-fold feed (add_chunk): their
         # coarse window must fall back to the full fold rescan.
         delta = getattr(self.rollups, "delta", None)
         if delta is not None:
-            delta.invalidate(skey, base_ts)
+            delta.invalidate(s.skey, base_ts)
         if self.config.enable_compactions:
             self.compactionq.add(row)
         self.datapoints_added += 1
-        self._account_points(tenant, metric, tag_map, 1, skey)
-        self._observe(skey, metric_uid, pairs,
-                      np.asarray([value], np.float64))
+        if self.tenants is not None:
+            self.tenants.note_points(tenant, s.label(), 1)
+        if self.sketches is not None:
+            self.sketches.observe(s.skey, np.asarray([value], np.float64),
+                                  s.tag_uids)
         if self.devwindow is not None:
-            self.devwindow.append(metric_uid, skey,
+            self.devwindow.append(s.metric_uid, s.skey,
                                   np.asarray([timestamp], np.int64),
                                   np.asarray([value], np.float32))
 
@@ -723,7 +734,8 @@ class TSDB:
                   is_float: np.ndarray | None = None,
                   int_values: np.ndarray | None = None,
                   tenant: str = "default", sync: bool = True) -> int:
-        """Columnar ingest for one series: pre-compacted cell per row-hour.
+        """Columnar ingest for one series: pre-compacted cell per row-hour
+        (the one-series case of ``add_chunk``).
 
         ``values`` may be an integer or floating dtype; float points are
         stored as 4-byte floats (matching telnet ingest), int points on
@@ -739,8 +751,6 @@ class TSDB:
         timestamps = np.asarray(timestamps, dtype=np.int64)
         if timestamps.size == 0:
             return 0
-        if (timestamps & ~np.int64(0xFFFFFFFF)).any():
-            raise ValueError("timestamp out of range in batch")
         if is_float is not None:
             fmask = np.asarray(is_float, dtype=bool)
             fvals = np.asarray(values, dtype=np.float64)
@@ -756,33 +766,45 @@ class TSDB:
             ivals = np.asarray(values, dtype=np.int64)
             fvals = ivals.astype(np.float64)
             fmask = np.zeros(timestamps.shape, dtype=bool)
+        n, errors = self.add_chunk(
+            ((metric, tag_map),), None, timestamps, fvals, ivals, fmask,
+            durable=durable, tenant=tenant, sync=sync)
+        if errors:
+            raise errors[0]
+        return n
 
-        # One vectorized pass for the whole series: global sort + dedup
-        # (same-timestamp points are same-hour by definition), then all
-        # row-hours' cells encoded in one flat-buffer pass.
-        ts_s, f_s, i_s, m_s = codec_np.sort_dedup(
-            timestamps, fvals, ivals, fmask)
-        base = ts_s - ts_s % MAX_TIMESPAN
-        deltas = ts_s - base
-        row_starts = np.concatenate(
-            ([0], np.flatnonzero(np.diff(base)) + 1))
-        quals, vals = codec_np.encode_cells_multi(deltas, f_s, i_s, m_s,
-                                                  row_starts)
+    # Resolved series kept by (metric, tags): see _resolve_series.
+    _SERIES_CACHE_CAP = 1 << 18
+
+    def _resolve_series(self, tenant: str, metric: str,
+                        tag_map: dict[str, str]) -> "_Series":
+        """A series' metric UID, row-key template and series key. One
+        seen before is a dict probe; a new one is resolved behind the
+        tenant gate, admitted and registered in the sketch directory
+        (all of which may raise, for this series alone) and kept.
+
+        What is kept never goes stale while the objects it was checked
+        against live: UIDs are assigned once, an admitted series stays
+        in the accountant's seen-set, a registered one in the sketch
+        directory. ``_init_sketches`` / ``_init_tenants`` replace those
+        objects and empty the cache; so does ``drop_caches``, the
+        operator's way to make a TSD forget a name mapping that was
+        changed out of band."""
+        key = (metric, frozenset(tag_map.items()))
+        s = self._series_cache.get(key)
+        if s is not None:
+            return s
+        _M_SERIES_COLD.inc()
         metric_uid, pairs = self._row_parts_admitted(tenant, metric,
                                                      tag_map)
         tmpl = bytes(codec.row_key(metric_uid, 0, pairs))
-        # All row keys in one vectorized pass: broadcast the template,
-        # stamp the base-time bytes, keep the CONTIGUOUS blob. The
-        # per-row struct.pack + bytearray copy loop was ~15% of batch
-        # ingest; the per-cell (key, qual, value) tuple list after it
-        # was another ~1 us/row-hour, so the blob now flows straight
-        # into put_many_columnar (which also writes it to the WAL
-        # record as-is).
-        L = len(tmpl)
-        keys = np.tile(np.frombuffer(tmpl, np.uint8), (len(quals), 1))
-        keys[:, UID_WIDTH:UID_WIDTH + TIMESTAMP_BYTES] = (
-            base[row_starts].astype(">u4").view(np.uint8).reshape(-1, 4))
-        kb = keys.tobytes()
+        skey = codec.series_key(tmpl)
+        # Tenant admission precedes both the directory registration
+        # and the put: a NEW series from an over-budget tenant refuses
+        # here (TenantLimitError, declared on the wire) before any
+        # byte lands — existing series pass the seen-set check and
+        # keep ingesting regardless of the tenant's budget.
+        self._admit_series(tenant, skey, metric)
         # The series enters the sketch slot DIRECTORY before any row
         # becomes visible in storage: the executor's bloom-pruning
         # hint treats the directory as a complete superset of series
@@ -790,68 +812,294 @@ class TSDB:
         # a window where a concurrent query prunes the shard holding
         # this series' first rows. (Values fold after the put as
         # before; over-registering an unapplied series is harmless.)
-        skey = codec.series_key(kb[:L])
-        # Tenant admission precedes both the directory registration
-        # and the put: a NEW series from an over-budget tenant refuses
-        # here (TenantLimitError, declared on the wire) before any
-        # byte lands — existing series pass the seen-set check and
-        # keep ingesting regardless of the tenant's budget.
-        self._admit_series(tenant, skey, metric)
         if self.sketches is not None:
             self.sketches.note_series(skey)
+        s = _Series(metric, tag_map, metric_uid, tmpl, skey,
+                    [(metric_uid, k, v) for k, v in pairs])
+        if len(self._series_cache) >= self._SERIES_CACHE_CAP:
+            self._series_cache.clear()
+        self._series_cache[key] = s
+        return s
+
+    def add_chunk(self, series, sid: np.ndarray | None,
+                  timestamps: np.ndarray, fvalues: np.ndarray,
+                  ivalues: np.ndarray, is_float: np.ndarray,
+                  durable: bool = True, tenant: str = "default",
+                  sync: bool = True) -> tuple[int, dict[int, Exception]]:
+        """Columnar ingest of a decoded chunk: many series, one put.
+
+        ``series[s]`` is a (metric, tags) pair and ``sid[i]`` the series
+        of point i (None: every point is of ``series[0]``); the value
+        columns are ``add_batch``'s, typed a point. The chunk is sorted
+        and deduplicated by (series, timestamp) once, every (series,
+        row-hour) cell encoded in one pass, and written with ONE
+        ``put_many_columnar`` a row-key length: one WAL record, applied
+        whole or not at all on replay; then one device-window append a
+        metric and one sketch observation.
+
+        Series stay independent: one that cannot be written (a
+        conflicting duplicate, an unknown metric, a tenant over its
+        limit) is left out with its exception in the returned dict, by
+        series index, and the rest land. An exception of the put itself
+        (a fenced writer, a throttle) is every series' of that put; on a
+        throttle the rows that did apply are queued for compaction and
+        every metric of the chunk drops its device window, since which
+        rows landed is unknowable from here. Returns (points written,
+        errors)."""
+        errors: dict[int, Exception] = {}
+        ts = np.asarray(timestamps, dtype=np.int64)
+        if ts.size == 0:
+            return 0, errors
+        with _M_RESOLVE.time():
+            if (ts & ~np.int64(0xFFFFFFFF)).any():
+                err = ValueError("timestamp out of range in batch")
+                if sid is None:
+                    return 0, {0: err}
+                off = (ts & ~np.int64(0xFFFFFFFF)) != 0
+                errors.update((s, err)
+                              for s in np.unique(sid[off]).tolist())
+                keep = ~np.isin(sid, list(errors))
+                sid, ts, fvalues, ivalues, is_float = (
+                    sid[keep], ts[keep], fvalues[keep], ivalues[keep],
+                    is_float[keep])
+            # One vectorized pass for the whole chunk: sort + dedup by
+            # (series, timestamp); same-timestamp points are same-hour
+            # by definition.
+            sid_s, ts_s, f_s, i_s, m_s, bad = codec_np.sort_dedup_multi(
+                sid, ts, fvalues, ivalues, is_float)
+            for s, at in bad.items():
+                errors[s] = codec_np.duplicate_data_error(at)
+            # Each distinct series, resolved once; ``local`` numbers the
+            # resolved ones 0.. in series order.
+            resolved: list[_Series] = []
+            sid_of: list[int] = []      # local number -> series index
+            if sid_s is None:
+                distinct = (0,) if len(ts_s) else ()
+            else:
+                distinct = np.unique(sid_s).tolist()
+                local = np.full(len(series), -1, np.int64)
+            for s in distinct:
+                metric, tag_map = series[s]
+                try:
+                    r = self._resolve_series(tenant, metric, tag_map)
+                except Exception as e:
+                    errors[s] = e
+                    continue
+                if sid_s is not None:
+                    local[s] = len(resolved)
+                resolved.append(r)
+                sid_of.append(s)
+            # ``loc``: the local number of each point's series; None
+            # where one series is left. That is add_batch's case (a
+            # series' whole span a call, 40,000 calls a store build):
+            # from here on it runs the same steps on scalars, without
+            # the arrays that tell a chunk's series apart.
+            loc = None
+            if sid_s is not None and len(resolved):
+                loc = local[sid_s]
+                if len(errors):
+                    keep = loc >= 0
+                    loc, ts_s, f_s, i_s, m_s = (
+                        loc[keep], ts_s[keep], f_s[keep], i_s[keep],
+                        m_s[keep])
+                if len(resolved) == 1:
+                    loc = None
+        _M_CHUNKS.inc()
+        _M_CHUNK_SERIES.inc(len(resolved))
+        if not resolved or len(ts_s) == 0:
+            return 0, errors
+        with _M_ENCODE.time():
+            # All (series, row-hour) cells in one flat-buffer pass, and
+            # all row keys in one (_key_blobs).
+            base = ts_s - ts_s % MAX_TIMESPAN
+            row_starts = self._row_starts(base, loc)
+            quals, vals = codec_np.encode_cells_multi(
+                ts_s - base, f_s, i_s, m_s, row_starts)
+            row_loc = None if loc is None else loc[row_starts]
+            puts = self._key_blobs(resolved, row_loc, base[row_starts])
         # Rows that already held cells BEFORE the put become multi-cell
         # and must be queued so the per-batch compacted cells merge into
         # one; the store reports that per row in a single locked pass.
-        # A mid-batch throttle still queues the rows that DID apply.
         delta = getattr(self.rollups, "delta", None)
-        try:
-            existed = self.store.put_many_columnar(
-                self.table, FAMILY, kb, L, quals, vals, durable=durable,
-                sync=sync)
-        except PleaseThrottleError as e:
-            # Which rows landed is unknowable from here; the batch's
-            # rollup windows can no longer be folded incrementally.
-            if delta is not None:
-                delta.kill_batch(skey, base[row_starts])
-            existed = getattr(e, "partial_existed", [])
-            if self.config.enable_compactions:
-                for i, ex in enumerate(existed):
-                    if ex:
-                        self.compactionq.add(kb[i * L:(i + 1) * L])
-            # Rows that DID apply are now in storage but will never be
-            # appended to the device window (this raise skips it), and a
-            # later retry of the batch would fail its monotonicity check
-            # anyway — drop the metric's window so queries fall back to
-            # the scan path instead of silently serving a partial view.
-            if self.devwindow is not None:
-                self.devwindow.invalidate(metric_uid)
-            raise
-        # any() is a C-level scan: the sustained-ingest shape is
-        # all-new rows, where enumerating millions of False flags per
-        # batch would cost more than the batch's dict inserts.
-        if self.config.enable_compactions and any(existed):
-            for i, e in enumerate(existed):
-                if e:
-                    self.compactionq.add(kb[i * L:(i + 1) * L])
+        existed_of_row = np.zeros(len(row_starts), bool)
+        failed: list[int] = []     # local numbers whose put raised
+        with _M_PUT.time():
+            for L, rows, kb in puts:
+                q = quals if rows is None else [quals[r] for r in rows]
+                v = vals if rows is None else [vals[r] for r in rows]
+                try:
+                    existed = self.store.put_many_columnar(
+                        self.table, FAMILY, kb, L, q, v, durable=durable,
+                        sync=sync)
+                except Exception as e:
+                    # Every series of this put, and where its rows lie.
+                    at = (np.arange(len(row_starts)) if rows is None
+                          else np.asarray(rows))
+                    of = (np.zeros(len(at), np.int64) if row_loc is None
+                          else row_loc[at])
+                    group = np.unique(of).tolist()
+                    failed += group
+                    for j in group:
+                        errors[sid_of[j]] = e
+                    if not isinstance(e, PleaseThrottleError):
+                        continue
+                    # Which rows landed is unknowable from here; the
+                    # chunk's rollup windows can no longer be folded
+                    # incrementally.
+                    if delta is not None:
+                        for j in group:
+                            delta.kill_batch(resolved[j].skey,
+                                             base[row_starts[at[of == j]]])
+                    # A mid-batch throttle still queues the rows that
+                    # DID apply.
+                    existed = getattr(e, "partial_existed", [])
+                    # Rows that DID apply are now in storage but will
+                    # never be appended to the device window, and a
+                    # later retry of the chunk would fail its
+                    # monotonicity check anyway — drop the windows so
+                    # queries fall back to the scan path instead of
+                    # silently serving a partial view.
+                    if self.devwindow is not None:
+                        for uid in {r.metric_uid for r in resolved}:
+                            self.devwindow.invalidate(uid)
+                # any() is a C-level scan: the sustained-ingest shape is
+                # all-new rows, where enumerating millions of False
+                # flags per batch would cost more than the batch's dict
+                # inserts.
+                if any(existed):
+                    had = np.flatnonzero(existed)
+                    had_rows = had if rows is None else np.asarray(rows)[had]
+                    existed_of_row[had_rows] = True
+                    if self.config.enable_compactions:
+                        self.compactionq.add_many(
+                            [kb[i * L:(i + 1) * L] for i in had.tolist()],
+                            base[row_starts[had_rows]].tolist())
+        if failed:
+            if len(failed) == len(resolved):
+                return 0, errors
+            # The series of a put that failed take no further part.
+            gone = np.zeros(len(resolved), bool)
+            gone[failed] = True
+            keep = ~gone[loc]
+            existed_of_row = existed_of_row[~gone[row_loc]]
+            renum = np.cumsum(~gone) - 1
+            resolved = [r for r, g in zip(resolved, gone) if not g]
+            loc, ts_s, f_s, i_s, m_s, base = (
+                None if len(resolved) == 1 else renum[loc[keep]],
+                ts_s[keep], f_s[keep], i_s[keep], m_s[keep], base[keep])
+            row_starts = self._row_starts(base, loc)
+            row_loc = None if loc is None else loc[row_starts]
+        n = len(ts_s)
+        # How many points each series has (sorted by series: one run
+        # each).
+        counts = ([n] if loc is None else
+                  np.bincount(loc, minlength=len(resolved)).tolist())
         # Rollup delta accumulators (rollup/delta.py): the applied
-        # batch's columns ARE what a checkpoint fold's raw rescan
+        # chunk's columns ARE what a checkpoint fold's raw rescan
         # would decode, so buffer them for the incremental fold path.
         if delta is not None:
-            delta.feed(skey, ts_s, f_s, i_s, m_s, base, row_starts,
-                       existed)
-        n = len(ts_s)
+            ends = np.cumsum(counts)
+            row_of_series = ([0, len(row_starts)] if row_loc is None else
+                             np.searchsorted(row_loc,
+                                             np.arange(len(resolved) + 1)))
+            for j, r in enumerate(resolved):
+                a, b = int(ends[j] - counts[j]), int(ends[j])
+                ra, rb = row_of_series[j], row_of_series[j + 1]
+                delta.feed(r.skey, ts_s[a:b], f_s[a:b], i_s[a:b],
+                           m_s[a:b], base[a:b], row_starts[ra:rb] - a,
+                           existed_of_row[ra:rb].tolist())
         self.datapoints_added += n
-        self._account_points(tenant, metric, tag_map, n, skey)
-        # Sketch fold covers fully applied batches only (a throttled
-        # batch raised above); values as stored, floats and ints alike.
-        # One float32 conversion shared by both consumers (the digests
-        # quantize to f32 anyway; the window stores f32).
+        if self.tenants is not None:
+            self.tenants.note_points_many(
+                tenant, [r.label() for r in resolved], counts)
+        # Sketch fold covers fully applied puts only; values as stored,
+        # floats and ints alike. One float32 conversion shared by both
+        # consumers (the digests quantize to f32 anyway; the window
+        # stores f32).
         if self.sketches is not None or self.devwindow is not None:
             f32 = f_s.astype(np.float32)
-            self._observe(skey, metric_uid, pairs, f32)
+            if self.sketches is not None:
+                with _M_SKETCH.time():
+                    # A series' tag values fold into the HLLs the first
+                    # time its points apply; register max is idempotent,
+                    # so later chunks have nothing to add.
+                    fresh = [r for r in resolved if not r.observed]
+                    self.sketches.observe_many(
+                        [r.skey for r in resolved], loc, f32,
+                        [t for r in fresh for t in r.tag_uids])
+                    for r in fresh:
+                        r.observed = True
             if self.devwindow is not None:
-                self.devwindow.append(metric_uid, skey, ts_s, f32)
-        return n
+                with _M_WINDOW.time():
+                    self._append_window(resolved, loc, ts_s, f32)
+        return n, errors
+
+    @staticmethod
+    def _key_blobs(resolved, row_loc: np.ndarray | None,
+                   row_base: np.ndarray) -> list:
+        """The row keys of a chunk's rows, one CONTIGUOUS blob a
+        row-key length (it flows into put_many_columnar and on into the
+        WAL record as-is): ``(length, rows, blob)`` with ``rows`` the
+        rows of that length, None for every row. Row r is of series
+        ``resolved[row_loc[r]]`` (None: all of ``resolved[0]``): its
+        template with the base-time bytes stamped."""
+        stamp = row_base.astype(">u4").view(np.uint8).reshape(-1, 4)
+        when = slice(UID_WIDTH, UID_WIDTH + TIMESTAMP_BYTES)
+        if row_loc is None:
+            tmpl = resolved[0].tmpl
+            keys = np.tile(np.frombuffer(tmpl, np.uint8), (len(stamp), 1))
+            keys[:, when] = stamp
+            return [(len(tmpl), None, keys.tobytes())]
+        key_len = np.fromiter((len(r.tmpl) for r in resolved), np.int64,
+                              len(resolved))
+        puts = []
+        for L in np.unique(key_len).tolist():
+            members = np.flatnonzero(key_len == L)
+            whole = len(members) == len(resolved)
+            rows = None if whole else np.flatnonzero(key_len[row_loc] == L)
+            tmpls = np.frombuffer(
+                b"".join(resolved[j].tmpl for j in members.tolist()),
+                np.uint8).reshape(-1, L)
+            keys = tmpls[row_loc if whole else
+                         np.searchsorted(members, row_loc[rows])]
+            keys[:, when] = stamp if whole else stamp[rows]
+            puts.append((L, None if whole else rows.tolist(),
+                         keys.tobytes()))
+        return puts
+
+    @staticmethod
+    def _row_starts(base: np.ndarray, loc: np.ndarray | None) -> np.ndarray:
+        """Where a (series, row-hour) run of points sorted by (series,
+        timestamp) begins; ``loc`` None is one series."""
+        brk = base[1:] != base[:-1]
+        if loc is not None:
+            brk |= loc[1:] != loc[:-1]
+        return np.concatenate(([0], np.flatnonzero(brk) + 1))
+
+    def _append_window(self, resolved, loc, ts_s, f32) -> None:
+        """The applied chunk into the device window: one append a
+        metric (points are sorted by series, so a metric's series are
+        runs; metrics interleave only where a chunk's series do)."""
+        dw = self.devwindow
+        if len(resolved) == 1:
+            dw.append(resolved[0].metric_uid, resolved[0].skey, ts_s, f32)
+            return
+        uids: dict[bytes, int] = {}
+        metric_of = np.fromiter(
+            (uids.setdefault(r.metric_uid, len(uids)) for r in resolved),
+            np.int64, len(resolved))
+        if len(uids) == 1:
+            dw.append_many(resolved[0].metric_uid,
+                           [r.skey for r in resolved], loc, ts_s, f32)
+            return
+        metric_of_pt = metric_of[loc]
+        renum = np.zeros(len(resolved), np.int64)
+        for uid, m in uids.items():
+            members = np.flatnonzero(metric_of == m)
+            pts = np.flatnonzero(metric_of_pt == m)
+            renum[members] = np.arange(len(members))
+            dw.append_many(uid, [resolved[j].skey for j in members],
+                           renum[loc[pts]], ts_s[pts], f32[pts])
 
     # ------------------------------------------------------------------
     # Compaction
@@ -1123,9 +1371,14 @@ class TSDB:
         return self.tagv.suggest(prefix)
 
     def drop_caches(self) -> None:
+        """Forget every cached name <-> UID mapping: the three UID
+        caches and the resolved series built from them, so that a name
+        renamed or repaired out of band (``uid rename``, fsck) resolves
+        afresh at its next put."""
         self.metrics.drop_caches()
         self.tagk.drop_caches()
         self.tagv.drop_caches()
+        self._series_cache.clear()
 
     def flush(self) -> None:
         """Flush compactions then the storage engine (reference :384-417)."""

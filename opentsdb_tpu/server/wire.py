@@ -19,6 +19,7 @@ from typing import NamedTuple
 import numpy as np
 
 from opentsdb_tpu.core import tags as tags_mod
+from opentsdb_tpu.obs import trace as obs_trace
 from opentsdb_tpu.obs.registry import METRICS as _metrics
 
 LOG = logging.getLogger(__name__)
@@ -763,61 +764,50 @@ def pipelined_ingest(tsdb, chunks, durable: bool = True,
     return total, errors
 
 
+def series_error(metric: str, e: Exception) -> str:
+    """One refused series' line. Stable machine-readable tags for
+    policy refusals: the server's error classifier keys on "[fenced]" /
+    "[tenant-limit]", not on exception message wording that could
+    drift. A tenant-limit refusal is per-series: the tenant's EXISTING
+    series in the batch still ingest — only the new one is refused."""
+    from opentsdb_tpu.core.errors import (FencedWriterError,
+                                          TenantLimitError)
+    if isinstance(e, FencedWriterError):
+        tag = "[fenced] "
+    elif isinstance(e, TenantLimitError):
+        tag = "[tenant-limit] "
+    else:
+        tag = ""
+    return f"{metric}: {tag}{e}"
+
+
 def ingest_batch(tsdb, batch: DecodedBatch, durable: bool = True,
                  tenant: str = "default") -> tuple[int, list[str]]:
-    """Feed a decoded batch into the TSDB via the columnar write path.
+    """Feed a decoded batch into the TSDB as ONE multi-series put
+    (``TSDB.add_chunk``): one sort, one encode, one WAL record a row-key
+    length, whatever the number of series.
 
     Series are ingested independently: one series failing (unknown
-    metric, conflicting duplicate, throttle) does not drop the others —
-    matching the per-line put semantics. Returns (points_written,
-    per-series error strings). One argsort groups points by series;
-    no per-series full-array masks.
+    metric, conflicting duplicate, tenant limit) does not drop the
+    others — matching the per-line put semantics. Returns
+    (points_written, per-series error strings in series order).
     """
-    n = 0
-    errors: list[str] = []
     if len(batch.sid) == 0:
-        return 0, errors
-    order = np.argsort(batch.sid, kind="stable")
-    sid_sorted = batch.sid[order]
-    starts = np.concatenate(
-        ([0], np.flatnonzero(np.diff(sid_sorted)) + 1, [len(order)]))
-    # Under WAL group commit each per-series put skips its own barrier
-    # (sync=False) and ONE covering barrier runs before this returns —
-    # the batch pays a single fsync wait instead of one per series,
-    # while the caller's ack still only happens after that fsync. The
-    # try/finally keeps the guarantee when a put raises mid-batch:
-    # series already written are barriered before the error surfaces.
-    try:
-        for i in range(len(starts) - 1):
-            run = order[starts[i]:starts[i + 1]]
-            s = int(sid_sorted[starts[i]])
-            metric, tag_map = batch.series[s]
-            try:
-                n += tsdb.add_batch(
-                    metric, batch.timestamps[run], batch.fvalues[run],
-                    tag_map, durable=durable,
-                    is_float=batch.is_float[run],
-                    int_values=batch.ivalues[run], tenant=tenant,
-                    sync=False)
-            except Exception as e:
-                # Stable machine-readable tags for policy refusals: the
-                # server's error classifier keys on "[fenced]" /
-                # "[tenant-limit]", not on exception message wording
-                # that could drift. A tenant-limit refusal is
-                # per-series: the tenant's EXISTING series in this
-                # batch still ingested above/below — only the new one
-                # refused.
-                from opentsdb_tpu.core.errors import (FencedWriterError,
-                                                      TenantLimitError)
-                if isinstance(e, FencedWriterError):
-                    tag = "[fenced] "
-                elif isinstance(e, TenantLimitError):
-                    tag = "[tenant-limit] "
-                else:
-                    tag = ""
-                errors.append(f"{metric}: {tag}{e}")
-    finally:
-        barrier = getattr(tsdb.store, "wal_barrier", None)
-        if barrier is not None:
-            barrier()
-    return n, errors
+        return 0, []
+    # Under WAL group commit the put skips its own barrier (sync=False)
+    # and ONE covering barrier runs before this returns, while the
+    # caller's ack still only happens after that fsync. The try/finally
+    # keeps the guarantee when the put raises: whatever was written is
+    # barriered before the error surfaces.
+    with obs_trace.timed("ingest.batch"):
+        try:
+            n, errs = tsdb.add_chunk(
+                batch.series, batch.sid, batch.timestamps, batch.fvalues,
+                batch.ivalues, batch.is_float, durable=durable,
+                tenant=tenant, sync=False)
+        finally:
+            barrier = getattr(tsdb.store, "wal_barrier", None)
+            if barrier is not None:
+                barrier()
+    return n, [series_error(batch.series[s][0], errs[s])
+               for s in sorted(errs)]

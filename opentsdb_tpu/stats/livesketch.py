@@ -16,8 +16,14 @@ Design (SURVEY.md §5.4, §7.4):
 - **Fixed-shape stacks.** All digests live in two [C, K] arrays
   (means/weights), all HLLs in one [C, 2^p] int32 array; C doubles on
   demand. One extra trash row absorbs padded scatter indices, so every
-  update is a single fixed-shape jitted call regardless of how many
-  sketches it touches.
+  update is a fixed-shape jitted call regardless of how many sketches
+  it touches.
+- **Fold shapes follow the deployment, never the clock.** How many
+  series and tag values a fold holds depends on when it runs (a
+  checkpoint's snapshot folds whatever is buffered), so a fold is cut
+  into batches of a few fixed shapes (``_fold_rows``, ``_HLL_ROWS`` x
+  ``_HLL_ITEMS``): a width class's first fold compiles its shapes, on
+  the folder thread, and no later one compiles.
 - **Buffered folding with a staleness bound.** ``observe()`` appends to a
   host-side buffer (O(1), no device work on the ingest hot path); full
   buffers hand off to a background folder thread (bounded queue, so a
@@ -96,9 +102,17 @@ class LiveSketches:
         self._td_weights = jnp.zeros((_PAD_MIN, compression), jnp.float32)
         self._hll_regs = jnp.zeros((_PAD_MIN, 1 << hll_p), jnp.int32)
         # host-side buffers
-        self._td_buf: dict[int, list[np.ndarray]] = {}
+        self._td_buf: list[tuple[np.ndarray, np.ndarray]] = []   # (slot
+        #                               of each value, values) a batch
         self._hll_buf: dict[int, set[int]] = {}
         self._buffered = 0
+        # (stack capacity, width class) pairs whose fold shapes this
+        # process has run (_fold_td_rows).
+        self._td_warm: set[tuple[int, int]] = set()
+        # A store loaded from a snapshot (a daemon's boot) runs the fold
+        # shapes of its first batch's width class before that batch
+        # returns (_warm_first): see observe_many.
+        self._warm_first = False
         # background folder: bounded queue of swapped-out buffer pairs
         import queue as _queue
 
@@ -170,16 +184,33 @@ class LiveSketches:
 
     def observe(self, series_key: bytes, values: np.ndarray,
                 tag_uids: list[tuple[bytes, bytes, bytes]]) -> None:
-        """Record one series batch: ``values`` fold into the series
-        digest; each (metric_uid, tagk_uid, tagv_uid) folds the tag value
-        into the pair's HLL. O(1) host work; device folding is deferred
-        to flush()."""
+        """Record one series batch: the one-series case of
+        ``observe_many``."""
+        self.observe_many((series_key,), None, values, tag_uids)
+
+    def observe_many(self, series_keys, series_of_point, values,
+                     tag_uids) -> None:
+        """Record one batch of many series: ``values[i]`` folds into the
+        digest of ``series_keys[series_of_point[i]]`` (None: all into
+        ``series_keys[0]``); each (metric_uid, tagk_uid, tagv_uid) folds
+        the tag value into the pair's HLL. One lock turn, O(series) dict
+        probes on the host; device folding is deferred to flush()."""
         with self._lock:
-            if len(values):
-                self._td_buf.setdefault(
-                    self._td_slot(series_key), []).append(
-                        np.asarray(values, np.float32))
-                self._buffered += len(values)
+            n = len(values)
+            if n:
+                one = series_of_point is None
+                slots = (self._td_slot(series_keys[0]) if one else
+                         np.fromiter(map(self._td_slot, series_keys),
+                                     np.int32, len(series_keys)))
+                if self._warm_first:
+                    self._warm_first = False
+                    self._warm(n if one else int(
+                        np.bincount(series_of_point).max()))
+                self._td_buf.append(
+                    (np.full(n, slots, np.int32) if one
+                     else slots[series_of_point],
+                     np.asarray(values, np.float32)))
+                self._buffered += n
             for metric_uid, tagk_uid, tagv_uid in tag_uids:
                 slot = self._hll_slot(metric_uid, tagk_uid)
                 self._hll_buf.setdefault(slot, set()).add(
@@ -187,12 +218,29 @@ class LiveSketches:
             if self._buffered >= self.flush_points:
                 self._hand_off_locked()
 
+    def _warm(self, longest: int) -> None:
+        """Run, empty, the fold shapes a batch whose longest series has
+        ``longest`` values will fold in, at the capacity the directory
+        needs: a daemon booted on a store knows both from its first
+        batch, and compiles there (a deployment's warm-up, the first
+        write after a restart) what the folder thread would otherwise
+        compile at some later moment, beside reads."""
+        widths = self._FOLD_WIDTHS
+        P = widths[int(np.searchsorted(
+            widths, min(longest, self._MAX_CHUNK)))]
+        none = np.empty(0, np.int64)
+        with self._state_lock:
+            self._ensure_capacity(len(self._td_slots),
+                                  len(self._hll_slots))
+            self._fold_td_rows(none, none, none, none, P)
+            self._fold_hll_rows([], [])
+
     def _hand_off_locked(self) -> None:
         """Swap the buffers out and queue them for the folder thread
         (or fold inline when background=False). Caller holds _lock."""
         if not self._td_buf and not self._hll_buf:
             return
-        td_buf, self._td_buf = self._td_buf, {}
+        td_buf, self._td_buf = self._td_buf, []
         hll_buf, self._hll_buf = self._hll_buf, {}
         self._buffered = 0
         if not self.background:
@@ -228,73 +276,140 @@ class LiveSketches:
             err, self._fold_error = self._fold_error, None
             raise err
 
-    # Fold-batch bounds: chunk long series to _MAX_CHUNK values and cap
-    # a fold call at _MAX_FOLD_CELLS dense cells, so flush memory is
-    # O(total buffered points), never (series x longest-series) — one
-    # hot series can't blow the padding up for a thousand cold ones.
+    # Fold-batch bounds: a series' values fold ``_MAX_CHUNK`` at a
+    # time, each piece padded to a width of ``_FOLD_WIDTHS``, and a fold
+    # call holds a fixed number of rows of one width (``_fold_rows``),
+    # so flush memory is O(total buffered points), never (series x
+    # longest-series), and the shapes a process compiles are those few
+    # whatever a fold happens to hold.
     _MAX_CHUNK = 4096
-    _MAX_FOLD_CELLS = 1 << 22
+    _FOLD_WIDTHS = tuple(8 << k for k in range(10))      # 8 .. 4096
+    _FOLD_CELLS = 1 << 17
+    _FOLD_ROWS_SMALL = 64
+    _HLL_ROWS = 64
+    _HLL_ITEMS = 512
 
-    def _fold_td_group(self, group: list[tuple[int, np.ndarray]],
-                       P: int) -> None:
-        S = _pad(len(group))
+    @classmethod
+    def _fold_rows(cls, P: int) -> tuple[int, ...]:
+        """The row counts a fold of width ``P`` comes in: the batch of
+        a full buffer, and a small one for the handful of series a
+        query's or a snapshot's flush may find."""
+        big = max(cls._FOLD_CELLS // P, 1)
+        return ((cls._FOLD_ROWS_SMALL, big)
+                if big > cls._FOLD_ROWS_SMALL else (big,))
+
+    def _fold_td(self, slots: np.ndarray, row_of: np.ndarray,
+                 pos: np.ndarray, vals: np.ndarray, S: int,
+                 P: int) -> None:
+        """One fold call of shape (S, P): row r takes slot ``slots[r]``
+        (rows past them scatter out of bounds and are dropped), value i
+        sits at ``[row_of[i], pos[i]]``."""
         batch = np.zeros((S, P), np.float32)
         valid = np.zeros((S, P), bool)
-        # Padded rows scatter out of bounds and are dropped.
+        batch[row_of, pos] = vals
+        valid[row_of, pos] = True
         idx = np.full(S, self._td_means.shape[0], np.int32)
-        for r, (s, v) in enumerate(group):
-            batch[r, :len(v)] = v
-            valid[r, :len(v)] = True
-            idx[r] = s
+        idx[:len(slots)] = slots
         self._td_means, self._td_weights = _fold_tdigests(
             self._td_means, self._td_weights, jnp.asarray(idx),
             jnp.asarray(batch), jnp.asarray(valid),
             compression=self.compression)
 
-    def _fold_buffers(self, td_buf: dict, hll_buf: dict) -> None:
+    def _fold_buffers(self, td_buf: list, hll_buf: dict) -> None:
         """Fold one swapped-out buffer pair into the device stacks.
         Runs on the folder thread (or inline when background=False);
         serialized by _state_lock."""
         with self._state_lock:
             if td_buf:
-                self._ensure_capacity(max(td_buf) + 1, 0)
-                # Per-slot chunk queues; each round folds at most one
-                # chunk per slot (scatter indices must be unique within
-                # a fold), bucketed by padded length to bound padding
-                # waste and the number of distinct jit shapes.
-                queues: dict[int, list[np.ndarray]] = {}
-                for s, chunks in td_buf.items():
-                    v = np.concatenate(chunks)
-                    queues[s] = [v[off:off + self._MAX_CHUNK]
-                                 for off in range(0, len(v),
-                                                  self._MAX_CHUNK)]
-                while queues:
-                    by_p: dict[int, list] = {}
-                    for s in sorted(queues):
-                        v = queues[s].pop(0)
-                        by_p.setdefault(_pad(len(v)), []).append((s, v))
-                    queues = {s: q for s, q in queues.items() if q}
-                    for P, plist in sorted(by_p.items()):
-                        rows = max(self._MAX_FOLD_CELLS // P, 1)
-                        for i in range(0, len(plist), rows):
-                            self._fold_td_group(plist[i:i + rows], P)
+                slot = np.concatenate([s for s, _ in td_buf])
+                vals = np.concatenate([v for _, v in td_buf])
+                self._ensure_capacity(int(slot.max()) + 1, 0)
+                # By slot, values in arrival order; a slot's values are
+                # cut into pieces of _MAX_CHUNK, and round k folds every
+                # slot's k-th piece (scatter indices must be unique
+                # within a fold), a width class at a time.
+                if (slot[1:] < slot[:-1]).any():
+                    order = np.argsort(slot, kind="stable")
+                    slot, vals = slot[order], vals[order]
+                first = np.flatnonzero(
+                    np.concatenate(([True], slot[1:] != slot[:-1])))
+                counts = np.diff(np.append(first, len(slot)))
+                rank = np.arange(len(slot)) - np.repeat(first, counts)
+                piece = rank // self._MAX_CHUNK
+                pos = rank % self._MAX_CHUNK
+                widths = np.asarray(self._FOLD_WIDTHS)
+                for k in range(int(piece.max()) + 1):
+                    pts = np.flatnonzero(piece == k)
+                    # The rows of this round: one a slot that has a
+                    # k-th piece, its length deciding its width class.
+                    starts = pts[pos[pts] == 0]
+                    lens = np.minimum(counts[np.searchsorted(
+                        first, starts, "right") - 1]
+                        - k * self._MAX_CHUNK, self._MAX_CHUNK)
+                    klass = np.searchsorted(widths, lens)
+                    row_of_pt = np.repeat(np.arange(len(starts)), lens)
+                    klass_of_pt = klass[row_of_pt]
+                    for c in np.unique(klass):
+                        rows = np.flatnonzero(klass == c)
+                        mine = pts[klass_of_pt == c]
+                        self._fold_td_rows(
+                            slot[starts[rows]],
+                            np.searchsorted(rows, row_of_pt[klass_of_pt == c]),
+                            pos[mine], vals[mine], int(widths[c]))
             if hll_buf:
                 self._ensure_capacity(0, max(hll_buf) + 1)
-                slots = sorted(hll_buf)
-                uids = [np.fromiter(hll_buf[s], np.int32)
-                        for s in slots]
-                H = _pad(len(slots))
-                U = _pad(max(len(u) for u in uids))
-                items = np.zeros((H, U), np.int32)
-                valid = np.zeros((H, U), bool)
-                for i, u in enumerate(uids):
-                    items[i, :len(u)] = u
-                    valid[i, :len(u)] = True
-                idx = np.full(H, self._hll_regs.shape[0], np.int32)
-                idx[:len(slots)] = slots
-                self._hll_regs = _fold_hlls(
-                    self._hll_regs, jnp.asarray(idx), jnp.asarray(items),
-                    jnp.asarray(valid), p=self.hll_p)
+                # Rows of at most _HLL_ITEMS values of one slot; a slot
+                # with more takes several (register max is idempotent,
+                # so a slot may recur within a fold).
+                U = self._HLL_ITEMS
+                row_slot: list[int] = []
+                row_items: list[np.ndarray] = []
+                for s in sorted(hll_buf):
+                    u = np.fromiter(hll_buf[s], np.int32)
+                    for off in range(0, len(u), U):
+                        row_slot.append(s)
+                        row_items.append(u[off:off + U])
+                self._fold_hll_rows(row_slot, row_items)
+
+    def _fold_hll_rows(self, row_slot: list, row_items: list) -> None:
+        """Fold rows of tag values (row r: ``row_items[r]`` into slot
+        ``row_slot[r]``) in calls of the one HLL shape; with no row,
+        one empty call (that compiles it)."""
+        H, U = self._HLL_ROWS, self._HLL_ITEMS
+        for lo in range(0, max(len(row_slot), 1), H):
+            items = np.zeros((H, U), np.int32)
+            valid = np.zeros((H, U), bool)
+            idx = np.full(H, self._hll_regs.shape[0], np.int32)
+            for r, u in enumerate(row_items[lo:lo + H]):
+                items[r, :len(u)] = u
+                valid[r, :len(u)] = True
+                idx[r] = row_slot[lo + r]
+            self._hll_regs = _fold_hlls(
+                self._hll_regs, jnp.asarray(idx), jnp.asarray(items),
+                jnp.asarray(valid), p=self.hll_p)
+
+    def _fold_td_rows(self, slots: np.ndarray, row_of: np.ndarray,
+                      pos: np.ndarray, vals: np.ndarray, P: int) -> None:
+        """Fold rows of one width class ``P`` (row r takes slot
+        ``slots[r]``; value i sits at ``[row_of[i], pos[i]]``, rows
+        ascending) in calls of that class's fixed shapes. The first
+        fold of a class in this process (and of this stack capacity)
+        also runs the class's other shape, empty, so that whatever a
+        later fold holds finds its program compiled."""
+        shapes = self._fold_rows(P)
+        warm = (self._td_means.shape[0], P)
+        if warm not in self._td_warm:
+            self._td_warm.add(warm)
+            none = np.empty(0, np.int64)
+            for S in shapes:
+                self._fold_td(none, none, none, none, S, P)
+        big = shapes[-1]
+        for lo in range(0, len(slots), big):
+            hi = min(lo + big, len(slots))
+            S = next(s for s in shapes if s >= hi - lo)
+            a, b = np.searchsorted(row_of, (lo, hi))
+            self._fold_td(slots[lo:hi], row_of[a:b] - lo, pos[a:b],
+                          vals[a:b], S, P)
 
     # -- query-side API ----------------------------------------------------
 
@@ -385,30 +500,47 @@ class LiveSketches:
                                 other._hll_regs[oslot]))
 
     def save(self, path: str) -> None:
-        """Snapshot device state to a host .npz (atomic via tmp+rename)."""
+        """Snapshot device state to a host .npz (atomic via tmp+rename).
+
+        Only the hand-off of what is buffered and the copy of the slot
+        maps hold the lock ``observe`` takes; the folds the snapshot
+        waits for, the device-to-host copy and the file run beside
+        ingest. Observations that arrive meanwhile may be in the
+        snapshot or not: it then covers more than storage's spill will,
+        the over-cover the checkpoint order already accepts (exact for
+        HLLs, within tolerance for digests)."""
+        self.flush()
+        with self._state_lock:
+            stacks = (self._td_means, self._td_weights, self._hll_regs)
+        # The slot maps AFTER the stacks: a slot is assigned before its
+        # values are buffered, so every row of the stacks that holds
+        # data has its key here, and a key whose row the stacks lack
+        # yet gets a zero row below.
         with self._lock:
-            self.flush()
-            with self._state_lock:
-                self._ensure_capacity(len(self._td_slots),
-                                      len(self._hll_slots))
             td_keys = sorted(self._td_slots, key=self._td_slots.get)
             hll_keys = sorted(self._hll_slots, key=self._hll_slots.get)
-            tmp = path + ".tmp"
-            with open(tmp, "wb") as f:
-                np.savez(
-                    f,
-                    td_keys=np.array(td_keys, dtype=object),
-                    hll_metric=np.array([k[0] for k in hll_keys],
-                                        dtype=object),
-                    hll_tagk=np.array([k[1] for k in hll_keys],
-                                      dtype=object),
-                    td_means=np.asarray(self._td_means),
-                    td_weights=np.asarray(self._td_weights),
-                    hll_regs=np.asarray(self._hll_regs),
-                    meta=np.array([self.compression, self.hll_p]))
-                f.flush()
-                os.fsync(f.fileno())
-            os.replace(tmp, path)
+        means, weights, regs = (np.asarray(a) for a in stacks)
+
+        def cover(a: np.ndarray, rows: int) -> np.ndarray:
+            short = _pad(rows) - a.shape[0]
+            return a if short <= 0 else np.pad(a, ((0, short), (0, 0)))
+
+        tmp = path + ".tmp"
+        with open(tmp, "wb") as f:
+            np.savez(
+                f,
+                td_keys=np.array(td_keys, dtype=object),
+                hll_metric=np.array([k[0] for k in hll_keys],
+                                    dtype=object),
+                hll_tagk=np.array([k[1] for k in hll_keys],
+                                  dtype=object),
+                td_means=cover(means, len(td_keys)),
+                td_weights=cover(weights, len(td_keys)),
+                hll_regs=cover(regs, len(hll_keys)),
+                meta=np.array([self.compression, self.hll_p]))
+            f.flush()
+            os.fsync(f.fileno())
+        os.replace(tmp, path)
 
     @classmethod
     def load(cls, path: str, flush_points: int = 65536) -> "LiveSketches":
@@ -425,6 +557,7 @@ class LiveSketches:
         self._hll_slots = {
             (bytes(m), bytes(t)): i
             for i, (m, t) in enumerate(zip(z["hll_metric"], z["hll_tagk"]))}
+        self._warm_first = True
         return self
 
 

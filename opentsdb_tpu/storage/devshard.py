@@ -153,26 +153,60 @@ class ShardedDeviceWindow:
 
     def append(self, metric_uid: bytes, series_key: bytes,
                timestamps: np.ndarray, values: np.ndarray) -> None:
+        self.append_many(metric_uid, (series_key,), None, timestamps,
+                         values)
+
+    def append_many(self, metric_uid: bytes, series_keys,
+                    series_of_point: np.ndarray | None,
+                    timestamps: np.ndarray, values: np.ndarray) -> None:
+        """``DeviceWindow.append_many`` over the fleet: each series'
+        points go to the shard its key hashes to, one call a shard."""
         if len(timestamps) == 0:
             return
         with self._lock:
             if metric_uid in self._dirty_metrics:
                 return
-            idx = series_hash(series_key) % len(self._shards)
-            shard = self._shards[idx]
-            self._metric_shards.setdefault(metric_uid, set()).add(idx)
+            n_shards = len(self._shards)
+            owner = np.fromiter(
+                (series_hash(k) % n_shards for k in series_keys),
+                np.int64, len(series_keys))
+            owners = np.unique(owner).tolist()
+            self._metric_shards.setdefault(metric_uid, set()).update(
+                owners)
             if self._journal is not None:
                 # Journal COPIES under the gate lock: the record must be
                 # immutable (replay happens later) and ordered with the
-                # reshard's snapshot boundary.
-                self._journal.append(
-                    (metric_uid, series_key,
-                     np.array(timestamps, np.int64),
-                     np.array(values, np.float32)))
+                # reshard's snapshot boundary. A record a series: a
+                # reshard is rare, and its replay re-routes by series.
+                ts = np.array(timestamps, np.int64)
+                vals = np.array(values, np.float32)
+                if series_of_point is None:
+                    self._journal.append(
+                        (metric_uid, series_keys[0], ts, vals))
+                else:
+                    for j, key in enumerate(series_keys):
+                        pts = series_of_point == j
+                        if pts.any():
+                            self._journal.append(
+                                (metric_uid, key, ts[pts], vals[pts]))
             # Delegate under the fleet lock: the reshard gate's
             # quiesce+snapshot must never interleave with a half-landed
             # append (staged in neither the snapshot nor the journal).
-            shard.append(metric_uid, series_key, timestamps, values)
+            if series_of_point is None or len(owners) == 1:
+                self._shards[owners[0]].append_many(
+                    metric_uid, series_keys, series_of_point,
+                    timestamps, values)
+                return
+            owner_of_point = owner[series_of_point]
+            local = np.zeros(len(series_keys), np.int64)
+            for idx in owners:
+                mine = np.flatnonzero(owner == idx)
+                local[mine] = np.arange(len(mine))
+                pts = np.flatnonzero(owner_of_point == idx)
+                self._shards[idx].append_many(
+                    metric_uid, [series_keys[j] for j in mine],
+                    local[series_of_point[pts]], timestamps[pts],
+                    values[pts])
 
     def flush(self) -> None:
         with self._lock:
@@ -408,7 +442,8 @@ class ShardedDeviceWindow:
                "devwindow.points.evicted": 0,
                "devwindow.upload_stalls": 0,
                "devwindow.metrics": 0,
-               "devwindow.points.resident": 0}
+               "devwindow.points.resident": 0,
+               "devwindow.chunks": 0}
 
         class _Sink:
             def record(self, name, value):

@@ -269,7 +269,8 @@ class _MetricWindow:
     def __init__(self) -> None:
         self.sids: dict[bytes, int] = {}
         self.keys: list[bytes] = []
-        self.last_ts: list[int] = []
+        self.last_ts = np.full(64, -1, np.int64)   # by sid, grown
+        #                                            by doubling
         self.epoch: int | None = None
         self.chunks: list[dict] = []      # ts/vals/sid device + n/max_ts
         #                                   + the zone map
@@ -374,8 +375,21 @@ class DeviceWindow:
     def append(self, metric_uid: bytes, series_key: bytes,
                timestamps: np.ndarray, values: np.ndarray) -> None:
         """Record one series batch (timestamps int64 sorted ascending,
-        values float64/float32). O(1) host work plus a device upload
-        every ``staging_points`` points."""
+        values float64/float32): the one-series case of
+        ``append_many``."""
+        self.append_many(metric_uid, (series_key,), None, timestamps,
+                         values)
+
+    def append_many(self, metric_uid: bytes, series_keys,
+                    series_of_point: np.ndarray | None,
+                    timestamps: np.ndarray, values: np.ndarray) -> None:
+        """Record one batch of many series of a metric in one lock turn
+        and ONE staged triple: ``series_of_point[i]`` indexes
+        ``series_keys`` (distinct), points sorted by (that index,
+        timestamp) with timestamps strictly ascending within a series;
+        None is all points of ``series_keys[0]``. O(series) dict probes
+        and a handful of array operations on the host, plus a device
+        upload every ``staging_points`` points."""
         n = len(timestamps)
         if n == 0:
             return
@@ -385,23 +399,53 @@ class DeviceWindow:
                 mw = self._metrics[metric_uid] = _MetricWindow()
             if mw.dirty:
                 return
-            sid = mw.sids.get(series_key)
-            if sid is None:
-                sid = len(mw.keys)
-                mw.sids[series_key] = sid
-                mw.keys.append(series_key)
-                mw.last_ts.append(-1)
-                mw.generation += 1
-            if int(timestamps[0]) <= mw.last_ts[sid]:
+            sids = mw.sids
+            first_new = len(mw.keys)
+            ids = [sids.get(k) for k in series_keys]
+            if None in ids:
+                for j, k in enumerate(series_keys):
+                    if ids[j] is None:
+                        ids[j] = sids[k] = len(mw.keys)
+                        mw.keys.append(k)
+                mw.generation += len(mw.keys) - first_new
+                if len(mw.keys) > len(mw.last_ts):
+                    grown = np.full(max(2 * len(mw.last_ts),
+                                        len(mw.keys)), -1, np.int64)
+                    grown[:first_new] = mw.last_ts[:first_new]
+                    mw.last_ts = grown
+            ts = np.array(timestamps, np.int64)
+            if series_of_point is None:
+                # One series (the boot's refill: a call a row-hour):
+                # the same check on scalars.
+                ids = ids[0]
+                first, lasts = ts[0], ts[-1]
+                sid = np.full(n, ids, np.int32)
+                stale = first <= mw.last_ts[ids]
+            else:
+                # Series absent from this batch's points (none, from a
+                # caller that lists the distinct series of its points)
+                # drop out here: bincount gives them no run.
+                counts = np.bincount(series_of_point,
+                                     minlength=len(ids))
+                have = counts > 0
+                ends = np.cumsum(counts)[have]
+                ids = np.asarray(ids, np.int64)
+                sid = ids[series_of_point].astype(np.int32)
+                ids = ids[have]
+                lasts = ts[ends - 1]
+                stale = (ts[ends - counts[have]] <= mw.last_ts[ids]).any()
+            if stale:
                 # Out-of-order or rewritten timestamp: correctness now
                 # needs storage's dedup/overwrite semantics. Mark the
                 # metric dirty and free its device state — every query
                 # falls back to the scan path from here on.
                 self._mark_dirty(mw)
                 return
-            mw.last_ts[sid] = int(timestamps[-1])
+            mw.last_ts[ids] = lasts
             if mw.epoch is None:
-                mw.epoch = int(timestamps[0])
+                # The batch's first point, as a one-series-at-a-time
+                # feed of the same points would have set it.
+                mw.epoch = int(ts[0])
             # Stage COPIES: the window owns its buffers. asarray would
             # alias a caller's array of the right dtype, and since
             # sort_dedup's sorted fast path started returning the
@@ -409,9 +453,9 @@ class DeviceWindow:
             # buffer would silently rewrite staged timestamps under
             # the window. The memcpy is ~12 B/point, noise next to the
             # upload it feeds.
-            mw.staged_ts.append(np.array(timestamps, np.int64))
+            mw.staged_ts.append(ts)
             mw.staged_vals.append(np.array(values, np.float32))
-            mw.staged_sid.append(np.full(n, sid, np.int32))
+            mw.staged_sid.append(sid)
             mw.staged_n += n
             self.appended_points += n
             work = (self._take_staged(mw)
@@ -918,5 +962,11 @@ class DeviceWindow:
             # What the resident chunks' columns hold on the device,
             # padding included.
             collector.record("devwindow.bytes", self._total_bytes)
+            # Chunks resident over all metrics: a stage dispatches one
+            # fold for each of a metric's chunks its range can hit, and
+            # a read that drains a metric's staged points cuts one.
+            collector.record(
+                "devwindow.chunks",
+                sum(len(mw.chunks) for mw in self._metrics.values()))
         if device:
             record_device_memory(collector, self.device)

@@ -47,6 +47,7 @@ import base64
 import json
 import os
 import threading
+from itertools import islice
 
 import numpy as np
 
@@ -99,7 +100,12 @@ class SpaceSaving:
         if len(self.items) < self.capacity:
             self.items[key] = [weight, 0]
             return
-        victim = min(self.items, key=lambda k: self.items[k][0])
+        # The first entry of the least count, in insertion order (what
+        # min() over the keys by count picks), found over a flat list:
+        # a stream of mostly-new keys pays this on every arrival.
+        counts = [ent[0] for ent in self.items.values()]
+        victim = next(islice(self.items, counts.index(min(counts)),
+                             None))
         vcount = self.items.pop(victim)[0]
         self.items[key] = [vcount + weight, vcount]
 
@@ -261,6 +267,17 @@ class TenantAccountant:
             st = self._state(tenant)
             st.points += n
             st.hh_series.offer(series_label, n)
+
+    def note_points_many(self, tenant: str, series_labels,
+                         counts) -> None:
+        """``note_points`` for each (label, n) pair in order, in one
+        lock turn."""
+        with self._lock:
+            st = self._state(tenant)
+            offer = st.hh_series.offer
+            for label, n in zip(series_labels, counts):
+                st.points += n
+                offer(label, n)
 
     def record_refusal(self, tenant: str, warn_only: bool) -> None:
         with self._lock:
