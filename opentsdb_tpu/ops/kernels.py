@@ -6,10 +6,17 @@ Design rules (SURVEY.md §7.4):
   XLA computation per (shape, static-arg) combination.
 - The primary layout is FLAT: all points of all series in a query live in
   one [N] array with a parallel [N] series-id array, so ragged series waste
-  no compute. Downsample + group-by is then one fused pair of segment
-  reductions (points -> series x bucket -> bucket), which XLA maps onto the
-  VPU with no gather/scatter loops — this replaces the reference's k-way
-  merge iterator stack (SpanGroup.SGIterator, Span.DownsamplingIterator).
+  no compute. Downsample + group-by is then a pair of segment reductions
+  (points -> series x bucket -> bucket) — this replaces the reference's
+  k-way merge iterator stack (SpanGroup.SGIterator,
+  Span.DownsamplingIterator). The second is dense [S, B] work on the VPU.
+  The first is a scatter, and XLA:TPU applies a scatter's updates one
+  after another: 8.9 ns an update on a v5e, four orders of magnitude
+  under the HBM roofline, and all the device did in the resident cells
+  until PR 39 (PERF.md §5-§6). So the resident fold (window.chunk_fold)
+  reduces each run of equal segment ids on the VPU first and scatters
+  one update a run (_scatter_runs); the raw plan's _segment_moments
+  still scatters a point at a time.
 - Timestamps enter as int32 *offsets from the query start*; values as
   float32. Bucket mean-timestamps are computed relative to each bucket
   start so float32 stays exact (offsets < interval <= 2^24).
@@ -435,13 +442,98 @@ def _stage_tail(series_values, series_mask, presence, *, num_buckets,
     return series_values, series_mask, filled, in_range, presence
 
 
+# The run reduction of window.chunk_fold: a block is cut into tiles of
+# _FOLD_TILE slots, and a turn of its inner loop hands the scatters
+# _FOLD_RUNS runs of every tile (_scatter_runs). Both powers of two;
+# a block shorter than a tile is one tile. Their ratio is what the
+# scatters see (block * _FOLD_RUNS / _FOLD_TILE updates a turn, and
+# _FOLD_TILE / _FOLD_RUNS turns for a block in no order at all), the
+# tile what the vector unit sees (a turn compares every slot of a tile
+# with _FOLD_RUNS run numbers). Settled on a v5e (PERF.md §6, PR 39):
+# at this ratio tiles of 32 to 512 slots read alike within 15%, twice
+# the ratio doubles an hourly block's time, and a quarter of it (512 x 8)
+# takes an hourly block to 0.03 ms and a 1-min one back to 1.13.
+_FOLD_TILE = 128
+_FOLD_RUNS = 8
+
+
+def _scatter_runs(part, v, ok, seg, dump):
+    """Fold one block's slots into the accumulators of ``part`` (a dict
+    keyed by statistic: ``count`` the slots with ``ok``, ``sum`` /
+    ``min`` / ``max`` of ``v`` under ``ok``), one scatter update a RUN
+    of equal segment ids and not one a slot. XLA:TPU applies a
+    scatter's updates one after another (8.9 ns each on a v5e), and
+    every writer stages a chunk in runs (a series-hour is 360
+    consecutive slots of one series, so an hourly bucket's segment 360
+    times in a row), so nearly all of a slot-wise scatter repeats its
+    last index.
+
+    The block is viewed as tiles of _FOLD_TILE slots; within a tile a
+    slot whose segment differs from its predecessor's starts a run, and
+    a running count numbers the tile's runs. A turn of the loop takes
+    runs [j * _FOLD_RUNS, (j + 1) * _FOLD_RUNS) of EVERY tile: one
+    masked reduction over the tile a statistic (compare, select and
+    reduce fuse; no [tile, runs, tiles] tensor is written), the run's
+    segment by a max under the same mask (no slot of that run number
+    in the tile: the dump segment), and the same scatters as before
+    over tiles x _FOLD_RUNS updates. The trip count is DATA, the worst
+    tile's run count: one turn for hourly runs, a few for 1-min ones,
+    _FOLD_TILE / _FOLD_RUNS for slots in no order, which are then the
+    slot-wise scatter's updates and never more. Slots the range cut
+    (``ok`` false, the dump segment) split a run, harmlessly. The sums
+    are float32 sums of the slots themselves (no difference of prefix
+    sums, which would cancel); count, min and max take no rounding, so
+    they are the slot-wise scatter's bits for any order.
+
+    Returns ``part`` updated and the updates each scatter was handed
+    (int32: turns x tiles x _FOLD_RUNS)."""
+    tile = min(_FOLD_TILE, seg.shape[0])
+    runs = min(_FOLD_RUNS, tile)
+    tiles = seg.shape[0] // tile
+    # A tile a COLUMN, its slots down axis 0: the reductions of a turn
+    # then run along the major axis and the tiles fill the lanes, which
+    # is worth 11-16 us a turn on a v5e over a tile a row (a block in
+    # no order: 0.95 ms against 1.20).
+    v, ok, seg = (a.reshape(tiles, tile).T for a in (v, ok, seg))
+    start = jnp.concatenate(
+        [jnp.ones((1, tiles), bool), seg[1:] != seg[:-1]], axis=0)
+    run = jnp.cumsum(start.astype(jnp.int32), axis=0) - 1
+    turns = jnp.max(run[-1]) // runs + 1
+    lanes = jnp.arange(runs, dtype=jnp.int32)[:, None]
+
+    def turn(j, part):
+        hit = (run - j * runs)[:, None, :] == lanes
+        at = jnp.max(jnp.where(hit, seg[:, None, :], -1), axis=0).ravel()
+        at = jnp.where(at < 0, dump, at)
+        live = hit & ok[:, None, :]
+        feed = v[:, None, :]
+        part = dict(part)
+        if "count" in part:
+            part["count"] = part["count"].at[at].add(
+                jnp.sum(live.astype(jnp.float32), axis=0).ravel())
+        if "sum" in part:
+            part["sum"] = part["sum"].at[at].add(
+                jnp.sum(jnp.where(live, feed, 0.0), axis=0).ravel())
+        if "min" in part:
+            part["min"] = part["min"].at[at].min(
+                jnp.min(jnp.where(live, feed, _POS_INF), axis=0).ravel())
+        if "max" in part:
+            part["max"] = part["max"].at[at].max(
+                jnp.max(jnp.where(live, feed, _NEG_INF), axis=0).ravel())
+        return part
+
+    return (jax.lax.fori_loop(0, turns, turn, part),
+            turns * (tiles * runs))
+
+
 @jit_plan(ExecPlan(
     name="window.chunk_fold", axis="series",
     static_argnames=("num_series", "num_buckets", "interval", "need",
                      "block"),
     donate_argnums=(4, 5, 6, 7, 8)))
 def _chunk_fold(rel_ts, vals, sid, valid, count, total, m2, mn, mx,
-                visit, *, num_series, num_buckets, interval, need, block):
+                handed, visit, *, num_series, num_buckets, interval, need,
+                block):
     """Fold the selected blocks of ONE resident chunk into the
     per-(series, bucket) accumulators. ``visit`` is one int32 vector,
     ``[lo, hi, shift, n, id_0 .. id_n-1, 0 ..]``: the range, the bucket
@@ -449,16 +541,26 @@ def _chunk_fold(rel_ts, vals, sid, valid, count, total, m2, mn, mx,
     devwindow's zone-map selection, DevChunks.blocks), padded to the
     chunk's block count. All of it is DATA, so a range never seen before
     runs the program already compiled: a ``fori_loop`` slices one block
-    a turn and scatters it into the chunk's partial statistics, and what
-    a turn costs follows the slots it is handed, not the points in
-    range. One vector and not five arguments because every host array
-    argument is its own host-to-device transfer at dispatch (measured
-    on a v5e, PERF.md §6: 0.35 ms a fold against 1.1 ms). Compiled once
-    per chunk shape class (chunks are pow2-padded, so there are only a
-    handful); accumulators are donated so the fold is in-place. The
-    stage driver issues these back-to-back ASYNC — dispatch does not
-    wait for the device, so K chunks cost ~K host-side submissions, not
-    K round trips.
+    a turn, reduces its runs of equal (series, bucket) to one value each
+    and scatters the runs into the chunk's partial statistics
+    (_scatter_runs). What a turn costs follows the RUNS of the slots it
+    is handed, not the points in range: on a v5e (PERF.md §6, PR 39)
+    0.09 ms a block of 65,536 slots in hourly or 5-min buckets (one
+    turn, 4,096 updates a scatter), 0.31 ms in 1-min buckets (three
+    turns, 12,288) and 0.95 ms for slots in no order (sixteen, 65,536),
+    where the slot-wise scatters it replaced took 1.18, 1.25 and 0.91
+    ms. A scatter also costs a pass over its operand, about 40 us at
+    the 4M segments of a 1-min grid, which is most of that case's turn.
+    ``handed`` is an int32 scalar carried through a stage's folds
+    beside the accumulators: the updates the scatters were handed
+    (tsd.devwindow.fold.updates). One vector and not five arguments
+    because every host array argument is its own host-to-device
+    transfer at dispatch (measured on a v5e, PERF.md §6: 0.35 ms a fold
+    against 1.1 ms). Compiled once per chunk shape class (chunks are
+    pow2-padded, so there are only a handful); accumulators are donated
+    so the fold is in-place. The stage driver issues these back-to-back
+    ASYNC — dispatch does not wait for the device, so K chunks cost ~K
+    host-side submissions, not K round trips.
 
     ``m2`` accumulates the exact pairwise (Chan et al.) combination,
     once a chunk: the chunk's M2 is centered on the CHUNK-local segment
@@ -478,19 +580,10 @@ def _chunk_fold(rel_ts, vals, sid, valid, count, total, m2, mn, mx,
         bucket = jnp.clip((r - shift) // interval, 0, num_buckets - 1)
         return v, ok, jnp.where(ok, s * num_buckets + bucket, nseg - 1)
 
-    def moments(i, part):
-        v, ok, seg = block_of(i)
-        part = dict(part)
-        part["count"] = part["count"].at[seg].add(ok.astype(jnp.float32))
-        if "sum" in part:
-            part["sum"] = part["sum"].at[seg].add(jnp.where(ok, v, 0.0))
-        if "min" in part:
-            part["min"] = part["min"].at[seg].min(
-                jnp.where(ok, v, _POS_INF))
-        if "max" in part:
-            part["max"] = part["max"].at[seg].max(
-                jnp.where(ok, v, _NEG_INF))
-        return part
+    def moments(i, carry):
+        part, handed = carry
+        part, n = _scatter_runs(part, *block_of(i), nseg - 1)
+        return part, handed + n
 
     zeros = jnp.zeros(nseg, jnp.float32)
     part = {"count": zeros}
@@ -500,15 +593,16 @@ def _chunk_fold(rel_ts, vals, sid, valid, count, total, m2, mn, mx,
         part["min"] = jnp.full(nseg, _POS_INF, jnp.float32)
     if "max" in need:
         part["max"] = jnp.full(nseg, _NEG_INF, jnp.float32)
-    part = jax.lax.fori_loop(0, n_blocks, moments, part)
+    part, handed = jax.lax.fori_loop(0, n_blocks, moments, (part, handed))
     c_cnt, c_tot = part["count"], part.get("sum")
     if "m2" in need:
         c_mean = c_tot / jnp.maximum(c_cnt, 1.0)
 
         def centered(i, c_m2):
             v, ok, seg = block_of(i)
-            d = jnp.where(ok, v - c_mean[seg], 0.0)
-            return c_m2.at[seg].add(d * d)
+            d = v - c_mean[seg]
+            return _scatter_runs({"sum": c_m2}, d * d, ok, seg,
+                                 nseg - 1)[0]["sum"]
 
         c_m2 = jax.lax.fori_loop(0, n_blocks, centered, zeros)
         # Chan combine with the running (count, total, m2): the
@@ -528,7 +622,7 @@ def _chunk_fold(rel_ts, vals, sid, valid, count, total, m2, mn, mx,
         mn = jnp.minimum(mn, part["min"])
     if "max" in need:
         mx = jnp.maximum(mx, part["max"])
-    return count, total, m2, mn, mx
+    return count, total, m2, mn, mx, handed
 
 
 @jit_plan(ExecPlan(
@@ -605,8 +699,11 @@ def window_series_stage_chunks(chunks, lo, hi, shift, *, num_series,
     nothing but slots the fold would have sent to its dump segment, so
     the grids are the same for any order of data. Without ``blocks``
     every chunk is folded whole, as one block.
-    Returns the window_series_stage contract: (series_values,
-    series_mask, filled, in_range, presence)."""
+    Returns the window_series_stage contract, (series_values,
+    series_mask, filled, in_range, presence), and after it the int32
+    device scalar the folds carried: the updates their scatters were
+    handed (_scatter_runs), for whoever counts them to fetch when it
+    likes."""
     need = _needs(agg_down)
     nseg = num_series * num_buckets + 1
     count = jnp.zeros(nseg, jnp.float32)
@@ -617,6 +714,7 @@ def window_series_stage_chunks(chunks, lo, hi, shift, *, num_series,
     m2 = jnp.zeros(nseg, jnp.float32)
     mn = jnp.full(nseg, _POS_INF, jnp.float32)
     mx = jnp.full(nseg, _NEG_INF, jnp.float32)
+    handed = jnp.zeros((), jnp.int32)
     for i, (rel_ts, vals, sid, valid) in enumerate(chunks):
         slots = rel_ts.shape[0]
         if blocks is None:
@@ -631,15 +729,15 @@ def window_series_stage_chunks(chunks, lo, hi, shift, *, num_series,
         visit = np.zeros(4 + slots // blk, np.int32)
         visit[:4] = lo, hi, shift, len(picked)
         visit[4:4 + len(picked)] = picked
-        count, total, m2, mn, mx = _chunk_fold(
-            rel_ts, vals, sid, valid, count, total, m2, mn, mx, visit,
-            num_series=num_series, num_buckets=num_buckets,
+        count, total, m2, mn, mx, handed = _chunk_fold(
+            rel_ts, vals, sid, valid, count, total, m2, mn, mx, handed,
+            visit, num_series=num_series, num_buckets=num_buckets,
             interval=interval, need=need, block=blk)
     return _chunk_stage_finish(
         count, total, m2, mn, mx, num_series=num_series,
         num_buckets=num_buckets, interval=interval, agg_down=agg_down,
         rate=rate, counter_max=counter_max, reset_value=reset_value,
-        counter=counter, drop_resets=drop_resets)
+        counter=counter, drop_resets=drop_resets) + (handed,)
 
 
 WINDOW_STAGE_PLAN = ExecPlan(
@@ -678,7 +776,9 @@ def _series_stage(ts, vals, sid, valid, *, num_series, num_buckets,
     searchsorted of the [S*B] grid — lost to the XLA scatter on TPU and
     CPU alike, because the grid-side searchsorted costs more than the
     scatter it replaces. The scatter path stays; a second attempt has
-    to win in the benchmark's cells."""
+    to win in the benchmark's cells. The resident fold's run reduction
+    (_scatter_runs, PR 39) is not yet in this plan: _segment_moments
+    scatters the packed stream a point at a time."""
     bucket = jnp.clip(ts // interval, 0, num_buckets - 1)
     seg = jnp.where(valid, sid * num_buckets + bucket,
                     num_series * num_buckets)

@@ -28,6 +28,7 @@ Un-downsampled queries keep the exact 1.1 union-grid semantics.
 
 from __future__ import annotations
 
+import collections
 import itertools
 import re
 import threading
@@ -89,6 +90,40 @@ _C_FOLD_WHOLE = _metrics.counter("devwindow.fold.stages.whole")
 # is the host's dispatches a stage, which grow with the span of the
 # range where the slots visited need not.
 _C_FOLD_DISPATCHES = _metrics.counter("devwindow.fold.dispatches")
+# The updates the folds' scatters were handed (kernels._scatter_runs:
+# one a run of equal (series, bucket) and not one a slot), beside
+# devwindow.fold.slots.visited: their ratio is what the run reduction
+# left of the scatters' work. A stage's count is a device scalar its
+# folds carried; it waits in _FOLD_HANDED until the stats are read (or
+# _FOLD_HANDED_MAX have gathered), so no sub-query pays a transfer for
+# it. A gauge over a running total, and not a counter, for that reason.
+_FOLD_HANDED: collections.deque = collections.deque()
+_FOLD_HANDED_MAX = 512
+_fold_updates_lock = threading.Lock()
+_fold_updates_total = 0
+
+
+def _fold_updates() -> int:
+    """The updates handed over since boot: the stages' counts that were
+    still on the device fetched and added to the total."""
+    global _fold_updates_total
+    with _fold_updates_lock:
+        # One drainer at a time, and the others only append.
+        handed = [_FOLD_HANDED.popleft() for _ in range(len(_FOLD_HANDED))]
+        if handed:
+            _fold_updates_total += sum(map(int, jax.device_get(handed)))
+        return _fold_updates_total
+
+
+def _fold_handed(handed) -> None:
+    """Keep one stage's count (window_series_stage_chunks' last output)
+    for the next reading of the stats."""
+    _FOLD_HANDED.append(handed)
+    if len(_FOLD_HANDED) > _FOLD_HANDED_MAX:
+        _fold_updates()
+
+
+_metrics.gauge("devwindow.fold.updates", _fold_updates)
 
 
 # What the raw plan read from storage and handed to its kernels: rows
@@ -1278,6 +1313,7 @@ class QueryExecutor:
                             interval=interval, agg_down=dsagg,
                             blocks=cols.blocks, block=cols.block,
                             **rate_kw)
+                        _fold_handed(grids[5])
                 except Exception as e:
                     # A near-HBM window can still OOM building the stage
                     # grids; degrade to the storage scan (the
@@ -1286,7 +1322,7 @@ class QueryExecutor:
                         return None
                     raise
                 # [5] fills with the host copy of presence on first fetch.
-                stage = list(grids) + [None]
+                stage = list(grids[:5]) + [None]
                 # Stages of this metric's EARLIER data versions can never
                 # hit again (version is monotonic) but each pins [S, B]
                 # grids in HBM the devwindow's own budget can't see — drop
@@ -1421,6 +1457,7 @@ class QueryExecutor:
                 num_series=_pad_size(S_i), num_buckets=num_buckets,
                 interval=interval, agg_down=dsagg,
                 blocks=sc.blocks, block=sc.block, **rate_kw)
+            _fold_handed(grids[5])
             parts.append((S_i, grids))
         if not parts:
             return None

@@ -320,6 +320,7 @@ class TestKernelsNamedByTheirPlan:
         fold = kernels._chunk_fold.lower(
             jnp.zeros(n, jnp.int32), jnp.zeros(n, jnp.float32),
             jnp.zeros(n, jnp.int32), jnp.ones(n, bool), *acc,
+            jnp.zeros((), jnp.int32),
             np.array([0, 100, 0, 1, 0], np.int32), num_series=s,
             num_buckets=b, interval=10, need=kernels._needs("max"),
             block=n)

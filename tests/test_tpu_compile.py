@@ -1,0 +1,82 @@
+"""The resident fold compiled for a described TPU v5e, at the shapes the
+benchmark's cells run it: no chip is attached and nothing runs, but the
+chip's own compiler says what the program is made of (the
+on-chip-measurement guide, section 2). All in this one file, the
+topology described inside a fixture: one process may load the TPU's
+library, and under several workers only the one handed this file does.
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from opentsdb_tpu.ops import kernels
+
+SLOTS, BLOCK, SERIES = 1 << 21, 1 << 16, 4096
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def compiled_fold(one_chip, agg, buckets, interval):
+    def of(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    nseg = SERIES * buckets + 1
+    return kernels._chunk_fold.lower(
+        of((SLOTS,), jnp.int32), of((SLOTS,), jnp.float32),
+        of((SLOTS,), jnp.int32), of((SLOTS,), jnp.bool_),
+        *[of((nseg,), jnp.float32)] * 5, of((), jnp.int32),
+        of((4 + SLOTS // BLOCK,), jnp.int32), num_series=SERIES,
+        num_buckets=buckets, interval=interval, need=kernels._needs(agg),
+        block=BLOCK).compile().as_text()
+
+
+def written(text):
+    """(operation, element count) of every array an instruction outside
+    a fusion's body produces: what the program writes to memory."""
+    out, fused = [], False
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(", line)
+        if head:
+            fused = "fused_computation" in head.group(2)
+            continue
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.+?) ([\w\-]+)\(", line)
+        if m and not fused:
+            for dims in re.findall(r"\[([\d,]+)\]", m.group(1)):
+                n = 1
+                for d in dims.split(","):
+                    n *= int(d)
+                out.append((m.group(2), n))
+    return out
+
+
+@pytest.mark.parametrize("agg,buckets,interval", [
+    ("max", 16, 3600), ("avg", 16, 3600), ("max", 1024, 60),
+    ("dev", 16, 3600)])
+def test_a_turn_of_the_run_reduction_stays_in_its_fusions(one_chip, agg,
+                                                          buckets, interval):
+    text = compiled_fold(one_chip, agg, buckets, interval)
+    # The compare / select / reduce of a turn fuse: nothing of the size
+    # of [tiles, tile, runs] is written, only [tiles, runs] results, the
+    # block's columns, the chunk's and the accumulators.
+    cube = BLOCK * kernels._FOLD_RUNS
+    nseg = SERIES * buckets + 1
+    big = [(op, n) for op, n in written(text)
+           if n >= cube and n not in (SLOTS, nseg)]
+    assert not big, big
+    # One program, its trip counts data: loops, and no branch.
+    assert " while(" in text and " conditional(" not in text
+    assert 'op_name="jit(_chunk_fold)/window.chunk_fold/' in text
