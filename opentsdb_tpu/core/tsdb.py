@@ -258,6 +258,7 @@ class TSDB:
 
         dw, points = self.devwindow, 0
         sp = obs_trace.Span("devwindow.refill")
+        sp.start()
         try:
             for key, cols in self.scan_columns(b"", b"\xff" * 64):
                 if len(cols.timestamps) == 0:
@@ -268,7 +269,7 @@ class TSDB:
                 points += len(cols.timestamps)
         except IllegalDataError:
             self.devwindow = None
-        sp.ms = (time.perf_counter() - sp.t0) * 1000.0
+        sp.stop()
         # Chunks cut so far: the last of a metric's points may still be
         # staged (a query of the metric uploads them).
         sp.tags.update(points=points, chunks=devstore.chunks_cut(dw),

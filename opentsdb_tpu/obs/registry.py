@@ -12,8 +12,10 @@ HTTP/telnet handler instruments.
 
 Cost discipline: an un-polled registry costs one attribute increment
 per counted event and one ``perf_counter`` pair + digest append per
-timed event; every instrumented site fires per *batch* or per
-*operation*, never per point. Rendering (``collect``,
+timed event (a ``thread_time_ns`` pair and a counter add more where the
+block also keeps its CPU time, ``Timer.time(cpu)``); every
+instrumented site fires per *batch* or per *operation*, never per
+point. Rendering (``collect``,
 ``prometheus_text``) only runs when ``/stats`` / ``/metrics`` is
 actually asked.
 
@@ -85,22 +87,29 @@ class Timer:
     def count(self) -> int:
         return self.digest.count
 
-    def time(self) -> "_TimerCtx":
-        return _TimerCtx(self)
+    def time(self, cpu: Counter | None = None) -> "_TimerCtx":
+        """Observe the block's wall time; with ``cpu``, also add the
+        milliseconds its thread was on a CPU to that counter."""
+        return _TimerCtx(self, cpu)
 
 
 class _TimerCtx:
-    __slots__ = ("timer", "t0")
+    __slots__ = ("timer", "cpu", "t0", "c0")
 
-    def __init__(self, timer: Timer) -> None:
+    def __init__(self, timer: Timer, cpu: Counter | None) -> None:
         self.timer = timer
+        self.cpu = cpu
 
     def __enter__(self) -> "_TimerCtx":
         self.t0 = time.perf_counter()
+        if self.cpu is not None:
+            self.c0 = time.thread_time_ns()
         return self
 
     def __exit__(self, *exc) -> None:
         self.timer.observe((time.perf_counter() - self.t0) * 1000.0)
+        if self.cpu is not None:
+            self.cpu.inc((time.thread_time_ns() - self.c0) / 1e6)
 
 
 def _tags_key(tags: dict | None) -> tuple:
@@ -157,7 +166,8 @@ class MetricsRegistry:
         for name, kind, tkey, obj in self._snapshot():
             base = " ".join(f"{k}={v}" for k, v in tkey)
             if kind == "counter":
-                collector.record(name, obj.value, base or None)
+                # A counter of milliseconds is a float: microseconds kept.
+                collector.record(name, round(obj.value, 3), base or None)
             elif kind == "gauge":
                 try:
                     v = obj.read()

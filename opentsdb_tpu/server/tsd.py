@@ -1680,11 +1680,21 @@ class TSDServer:
             # request's label.
             trace = (obs_trace.Trace(m, trace_id=trace_id)
                      if do_trace else None)
-            rs, plan, cached, ainfo = await loop.run_in_executor(
-                self._pool,
-                functools.partial(self.executor.run_approx,
-                                  spec, start, end, trace,
-                                  rollup_only=degrade, approx=aspec))
+            run = functools.partial(self.executor.run_approx,
+                                    spec, start, end, trace,
+                                    rollup_only=degrade, approx=aspec)
+            if trace is None:
+                rs, plan, cached, ainfo = await loop.run_in_executor(
+                    self._pool, run)
+            else:
+                # The sub-query's two waits outside its root span, as
+                # children of it: for a pool thread to begin it
+                # (http.q.queue) and, once that thread has returned,
+                # for this coroutine to run again (http.q.resume).
+                hops = obs_trace.Hops()
+                rs, plan, cached, ainfo = await loop.run_in_executor(
+                    self._pool, hops.run, run)
+                hops.attach(trace.root, "http.q")
             ajson = (ainfo.as_json() if hasattr(ainfo, "as_json")
                      else ainfo)
             self._note_plan(plan, approx=ajson is not None)
@@ -2285,6 +2295,9 @@ class TSDServer:
         rss = read_rss_bytes()
         if rss:
             c.record("process.rss_bytes", rss)
+        # Every thread's CPU time: its rise over an interval, beside the
+        # interval, is how many cores the process kept busy.
+        c.record("process.cpu_ms", round(time.process_time() * 1000.0, 3))
         c.record("traces.recorded", self.trace_ring.recorded)
         c.record("traces.slow", self.trace_ring.slow)
         # Serve tier: the staleness contract (replica role) and the

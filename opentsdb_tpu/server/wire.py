@@ -25,6 +25,7 @@ from opentsdb_tpu.obs.registry import METRICS as _metrics
 LOG = logging.getLogger(__name__)
 
 _M_PARSE = _metrics.timer("ingest.parse")
+_M_PARSE_CPU = _metrics.counter("ingest.parse.cpu_ms")
 
 _LIB_PATHS = (
     os.path.join(os.path.dirname(__file__), "..", "..", "native",
@@ -103,7 +104,7 @@ def decode_puts(buf: bytes, use_native: bool | None = None,
     (the telnet bulk path feeds one TCP read at a time) report exact
     stream line indices rather than batch-relative offsets.
     """
-    with _M_PARSE.time():
+    with _M_PARSE.time(_M_PARSE_CPU):
         if use_native is None:
             use_native = _NATIVE is not None
         if use_native and _NATIVE is not None:
@@ -534,7 +535,7 @@ def decode_json_puts(obj) -> DecodedBatch:
     typed entries. ``error_lines`` carries the failing point's array
     index.
     """
-    with _M_PARSE.time():
+    with _M_PARSE.time(_M_PARSE_CPU):
         return _decode_json_puts(obj)
 
 

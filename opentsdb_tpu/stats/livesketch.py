@@ -59,6 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from opentsdb_tpu.core.const import UID_WIDTH
+from opentsdb_tpu.obs import trace as _trace
 from opentsdb_tpu.ops import sketches
 
 _PAD_MIN = 8
@@ -315,6 +316,7 @@ class LiveSketches:
             jnp.asarray(batch), jnp.asarray(valid),
             compression=self.compression)
 
+    @_trace.timed("sketch.fold")
     def _fold_buffers(self, td_buf: list, hll_buf: dict) -> None:
         """Fold one swapped-out buffer pair into the device stacks.
         Runs on the folder thread (or inline when background=False);

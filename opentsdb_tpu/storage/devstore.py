@@ -68,6 +68,8 @@ from typing import NamedTuple
 
 import numpy as np
 
+from opentsdb_tpu.obs import trace as _trace
+
 LOG = logging.getLogger(__name__)
 
 
@@ -576,6 +578,7 @@ class DeviceWindow:
             self._uploads_completed += 1
             self._cond.notify_all()
 
+    @_trace.timed("devwindow.upload")
     def _upload(self, mw: _MetricWindow, batch, seq: int) -> None:
         """Upload one staged batch as a padded immutable chunk."""
         import jax

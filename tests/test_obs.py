@@ -228,6 +228,178 @@ class TestTrace:
         assert sp.ms >= 9
 
 
+def _spin(seconds):
+    t0 = time.perf_counter()
+    while time.perf_counter() - t0 < seconds:
+        pass
+
+
+def _walk(d):
+    yield d
+    for c in d.get("spans", ()):
+        yield from _walk(c)
+
+
+def _span_counters(name):
+    tags = {"span": name}
+    return (METRICS.counter("query.span.wall_ms", tags).value,
+            METRICS.counter("query.span.cpu_ms", tags).value)
+
+
+class TestCpuTime:
+    """CPU time beside wall time: every reading on one thread, so no
+    assertion depends on how two threads split a lock."""
+
+    def test_a_sleeping_span_reads_no_cpu(self):
+        tr = obs_trace.Trace("q")
+        with obs_trace.activate(tr):
+            with obs_trace.span("cpu.sleeps"):
+                time.sleep(0.05)
+        sp, = tr.root.children
+        assert sp.ms >= 49 and sp.cpu_ms < 5
+        # The root slept too, and the tree says so on every span.
+        assert tr.root.cpu_ms < 5 <= tr.root.ms
+        d = tr.to_dict()
+        assert all("cpu_ms" in n and "ms" in n for n in _walk(d))
+        assert d["spans"][0]["cpu_ms"] == round(sp.cpu_ms, 3)
+
+    def test_a_spinning_span_reads_its_wall_time(self):
+        # The best of five: a host that takes the core away mid-spin
+        # lengthens the wall time of that try alone.
+        best = 0.0
+        for _ in range(5):
+            tr = obs_trace.Trace("q")
+            with obs_trace.activate(tr):
+                with obs_trace.span("cpu.spins"):
+                    _spin(0.05)
+            sp, = tr.root.children
+            assert sp.ms >= 49 and sp.cpu_ms <= sp.ms + 1
+            best = max(best, sp.cpu_ms / sp.ms)
+            if best >= 0.8:
+                break
+        assert best >= 0.8
+
+    def test_timed_iter_keeps_the_cpu_of_its_pulls_alone(self):
+        tr = obs_trace.Trace("q")
+        with obs_trace.activate(tr):
+            def gen():
+                time.sleep(0.03)        # inside the first pull
+                yield 1
+                yield 2
+
+            for _ in obs_trace.timed_iter(gen(), tr.root, "cpu.pulls"):
+                _spin(0.03)             # between pulls: the caller's
+        sp, = tr.root.children
+        assert sp.tags["rows"] == 2
+        # The sleep is the span's, the spinning is not.
+        assert sp.ms >= 29 and sp.cpu_ms < 5
+        assert tr.root.cpu_ms >= 20
+
+    def test_a_closed_span_adds_to_the_two_tagged_counters(self):
+        names = ("cpu.counted", "cpu.counted.child", "query")
+        before = {n: _span_counters(n) for n in names}
+        tr = obs_trace.Trace("q")
+        with obs_trace.activate(tr):
+            with obs_trace.span("cpu.counted"):
+                with obs_trace.span("cpu.counted.child"):
+                    time.sleep(0.01)
+                _spin(0.01)
+        outer, = tr.root.children
+        inner, = outer.children
+        for name, sp in zip(names, (outer, inner, tr.root)):
+            wall, cpu = _span_counters(name)
+            assert wall - before[name][0] == pytest.approx(sp.ms)
+            assert cpu - before[name][1] == pytest.approx(sp.cpu_ms)
+        coll = StatsCollector("tsd")
+        METRICS.collect(coll)
+        lines = [ln.split() for ln in coll.lines
+                 if "span=cpu.counted.child" in ln]
+        assert sorted(w[0] for w in lines) == ["tsd.query.span.cpu_ms",
+                                               "tsd.query.span.wall_ms"]
+
+    @pytest.mark.parametrize("tags", [{}, {"kind": "a"}])
+    def test_timed_moves_the_cpu_counter_of_its_tags(self, tags):
+        timer = METRICS.timer("cpu.test.phase", tags or None)
+        cpu = METRICS.counter("cpu.test.phase.cpu_ms", tags or None)
+        other = METRICS.counter("cpu.test.phase.cpu_ms", {"kind": "b"})
+        n0, ms0, c0, o0 = timer.count, timer.total_ms, cpu.value, other.value
+        with obs_trace.timed("cpu.test.phase", **tags):
+            time.sleep(0.03)
+        slept = cpu.value - c0
+        assert timer.count == n0 + 1 and timer.total_ms - ms0 >= 29
+        assert 0 <= slept < 5
+        with obs_trace.timed("cpu.test.phase", **tags):
+            _spin(0.03)
+        assert timer.count == n0 + 2
+        assert cpu.value - c0 - slept >= 10
+        assert cpu.value - c0 <= timer.total_ms - ms0 + 2
+        assert other.value == o0
+
+    def test_timed_is_a_decorator_too(self):
+        timer = METRICS.timer("cpu.test.decorated")
+        cpu = METRICS.counter("cpu.test.decorated.cpu_ms")
+
+        @obs_trace.timed("cpu.test.decorated")
+        def work(x):
+            _spin(0.01)
+            return x + 1
+
+        n0, c0 = timer.count, cpu.value
+        assert [work(1), work(2)] == [2, 3]
+        assert timer.count == n0 + 2 and cpu.value - c0 >= 5
+
+    def test_a_timer_block_without_a_counter_reads_no_cpu_clock(
+            self, monkeypatch):
+        reg = MetricsRegistry()
+        t = reg.timer("plain")
+        calls = []
+        real = time.thread_time_ns
+        monkeypatch.setattr(time, "thread_time_ns",
+                            lambda: calls.append(1) or real())
+        with t.time():
+            pass
+        assert t.count == 1 and calls == []
+        cpu = reg.counter("plain.cpu_ms")
+        with t.time(cpu):
+            pass
+        assert t.count == 2 and len(calls) == 2 and cpu.value >= 0
+
+    def test_the_write_side_blocks_keep_their_cpu(self, tmp_path):
+        from opentsdb_tpu.server import wire
+        from opentsdb_tpu.stats.livesketch import LiveSketches
+        from opentsdb_tpu.storage.devstore import DeviceWindow
+
+        names = ("ingest.parse", "ingest.batch", "devwindow.upload",
+                 "sketch.fold")
+
+        def read():
+            return {n: (METRICS.timer(n).count,
+                        METRICS.counter(n + ".cpu_ms").value)
+                    for n in names}
+
+        before = read()
+        tsdb = TSDB(MemKVStore(), Config(auto_create_metrics=True,
+                                         device_window=False))
+        batch = wire.decode_puts(b"".join(
+            b"put cpu.w %d %d host=h%d\n" % (BASE + i, i, i % 3)
+            for i in range(300)))
+        assert wire.ingest_batch(tsdb, batch)[0] == 300
+        dw = DeviceWindow(staging_points=100, background=False)
+        dw.append(b"\x00\x00\x01", b"sk",
+                  BASE + np.arange(100, dtype=np.int64),
+                  np.ones(100, np.float32))
+        dw.flush()
+        sk = LiveSketches(background=False, flush_points=1 << 30)
+        sk.observe(b"\x00\x00\x01" + bytes(6), np.arange(50.0),
+                   [(b"\x00\x00\x01", b"\x00\x00\x01", b"\x00\x00\x01")])
+        sk.flush()
+        after = read()
+        for n in names:
+            assert after[n][0] > before[n][0], n
+            assert after[n][1] > before[n][1], n
+        tsdb.shutdown()
+
+
 class TestFaultDelaySpan:
     def test_wal_fsync_delay_lengthens_exactly_that_span(self, tmp_path):
         """The acceptance-criteria proof: an armed delay faultpoint on
@@ -356,18 +528,15 @@ class TestServerTraces:
         picks = [s for s in tr["spans"] if s["name"] == "planner.pick"]
         assert picks[0]["tags"]["plan"] == out[0]["rollup"]
         # Fragment-cache outcome is visible on the stitch spans.
-        def walk(d):
-            yield d
-            for c in d.get("spans", ()):
-                yield from walk(c)
-
-        stitches = [s for s in walk(tr) if s["name"] == "raw.stitch"]
+        stitches = [s for s in _walk(tr) if s["name"] == "raw.stitch"]
         assert stitches
         assert any(any(k.startswith("qcache_")
                        for k in s.get("tags", {}))
                    for s in stitches), stitches
-        # Top-level stage durations tile the query wall time (10%).
-        top = sum(s["ms"] for s in tr["spans"])
+        # Top-level stage durations tile the query wall time (10%);
+        # the two hops lie outside it.
+        top = sum(s["ms"] for s in tr["spans"]
+                  if not s["name"].startswith("http.q."))
         assert top >= 0.9 * tr["ms"], (top, tr["ms"])
 
     def test_raw_trace_and_query_scan_delay(self, tmp_path):
@@ -439,6 +608,105 @@ class TestServerTraces:
         assert rec["wall_ms"] > 0 and rec["slow"] is True
         assert rec["trace"]["spans"]  # span tree attached
 
+    def test_every_span_carries_cpu_and_every_root_its_two_hops(
+            self, tmp_path):
+        server, tsdb = make_server(tmp_path)
+        subs = ("sum:1h-avg:obs.metric", "max:obs.metric{host=*}")
+
+        async def drive(port):
+            q = (f"/q?start={BASE}&end={BASE + 2 * 86400 + 1800}"
+                 + "".join("&m=" + m for m in subs)
+                 + "&json&trace=1&nocache")
+            t0 = time.perf_counter()
+            st, body = await http_get(port, q)
+            wall = (time.perf_counter() - t0) * 1000.0
+            return st, body, wall, await http_get(port, "/stats")
+
+        st, body, wall, (st2, stats) = run_async(server, drive)
+        assert st == 200 and st2 == 200
+        trees = [r["trace"] for r in json.loads(body) if "trace" in r]
+        assert [t["tags"]["q"] for t in trees] == list(subs)
+        for tree in trees:
+            assert tree["name"] == "query"
+            for sp in _walk(tree):
+                assert 0 <= sp["cpu_ms"] <= sp["ms"] + 1, sp
+            hops = [s for s in _walk(tree)
+                    if s["name"].startswith("http.q.")]
+            assert sorted(s["name"] for s in hops) == ["http.q.queue",
+                                                       "http.q.resume"]
+            # On the root, outside its interval: the queue ends where
+            # the root starts, the resume starts where it ends.
+            assert [s["name"] for s in (tree["spans"][0],
+                                        tree["spans"][-1])] \
+                == ["http.q.queue", "http.q.resume"]
+            queue, resume = tree["spans"][0], tree["spans"][-1]
+            assert queue["ms"] >= 0 and resume["ms"] >= 0
+            assert queue["cpu_ms"] == resume["cpu_ms"] == 0
+            assert queue["t0"] <= tree["t0"] + 1e-3
+            assert resume["t0"] >= tree["t0"] + tree["ms"] / 1e3 - 1e-3
+        # The sub-queries run one after another inside the request.
+        assert sum(t["ms"] + t["spans"][0]["ms"] + t["spans"][-1]["ms"]
+                   for t in trees) <= wall
+        lines = {}
+        for ln in stats.decode().splitlines():
+            w = ln.split()
+            lines[w[0] + " " + " ".join(
+                t for t in w[3:] if not t.startswith("host="))] = float(w[2])
+        for name in ("query", "planner.pick", "http.q.queue",
+                     "http.q.resume"):
+            wall_ms = lines[f"tsd.query.span.wall_ms span={name}"]
+            assert 0 <= lines[f"tsd.query.span.cpu_ms span={name}"] \
+                <= wall_ms + 1
+        assert lines["tsd.query.span.wall_ms span=query"] >= sum(
+            t["ms"] for t in trees) - 0.01
+        assert lines["tsd.process.cpu_ms "] > 100
+        assert 0 < lines["tsd.http.q.encode.cpu_ms "] \
+            <= lines["tsd.http.q.encode.sum_ms "] + 1
+
+    def test_an_untraced_request_opens_no_span_and_moves_no_counter(
+            self, tmp_path, monkeypatch):
+        """Off means off: with no trace active ``span()`` is the shared
+        no-op, and an untraced /q builds no Span and no Hops, so it
+        takes none of their clock readings."""
+        server, tsdb = make_server(tmp_path, shards=1, rollups=False)
+        assert obs_trace._ACTIVE == 0
+        assert obs_trace.span("x") is obs_trace._NOOP
+        assert obs_trace.span("x", tag=1) is obs_trace.span("y")
+        made = []
+        start, hops = obs_trace.Span.start, obs_trace.Hops.__init__
+        monkeypatch.setattr(
+            obs_trace.Span, "start",
+            lambda self: made.append(self.name) or start(self))
+        monkeypatch.setattr(
+            obs_trace.Hops, "__init__",
+            lambda self: made.append("hops") or hops(self))
+        q = (f"/q?start={BASE}&end={BASE + 3600}"
+             "&m=sum:obs.metric&json&nocache")
+
+        def counters():
+            return {k[1][0][1]: _span_counters(k[1][0][1])
+                    for k in list(METRICS._metrics)
+                    if k[0] == "query.span.wall_ms"}
+
+        async def drive(port):
+            first = await http_get(port, q)
+            untraced = len(made), counters()
+            traced = await http_get(port, q + "&trace=1")
+            return first, untraced, traced
+
+        _span_counters("query")         # registered, so that it is held
+        before = counters()
+        enc = METRICS.counter("http.q.encode.cpu_ms").value
+        (st, body), (n_made, after), (st2, _b) = run_async(server, drive)
+        assert st == 200 and "trace" not in json.loads(body)[0]
+        assert n_made == 0 and after == before
+        # The guard can see: the traced twin of the request builds them
+        # and moves the counters.
+        assert st2 == 200 and "hops" in made and "query" in made
+        assert _span_counters("query")[0] > before["query"][0]
+        # ... and the encode's timed() block is on for both.
+        assert METRICS.counter("http.q.encode.cpu_ms").value > enc
+
     def test_untraced_json_has_no_trace_key(self, tmp_path):
         server, tsdb = make_server(tmp_path, shards=1, rollups=False)
 
@@ -463,7 +731,7 @@ class TestMetricsEndpoint:
         async def drive(port):
             # Exercise handlers first so handler timers have samples.
             await http_get(port, f"/q?start={BASE}&end={BASE + 3600}"
-                                 "&m=sum:obs.metric&json&nocache")
+                                 "&m=sum:obs.metric&json&nocache&trace=1")
             await http_get(port, "/stats")
             return await http_get(port, "/metrics")
 
@@ -472,6 +740,13 @@ class TestMetricsEndpoint:
         text = body.decode()
         n = validate_exposition(text)
         assert n > 50
+        # The traced request's span counters, tagged by span name, and
+        # the CPU counters beside the timers.
+        assert "# TYPE tsd_query_span_cpu_ms counter" in text
+        assert 'tsd_query_span_wall_ms{span="http.q.queue"}' in text
+        assert "# TYPE tsd_http_q_encode_cpu_ms counter" in text
+        assert 'tsd_checkpoint_phase_cpu_ms{phase="spill"}' in text
+        assert "tsd_process_cpu_ms " in text
         assert "# TYPE tsd_wal_appends counter" in text
         assert "# TYPE tsd_http_handler_ms summary" in text
         assert 'endpoint="/q"' in text
