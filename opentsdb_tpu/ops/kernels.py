@@ -526,23 +526,55 @@ def _scatter_runs(part, v, ok, seg, dump):
             turns * (tiles * runs))
 
 
+# The chunks one call of window.chunk_fold takes: a stage folds its
+# chunks _FOLD_GROUP at a time, shape class by shape class, so what the
+# host issues for a stage is 2 + the sum over classes of
+# ceil(chunks hit / _FOLD_GROUP) programs and not 7 + the chunks hit.
+# A turn of the fold slices its block out of each of the _FOLD_GROUP
+# operands and keeps one by a select, so the group costs a turn
+# _FOLD_GROUP reads of a block where one would do. Settled on a v5e
+# (PERF.md §6, PR 41): a block of 65,536 slots in hourly or 5-min runs
+# takes 87-92 us alone, 94-97 in a group of 4 and 105-107 in a group of
+# 8, which is more than the 15% the grouping was allowed to cost a turn.
+_FOLD_GROUP = 4
+
+
+@jit_plan(ExecPlan(
+    name="window.chunk_stage_start", axis="series",
+    static_argnames=("nseg",)))
+def _chunk_stage_start(*, nseg):
+    """What a stage's folds start from, as ONE program: the five
+    accumulators (count, total, m2 at zero, min at +inf, max at -inf)
+    and the ``handed`` scalar at zero. Compiled once a grid size."""
+    zeros = jnp.zeros(nseg, jnp.float32)
+    return (zeros, zeros, zeros, jnp.full(nseg, _POS_INF, jnp.float32),
+            jnp.full(nseg, _NEG_INF, jnp.float32), jnp.zeros((), jnp.int32))
+
+
 @jit_plan(ExecPlan(
     name="window.chunk_fold", axis="series",
     static_argnames=("num_series", "num_buckets", "interval", "need",
                      "block"),
-    donate_argnums=(4, 5, 6, 7, 8)))
-def _chunk_fold(rel_ts, vals, sid, valid, count, total, m2, mn, mx,
-                handed, visit, *, num_series, num_buckets, interval, need,
-                block):
-    """Fold the selected blocks of ONE resident chunk into the
-    per-(series, bucket) accumulators. ``visit`` is one int32 vector,
-    ``[lo, hi, shift, n, id_0 .. id_n-1, 0 ..]``: the range, the bucket
-    shift, and the ``n`` blocks of ``block`` slots to visit (the
-    devwindow's zone-map selection, DevChunks.blocks), padded to the
-    chunk's block count. All of it is DATA, so a range never seen before
-    runs the program already compiled: a ``fori_loop`` slices one block
-    a turn, reduces its runs of equal (series, bucket) to one value each
-    and scatters the runs into the chunk's partial statistics
+    donate_argnums=(1, 2, 3, 4, 5)))
+def _chunk_fold(chunks, count, total, m2, mn, mx, handed, visit, *,
+                num_series, num_buckets, interval, need, block):
+    """Fold the selected blocks of a GROUP of resident chunks of one
+    shape class into the per-(series, bucket) accumulators. ``chunks``
+    is a tuple of (rel_ts, vals, sid, valid) tuples, all of one shape
+    (the stage driver hands over _FOLD_GROUP of them, a short group's
+    empty places filled with its first chunk again: no memory, never
+    selected). ``visit`` is one int32 vector, ``[lo, hi, shift, n,
+    (which_0, id_0) .. (which_n-1, id_n-1), 0 ..]``: the range, the
+    bucket shift, and the ``n`` blocks of ``block`` slots to visit,
+    each as the operand it lies in and its id there (the devwindow's
+    zone-map selection, DevChunks.blocks, of every chunk of the group
+    as one list), padded to the group's block count. All of it is DATA,
+    so a range never seen before runs the program already compiled: a
+    ``fori_loop`` takes one block a turn (its slice out of every
+    operand, one kept by a select on the scalar ``which_i``: the body
+    is traced once whatever the group's size, and nothing branches),
+    reduces its runs of equal (series, bucket) to one value each
+    and scatters the runs into the call's partial statistics
     (_scatter_runs). What a turn costs follows the RUNS of the slots it
     is handed, not the points in range: on a v5e (PERF.md §6, PR 39)
     0.09 ms a block of 65,536 slots in hourly or 5-min buckets (one
@@ -556,26 +588,29 @@ def _chunk_fold(rel_ts, vals, sid, valid, count, total, m2, mn, mx,
     (tsd.devwindow.fold.updates). One vector and not five arguments
     because every host array argument is its own host-to-device
     transfer at dispatch (measured on a v5e, PERF.md §6: 0.35 ms a fold
-    against 1.1 ms). Compiled once per chunk shape class (chunks are
-    pow2-padded, so there are only a handful); accumulators are donated
-    so the fold is in-place. The stage driver issues these back-to-back
-    ASYNC — dispatch does not wait for the device, so K chunks cost ~K
-    host-side submissions, not K round trips.
+    against 1.1 ms), and one call a group and not one a chunk because
+    every program a stage issues from Python is a place where its
+    thread lets the interpreter lock go and has to win it back
+    (PERF.md §6, PR 41). Compiled once per chunk shape class (chunks
+    are pow2-padded, so there are only a handful); accumulators are
+    donated so the fold is in-place. The stage driver issues the calls
+    back-to-back ASYNC: dispatch does not wait for the device.
 
     ``m2`` accumulates the exact pairwise (Chan et al.) combination,
-    once a chunk: the chunk's M2 is centered on the CHUNK-local segment
+    once a call: the group's M2 is centered on the GROUP-local segment
     means (a second turn over the same blocks), then corrected by the
     mean shift against the running accumulator — numerically sound
     where a naive E[x^2]-E[x]^2 merge cancels catastrophically (same
     scheme as the sharded psum fan-in, parallel/sharded.py)."""
     lo, hi, shift, n_blocks = visit[0], visit[1], visit[2], visit[3]
-    block_ids = visit[4:]
+    picks = visit[4:].reshape(-1, 2)
     nseg = num_series * num_buckets + 1
 
     def block_of(i):
-        r, v, s, ok = (jax.lax.dynamic_slice_in_dim(
-            c, block_ids[i] * block, block)
-            for c in (rel_ts, vals, sid, valid))
+        which, at = picks[i, 0], picks[i, 1] * block
+        r, v, s, ok = (jax.lax.select_n(which, *(
+            jax.lax.dynamic_slice_in_dim(c, at, block) for c in column))
+            for column in zip(*chunks))
         ok = ok & (r >= lo) & (r <= hi)
         bucket = jnp.clip((r - shift) // interval, 0, num_buckets - 1)
         return v, ok, jnp.where(ok, s * num_buckets + bucket, nseg - 1)
@@ -663,9 +698,27 @@ STAGE_GRID_MAX = 1 << 24
 def stage_accumulator_bytes(cells: int = STAGE_GRID_MAX) -> int:
     """What the chunked stage's accumulators hold on the device while
     it folds a grid of ``cells``: the five float32 vectors
-    window_series_stage_chunks allocates (count, total, m2, min, max),
+    _chunk_stage_start allocates (count, total, m2, min, max),
     one segment a cell and the dump segment."""
     return 5 * 4 * (cells + 1)
+
+
+def fold_groups(chunks, blocks=None, block=None):
+    """The calls of window.chunk_fold a stage over this selection
+    issues, as ``[(block, [(chunk, picked), ..]), ..]``: the chunks with
+    a block picked, gathered by shape class in the order the classes
+    first appear in ``chunks`` and cut into groups of at most
+    _FOLD_GROUP. It follows nothing but the shapes of the chunk list
+    and the blocks picked: a list of one chunk is one group of one."""
+    classes = {}
+    for i, chunk in enumerate(chunks):
+        picked = (0,) if blocks is None else blocks[i]
+        if len(picked):
+            classes.setdefault(chunk[0].shape[0], []).append((chunk, picked))
+    return [(slots if blocks is None else min(block, slots),
+             members[at:at + _FOLD_GROUP])
+            for slots, members in classes.items()
+            for at in range(0, len(members), _FOLD_GROUP)]
 
 
 def window_series_stage_chunks(chunks, lo, hi, shift, *, num_series,
@@ -680,64 +733,65 @@ def window_series_stage_chunks(chunks, lo, hi, shift, *, num_series,
     full copy plus N-sized transients, capping it near half — the
     1B-points-resident north star, BASELINE.md).
 
-    Structure: one per-chunk fold jit (compiled once per pow2 chunk
-    shape class, NOT one giant unrolled program that would retrace on
-    every chunk-count change) driven by a host loop; async dispatch
+    Structure: a start program (_chunk_stage_start: the accumulators),
+    one fold jit a GROUP of up to _FOLD_GROUP chunks of one shape class
+    (fold_groups; compiled once per pow2 chunk shape class, NOT one
+    giant unrolled program that would retrace on every chunk-count
+    change) driven by a host loop, and the finish program: 2 + the
+    fold calls device programs from Python a stage. Async dispatch
     pipelines the folds on device and only the finish stage joins.
     Accumulators are donated, so peak HBM is the resident chunks + one
-    accumulator set + one chunk's transients.
+    accumulator set + one call's transients.
 
-    Every moment family merges exactly (dev via the chunk-locally-
+    Every moment family merges exactly (dev via the group-locally-
     centered M2 + Chan mean-shift correction — see _chunk_fold).
 
     ``chunks``: iterable of (rel_ts, values, sid, valid) tuples.
     ``blocks`` / ``block``: the devwindow's zone-map selection for
     [lo, hi] (DevChunks.blocks / .block) — per chunk, the ids of the
     ``block``-slot blocks whose recorded [min, max] timestamp meets the
-    range. A chunk with none is not dispatched at all, and of the
+    range. A chunk with none is in no call at all, and of the
     others only the listed blocks are visited; a block left out holds
     nothing but slots the fold would have sent to its dump segment, so
     the grids are the same for any order of data. Without ``blocks``
-    every chunk is folded whole, as one block.
+    every chunk is folded whole, as one block. count, min and max are
+    the same bits however the chunks are grouped; the float32 sums add
+    the same slots in another order of partial sums.
     Returns the window_series_stage contract, (series_values,
     series_mask, filled, in_range, presence), and after it the int32
     device scalar the folds carried: the updates their scatters were
     handed (_scatter_runs), for whoever counts them to fetch when it
     likes."""
     need = _needs(agg_down)
-    nseg = num_series * num_buckets + 1
-    count = jnp.zeros(nseg, jnp.float32)
     # Unused statistics still flow through the fold signature (static
     # ``need`` gates their updates to no-ops) so one jit serves every
     # mergeable aggregator per shape class.
-    total = jnp.zeros(nseg, jnp.float32)
-    m2 = jnp.zeros(nseg, jnp.float32)
-    mn = jnp.full(nseg, _POS_INF, jnp.float32)
-    mx = jnp.full(nseg, _NEG_INF, jnp.float32)
-    handed = jnp.zeros((), jnp.int32)
-    for i, (rel_ts, vals, sid, valid) in enumerate(chunks):
-        slots = rel_ts.shape[0]
-        if blocks is None:
-            blk, picked = slots, (0,)
-        else:
-            blk, picked = min(block, slots), blocks[i]
-        if not len(picked):
-            continue
-        # The vector's length follows the chunk's block count, whatever
-        # was picked: the shape, and so the program, follows the chunk's
+    acc = _chunk_stage_start(nseg=num_series * num_buckets + 1)
+    for blk, members in fold_groups(chunks, blocks, block):
+        first = members[0][0]
+        # The vector's length follows the class's block count and the
+        # group's places, whatever was picked and however many chunks
+        # came: the shape, and so the program, follows the chunks'
         # shape class alone.
-        visit = np.zeros(4 + slots // blk, np.int32)
-        visit[:4] = lo, hi, shift, len(picked)
-        visit[4:4 + len(picked)] = picked
-        count, total, m2, mn, mx, handed = _chunk_fold(
-            rel_ts, vals, sid, valid, count, total, m2, mn, mx, handed,
-            visit, num_series=num_series, num_buckets=num_buckets,
-            interval=interval, need=need, block=blk)
+        visit = np.zeros(
+            4 + 2 * _FOLD_GROUP * (first[0].shape[0] // blk), np.int32)
+        picks, at = visit[4:].reshape(-1, 2), 0
+        for which, (_chunk, picked) in enumerate(members):
+            rows = picks[at:at + len(picked)]
+            rows[:, 0], rows[:, 1] = which, picked
+            at += len(picked)
+        visit[:4] = lo, hi, shift, at
+        operands = tuple(chunk for chunk, _picked in members) + (
+            first,) * (_FOLD_GROUP - len(members))
+        acc = _chunk_fold(
+            operands, *acc, visit, num_series=num_series,
+            num_buckets=num_buckets, interval=interval, need=need,
+            block=blk)
     return _chunk_stage_finish(
-        count, total, m2, mn, mx, num_series=num_series,
-        num_buckets=num_buckets, interval=interval, agg_down=agg_down,
-        rate=rate, counter_max=counter_max, reset_value=reset_value,
-        counter=counter, drop_resets=drop_resets) + (handed,)
+        *acc[:5], num_series=num_series, num_buckets=num_buckets,
+        interval=interval, agg_down=agg_down, rate=rate,
+        counter_max=counter_max, reset_value=reset_value,
+        counter=counter, drop_resets=drop_resets) + (acc[5],)
 
 
 WINDOW_STAGE_PLAN = ExecPlan(

@@ -85,11 +85,18 @@ _C_FOLD_SKIPPED = _metrics.counter("devwindow.fold.slots.skipped")
 # maps engages.
 _C_FOLD_NARROWED = _metrics.counter("devwindow.fold.stages.narrowed")
 _C_FOLD_WHOLE = _metrics.counter("devwindow.fold.stages.whole")
-# window.chunk_fold calls: a stage built dispatches one for every chunk
-# its selection picked a block of, so dispatches / devwindow.stage.miss
-# is the host's dispatches a stage, which grow with the span of the
-# range where the slots visited need not.
+# window.chunk_fold calls: a stage built issues one for every group of
+# up to kernels._FOLD_GROUP chunks of one shape class its selection
+# picked a block of (kernels.fold_groups), so dispatches /
+# devwindow.stage.miss is the fold calls a stage, which grow with the
+# span of the range, a group at a time, where the slots visited need
+# not. stage.programs is every device program a stage build issued
+# from Python: the start (the accumulators), each fold call and the
+# finish, so 2 + the calls (a shard of the sharded window: its own
+# start and finish), and each one a place where the stage's thread
+# lets the interpreter lock go and has to win it back.
 _C_FOLD_DISPATCHES = _metrics.counter("devwindow.fold.dispatches")
+_C_STAGE_PROGRAMS = _metrics.counter("devwindow.stage.programs")
 # The updates the folds' scatters were handed (kernels._scatter_runs:
 # one a run of equal (series, bucket) and not one a slot), beside
 # devwindow.fold.slots.visited: their ratio is what the run reduction
@@ -1283,13 +1290,15 @@ class QueryExecutor:
                 ssp.tags["series"] = len(sids)
             if stage is None:
                 (_C_FOLD_NARROWED if narrowed else _C_FOLD_WHOLE).inc()
-                picked, of, visited, resident, dispatched = \
+                picked, of, visited, resident, folded, calls, programs = \
                     _dw_fold_extent(cols)
                 _C_FOLD_VISITED.inc(visited)
                 _C_FOLD_SKIPPED.inc(resident - visited)
-                _C_FOLD_DISPATCHES.inc(dispatched)
+                _C_FOLD_DISPATCHES.inc(calls)
+                _C_STAGE_PROGRAMS.inc(programs)
                 if ssp is not None:
-                    ssp.tags["chunks"] = dispatched
+                    ssp.tags["chunks"] = folded
+                    ssp.tags["calls"] = calls
                     ssp.tags["blocks"] = picked
                     ssp.tags["blocks_total"] = of
                 try:
@@ -2593,10 +2602,13 @@ def _dw_chunks(cols) -> list:
 def _dw_fold_extent(cols) -> tuple[int, ...]:
     """DevChunks.fold_extent() of a resident window's columns, summed
     over the sharded window's shards: (blocks picked, blocks in all,
-    slots picked, slots in all, chunks dispatched)."""
+    slots picked, slots in all, chunks hit, fold calls), and after
+    them the device programs the stage build issues: the calls and,
+    for each shard, its start and its finish."""
     shards = getattr(cols, "shards", None)
-    parts = [cols] if shards is None else filter(None, shards)
-    return tuple(map(sum, zip(*(p.fold_extent() for p in parts))))
+    parts = [cols] if shards is None else list(filter(None, shards))
+    sums = tuple(map(sum, zip(*(p.fold_extent() for p in parts))))
+    return sums + (sums[5] + 2 * len(parts),)
 
 
 def _is_device_oom(e: Exception) -> bool:

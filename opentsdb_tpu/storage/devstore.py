@@ -243,13 +243,15 @@ class DevChunks(NamedTuple):
             return self
         return self._replace(blocks=blocks)
 
-    def fold_extent(self) -> tuple[int, int, int, int, int]:
+    def fold_extent(self) -> tuple[int, int, int, int, int, int]:
         """What the selection hands the fold: (blocks picked, blocks in
-        all, slots picked, slots in all, chunks with a block picked:
-        the stage dispatches one fold for each of those and none for
-        the others). Slots count the chunks' padding too: a skipped
-        slot is skipped whatever it held."""
-        picked = of = visited = resident = dispatched = 0
+        all, slots picked, slots in all, chunks with a block picked,
+        the window.chunk_fold calls the stage issues for them: one a
+        group of chunks of one shape class, kernels.fold_groups, and
+        none for a chunk with no block picked). Slots count the chunks'
+        padding too: a skipped slot is skipped whatever it held."""
+        from opentsdb_tpu.ops import kernels
+        picked = of = visited = resident = hit = 0
         for chunk, ids in zip(self.chunks, self.blocks):
             slots = int(chunk[0].shape[0])
             blk = min(self.block, slots)
@@ -257,8 +259,10 @@ class DevChunks(NamedTuple):
             of += slots // blk
             visited += len(ids) * blk
             resident += slots
-            dispatched += len(ids) > 0
-        return picked, of, visited, resident, dispatched
+            hit += len(ids) > 0
+        calls = len(kernels.fold_groups(self.chunks, self.blocks,
+                                        self.block))
+        return picked, of, visited, resident, hit, calls
 
 
 class _MetricWindow:

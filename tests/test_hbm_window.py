@@ -82,6 +82,7 @@ def answers(daemon):
                                       extra="&nocache&trace=1")
             for name, qtype in TYPES.items()}
     before = {n: stat(n) for n in ("devwindow.fold.dispatches",
+                                   "devwindow.stage.programs",
                                    "devwindow.stage.miss")}
     misses = daemon.devwindow.window_misses
     got = serve(daemon, *(r.target for r in reqs.values()))
@@ -167,7 +168,7 @@ def test_every_type_is_resident_and_equals_the_reference(
             np.testing.assert_allclose(r.values, wv, rtol=RTOL)
 
 
-def test_dispatches_count_the_chunks_each_stage_folded(answers):
+def test_dispatches_count_the_calls_each_stage_issued(answers):
     reqs, got, before, after = answers
     tags = [t for _st, raw in got.values()
             for t in stage_tags(json.loads(raw))]
@@ -175,26 +176,47 @@ def test_dispatches_count_the_chunks_each_stage_folded(answers):
     assert after["devwindow.stage.miss"] - before[
         "devwindow.stage.miss"] == len(built) == sum(
             len(r.ms) for r in reqs.values())
+    calls = sum(t["calls"] for t in built)
     assert after["devwindow.fold.dispatches"] - before[
-        "devwindow.fold.dispatches"] == sum(t["chunks"] for t in built)
+        "devwindow.fold.dispatches"] == calls
+    # A stage's programs: its start, its fold calls, its finish.
+    assert after["devwindow.stage.programs"] - before[
+        "devwindow.stage.programs"] == calls + 2 * len(built)
     assert all(0 < t["chunks"] <= t["blocks"] <= t["blocks_total"]
                for t in built)
+    # A call a group of up to _FOLD_GROUP chunks of one shape class: the
+    # full chunks are one class, a metric's tail chunk another.
+    group = kernels._FOLD_GROUP
+    assert all(-(-t["chunks"] // group) <= t["calls"]
+               <= -(-t["chunks"] // group) + 1 for t in built)
+    assert calls < sum(t["chunks"] for t in built)
 
 
-def test_a_dispatch_is_one_chunk_fold_call(daemon, monkeypatch):
+def test_a_dispatch_is_one_chunk_fold_call_a_group(daemon, monkeypatch):
     calls = []
     fold = kernels._chunk_fold
-    monkeypatch.setattr(kernels, "_chunk_fold",
-                        lambda *a, **kw: calls.append(1) or fold(*a, **kw))
+
+    def counted(chunks, *a, **kw):
+        # The chunks a call was handed: a short group's empty places
+        # hold its first chunk again.
+        calls.append(len({id(c[0]) for c in chunks}))
+        return fold(chunks, *a, **kw)
+    monkeypatch.setattr(kernels, "_chunk_fold", counted)
     spec = QuerySpec(CFG["metrics"][3], {"host": "host_7"}, "max",
                      downsample=(300, "max"))
-    d0, s0 = stat("devwindow.fold.dispatches"), stat("devwindow.stage.miss")
+    names = ("devwindow.fold.dispatches", "devwindow.stage.programs",
+             "devwindow.stage.miss")
+    before = [stat(n) for n in names]
     start = CFG["t0"] + 4321
     _out, plan, _c = QueryExecutor(daemon, backend="tpu").run_with_plan(
         spec, start, start + 8 * 3600)
     assert plan == "resident"
-    assert stat("devwindow.stage.miss") - s0 == 1
-    assert stat("devwindow.fold.dispatches") - d0 == len(calls) >= 11
+    dispatches, programs, built = (stat(n) - b
+                                   for n, b in zip(names, before))
+    assert built == 1
+    assert sum(calls) >= 11
+    assert dispatches == len(calls) <= -(-sum(calls) // kernels._FOLD_GROUP) + 1
+    assert programs == 2 + len(calls)
 
 
 def stats_lines(daemon) -> dict:

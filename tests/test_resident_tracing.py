@@ -317,11 +317,11 @@ class TestKernelsNamedByTheirPlan:
         acc = [jnp.zeros(nseg, jnp.float32)] * 3 + [
             jnp.full(nseg, np.inf, jnp.float32),
             jnp.full(nseg, -np.inf, jnp.float32)]
+        chunk = (jnp.zeros(n, jnp.int32), jnp.zeros(n, jnp.float32),
+                 jnp.zeros(n, jnp.int32), jnp.ones(n, bool))
         fold = kernels._chunk_fold.lower(
-            jnp.zeros(n, jnp.int32), jnp.zeros(n, jnp.float32),
-            jnp.zeros(n, jnp.int32), jnp.ones(n, bool), *acc,
-            jnp.zeros((), jnp.int32),
-            np.array([0, 100, 0, 1, 0], np.int32), num_series=s,
+            (chunk, chunk), *acc, jnp.zeros((), jnp.int32),
+            np.array([0, 100, 0, 1, 1, 0, 0, 0], np.int32), num_series=s,
             num_buckets=b, interval=10, need=kernels._needs("max"),
             block=n)
         text = fold.as_text(debug_info=True)

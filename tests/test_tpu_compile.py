@@ -35,11 +35,14 @@ def compiled_fold(one_chip, agg, buckets, interval):
     def of(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     nseg = SERIES * buckets + 1
+    group = kernels._FOLD_GROUP
+    chunk = (of((SLOTS,), jnp.int32), of((SLOTS,), jnp.float32),
+             of((SLOTS,), jnp.int32), of((SLOTS,), jnp.bool_))
     return kernels._chunk_fold.lower(
-        of((SLOTS,), jnp.int32), of((SLOTS,), jnp.float32),
-        of((SLOTS,), jnp.int32), of((SLOTS,), jnp.bool_),
-        *[of((nseg,), jnp.float32)] * 5, of((), jnp.int32),
-        of((4 + SLOTS // BLOCK,), jnp.int32), num_series=SERIES,
+        (chunk,) * group, *[of((nseg,), jnp.float32)] * 5,
+        of((), jnp.int32),
+        of((4 + 2 * group * (SLOTS // BLOCK),), jnp.int32),
+        num_series=SERIES,
         num_buckets=buckets, interval=interval, need=kernels._needs(agg),
         block=BLOCK).compile().as_text()
 
