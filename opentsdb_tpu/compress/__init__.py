@@ -9,5 +9,7 @@ needs).
 - kernels.py: batched JAX decode and the fused decode-plus-aggregate
   stage (the decoded column lives only inside one XLA program).
 - fused.py: the query-side block source — coverage checks that decide
-  when a range can be served straight from compressed blocks.
+  when a range can be served straight from compressed blocks, and the
+  seek or walk that finds which.
+- devcache.py: decoded blocks kept on the device, a block a slab row.
 """

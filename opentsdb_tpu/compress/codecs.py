@@ -48,7 +48,8 @@ import zlib
 
 import numpy as np
 
-from opentsdb_tpu.core.const import FLAG_BITS, FLAG_FLOAT, LENGTH_MASK
+from opentsdb_tpu.core.const import (FLAG_BITS, FLAG_FLOAT, LENGTH_MASK,
+                                     TIMESTAMP_BYTES, UID_WIDTH)
 
 VERBATIM = 0
 TSF32 = 1
@@ -391,7 +392,6 @@ def _expand_keys(klen: np.ndarray, kpre: np.ndarray,
     column j (column-wise forward fill — no per-record Python)."""
     n = len(klen)
     kmax = int(klen.max()) if n else 0
-    K = np.zeros((n, kmax), np.uint8)
     suf_len = klen - kpre
     offs = np.zeros(n, np.int64)
     if n > 1:
@@ -404,12 +404,13 @@ def _expand_keys(klen: np.ndarray, kpre: np.ndarray,
     pos = np.minimum(offs[:, None] + (cols - kpre[:, None]),
                      max(len(ksuf) - 1, 0))
     S = np.where(own, ksuf[pos] if len(ksuf) else 0, 0).astype(np.uint8)
-    rows = np.arange(n)
-    for j in range(kmax):
-        src = np.where(own[:, j], rows, -1)
-        fill = np.maximum.accumulate(src)
-        valid = fill >= 0
-        K[valid, j] = S[fill[valid], j]
+    # ...and every other byte is the one above it: the most recent row
+    # whose own suffix covers the column, all columns in one pass.
+    fill = np.maximum.accumulate(
+        np.where(own, np.arange(n)[:, None], -1), axis=0)
+    K = np.where(fill >= 0,
+                 np.take_along_axis(S, np.maximum(fill, 0), axis=0),
+                 0).astype(np.uint8)
     return K
 
 
@@ -440,6 +441,43 @@ class TsBlock:
     def int_values(self) -> np.ndarray:
         d = _unzigzag(_unpack_varbytes(self.v_pay, self.v_nb))
         return np.cumsum(d)
+
+    def identity(self):
+        """The row keys of a data-table block taken apart: (metric
+        [n] int32, base time [n] int64, series keys: each row's key
+        less its base time), or None where a key is too short to be a
+        data row's. The series keys come in one pass where the keys
+        are one length (a metric's series mostly are)."""
+        K, klen = self.keys_matrix(), self.klen
+        lo, hi = UID_WIDTH, UID_WIDTH + TIMESTAMP_BYTES
+        if self.n == 0 or (klen < hi).any():
+            return None
+        metric = np.zeros(self.n, np.int32)
+        for c in range(lo):
+            metric = (metric << 8) | K[:, c]
+        base = np.zeros(self.n, np.int64)
+        for c in range(lo, hi):
+            base = (base << 8) | K[:, c]
+        width = int(klen[0])
+        if (klen == width).all():
+            flat = np.delete(K[:, :width], np.s_[lo:hi],
+                             axis=1).tobytes()
+            w = width - TIMESTAMP_BYTES
+            skeys = [flat[i:i + w] for i in range(0, len(flat), w)]
+        else:
+            skeys = [K[i, :lo].tobytes() + K[i, hi:klen[i]].tobytes()
+                     for i in range(self.n)]
+        return metric, base, skeys
+
+    def columns(self):
+        """The block's points as two columns in row order: qualifier
+        deltas (int64: a point's seconds past its row's base time) and
+        values (float32; a TSINT block's integers by way of float64,
+        as a row's decode casts them)."""
+        if self.tag == TSF32:
+            return self.deltas(), self.float_bits().view(np.float32)
+        return self.deltas(), self.int_values().astype(
+            np.float64).astype(np.float32)
 
 
 def parse_ts_block(tag: int, enc, keys_only: bool = False) -> TsBlock:
@@ -498,6 +536,57 @@ def parse_ts_block(tag: int, enc, keys_only: bool = False) -> TsBlock:
         raise BlockCodecError("trailing bytes after block payload")
     b.K = _expand_keys(b.klen, b.kpre, ksuf)
     return b
+
+
+class TsStreams:
+    """The two point streams of a TSF32/TSINT block as the file holds
+    them (views of ``enc``): packed nibble byte counts (high nibble
+    first, (P + 1) // 2 bytes) and payload bytes, with the points a
+    record and the largest byte count of either stream."""
+
+    __slots__ = ("n", "P", "npts", "ts_nib", "ts_pay", "v_nib",
+                 "v_pay", "max_nb")
+
+
+def ts_block_streams(enc) -> TsStreams:
+    """Find a block's streams without parsing its keys or unpacking a
+    nibble: what the device decode uploads (compress/devcache.py). The
+    same length checks as ``parse_ts_block``."""
+    buf = np.frombuffer(enc, np.uint8)
+    if len(buf) < _HDR.size:
+        raise BlockCodecError("block header truncated")
+    n, P, tlen, _fam = _HDR.unpack_from(enc, 0)
+    s = TsStreams()
+    s.n, s.P = n, P
+    off = _HDR.size + tlen + 3 * n        # table, klen, kpre
+    (ksuf_len,) = _U32.unpack_from(enc, off)
+    off += 4 + ksuf_len
+    s.npts = buf[off:off + 2 * n].view(">u2").astype(np.int32)
+    off += 2 * n
+    if int(s.npts.sum()) != P:
+        raise BlockCodecError("point count mismatch")
+    nib = (P + 1) // 2
+    out = []
+    for _ in range(2):
+        (pay_len,) = _U32.unpack_from(enc, off)
+        off += 4
+        nibs = buf[off:off + nib]
+        off += nib
+        pay = buf[off:off + pay_len]
+        off += pay_len
+        if len(nibs) != nib or len(pay) != pay_len:
+            raise BlockCodecError("block payload truncated")
+        hi, lo = nibs >> 4, nibs & 0xF
+        if int(hi.sum(dtype=np.int64)) + int(lo.sum(dtype=np.int64)) \
+                != pay_len:
+            raise BlockCodecError("payload length mismatch")
+        out.append((nibs, pay, max(int(hi.max(initial=0)),
+                                   int(lo.max(initial=0)))))
+    if off != len(buf):
+        raise BlockCodecError("trailing bytes after block payload")
+    (s.ts_nib, s.ts_pay, a), (s.v_nib, s.v_pay, b) = out
+    s.max_nb = max(a, b)
+    return s
 
 
 def _decode_ts_raw(tag: int, enc) -> bytes:

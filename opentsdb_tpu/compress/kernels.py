@@ -180,120 +180,183 @@ fused_block_stage = compile_with_plan(
              static_argnames=_FUSED_STATICS))
 
 
-def _fused_block_stage_sel(ts_nb, ts_pay, v_nb, v_pay, first_idx,
-                           blk_first, sel, rel_base, sid, valid,
-                           lo, hi, shift, *,
-                           num_series, num_buckets, interval, agg_down,
-                           rate=False, counter_max=0.0, reset_value=0.0,
-                           counter=False, drop_resets=False,
-                           vkind="f32"):
-    """The fused stage with the selector's matched-point compaction:
-    decode the FULL streams (the value chains span whole blocks, so
-    decode cannot skip records), then gather only the matched points
-    into the window stage. ``sel`` is the host-computed matched-point
-    index vector; ``rel_base``/``sid``/``valid`` are already gathered
-    on host to the same [M] layout (padding entries valid=False).
-    Stage cost scales with the MATCH fraction instead of the scan
-    width — the tag-filtered dashboard's win. Bit-identical to the
-    unselected stage: dropped points belong to records the stage
-    would have masked out anyway, and kept points stay in stream
-    order, so every per-(series, bucket) reduction sees the same
-    operands in the same order."""
-    deltas, vals = decode_points(ts_nb, ts_pay, v_nb, v_pay,
-                                 first_idx, blk_first,
-                                 jnp.int32(0), vkind=vkind)
-    rel_ts = rel_base + deltas[sel]
-    return _window_series_stage(
-        rel_ts, vals[sel], sid, valid, lo, hi, shift,
-        num_series=num_series, num_buckets=num_buckets,
-        interval=interval, agg_down=agg_down, rate=rate,
-        counter_max=counter_max, reset_value=reset_value,
-        counter=counter, drop_resets=drop_resets)
-
-
-fused_block_stage_sel = compile_with_plan(
-    _fused_block_stage_sel,
-    ExecPlan(name="compress.fused_stage_sel", axis="block",
-             static_argnames=_FUSED_STATICS))
-
-
 # -- device block cache legs ------------------------------------------------
 #
-# The devcache (compress/devcache.py) keeps each block's QUERY-
-# INDEPENDENT decoded columns resident on device: per-point qualifier
-# deltas, decoded f32 values, and the point->record map. A repeat
-# query then uploads only per-RECORD arrays (base time, series id,
-# validity — ~two orders of magnitude smaller than the point stream)
-# and one program expands them point-wise and runs the same window
-# stage. Answers are bit-identical to the byte-stream fused program:
-# identical decode math, identical point order, identical stage.
+# The devcache (compress/devcache.py) keeps the QUERY-INDEPENDENT
+# decoded columns of single blocks resident on device, a block a row
+# of two [slots, P_BLK] slabs: per-point qualifier deltas and decoded
+# f32 values. A miss uploads the block's streams as the file holds
+# them (packed nibbles, payload bytes, per-record point counts) and
+# decodes them into its row; a query then uploads per-RECORD arrays
+# for whole blocks (the dense leg) or per-POINT arrays for its matched
+# points alone (the selective leg) and runs the same window stage.
+# Answers are bit-identical to the byte-stream fused program:
+# identical decode math (the XOR/delta chains never cross block
+# boundaries), identical point order, and padding points belong to a
+# pad record every query marks invalid.
+#
+# On the TPU a gather or a scatter costs ~10 ns an ELEMENT whatever it
+# moves (the first fill program here spent 65 ms on 8 blocks: a
+# searchsorted, eight byte gathers and three gathers by record, a
+# third of a million elements each), while a scan along a row is
+# nearly free. So nothing below gathers by point but the two payload
+# reads of a fill: what a point takes from its record is spread by a
+# scatter of the RECORDS' differences at their first points and a
+# cumsum along the row (``_spread``), and the XOR chain is a
+# log-step shift-and-xor.
 
-def block_decode_columns(ts_nb, ts_pay, v_nb, v_pay, first_idx,
-                         blk_first, *, vkind="f32"):
-    """One gather's cached device columns: (qualifier deltas int32,
-    values float32) over the concatenated block streams. Padding
-    points carry nb == 0 and first_idx/blk_first == their own index,
-    so they decode to exact zeros."""
-    qd, vals = decode_points(ts_nb, ts_pay, v_nb, v_pay, first_idx,
-                             blk_first, jnp.zeros_like(first_idx),
-                             vkind=vkind)
-    return qd, vals
+def _nibbles(packed, n):
+    """[B, n] int32 byte counts from [B, n // 2] packed nibbles (high
+    nibble first, codecs._pack_nibbles)."""
+    b = packed.astype(jnp.int32)
+    return jnp.stack([b >> 4, b & 15], axis=-1).reshape(
+        packed.shape[0], n)
 
 
-block_decode_columns_jit = compile_with_plan(
-    block_decode_columns,
-    ExecPlan(name="compress.devcache_decode", axis="block",
-             static_argnames=("vkind",)))
+def _row_varbytes_u32(pay, nb):
+    """``_varbytes_u32`` a block a row: [B, P] values from [B, W]
+    payload bytes, each row's bytes packed from its own column 0. One
+    gather a point: of the four bytes at its offset as one big-endian
+    word, shifted down to the ``nb`` (at most 4) it owns."""
+    b = jnp.pad(pay.astype(jnp.uint32), ((0, 0), (0, 3)))
+    w = pay.shape[1]
+    word = ((b[:, :w] << 24) | (b[:, 1:w + 1] << 16)
+            | (b[:, 2:w + 2] << 8) | b[:, 3:w + 3])
+    off = jnp.clip(jnp.cumsum(nb, axis=1) - nb, 0, w - 1)
+    got = jnp.take_along_axis(word, off, axis=1)
+    shift = (jnp.where(nb > 0, 4 - nb, 0) * 8).astype(jnp.uint32)
+    return jnp.where(nb > 0, got >> shift, jnp.uint32(0))
+
+
+def _spread(per_rec, starts, width):
+    """[B, width] from [B, R]: each point the value of its record,
+    where record r's points begin at ``starts[:, r]`` (ascending and
+    under ``width``: a row always ends in a padding point). The
+    records' differences added at their first points, summed along
+    the row: a record with no point adds its difference where the
+    next one begins, so the sums telescope whatever the counts. The
+    places ascend through the batch, which the scatter is told (it
+    compiles in half a second so, in eight otherwise). int32
+    wraparound is deliberate."""
+    rows = per_rec.shape[0]
+    prev = jnp.pad(per_rec[:, :-1], ((0, 0), (1, 0)))
+    at = jnp.arange(rows, dtype=jnp.int32)[:, None] * width + starts
+    return jnp.cumsum(jax.ops.segment_sum(
+        (per_rec - prev).reshape(-1), at.reshape(-1),
+        num_segments=rows * width,
+        indices_are_sorted=True).reshape(rows, width), axis=1)
+
+
+def _row_seg_cumsum(x, starts):
+    """``_seg_cumsum`` a block a row: the inclusive cumsum of ``x``
+    less what had run up before each point's record began."""
+    c = jnp.cumsum(x, axis=1)
+    before = jnp.take_along_axis(c - x, starts, axis=1)
+    return c - _spread(before, starts, x.shape[1])
+
+
+def _row_xor_scan(x):
+    """Inclusive XOR prefix along each row, by doubling: log2(P)
+    shift-and-xor passes. (lax.associative_scan's odd/even slicing
+    takes the TPU's compiler 12 s to lower at this size, the 1-D
+    programs above as long for each cumsum: the fill is 2-D and
+    compiles in seconds.)"""
+    k = 1
+    while k < x.shape[1]:
+        x = x ^ jnp.pad(x[:, :-k], ((0, 0), (k, 0)))
+        k <<= 1
+    return x
+
+
+def _slab_fill(slab_qd, slab_vals, slots, ts_nib, ts_pay, v_nib, v_pay,
+               npts, *, vkind="f32"):
+    """Decode a batch of B blocks into their slab rows (donated, so in
+    place): ``decode_points``' math a block a row, since no chain
+    crosses a block. ``ts_pay`` / ``v_pay`` [B, 4 P] hold each block's
+    payload from column 0; ``npts`` [B, R_BLK] is each block's points
+    a record, zero past its last record: the first of those is the pad
+    record, where a row's padding points begin (they decode to a zero
+    delta and are invalid in every query). ``slots`` past the slab (a
+    short batch's padding) are dropped."""
+    P = slab_qd.shape[1]
+    starts = jnp.cumsum(npts, axis=1) - npts
+    ent = _unzigzag32(_row_varbytes_u32(ts_pay, _nibbles(ts_nib, P)))
+    qd = _row_seg_cumsum(_row_seg_cumsum(ent, starts), starts)
+    x = _row_varbytes_u32(v_pay, _nibbles(v_nib, P))
+    if vkind == "int":
+        vals = jnp.cumsum(_unzigzag32(x), axis=1).astype(jnp.float32)
+    else:
+        vals = jax.lax.bitcast_convert_type(_row_xor_scan(x),
+                                            jnp.float32)
+    return (slab_qd.at[slots].set(qd, mode="drop"),
+            slab_vals.at[slots].set(vals, mode="drop"))
+
+
+slab_fill = compile_with_plan(
+    _slab_fill,
+    ExecPlan(name="compress.devcache_fill", axis="block",
+             static_argnames=("vkind",), donate_argnums=(0, 1)))
 
 _DEV_STATICS = ("num_series", "num_buckets", "interval", "agg_down",
                 "rate", "counter", "drop_resets")
 
 
-def _devcache_window_stage(qd, vals, rec_of_pt, rel_base, sid, valid,
-                           lo, hi, shift, counter_max, reset_value, *,
-                           num_series, num_buckets, interval, agg_down,
-                           rate=False, counter=False,
-                           drop_resets=False):
-    """Window stage over cached decoded columns: expand the per-record
-    uploads point-wise (three gathers) and reduce — no payload bytes,
-    no decode. Padding points map to a trailing pad record with
-    valid=False."""
-    rel_ts = rel_base[rec_of_pt] + qd
+def _slab_stage_rows(slab_qd, slab_vals, slots, starts, rel_base, sid,
+                     valid, lo, hi, shift, counter_max, reset_value, *,
+                     num_series, num_buckets, interval, agg_down,
+                     rate=False, counter=False, drop_resets=False):
+    """Window stage over whole cached blocks (the dense leg): gather
+    the K rows of ``slots`` and spread the per-record uploads
+    [K, R_BLK] over their points — no payload bytes, no decode.
+    ``starts`` is where in its row each record's points begin; a
+    row's padding points belong to the pad record, which is invalid,
+    and a padding slot's records are all invalid."""
+    P = slab_qd.shape[1]
+    # The four point streams are made whole before the stage reads
+    # them: fused into its scatters, the TPU's compiler takes 30 s over
+    # the program where the parts take 5.
+    rel_ts, vals, sid, valid = jax.lax.optimization_barrier((
+        (_spread(rel_base, starts, P) + slab_qd[slots]).reshape(-1),
+        slab_vals[slots].reshape(-1),
+        _spread(sid, starts, P).reshape(-1),
+        _spread(valid.astype(jnp.int32), starts, P).reshape(-1) > 0))
     return _window_series_stage(
-        rel_ts, vals, sid[rec_of_pt], valid[rec_of_pt], lo, hi, shift,
+        rel_ts, vals, sid, valid, lo, hi, shift,
         num_series=num_series, num_buckets=num_buckets,
         interval=interval, agg_down=agg_down, rate=rate,
         counter_max=counter_max, reset_value=reset_value,
         counter=counter, drop_resets=drop_resets)
 
 
-devcache_window_stage = compile_with_plan(
-    _devcache_window_stage,
+slab_stage_rows = compile_with_plan(
+    _slab_stage_rows,
     ExecPlan(name="compress.devcache_stage", axis="block",
              static_argnames=_DEV_STATICS))
 
 
-def _devcache_window_stage_sel(qd, vals, rec_of_pt, sel, rel_base,
-                               sid, valid, lo, hi, shift, counter_max,
-                               reset_value, *, num_series, num_buckets,
-                               interval, agg_down, rate=False,
-                               counter=False, drop_resets=False):
-    """Window stage over cached columns with the selector's matched-
-    point compaction: gather only the matched points (``sel``, padded
-    with an index whose record is invalid) before expanding the
-    per-record uploads — stage cost scales with the match fraction,
-    and the cached columns stay selector-independent."""
-    rec_g = rec_of_pt[sel]
-    rel_ts = rel_base[rec_g] + qd[sel]
+def _slab_stage_sel(slab_qd, slab_vals, row, col, rel_base, sid, valid,
+                    lo, hi, shift, counter_max, reset_value, *,
+                    num_series, num_buckets, interval, agg_down,
+                    rate=False, counter=False, drop_resets=False):
+    """Window stage over the matched points alone (the selective leg):
+    ``row`` / ``col`` [M] are each matched point's slab row and its
+    place in the block (a gather of M elements: the slabs are not
+    flattened, which would copy them), the other three its record's
+    base time, series and validity, expanded on the host — stage cost
+    scales with the match and not with the blocks touched, and the
+    cached columns stay selector-independent. Kept points stay in
+    stream order, so every per-(series, bucket) reduction sees the
+    operands the dense leg would hand it."""
     return _window_series_stage(
-        rel_ts, vals[sel], sid[rec_g], valid[rec_g], lo, hi, shift,
+        rel_base + slab_qd[row, col], slab_vals[row, col], sid, valid,
+        lo, hi, shift,
         num_series=num_series, num_buckets=num_buckets,
         interval=interval, agg_down=agg_down, rate=rate,
         counter_max=counter_max, reset_value=reset_value,
         counter=counter, drop_resets=drop_resets)
 
 
-devcache_window_stage_sel = compile_with_plan(
-    _devcache_window_stage_sel,
+slab_stage_sel = compile_with_plan(
+    _slab_stage_sel,
     ExecPlan(name="compress.devcache_stage_sel", axis="block",
              static_argnames=_DEV_STATICS))

@@ -259,24 +259,92 @@ class TSDB:
         dw, points = self.devwindow, 0
         sp = obs_trace.Span("devwindow.refill")
         sp.start()
+        runs = self._stored_block_runs()
         try:
-            for key, cols in self.scan_columns(b"", b"\xff" * 64):
-                if len(cols.timestamps) == 0:
-                    continue
-                pr = codec.parse_row_key(key)
-                dw.append(pr.metric_uid, codec.series_key(key),
-                          cols.timestamps, cols.values)
-                points += len(cols.timestamps)
+            if runs is not None:
+                # A store of columnar blocks is read as columns: a
+                # block's rows of one metric-hour in one call, where
+                # the scan below would frame them as rows again to
+                # decode them one by one.
+                for metric_uid, skeys, counts, ts, vals in runs:
+                    dw.append_rows(metric_uid, skeys, counts, ts, vals)
+                    points += len(ts)
+            else:
+                for key, cols in self.scan_columns(b"", b"\xff" * 64):
+                    if len(cols.timestamps) == 0:
+                        continue
+                    pr = codec.parse_row_key(key)
+                    dw.append(pr.metric_uid, codec.series_key(key),
+                              cols.timestamps, cols.values)
+                    points += len(cols.timestamps)
         except IllegalDataError:
             self.devwindow = None
         sp.stop()
         # Chunks cut so far: the last of a metric's points may still be
         # staged (a query of the metric uploads them).
         sp.tags.update(points=points, chunks=devstore.chunks_cut(dw),
+                       columnar=runs is not None,
                        seconds=round(sp.ms / 1000.0, 3))
         self.devwindow_refill = sp.to_dict()
         LOG.info("device window refilled: %d points in %d chunks, "
                  "%.1f s", points, sp.tags["chunks"], sp.ms / 1000.0)
+
+    def _stored_block_runs(self):
+        """Every stored point of the data table in key order, a run of
+        whole rows at a time, straight from TSST4 columnar blocks:
+        (metric_uid, series keys, points a row, timestamps, values)
+        for each run of a block's rows that share a metric and a base
+        time (so the run's series are distinct). None where that could
+        differ from ``scan_columns``, which then serves: rows in the
+        memtable, a frozen tier or tombstones, a generation that is
+        not format v4, generations whose key ranges overlap (a row may
+        then have cells in two of them), or a block of the table that
+        is not TSF32/TSINT (rows of several cells, annotations)."""
+        from opentsdb_tpu.compress import codecs, fused
+        from opentsdb_tpu.core.errors import IllegalDataError
+
+        store, table = self.store, self.table
+        if getattr(store, "encoded_range", None) is None \
+                or getattr(store, "memtable_keys", None) is None \
+                or store.memtable_keys(table):
+            return None
+        spans = store.encoded_range(table, b"", None)
+        if not spans:
+            return None
+        plan, last = [], None
+        for sst, lo, hi in spans:
+            keys = sst._index[table][0]
+            if last is not None and keys[lo] <= last:
+                return None
+            last = keys[hi - 1]
+            for j in fused.block_range(sst, table, lo, hi):
+                if sst.block_header(j)[0] not in (codecs.TSF32,
+                                                  codecs.TSINT):
+                    return None
+                plan.append((sst, j))
+
+        def runs():
+            for sst, j in plan:
+                b = codecs.parse_ts_block(sst.block_header(j)[0],
+                                          sst.block_enc(j))
+                ident = b.identity()
+                if ident is None or b.table != table.encode():
+                    raise IllegalDataError(
+                        f"{sst.path}: block {j} holds no data rows")
+                metric, base, skeys = ident
+                qd, vals = b.columns()
+                ts = base[b.rec_of_pt] + qd
+                fused.prime(sst, table, j, b)
+                cuts = np.flatnonzero(
+                    (np.diff(metric) != 0) | (np.diff(base) != 0)) + 1
+                edges = [0, *cuts.tolist(), b.n]
+                for a, z in zip(edges[:-1], edges[1:]):
+                    p0 = int(b.first_pt[a])
+                    p1 = int(b.first_pt[z]) if z < b.n else b.P
+                    yield (skeys[a][:UID_WIDTH], skeys[a:z],
+                           b.npts[a:z], ts[p0:p1], vals[p0:p1])
+
+        return runs()
 
     # ------------------------------------------------------------------
     # Streaming sketches

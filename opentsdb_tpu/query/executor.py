@@ -62,6 +62,15 @@ _M_FUSED = _metrics.timer("compress.fused_agg")
 # no-silent-declines contract is these three instruments agreeing.
 _C_FUSED_ATTEMPT = _metrics.counter("compress.fused.attempt")
 _C_FUSED_SERVED = _metrics.counter("compress.fused.served")
+# What the gathers of the fused plan met: the points of the blocks they
+# touched, of those the points of matching in-range records, the
+# blocks' payload bytes (the larger stream of each), and the bytes the
+# byte-stream leg sent to the device (the block cache counts
+# its own fills: compress.devcache.uploaded_bytes).
+_C_FUSED_POINTS = _metrics.counter("compress.fused.points")
+_C_FUSED_MATCHED = _metrics.counter("compress.fused.matched_points")
+_C_FUSED_PAYLOAD = _metrics.counter("compress.fused.payload_bytes")
+_C_FUSED_UPLOADED = _metrics.counter("compress.fused.uploaded_bytes")
 _metrics.gauge(
     "compress.fused.coverage",
     lambda: (_C_FUSED_SERVED.value / _C_FUSED_ATTEMPT.value
@@ -425,6 +434,11 @@ class QueryExecutor:
         # dropped generation; eligibility (dirty range, format mix) is
         # re-checked per query — only the decode+stage compute caches.
         self._fused_stage_cache = LRUCache(4)
+        # What a gather of the fused plan would else work out anew: the
+        # selector's verdict a series key, by (metric, filter), and a
+        # series' tags by name.
+        self._fused_sel_memo = LRUCache(64)
+        self._fused_named: dict[bytes, dict[str, str]] = {}
         # Device-side decoded-block cache (compress/devcache.py):
         # per-block query-independent columns stay resident on device,
         # bounded by total cached points. Keyed by SSTable OBJECT +
@@ -1617,17 +1631,22 @@ class QueryExecutor:
     def _run_fused_inner(self, spec, start, end, agg, metric_uid,
                          exact, group_bys, interval, dsagg, qbase,
                          b_lo, b_hi):
+        """The fused plan past its gates, as five spans under
+        planner.pick (README, "Observability"): fused.gather (which
+        blocks, their records, the groups), fused.dispatch (the
+        uploads and the calls of the stage and apply programs),
+        fused.wait (traced requests only, as aggregate.wait),
+        fused.fetch, fused.results."""
         from opentsdb_tpu.compress import fused as _fused
-        from opentsdb_tpu.compress import kernels as _ckernels
         tsdb = self.tsdb
         rate_kw = self._rate_kw(spec)
+        fk = _filter_key(exact, group_bys)
         # The tag filter is part of the stage's identity now that it's
         # pushed into the gather (filtered-out series never reach the
         # stage grid) — leaving it out would serve one filter's grid
         # under another's key.
         skey_cache = (metric_uid, b_lo, b_hi, interval, dsagg, start,
-                      end, _filter_key(exact, group_bys),
-                      tuple(sorted(rate_kw.items())))
+                      end, fk, tuple(sorted(rate_kw.items())))
         hit = self._fused_stage_cache.get(skey_cache)
         if hit is not None:
             gens_hit, src_keys, epoch, stage, groups = hit
@@ -1646,28 +1665,43 @@ class QueryExecutor:
                         in zip(spans, gens_hit)):
                 hit = None
                 self._fused_stage_cache.pop(skey_cache)
+        src = None
         if hit is None:
-            selector = self._series_selector(exact, group_bys)
-            use_dev = self._devcache is not None and self.mesh is None
-            try:
-                src = _fused.gather(tsdb.store, tsdb.table, metric_uid,
-                                    b_lo, b_hi, selector=selector,
-                                    points=not use_dev)
-            except _fused.Decline as d:
-                _count_decline(d.reason)
-                return None
+            with obs_trace.span("fused.gather") as sp:
+                memo = self._fused_sel_memo.get((metric_uid, fk))
+                if memo is None:
+                    memo = {}
+                    self._fused_sel_memo.put((metric_uid, fk), memo)
+                try:
+                    src = _fused.gather(
+                        tsdb.store, tsdb.table, metric_uid, b_lo, b_hi,
+                        selector=self._series_selector(exact, group_bys),
+                        series_keys=self._series_hint(
+                            metric_uid, exact, group_bys).get(
+                                "series_keys"),
+                        sel_memo=memo)
+                except _fused.Decline as d:
+                    _count_decline(d.reason)
+                    return None
+                if sp is not None:
+                    dc = self._devcache
+                    sp.tags.update(
+                        blocks=len(src.blocks), points=src.npoints,
+                        matched=src.matched,
+                        series=len(src.series_keys),
+                        payload_bytes=src.payload_bytes(),
+                        cached=dc.held(src) if dc is not None else 0)
             if src.npoints == 0:
                 return []
+            _C_FUSED_POINTS.inc(src.npoints)
+            _C_FUSED_MATCHED.inc(src.matched)
+            _C_FUSED_PAYLOAD.inc(src.payload_bytes())
             epoch = src.epoch
             src_keys = src.series_keys
             groups = src.groups
-        else:
-            src = None
-            use_dev = False
         if not groups:
             return []
-        S_all = len(src_keys)
-        S_pad = _pad_size(S_all)
+        S_pad = _pad_size(len(src_keys))
         imin, imax = -(2**31), 2**31 - 1
         if not imin <= qbase - epoch <= imax:
             _count_decline("int32-span")
@@ -1676,194 +1710,180 @@ class QueryExecutor:
         if S_pad * num_buckets >= 2**31:
             _count_decline("grid-too-large")
             return None
-        named = {sid: self._named_tags(src_keys[sid])
-                 for sids in groups.values() for sid in sids}
-        lo32 = np.int32(min(max(start - epoch, imin), imax))
-        hi32 = np.int32(min(max(end - epoch, imin), imax))
-        shift32 = np.int32(qbase - epoch)
-        if hit is None:
-            vkind = src.kind
-            if use_dev:
-                # Warm blocks: decoded columns already on device, so
-                # the dispatch uploads only per-record arrays (plus
-                # the matched-point index vector for selective
-                # filters) and runs the decode-free stage
-                # (bit-identical math).
-                qd, vals, rec, _P, _P_pad, _R = \
-                    self._devcache.columns(src)
-                rel_base, sid_r, valid_r, sel = \
-                    self._devcache.record_inputs(
-                        src, S_pad, selective=selector is not None)
-                dev_kw = dict(
-                    num_series=S_pad, num_buckets=num_buckets,
-                    interval=interval, agg_down=dsagg,
-                    rate=rate_kw["rate"], counter=rate_kw["counter"],
-                    drop_resets=rate_kw["drop_resets"])
-                if sel is not None:
-                    stage = list(_ckernels.devcache_window_stage_sel(
-                        qd, vals, rec, sel, rel_base, sid_r, valid_r,
-                        lo32, hi32, shift32,
-                        np.float32(rate_kw["counter_max"]),
-                        np.float32(rate_kw["reset_value"]),
-                        **dev_kw)) + [None]
-                else:
-                    stage = list(_ckernels.devcache_window_stage(
-                        qd, vals, rec, rel_base, sid_r, valid_r,
-                        lo32, hi32, shift32,
-                        np.float32(rate_kw["counter_max"]),
-                        np.float32(rate_kw["reset_value"]),
-                        **dev_kw)) + [None]
-            else:
-                P_pad = _pad_fine(src.npoints)
-                def pad(a, dtype, fill=0):
-                    out = np.full(P_pad, fill, dtype)
-                    out[:len(a)] = a
-                    return out
-                def padbuf(a):
-                    # Payload bytes pad pow2: decode compute is
-                    # per-POINT, byte padding costs only upload, and
-                    # one compile class per octave keeps shifted
-                    # windows from recompiling on byte-length wobble.
-                    n = max(len(a), 1)
-                    p = 1 << (n - 1).bit_length()
-                    out = np.zeros(p, np.uint8)
-                    out[:len(a)] = a
-                    return out
-                # With a mesh configured the fused stage runs through
-                # the plane's pjit-preferred leg: the point stream
-                # (whole compressed blocks) shards over the mesh,
-                # payloads and the [S, B] outputs replicate
-                # (compress/kernels.py FUSED_STAGE_PLAN). Shapes that
-                # don't divide the mesh run the single-device compile
-                # — counted (mesh-indivisible) but still served fused,
-                # never a fallback to the scan.
-                mesh_leg = (self.mesh is not None
-                            and P_pad % int(self.mesh.devices.size)
-                            == 0)
-                if self.mesh is not None and not mesh_leg:
-                    _count_decline("mesh-indivisible")
-                if mesh_leg:
-                    fused_fn = _ckernels.fused_block_stage_mesh(
-                        self.mesh, num_series=S_pad,
-                        num_buckets=num_buckets, interval=interval,
-                        agg_down=dsagg, rate=rate_kw["rate"],
-                        counter=rate_kw["counter"],
-                        drop_resets=rate_kw["drop_resets"],
-                        vkind=vkind)
-                    stage = list(fused_fn(
-                        pad(src.ts_nb, np.int32), padbuf(src.ts_pay),
-                        pad(src.v_nb, np.int32), padbuf(src.v_pay),
-                        pad(src.first_idx, np.int32),
-                        pad(src.blk_first, np.int32),
-                        pad(src.rel_base_pt, np.int32),
-                        pad(np.minimum(src.sid_pt, S_pad - 1),
-                            np.int32),
-                        pad(src.valid, bool, False),
-                        lo32, hi32, shift32,
-                        np.float32(rate_kw["counter_max"]),
-                        np.float32(rate_kw["reset_value"]))) + [None]
-                else:
-                    matched = (np.flatnonzero(src.valid)
-                               if selector is not None else None)
-                    if matched is not None \
-                            and len(matched) < src.npoints:
-                        # Selective filter: decode the full streams
-                        # (value chains span whole blocks) but stage
-                        # only the matched points — stage cost scales
-                        # with the match fraction. Padding sel
-                        # entries re-read point 0 under valid=False.
-                        M_pad = _pad_fine(max(len(matched), 1))
-                        def padm(a, dtype, fill=0):
-                            out = np.full(M_pad, fill, dtype)
-                            out[:len(matched)] = a
-                            return out
-                        stage = list(_ckernels.fused_block_stage_sel(
-                            pad(src.ts_nb, np.int32),
-                            padbuf(src.ts_pay),
-                            pad(src.v_nb, np.int32),
-                            padbuf(src.v_pay),
-                            pad(src.first_idx, np.int32),
-                            pad(src.blk_first, np.int32),
-                            padm(matched, np.int32),
-                            padm(src.rel_base_pt[matched], np.int32),
-                            padm(np.minimum(src.sid_pt[matched],
-                                            S_pad - 1), np.int32),
-                            padm(np.ones(len(matched), bool), bool,
-                                 False),
-                            lo32, hi32, shift32,
-                            num_series=S_pad, num_buckets=num_buckets,
-                            interval=interval, agg_down=dsagg,
-                            vkind=vkind, **rate_kw)) + [None]
-                    else:
-                        stage = list(_ckernels.fused_block_stage(
-                            pad(src.ts_nb, np.int32),
-                            padbuf(src.ts_pay),
-                            pad(src.v_nb, np.int32),
-                            padbuf(src.v_pay),
-                            pad(src.first_idx, np.int32),
-                            pad(src.blk_first, np.int32),
-                            pad(src.rel_base_pt, np.int32),
-                            pad(np.minimum(src.sid_pt, S_pad - 1),
-                                np.int32),
-                            pad(src.valid, bool, False),
-                            lo32, hi32, shift32,
-                            num_series=S_pad, num_buckets=num_buckets,
-                            interval=interval, agg_down=dsagg,
-                            vkind=vkind, **rate_kw)) + [None]
-            # Key the entry on the SNAPSHOT the stage was actually
-            # computed from (src.spans — not a fresh encoded_range,
-            # which a checkpoint racing this query could have moved
-            # past the gathered data). The held objects both pin
-            # against id reuse and make hit-validation pure identity.
-            self._fused_stage_cache.put(
-                skey_cache,
-                (tuple(g for g, _, _ in src.spans),
-                 src_keys, epoch, stage, groups))
-        sv, sm, filled, in_range, presence_dev = stage[:5]
         gkeys = sorted(groups)
         G = _pad_size(len(gkeys))
         ngroups = 1 if len(gkeys) == 1 else G
-        include = np.zeros(S_pad, bool)
-        gmap = np.full(S_pad, G - 1, np.int32)
-        for gi, gkey in enumerate(gkeys):
-            for sid in groups[gkey]:
-                include[sid] = True
-                gmap[sid] = gi
         b_live = int((end - qbase) // interval + 1)
         g_out = min(ngroups, _pad64(len(gkeys)))
         b_out = min(num_buckets, _pad64(b_live))
-        shrink = dict(g_out=g_out, b_out=b_out,
-                      wire_bf16=bool(tsdb.config.wire_bf16))
-        if agg.kind == "percentile":
-            gv, gm = kernels.window_quantile_apply(
-                sm, filled, in_range, include, gmap,
-                np.array([agg.quantile], np.float32),
-                num_groups=ngroups, **shrink)
-        else:
-            gv, gm = kernels.window_moment_apply(
-                sv, sm, filled, in_range, include, gmap,
-                num_groups=ngroups, agg_group=spec.aggregator,
-                **shrink)
-        if stage[5] is None:
-            gv, gm, stage[5] = jax.device_get((gv, gm, presence_dev))
-        else:
-            gv, gm = jax.device_get((gv, gm))
-        has_points = stage[5]
-        gm = np.unpackbits(gm, axis=1, count=b_out).astype(bool)
-        results = []
-        for gi, gkey in enumerate(gkeys):
-            live = [sid for sid in groups[gkey] if has_points[sid]]
-            if not live:
-                continue
-            tags, aggregated = self._group_tags(
-                [named[sid] for sid in live])
-            mask = gm[gi]
-            grid_ts = (np.flatnonzero(mask).astype(np.int64) * interval
-                       + qbase)
-            results.append(QueryResult(
-                spec.metric, tags, aggregated, grid_ts,
-                gv[gi][mask].astype(np.float64)))
+        with obs_trace.span("fused.dispatch") as sp:
+            if src is not None:
+                try:
+                    stage, leg = self._fused_stage(
+                        src, S_pad, num_buckets, interval, dsagg,
+                        rate_kw,
+                        np.int32(min(max(start - epoch, imin), imax)),
+                        np.int32(min(max(end - epoch, imin), imax)),
+                        np.int32(qbase - epoch))
+                except _fused.Decline as d:
+                    _count_decline(d.reason)
+                    return None
+                # Key the entry on the SNAPSHOT the stage was actually
+                # computed from (src.spans — not a fresh encoded_range,
+                # which a checkpoint racing this query could have moved
+                # past the gathered data). The held objects both pin
+                # against id reuse and make hit-validation pure identity.
+                self._fused_stage_cache.put(
+                    skey_cache,
+                    (tuple(g for g, _, _ in src.spans),
+                     src_keys, epoch, stage, groups))
+            else:
+                leg = "cached"
+            sv, sm, filled, in_range, presence_dev = stage[:5]
+            include = np.zeros(S_pad, bool)
+            gmap = np.full(S_pad, G - 1, np.int32)
+            for gi, gkey in enumerate(gkeys):
+                sids = groups[gkey]
+                include[sids] = True
+                gmap[sids] = gi
+            shrink = dict(g_out=g_out, b_out=b_out,
+                          wire_bf16=bool(tsdb.config.wire_bf16))
+            if agg.kind == "percentile":
+                gv, gm = kernels.window_quantile_apply(
+                    sm, filled, in_range, include, gmap,
+                    np.array([agg.quantile], np.float32),
+                    num_groups=ngroups, **shrink)
+            else:
+                gv, gm = kernels.window_moment_apply(
+                    sv, sm, filled, in_range, include, gmap,
+                    num_groups=ngroups, agg_group=spec.aggregator,
+                    **shrink)
+            if sp is not None:
+                sp.tags["leg"] = leg
+        if obs_trace.current_span() is not None:
+            # Traced requests only, as aggregate.wait: untraced the
+            # fetch below blocks as it always did.
+            with obs_trace.span("fused.wait"):
+                jax.block_until_ready((gv, gm))
+        with obs_trace.span("fused.fetch") as sp:
+            if stage[5] is None:
+                gv, gm, stage[5] = jax.device_get((gv, gm, presence_dev))
+            else:
+                gv, gm = jax.device_get((gv, gm))
+            if sp is not None:
+                sp.tags["bytes"] = int(gv.nbytes + gm.nbytes)
+        with obs_trace.span("fused.results") as sp:
+            has_points = stage[5]
+            gm = np.unpackbits(gm, axis=1, count=b_out).astype(bool)
+            named = self._fused_named
+            if len(named) > 1 << 20:
+                named.clear()
+            results = []
+            for gi, gkey in enumerate(gkeys):
+                live = [src_keys[sid] for sid in groups[gkey]
+                        if has_points[sid]]
+                if not live:
+                    continue
+                members = []
+                for sk in live:
+                    tags = named.get(sk)
+                    if tags is None:
+                        tags = named[sk] = self._named_tags(sk)
+                    members.append(tags)
+                tags, aggregated = self._group_tags(members)
+                mask = gm[gi]
+                grid_ts = (np.flatnonzero(mask).astype(np.int64) * interval
+                           + qbase)
+                results.append(QueryResult(
+                    spec.metric, tags, aggregated, grid_ts,
+                    gv[gi][mask].astype(np.float64)))
+            if sp is not None:
+                sp.tags["results"] = len(results)
         return results
+
+    def _fused_stage(self, src, S_pad, num_buckets, interval, dsagg,
+                     rate_kw, lo32, hi32, shift32):
+        """Dispatch the window stage of one gather; returns (the stage
+        contract as a list with a slot for the fetched presence, the
+        leg that ran). On one device the gather's blocks are decoded
+        into the block cache's slabs (misses only) and the stage reads
+        them: per matched point where the selector keeps under half of
+        the points of the blocks it touches (``sel``), else per whole
+        block (``rows``); without the cache, or for a gather its slabs
+        cannot hold, the plan declines (``cache-off``, ``oversize``)
+        and the raw plan serves. Across a mesh the byte-stream leg
+        decodes and stages in one program (``mesh``)."""
+        from opentsdb_tpu.compress import fused as _fused
+        from opentsdb_tpu.compress import kernels as _ckernels
+        statics = dict(
+            num_series=S_pad, num_buckets=num_buckets,
+            interval=interval, agg_down=dsagg, rate=rate_kw["rate"],
+            counter=rate_kw["counter"],
+            drop_resets=rate_kw["drop_resets"])
+        scalars = (lo32, hi32, shift32,
+                   np.float32(rate_kw["counter_max"]),
+                   np.float32(rate_kw["reset_value"]))
+        if self.mesh is None:
+            dc = self._devcache
+            if dc is None:
+                raise _fused.Decline("cache-off")
+            selective = 2 * src.matched <= src.npoints
+
+            def run(qd, vals, slots):
+                inputs = (dc.point_inputs if selective
+                          else dc.record_inputs)(src, slots, S_pad)
+                return (_ckernels.slab_stage_sel if selective
+                        else _ckernels.slab_stage_rows)(
+                    qd, vals, *inputs, *scalars, **statics)
+
+            out = dc.stage(src, run)
+            if out is None:
+                raise _fused.Decline("oversize")
+            return list(out) + [None], "sel" if selective else "rows"
+        # The plane's pjit-preferred leg: the point stream (whole
+        # compressed blocks) shards over the mesh, payloads and the
+        # [S, B] outputs replicate (compress/kernels.py
+        # FUSED_STAGE_PLAN). Shapes that don't divide the mesh run the
+        # single-device compile — counted (mesh-indivisible) but still
+        # served fused, never a fallback to the scan.
+        ps = src.point_stream()
+        npoints = len(ps.valid)
+        _C_FUSED_UPLOADED.inc(
+            14 * npoints + len(ps.ts_pay) + len(ps.v_pay))
+        P_pad = _pad_fine(npoints)
+
+        def pad(a, dtype, fill=0):
+            out = np.full(P_pad, fill, dtype)
+            out[:len(a)] = a
+            return out
+
+        def padbuf(a):
+            # Payload bytes pad pow2: decode compute is per-POINT,
+            # byte padding costs only upload, and one compile class
+            # per octave keeps shifted windows from recompiling on
+            # byte-length wobble.
+            n = max(len(a), 1)
+            out = np.zeros(1 << (n - 1).bit_length(), np.uint8)
+            out[:len(a)] = a
+            return out
+
+        args = (pad(ps.ts_nb, np.int32), padbuf(ps.ts_pay),
+                pad(ps.v_nb, np.int32), padbuf(ps.v_pay),
+                pad(ps.first_idx, np.int32),
+                pad(ps.blk_first, np.int32),
+                pad(ps.rel_base_pt, np.int32),
+                pad(np.minimum(ps.sid_pt, S_pad - 1), np.int32),
+                pad(ps.valid, bool, False))
+        if P_pad % int(self.mesh.devices.size) == 0:
+            fused_fn = _ckernels.fused_block_stage_mesh(
+                self.mesh, vkind=src.kind, **statics)
+            return list(fused_fn(*args, *scalars)) + [None], "mesh"
+        _count_decline("mesh-indivisible")
+        out = _ckernels.fused_block_stage(
+            *args, *scalars[:3], **statics, vkind=src.kind,
+            counter_max=rate_kw["counter_max"],
+            reset_value=rate_kw["reset_value"])
+        return list(out) + [None], "bytes"
 
     # -- CPU oracle backend -------------------------------------------
 

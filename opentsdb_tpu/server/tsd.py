@@ -1233,7 +1233,8 @@ class TSDServer:
                 fused["declines"][reason] = \
                     fused["declines"].get(reason, 0) + obj.value
             elif name.startswith("compress.devcache."):
-                fused["devcache"][name.rsplit(".", 1)[1]] = obj.value
+                fused["devcache"][name.rsplit(".", 1)[1]] = (
+                    obj.read() if kind == "gauge" else obj.value)
         fused["coverage"] = (fused["served"] / fused["attempt"]
                              if fused["attempt"] else 0.0)
         # The ingest fast path (wire decode + WAL group commit):

@@ -386,6 +386,30 @@ class DeviceWindow:
         self.append_many(metric_uid, (series_key,), None, timestamps,
                          values)
 
+    def append_rows(self, metric_uid: bytes, series_keys, counts,
+                    timestamps: np.ndarray, values: np.ndarray) -> None:
+        """A run of whole rows of distinct series of a metric, row
+        ``i`` the next ``counts[i]`` points (the boot's refill from
+        columnar blocks), recorded as ``append`` would record them a
+        row at a time: a chunk is cut after the row that fills the
+        staging batch, wherever in the run that row lies, so the
+        window's chunks do not depend on how the store framed its
+        rows. One ``append_many`` a chunk the run reaches into."""
+        ends = np.cumsum(counts)
+        i, n = 0, len(ends)
+        while i < n:
+            with self._lock:
+                mw = self._metrics.get(metric_uid)
+                room = self.staging_points - (mw.staged_n if mw else 0)
+            a = int(ends[i - 1]) if i else 0
+            k = min(int(np.searchsorted(ends, a + room, "left")), n - 1)
+            z = int(ends[k])
+            self.append_many(
+                metric_uid, series_keys[i:k + 1],
+                np.repeat(np.arange(k + 1 - i), counts[i:k + 1]),
+                timestamps[a:z], values[a:z])
+            i = k + 1
+
     def append_many(self, metric_uid: bytes, series_keys,
                     series_of_point: np.ndarray | None,
                     timestamps: np.ndarray, values: np.ndarray) -> None:

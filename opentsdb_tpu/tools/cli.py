@@ -108,7 +108,8 @@ def _open_list() -> list:
     return lst
 
 
-def _require_window_fits(cfg: Config) -> None:
+def _require_window_fits(cfg: Config,
+                         block_points: int | None = None) -> None:
     """Refuse to boot with a device-window budget the serving device
     cannot hold beside one query's stage at the largest grid the
     resident plan serves (storage/devstore.py ``require_fits``): found
@@ -116,7 +117,10 @@ def _require_window_fits(cfg: Config) -> None:
     failing in the middle of the refill. A sharded window
     (--devwindow-shards) is checked by the share of the budget its
     fullest device holds. A device that states no limit (a CPU) is not
-    checked."""
+    checked. On a device that states one, the block cache of the fused
+    plan is bounded here too (``Config.devblock_points``): by half of
+    what is left beside the window and a query's stage, or by the
+    argv's ``block_points`` where that is less."""
     import jax
 
     from opentsdb_tpu.ops import kernels
@@ -136,6 +140,23 @@ def _require_window_fits(cfg: Config) -> None:
             mem and mem["bytes_limit"])
     except ValueError as e:
         raise SystemExit(f"tsd: --device-window-points: {e}") from None
+    if not mem or cfg.devblock_points <= 0:
+        return
+    # The device block cache (compress/devcache.py) may grow to half of
+    # what the device has left, the other half staying free for the
+    # stages' own arrays; --device-block-points can only lower that.
+    # What it allocates is what the store's blocks need, up to this.
+    from opentsdb_tpu.compress import devcache
+    left = (mem["bytes_limit"]
+            - devstore.window_bytes(points, cfg.device_window_staging)
+            - kernels.stage_accumulator_bytes())
+    most = max(left // 2 // devcache.POINT_BYTES, 1)
+    cfg.devblock_points = most if block_points is None \
+        else min(block_points, most)
+    LOG.info("device block cache: up to %d points (%d bytes of the "
+             "device's %d)", cfg.devblock_points,
+             cfg.devblock_points * devcache.POINT_BYTES,
+             mem["bytes_limit"])
 
 
 def make_tsdb(args, start_thread: bool = False) -> TSDB:
@@ -218,6 +239,11 @@ def make_tsdb(args, start_thread: bool = False) -> TSDB:
             # 1 << 20), and never a larger upload than the default's.
             cfg.device_window_staging = min(cfg.device_window_staging,
                                             max(budget // 64, 1024))
+        blocks = getattr(args, "device_block_points", None)
+        if blocks is not None and blocks < 0:
+            raise SystemExit("--device-block-points must not be negative")
+        if blocks is not None:
+            cfg.devblock_points = blocks
         cfg.rollup_device_fold = getattr(args, "rollup_device_fold",
                                          False)
         if cfg.mesh_plane:
@@ -242,7 +268,7 @@ def make_tsdb(args, start_thread: bool = False) -> TSDB:
         jaxenv.require_serving_device(cfg.backend)
         if cfg.backend != "cpu" and not getattr(args, "read_only", False):
             # (A read-only daemon keeps no device window: core/tsdb.py.)
-            _require_window_fits(cfg)
+            _require_window_fits(cfg, blocks)
         cfg.slow_query_ms = getattr(args, "slow_query_ms", 0.0)
         cfg.selfmon_interval_s = getattr(args, "selfmon_interval", 0.0)
         cfg.trace_sample_n = getattr(args, "trace_sample_n", 0)
@@ -1029,6 +1055,21 @@ def main(argv: list[str] | None = None) -> int:
                         "(/api/mesh/reshard). 0 = one resident window "
                         "(defaulted to the local device count under "
                         "--mesh-plane)")
+    p.add_argument("--device-block-points", type=int, default=None,
+                   metavar="N",
+                   help="budget of the device block cache of the fused "
+                        "plan (compress/devcache.py), in decoded points "
+                        "(8 B a point of HBM): what a deployment whose "
+                        "history is stored in TSST4 blocks keeps decoded "
+                        "on the device beside its window; past it the "
+                        "least recently used blocks make room and are "
+                        "decoded again when asked for. The daemon holds "
+                        "it to half of what the device has left, which "
+                        "is also what it is unstated (8,388,608 points "
+                        "where the device states no memory); 0 turns "
+                        "the cache, and on one device the fused plan, "
+                        "off. /stats has what the cache holds as "
+                        "tsd.compress.devcache.bytes")
     p.add_argument("--device-window-points", type=int, default=0,
                    metavar="N",
                    help="budget of the device-resident hot window, in "

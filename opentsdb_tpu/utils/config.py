@@ -59,14 +59,18 @@ class Config:
     # exact (the path declines rather than approximates); off forces
     # the classic decode-then-reduce scan.
     sstable_fused_agg: bool = True
-    # Device-side block cache (compress/devcache.py): total decoded
-    # POINTS kept resident on device (~12 bytes/point across the
-    # qualifier-delta/value/record columns). Warm fused queries then
-    # upload only per-record arrays instead of re-uploading and
-    # re-decoding payload byte streams. Sized so a dashboard's whole
-    # battery of rows over one window shares a single resident decode
-    # alongside a second window's entry (~100 MB at the default).
-    # 0 disables the cache.
+    # Device-side block cache (compress/devcache.py): the most decoded
+    # POINTS it may keep resident on device (8 bytes a point: the
+    # qualifier-delta and value columns), a block a row of its
+    # slabs. Warm fused queries then upload per-record arrays, or their
+    # matched points alone, instead of re-uploading and re-decoding
+    # payload byte streams. ~100 MB at the default, which a CPU backend
+    # keeps; a daemon takes --device-block-points and, on a device that
+    # states its memory, holds this at boot to half of what the device
+    # has left beside its window, which is also what it is unstated
+    # (tools/cli.py). What is allocated is what the store's blocks
+    # need, up to this; past it the least recently used blocks make
+    # room. 0 disables the cache, and on one device the fused plan.
     devblock_points: int = 1 << 23
 
     # core behavior (names mirror the reference's system properties)

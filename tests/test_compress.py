@@ -823,6 +823,40 @@ class TestFusedDeclineCounters:
             t4.shutdown()
             t0.shutdown()
 
+    @pytest.mark.parametrize("case", ["mixed-row", "wide-int"])
+    def test_block_ineligible_decline(self, tmp_path, case):
+        """A block the kernels cannot consume declines the whole gather
+        'block-ineligible', and the scan serves: a row that holds
+        integers and floats (its block falls to zlib, so it has no
+        columns to decode), or integers whose deltas take more than
+        the four payload bytes a point the device indexes."""
+        t4 = _mk_tpu_tsdb(tmp_path, "bi4" + case, "tsst4")
+        t0 = _mk_tpu_tsdb(tmp_path, "bi0" + case, "none")
+        try:
+            for t in (t4, t0):
+                _int_batch(t, "m.d", "a", BASE, 6 * 3600, 300, 5)
+                ts = BASE + np.arange(0, 6 * 3600, 300, dtype=np.int64)
+                if case == "mixed-row":
+                    t.add_batch("m.d", ts + 7, np.random.default_rng(
+                        1).normal(size=len(ts)), {"host": "a"})
+                else:
+                    t.add_batch("m.d", ts, 2**40 + np.arange(len(ts)),
+                                {"host": "b"})
+                t.checkpoint()
+            from opentsdb_tpu.compress.codecs import TSINT, ZLIB
+            sst = t4.store._ssts[-1]
+            tags = {sst.block_header(j)[0]
+                    for j in range(sst.block_count)}
+            assert (ZLIB if case == "mixed-row" else TSINT) in tags
+            before = _decline_count("block-ineligible")
+            plan4 = _pair_answers(t4, t0, self.SPEC,
+                                  BASE + 100, BASE + 5 * 3600)
+            assert plan4 == "raw"
+            assert _decline_count("block-ineligible") == before + 1
+        finally:
+            t4.shutdown()
+            t0.shutdown()
+
     def test_mesh_indivisible_counted_still_serves(self, tmp_path):
         """A mesh whose device count does not divide the padded point
         grid declines the SHARDED leg (counted) but still serves the
@@ -906,11 +940,11 @@ class TestDeviceBlockCache:
 
     def test_selector_compaction_bit_identical(self, tmp_path):
         """A literal tag filter that drops most records runs the
-        compacted (sel-gather) stage: decode the full stream, gather
-        only matching points, stage cost proportional to the match.
-        Answers must stay bit-identical to the codec=none scan on BOTH
-        legs — the device cache's devcache_window_stage_sel and the
-        byte path's fused_block_stage_sel."""
+        selective stage (slab_stage_sel): the blocks decoded whole into
+        the cache, only the matching points gathered, stage cost
+        proportional to the match. Answers stay bit-identical to the
+        codec=none scan; with the cache off the plan declines
+        (``cache-off``) and the raw plan gives the same answers."""
         t4 = _mk_tpu_tsdb(tmp_path, "sc4", "tsst4")
         t0 = _mk_tpu_tsdb(tmp_path, "sc0", "none")
         try:
@@ -932,14 +966,16 @@ class TestDeviceBlockCache:
                 # Group-by over a selective subset.
                 QuerySpec("m.d", {"host": "h2", "dc": "*"}, "max",
                           downsample=(7200, "max"))]
-            for legs in ("devcache", "bytes"):
-                ex4._devcache = ex4._devcache if legs == "devcache" \
-                    else None
+            for cache, plan in (("on", "fused"), ("off", "raw")):
+                if cache == "off":
+                    ex4._devcache = None
                 ex4._frag_cache.clear()
+                ex4._fused_stage_cache.clear()
+                declined = _decline_count("cache-off")
                 for spec in specs:
                     r4, plan4, _ = ex4.run_with_plan(
                         spec, BASE + 100, BASE + 20 * 3600)
-                    assert plan4 == "fused", (legs, spec.tags)
+                    assert plan4 == plan, (cache, spec.tags)
                     r0, plan0, _ = ex0.run_with_plan(
                         spec, BASE + 100, BASE + 20 * 3600)
                     assert plan0 == "raw"
@@ -949,6 +985,8 @@ class TestDeviceBlockCache:
                         assert np.array_equal(a.timestamps,
                                               b.timestamps)
                         assert np.array_equal(a.values, b.values)
+                assert _decline_count("cache-off") - declined == (
+                    len(specs) if cache == "off" else 0)
         finally:
             t4.shutdown()
             t0.shutdown()

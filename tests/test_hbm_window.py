@@ -416,6 +416,47 @@ class TestBootCheck:
         finally:
             tsdb.shutdown()
 
+    @pytest.mark.parametrize("stated", [None, 1 << 26, 1 << 40, 0])
+    def test_the_block_cache_is_held_to_what_the_device_has_left(
+            self, tmp_path, monkeypatch, stated):
+        """The fused plan's block cache beside the window (8 B a
+        decoded point): at most half of what the device has left
+        beside the default window and a query's stage, which is what
+        it gets unstated; the argv can only lower that; 0 turns it
+        off."""
+        from opentsdb_tpu.compress import devcache
+        v5e = 16_909_336_576
+        left = v5e - devstore.window_bytes(1 << 26, 1 << 20) - self.STAGE
+        most = left // 2 // devcache.POINT_BYTES
+        assert 1 << 26 < most < 1 << 40
+        flags = () if stated is None else (
+            "--device-block-points", str(stated))
+        tsdb = self.boot(tmp_path, monkeypatch, v5e, *flags)
+        try:
+            assert tsdb.config.devblock_points == (
+                most if stated is None else min(stated, most))
+        finally:
+            tsdb.shutdown()
+
+    def test_a_block_cache_is_not_held_where_no_limit_is_stated(
+            self, tmp_path, monkeypatch):
+        from opentsdb_tpu.utils.config import Config
+        tsdb = self.boot(tmp_path, monkeypatch, None)
+        try:
+            assert tsdb.config.devblock_points \
+                == Config().devblock_points == 1 << 23
+        finally:
+            tsdb.shutdown()
+        tsdb = self.boot(tmp_path, monkeypatch, None,
+                         "--device-block-points", str(1 << 40))
+        try:
+            assert tsdb.config.devblock_points == 1 << 40
+        finally:
+            tsdb.shutdown()
+        with pytest.raises(SystemExit):
+            self.boot(tmp_path, monkeypatch, None,
+                      "--device-block-points", "-1")
+
     def test_a_sharded_window_is_checked_by_its_fullest_device(
             self, tmp_path, monkeypatch):
         # Eight virtual devices: 1 << 30 points over 8 shards is 1 << 27

@@ -83,3 +83,62 @@ def test_a_turn_of_the_run_reduction_stays_in_its_fusions(one_chip, agg,
     # One program, its trip counts data: loops, and no branch.
     assert " while(" in text and " conditional(" not in text
     assert 'op_name="jit(_chunk_fold)/window.chunk_fold/' in text
+
+
+# The device block cache's programs (compress/kernels.py) at the shapes
+# tsbs-cpu-13h-tsst4 runs them: of its 4,421 blocks of 42,480 points
+# the 1,560 its budget of 1 << 26 points has rows of 43,008 for, 118
+# records a block in 128, a fill of 8 blocks.
+ROWS, P_BLK, R_BLK, FILL = (1 << 26) // 43008, 43008, 128, 8
+SLAB_BYTES = ROWS * P_BLK * 4
+
+
+def slab_shapes(one_chip):
+    def of(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    scalars = [of((), jnp.int32)] * 3 + [of((), jnp.float32)] * 2
+    return of, [of((ROWS, P_BLK), dt)
+                for dt in (jnp.int32, jnp.float32)], scalars
+
+
+@pytest.mark.parametrize("vkind", ["f32", "int"])
+def test_a_fill_decodes_into_the_slabs_in_place(one_chip, vkind):
+    from opentsdb_tpu.compress import kernels as ckernels
+    of, slabs, _ = slab_shapes(one_chip)
+    compiled = ckernels.slab_fill.lower(
+        *slabs, of((FILL,), jnp.int32),
+        of((FILL, P_BLK // 2), jnp.uint8), of((FILL, P_BLK * 4), jnp.uint8),
+        of((FILL, P_BLK // 2), jnp.uint8), of((FILL, P_BLK * 4), jnp.uint8),
+        of((FILL, R_BLK), jnp.int32), vkind=vkind).compile()
+    # The two slabs are donated and come back as the outputs: no
+    # second copy of them, and a fill's own arrays are a few blocks'.
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * SLAB_BYTES
+    assert mem.temp_size_in_bytes < SLAB_BYTES // 8
+
+
+def test_the_selective_stage_reads_the_slabs_where_they_lie(one_chip):
+    """A one-host request stages 8,192 points: it gathers them out of
+    the slabs by row and column and copies no slab (flattened, the
+    compiler relays one out, 870 MB a sub-query)."""
+    from opentsdb_tpu.compress import kernels as ckernels
+    of, slabs, scalars = slab_shapes(one_chip)
+    m = 8192
+    compiled = ckernels.slab_stage_sel.lower(
+        *slabs, *[of((m,), jnp.int32)] * 4,
+        of((m,), jnp.bool_), *scalars, num_series=16, num_buckets=256,
+        interval=300, agg_down="max").compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 24
+
+
+def test_the_dense_stage_compiles_at_the_fleet_wide_gather(one_chip):
+    """Every host of a metric over 13 row-hours: 425 blocks in 512
+    rows, 22M points into a 4,096 x 16 grid beside the slabs."""
+    from opentsdb_tpu.compress import kernels as ckernels
+    of, slabs, scalars = slab_shapes(one_chip)
+    k = 512
+    compiled = ckernels.slab_stage_rows.lower(
+        *slabs, of((k,), jnp.int32), *[of((k, R_BLK), jnp.int32)] * 3,
+        of((k, R_BLK), jnp.bool_), *scalars, num_series=SERIES,
+        num_buckets=16, interval=3600, agg_down="avg").compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30

@@ -524,14 +524,15 @@ def test_compress_kernels_chip_parity(tmp_path, ints):
         src = fused.gather(t.store, t.table, t.metrics.get_id("m.c4"),
                            BT, BT + 24 * 3600)
         assert src.kind == ("int" if ints else "f32")
+        ps = src.point_stream()
         rel, vals = ckernels.decode_points_jit(
-            src.ts_nb.astype(np.int32), src.ts_pay,
-            src.v_nb.astype(np.int32), src.v_pay,
-            src.first_idx, src.blk_first,
-            src.rel_base_pt.astype(np.int32), vkind=src.kind)
+            ps.ts_nb.astype(np.int32), ps.ts_pay,
+            ps.v_nb.astype(np.int32), ps.v_pay,
+            ps.first_idx, ps.blk_first,
+            ps.rel_base_pt.astype(np.int32), vkind=src.kind)
         assert next(iter(vals.devices())).platform == "tpu"
-        ok = np.asarray(src.valid)
-        got_sid = np.asarray(src.sid_pt)[ok]
+        ok = np.asarray(ps.valid)
+        got_sid = np.asarray(ps.sid_pt)[ok]
         got_ts = np.asarray(rel)[ok].astype(np.int64) + src.epoch
         got_v = np.asarray(vals)[ok]
         sid_of = {k: i for i, k in enumerate(src.series_keys)}
