@@ -153,6 +153,15 @@ _C_RAW_POINTS = _metrics.counter("query.raw.points")
 # rollup planner's per-bucket records, an expert batch's groups).
 _C_PACK_FLAT = _metrics.counter("query.pack.flat_points")
 _C_PACK_SPANS = _metrics.counter("query.pack.span_points")
+# The groups a grid plan (resident, fused) answered, by where their
+# labels came from: kept = taken from the plan that made the groups
+# (_GridGroups.labels), computed = worked out on the request (the
+# first answer of a plan builds its labels, and a group with a member
+# that has no point in range is always labelled anew, over its live
+# members). kept / (kept + computed) says how often the plans are
+# still held when their groups are asked for again.
+_C_LABELS_KEPT = _metrics.counter("query.results.labels.kept")
+_C_LABELS_COMPUTED = _metrics.counter("query.results.labels.computed")
 
 
 def _count_decline(reason: str) -> None:
@@ -439,6 +448,11 @@ class QueryExecutor:
         # series' tags by name.
         self._fused_sel_memo = LRUCache(64)
         self._fused_named: dict[bytes, dict[str, str]] = {}
+        # A gather's groups as its answer takes them (_GridGroups, the
+        # labels kept), by what the groups are a function of: (metric,
+        # filter) as the key, the gather's series directory in the
+        # value, as the generation is in _dw_plan_cache's.
+        self._fused_plan_cache = LRUCache(64)
         # Device-side decoded-block cache (compress/devcache.py):
         # per-block query-independent columns stay resident on device,
         # bounded by total cached points. Keyed by SSTable OBJECT +
@@ -1198,7 +1212,7 @@ class QueryExecutor:
                 sp.tags["miss"] = dw.last_miss()
             return None
         with obs_trace.span("resident.groups") as gsp:
-            groups, named, plan_hit = self._devwindow_groups(
+            groups, named, grid, plan_hit = self._devwindow_groups(
                 dw, metric_uid, cols, exact, group_bys)
             if not groups:
                 return []
@@ -1225,7 +1239,7 @@ class QueryExecutor:
                 # wrap. Scan path handles it (per-group kernels, smaller
                 # grids).
                 return None
-            gkeys = sorted(groups)
+            gkeys = grid.gkeys
             G = _pad_size(len(gkeys))
             # Device-resident include/gmap, cached per (window instance,
             # plan, generation, padding): every fresh host array argument
@@ -1420,21 +1434,9 @@ class QueryExecutor:
                 return None
             raise
         with obs_trace.span("resident.results") as sp:
-            has_points = stage[5]
-            gm = np.unpackbits(gm, axis=1, count=b_out).astype(bool)
-            results = []
-            for gi, gkey in enumerate(gkeys):
-                live = [sid for sid in groups[gkey] if has_points[sid]]
-                if not live:
-                    continue
-                tags, aggregated = self._group_tags(
-                    [named[sid] for sid in live])
-                mask = gm[gi]
-                grid_ts = (np.flatnonzero(mask).astype(np.int64) * interval
-                           + qbase)
-                results.append(QueryResult(
-                    spec.metric, tags, aggregated, grid_ts,
-                    gv[gi][mask].astype(np.float64)))
+            results = _grid_results(spec.metric, grid, named.__getitem__,
+                                    stage[5], gv, gm, b_out, interval,
+                                    qbase)
             if sp is not None:
                 sp.tags["results"] = len(results)
         return results
@@ -1508,9 +1510,11 @@ class QueryExecutor:
                           group_bys):
         """Filter + group the window's series directory on host UIDs.
 
-        Returns ({group_key_tuple: [sid]}, {sid: named_tags}, whether
-        the plan cache held them); cached per (window instance, metric,
-        filter) until the directory grows.
+        Returns ({group_key_tuple: [sid]}, {sid: named_tags}, the
+        groups as the answer takes them (_GridGroups, their labels kept
+        from the first answer on), whether the plan cache held them);
+        cached per (window instance, metric, filter) until the
+        directory grows.
         ``dw`` is the SAME window object ``cols`` came from (passed by
         the caller, not re-read from self.tsdb — a swap between capture
         and here must not cache the old window's plan under the new
@@ -1520,11 +1524,12 @@ class QueryExecutor:
         cache = self._dw_plan_cache
         hit = cache.get(fkey)
         if hit is not None and hit[0] == cols.generation:
-            return hit[1], hit[2], True
+            return hit[1], hit[2], hit[3], True
         groups, named = self._series_groups(cols.series_keys, exact,
                                             group_bys)
-        cache.put(fkey, (cols.generation, groups, named))
-        return groups, named, False
+        grid = _GridGroups(groups)
+        cache.put(fkey, (cols.generation, groups, named, grid))
+        return groups, named, grid, False
 
     # -- fused decode-aggregate path (TSST4 blocks) --------------------
 
@@ -1710,7 +1715,14 @@ class QueryExecutor:
         if S_pad * num_buckets >= 2**31:
             _count_decline("grid-too-large")
             return None
-        gkeys = sorted(groups)
+        grid = self._fused_plan_cache.get((metric_uid, fk))
+        if grid is None or grid.series_keys != src_keys:
+            # New to this executor, or the store has gained or lost a
+            # series of the range since: the groups' sids are positions
+            # in the gather's directory, so the kept labels go with it.
+            grid = _GridGroups(groups, src_keys)
+            self._fused_plan_cache.put((metric_uid, fk), grid)
+        gkeys = grid.gkeys
         G = _pad_size(len(gkeys))
         ngroups = 1 if len(gkeys) == 1 else G
         b_live = int((end - qbase) // interval + 1)
@@ -1773,30 +1785,19 @@ class QueryExecutor:
             if sp is not None:
                 sp.tags["bytes"] = int(gv.nbytes + gm.nbytes)
         with obs_trace.span("fused.results") as sp:
-            has_points = stage[5]
-            gm = np.unpackbits(gm, axis=1, count=b_out).astype(bool)
             named = self._fused_named
             if len(named) > 1 << 20:
                 named.clear()
-            results = []
-            for gi, gkey in enumerate(gkeys):
-                live = [src_keys[sid] for sid in groups[gkey]
-                        if has_points[sid]]
-                if not live:
-                    continue
-                members = []
-                for sk in live:
-                    tags = named.get(sk)
-                    if tags is None:
-                        tags = named[sk] = self._named_tags(sk)
-                    members.append(tags)
-                tags, aggregated = self._group_tags(members)
-                mask = gm[gi]
-                grid_ts = (np.flatnonzero(mask).astype(np.int64) * interval
-                           + qbase)
-                results.append(QueryResult(
-                    spec.metric, tags, aggregated, grid_ts,
-                    gv[gi][mask].astype(np.float64)))
+
+            def tags_of(sid: int) -> dict[str, str]:
+                sk = src_keys[sid]
+                tags = named.get(sk)
+                if tags is None:
+                    tags = named[sk] = self._named_tags(sk)
+                return tags
+
+            results = _grid_results(spec.metric, grid, tags_of, stage[5],
+                                    gv, gm, b_out, interval, qbase)
             if sp is not None:
                 sp.tags["results"] = len(results)
         return results
@@ -2629,6 +2630,106 @@ def _dw_fold_extent(cols) -> tuple[int, ...]:
     parts = [cols] if shards is None else list(filter(None, shards))
     sums = tuple(map(sum, zip(*(p.fold_extent() for p in parts))))
     return sums + (sums[5] + 2 * len(parts),)
+
+
+class _GridGroups:
+    """The groups of a grid plan (resident, fused) as its answer takes
+    them, kept with the plan that made the groups: the sorted group
+    keys (row ``i`` of the fetched grids is ``gkeys[i]``), the groups'
+    members as one flat array of series ids with the groups' offsets
+    into it and their sizes, and, from the first answer on, one ``(tags, aggregated)``
+    a group over its whole membership (``_grid_results`` builds them).
+    ``series_keys`` is the directory the ids are positions in, where
+    the plan is told by it (fused); the resident plan is told by its
+    window's generation."""
+
+    __slots__ = ("gkeys", "members", "offsets", "sizes", "labels",
+                 "series_keys")
+
+    def __init__(self, groups: dict[tuple, list[int]],
+                 series_keys: list[bytes] | None = None) -> None:
+        self.gkeys = sorted(groups)
+        self.sizes = np.array([len(groups[g]) for g in self.gkeys],
+                              np.intp)
+        self.offsets = np.zeros(len(self.gkeys) + 1, np.intp)
+        np.cumsum(self.sizes, out=self.offsets[1:])
+        self.members = np.fromiter(
+            (sid for g in self.gkeys for sid in groups[g]), np.intp,
+            int(self.offsets[-1]))
+        self.labels: list[tuple[dict[str, str], list[str]]] | None = None
+        self.series_keys = series_keys
+
+
+def _grid_results(metric: str, grid: _GridGroups, tags_of, has_points,
+                  gv, gm, b_out: int, interval: int,
+                  qbase: int) -> list[QueryResult]:
+    """The answer of a grid plan out of its fetched grids, by whole-
+    array operations: a result a group with a live member, in the
+    order of ``grid.gkeys``.
+
+    ``gv`` / ``gm`` are the fetched ``[g_out, b_out]`` values and their
+    bit-packed mask (row ``i``: group ``i``), ``has_points`` the
+    presence of every series id (a bool array), ``tags_of(sid)`` a series' named tags.
+    A series with no point in range must not shape its group's labels
+    nor leave an empty group behind (the scan path never sees it): a
+    group with none alive is dropped, a group with all alive takes the
+    labels kept with the plan, and a group in between is labelled anew
+    over its live members.
+
+    What is handed out is shared and read-only: the tag dicts and the
+    aggregated lists between every answer of the plan (nothing under
+    opentsdb_tpu/ writes to a QueryResult's fields), the timestamps
+    between the results of one answer where their rows' masks are one
+    (hosts that report in step), the values as rows or slices of one
+    float64 array."""
+    members, offsets = grid.members, grid.offsets
+    alive = has_points[members]
+    nlive = np.add.reduceat(alive, offsets[:-1], dtype=np.intp)
+    rows = np.flatnonzero(nlive)
+    if not len(rows):
+        return []
+    whole = (nlive == grid.sizes)[rows]
+
+    def label(gi: int, live_only: bool):
+        sids = members[offsets[gi]:offsets[gi + 1]]
+        if live_only:
+            sids = sids[alive[offsets[gi]:offsets[gi + 1]]]
+        return QueryExecutor._group_tags(
+            [tags_of(sid) for sid in sids.tolist()])
+
+    kept = grid.labels is not None
+    if not kept:
+        # The plan's first answer (two at once build the same twice).
+        grid.labels = [label(gi, False) for gi in range(len(grid.gkeys))]
+    live = rows.tolist()
+    labels = [lab if w else label(gi, True) for gi, w, lab
+              in zip(live, whole.tolist(),
+                     map(grid.labels.__getitem__, live))]
+    n_kept = np.count_nonzero(whole) if kept else 0
+    _C_LABELS_KEPT.inc(n_kept)
+    _C_LABELS_COMPUTED.inc(len(rows) - n_kept)
+    # The live rows: their values, and their masks as [R, b_out] bytes.
+    gv = gv[rows]
+    bits = np.unpackbits(gm[rows], axis=1, count=b_out)
+    if (bits == bits[0]).all():
+        cols = np.flatnonzero(bits[0])
+        ts = cols.astype(np.int64) * interval + qbase
+        ts.flags.writeable = False
+        values = gv[:, cols].astype(np.float64)
+        values.flags.writeable = False
+        stamps = itertools.repeat(ts)
+    else:
+        r, c = np.nonzero(bits)
+        flat_ts = c.astype(np.int64) * interval + qbase
+        flat_ts.flags.writeable = False
+        flat_vals = gv[r, c].astype(np.float64)
+        flat_vals.flags.writeable = False
+        ends = np.cumsum(bits.sum(axis=1, dtype=np.intp)).tolist()
+        cuts = list(zip([0] + ends[:-1], ends))
+        stamps = (flat_ts[lo:hi] for lo, hi in cuts)
+        values = (flat_vals[lo:hi] for lo, hi in cuts)
+    return [QueryResult(metric, tags, aggregated, ts, v)
+            for (tags, aggregated), ts, v in zip(labels, stamps, values)]
 
 
 def _is_device_oom(e: Exception) -> bool:

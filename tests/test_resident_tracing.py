@@ -66,13 +66,16 @@ async def http_get(port, target):
 
 
 def serve(tsdb, *targets):
-    """Start a server on ``tsdb``, GET each target in turn, stop."""
+    """Start a server on ``tsdb``, GET each target in turn (a callable
+    among them is called in its turn, between two requests of the one
+    server), stop."""
     server = TSDServer(tsdb)
 
     async def main():
         await server.start()
         try:
-            return [await http_get(server.port, t) for t in targets]
+            return [t() if callable(t) else await http_get(server.port, t)
+                    for t in targets]
         finally:
             server.selfmon.stop()
             server._pool.shutdown(wait=False)
@@ -97,6 +100,20 @@ def stat(name, **tags):
         if w[0] == "tsd." + name and want <= set(w[3:]):
             return float(w[2])
     raise KeyError(name)
+
+
+def labels_moved():
+    """A callable that gives, each time it is called, how far the
+    counters query.results.labels.(kept, computed) moved since its
+    last call (or since it was made)."""
+    kept = METRICS.counter("query.results.labels.kept")
+    computed = METRICS.counter("query.results.labels.computed")
+    last = [kept.value, computed.value]
+
+    def moved():
+        was, last[:] = list(last), [kept.value, computed.value]
+        return last[0] - was[0], last[1] - was[1]
+    return moved
 
 
 def walk(tree):
@@ -167,6 +184,51 @@ class TestResidentSpans:
         assert ids[2] != ids[0]
         assert ids[3] == "feedbeef"      # a hop keeps the router's id
         assert all("t0" in r["trace"] for r in recs)
+
+
+class TestResultsLabelsKeptWithThePlan:
+    def test_the_second_answer_takes_the_first_ones_labels(self, tmp_path):
+        """The same group-by-host request twice: byte-equal bodies,
+        equal to the raw plan's; the first builds the plan's labels, the
+        second takes them; a series the directory gains (a generation
+        bump) makes the next request build them again."""
+        tsdb = make_tsdb(tmp_path)
+        (tmp_path / "raw").mkdir()
+        plain = make_tsdb(tmp_path / "raw", device_window=False)
+        m = "max:5m-max:res.cpu{host=*}"
+        end = BASE + SPAN - 10
+        moved = labels_moved()
+
+        def grow():
+            tsdb.add_batch("res.cpu", np.array([BASE + 60], np.int64),
+                           np.array([7.0], np.float32), {"host": "new"})
+
+        ask = q(BASE, end, m, trace=False)
+        ((st1, b1), d1, (st2, b2), d2, (st3, b3), d3, _, (st4, b4),
+         d4) = serve(tsdb, ask, moved, ask, moved, q(BASE, end, m), moved,
+                     grow, ask, moved)
+        assert (st1, st2, st3, st4) == (200,) * 4
+        assert b1 == b2
+        assert d1 == (0, HOSTS) and d2 == (HOSTS, 0) and d3 == (HOSTS, 0)
+        assert d4 == (0, HOSTS + 1)
+        out = json.loads(b1)
+        assert len(out) == HOSTS
+        assert all(r["rollup"] == "resident" for r in out)
+        ((st0, b0),) = serve(plain, ask)
+        want = json.loads(b0)
+        assert st0 == 200 and all(r["rollup"] == "raw" for r in want)
+        # But for the plan's name the two bodies are one, byte for byte.
+        assert b1.replace(b'"resident"', b'"raw"') == b0
+        # Traced, the span still says how many results it built.
+        tree = json.loads(b3)[0]["trace"]
+        (res,) = [s for s in walk(tree) if s["name"] == "resident.results"]
+        assert res["tags"]["results"] == HOSTS
+        grown = json.loads(b4)
+        assert len(grown) == HOSTS + 1
+        (new,) = [r for r in grown if r["tags"]["host"] == "new"]
+        assert new["dps"] == {str(BASE): 7.0}
+        rest = [r for r in grown if r["tags"]["host"] != "new"]
+        assert [r["dps"] for r in rest] == [r["dps"] for r in out]
 
 
 class TestUntracedPathUnchanged:

@@ -19,7 +19,8 @@ from opentsdb_tpu.compress import kernels as ckernels
 from opentsdb_tpu.obs.registry import METRICS
 from opentsdb_tpu.ops import kernels
 from opentsdb_tpu.tools import cli
-from tests.test_resident_tracing import serve, stat, walk
+from tests.test_resident_tracing import (labels_moved, serve, stat,
+                                          walk)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 REHEARSAL = os.path.join(ROOT, "benchmarks", "tests", "rehearsal")
@@ -361,3 +362,52 @@ def test_a_second_round_of_drawn_ranges_compiles_nothing(
     # New hosts, new ranges, blocks decoded anew: no new program.
     assert programs() == compiled
     assert stat("compress.devcache.miss") > misses
+
+
+def test_the_second_fused_answer_takes_the_first_ones_labels(daemons):
+    """The same group-by-host request twice from the compressed
+    history: byte-equal bodies, equal to the raw plan's over the plain
+    store; the first answer builds the plan's labels, the second takes
+    them; a series the store gains (spilled by a checkpoint, so the
+    gather's directory grows) makes the next request build them again.
+    Last in the file: it adds a series to the module's store."""
+    t4, t0 = daemons
+    hosts = CFG4["hosts"]
+    req = draw(81)["double-groupby-1"]
+    (m_text,) = req.ms
+    metric = tsbs.parse_m(m_text)["metric"]
+    ask = req.target.replace("&trace=1", "")
+    moved = labels_moved()
+
+    def grow():
+        t4.add_batch(metric, np.array([req.start + 30], np.int64),
+                     np.array([7.0], np.float32),
+                     {"host": "host_new", "region": "nowhere"})
+        t4.checkpoint()
+
+    ((st1, b1), d1, (st2, b2), d2, (st3, b3), d3, _, (st4, b4),
+     d4) = serve(t4, ask, moved, ask, moved, req.target, moved, grow, ask,
+                 moved)
+    assert (st1, st2, st3, st4) == (200,) * 4
+    assert b1 == b2
+    assert d1 == (0, hosts) and d2 == (hosts, 0) and d3 == (hosts, 0)
+    assert d4 == (0, hosts + 1)
+    out = json.loads(b1)
+    assert len(out) == hosts and all(r["rollup"] == "fused" for r in out)
+    ((st0, b0),) = serve(t0, ask)
+    want = json.loads(b0)
+    assert st0 == 200 and all(r["rollup"] == "raw" for r in want)
+    # But for the plan's name (and whether the raw plan's fragments
+    # were warm) the two bodies are one, byte for byte.
+    assert b1.replace(b'"fused"', b'"raw"') == \
+        b0.replace(b'"cached": true', b'"cached": false')
+    (res,) = [s for s in walk(json.loads(b3)[0]["trace"])
+              if s["name"] == "fused.results"]
+    assert res["tags"]["results"] == hosts
+    grown = json.loads(b4)
+    assert len(grown) == hosts + 1
+    assert all(r["rollup"] == "fused" for r in grown)
+    (new,) = [r for r in grown if r["tags"]["host"] == "host_new"]
+    assert list(new["dps"].values()) == [7.0]
+    assert [r["dps"] for r in grown if r is not new] == \
+        [r["dps"] for r in out]
