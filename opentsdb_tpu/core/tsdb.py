@@ -285,9 +285,15 @@ class TSDB:
         sp.tags.update(points=points, chunks=devstore.chunks_cut(dw),
                        columnar=runs is not None,
                        seconds=round(sp.ms / 1000.0, 3))
+        if hasattr(dw, "shard_appended_points"):
+            # A sharded window: the shards, and the points each took.
+            took = dw.shard_appended_points()
+            sp.tags.update(shards=len(took), shard_points=took)
         self.devwindow_refill = sp.to_dict()
         LOG.info("device window refilled: %d points in %d chunks, "
-                 "%.1f s", points, sp.tags["chunks"], sp.ms / 1000.0)
+                 "%.1f s%s", points, sp.tags["chunks"], sp.ms / 1000.0,
+                 f", a shard {sp.tags['shard_points']}"
+                 if "shards" in sp.tags else "")
 
     def _stored_block_runs(self):
         """Every stored point of the data table in key order, a run of

@@ -723,7 +723,7 @@ def fold_groups(chunks, blocks=None, block=None):
 
 def window_series_stage_chunks(chunks, lo, hi, shift, *, num_series,
                                num_buckets, interval, agg_down,
-                               blocks=None, block=None,
+                               blocks=None, block=None, device=None,
                                rate=False, counter_max=0.0,
                                reset_value=0.0, counter=False,
                                drop_resets=False):
@@ -756,7 +756,11 @@ def window_series_stage_chunks(chunks, lo, hi, shift, *, num_series,
     the grids are the same for any order of data. Without ``blocks``
     every chunk is folded whole, as one block. count, min and max are
     the same bits however the chunks are grouped; the float32 sums add
-    the same slots in another order of partial sums.
+    the same slots in another order of partial sums. ``device``: the
+    stage starts there and stays there, folds or no folds (the sharded
+    window's stages, each on its shard's device; None: where the
+    chunks' committed columns take it, and a stage of no fold to the
+    default device).
     Returns the window_series_stage contract, (series_values,
     series_mask, filled, in_range, presence), and after it the int32
     device scalar the folds carried: the updates their scatters were
@@ -766,7 +770,16 @@ def window_series_stage_chunks(chunks, lo, hi, shift, *, num_series,
     # Unused statistics still flow through the fold signature (static
     # ``need`` gates their updates to no-ops) so one jit serves every
     # mergeable aggregator per shape class.
-    acc = _chunk_stage_start(nseg=num_series * num_buckets + 1)
+    if device is None:
+        acc = _chunk_stage_start(nseg=num_series * num_buckets + 1)
+    else:
+        # Made on the device and committed to it: the folds, the finish
+        # and what the caller does with the grids are then one set of
+        # programs there, whether or not a chunk is folded.
+        with jax.default_device(device):
+            acc = jax.device_put(
+                _chunk_stage_start(nseg=num_series * num_buckets + 1),
+                device)
     for blk, members in fold_groups(chunks, blocks, block):
         first = members[0][0]
         # The vector's length follows the class's block count and the
@@ -792,6 +805,22 @@ def window_series_stage_chunks(chunks, lo, hi, shift, *, num_series,
         interval=interval, agg_down=agg_down, rate=rate,
         counter_max=counter_max, reset_value=reset_value,
         counter=counter, drop_resets=drop_resets) + (acc[5],)
+
+
+@jit_plan(ExecPlan(name="window.shard_combine", axis="series"))
+def shard_combine(parts, rows):
+    """The stage grids of a sharded window's shards (storage/devshard.py)
+    joined into the grids one window would have given: ``parts`` is, a
+    shard, the five grids of its window_series_stage_chunks, every
+    shard's of one padded shape and all on one device; ``rows`` [S]
+    int32 names, for each row of the joined grids, its row in the
+    shards' grids laid end to end, and a row past their end for a row
+    that is padding (zeros and False: what a stage gives a series id
+    nobody has). Which rows go where is DATA: one program joins every
+    metric whose shards pad alike, whatever each shard holds of it."""
+    return tuple(jnp.take(jnp.concatenate(grids), rows, axis=0,
+                          mode="fill", fill_value=0)
+                 for grids in zip(*parts))
 
 
 WINDOW_STAGE_PLAN = ExecPlan(

@@ -29,6 +29,7 @@ Un-downsampled queries keep the exact 1.1 union-grid semantics.
 from __future__ import annotations
 
 import collections
+import concurrent.futures
 import itertools
 import re
 import threading
@@ -106,6 +107,13 @@ _C_FOLD_WHOLE = _metrics.counter("devwindow.fold.stages.whole")
 # lets the interpreter lock go and has to win it back.
 _C_FOLD_DISPATCHES = _metrics.counter("devwindow.fold.dispatches")
 _C_STAGE_PROGRAMS = _metrics.counter("devwindow.stage.programs")
+# The sharded window's stages (storage/devshard.py): the shards a stage
+# built was folded on (a window_series_stage_chunks call each, so
+# shards / stage.miss is the fan-out: every shard of the metric where
+# no shard is dropped), and the bytes of the shards' grids that went
+# from their device to the combine device.
+_C_STAGE_SHARDS = _metrics.counter("devwindow.stage.shards")
+_C_GATHER_BYTES = _metrics.counter("mesh.resident.gather.bytes")
 # The updates the folds' scatters were handed (kernels._scatter_runs:
 # one a run of equal (series, bucket) and not one a slot), beside
 # devwindow.fold.slots.visited: their ratio is what the run reduction
@@ -437,6 +445,11 @@ class QueryExecutor:
         self._dw_mask_cache = LRUCache(128)
         self._dw_plan_cache = LRUCache(128)
         self._dw_stage_cache = LRUCache(4)
+        # The sharded window's stages: (device, programs' statics, the
+        # window's chunk shape classes) whose programs a shard's device
+        # has compiled (_dw_warm_shards). One forgotten is warmed again,
+        # from jit's own cache.
+        self._dw_shard_warm = LRUCache(256)
         # Fused-block stage cache (compress/): device grids keyed by
         # the generation set + range + downsample plan. Entries pin
         # their source SSTable objects so id() reuse can't alias a
@@ -1332,9 +1345,9 @@ class QueryExecutor:
                 try:
                     if sharded:
                         grids = self._dw_sharded_stage(
-                            cols, start, end, qbase,
-                            num_buckets=num_buckets, S_pad=S_pad,
-                            interval=interval, dsagg=dsagg,
+                            (dw.instance_id, metric_uid), cols, start,
+                            end, qbase, num_buckets=num_buckets,
+                            S_pad=S_pad, interval=interval, dsagg=dsagg,
                             rate_kw=rate_kw)
                         if grids is None:
                             return None
@@ -1441,17 +1454,29 @@ class QueryExecutor:
                 sp.tags["results"] = len(results)
         return results
 
-    def _dw_sharded_stage(self, cols, start: int, end: int, qbase: int,
-                          *, num_buckets: int, S_pad: int,
+    def _dw_sharded_stage(self, of: tuple, cols, start: int, end: int,
+                          qbase: int, *, num_buckets: int, S_pad: int,
                           interval: int, dsagg: str, rate_kw: dict):
         """The stage half of a resident query over the mesh-SHARDED
         hot set (storage/devshard.py): each shard's chunk fold runs on
-        its OWN device (the committed chunk inputs pin the jit there;
-        async dispatch overlaps the shards), then only the [S_shard, B]
-        stage grids — never the N-point columns — travel to the first
-        shard's device, concatenate in combined-directory order, and
-        pad to S_pad. Row order equals ``cols.series_keys`` order, so
-        include/gmap and the apply kernels are oblivious to sharding.
+        its OWN device (async dispatch overlaps the shards), then only
+        the [S_shard, B] stage grids — never the N-point columns —
+        travel to the first shard's device, where one program
+        (kernels.shard_combine) lays their rows out in
+        combined-directory order, padded to S_pad. Row order equals
+        ``cols.series_keys`` order, so include/gmap and the apply
+        kernels are oblivious to sharding. ``of``: (window instance,
+        metric), which with ``cols.generation`` names the directory.
+
+        Nothing here compiles for a metric, a host or a range of its
+        own: every shard folds into grids of one padded height (that of
+        the fullest), on its own device whether or not a block of its
+        chunks was picked, and which rows the join takes from where is
+        an array. What a request of some kind compiles, the first
+        request of that kind has compiled, on every device: a program
+        belongs to one device, the shard a one-host panel folds on
+        follows the host it drew, and a metric's series fall to the
+        shards in their own numbers.
 
         Numeric contract (declared, README "Serving mesh"): the
         per-shard folds are the SAME f32 kernels as the 1-shard path
@@ -1465,46 +1490,101 @@ class QueryExecutor:
         checked again here because the caller's probe reads the shards
         it captured — a reshard between the two is benign either way).
         """
-        import jax.numpy as jnp
         imin, imax = -(2**31), 2**31 - 1
-        parts = []
-        for sc in cols.shards:
-            if sc is None:
-                continue
-            if not imin <= qbase - sc.epoch <= imax:
-                return None
-            S_i = len(sc.series_keys)
-            grids = kernels.window_series_stage_chunks(
-                sc.chunks,
-                np.int32(min(max(start - sc.epoch, imin), imax)),
-                np.int32(min(max(end - sc.epoch, imin), imax)),
-                np.int32(qbase - sc.epoch),
-                num_series=_pad_size(S_i), num_buckets=num_buckets,
-                interval=interval, agg_down=dsagg,
-                blocks=sc.blocks, block=sc.block, **rate_kw)
-            _fold_handed(grids[5])
-            parts.append((S_i, grids))
-        if not parts:
+        live = [(i, sc) for i, sc in enumerate(cols.shards)
+                if sc is not None]
+        if not live or not all(imin <= qbase - sc.epoch <= imax
+                               for _i, sc in live):
             return None
-        try:
-            target = next(iter(parts[0][1][0].devices()))
-        except Exception:
-            target = None
-        outs = []
-        for gi in range(5):
-            rows = [jax.device_put(grids[gi][:S_i], target)
-                    for S_i, grids in parts]
-            cat = rows[0] if len(rows) == 1 else jnp.concatenate(rows)
-            short = S_pad - int(cat.shape[0])
-            if short:
-                # Zero/False rows are exactly what the 1-shard stage
-                # produces for its padding sids (no points: mask and
-                # in_range False, values 0) — the apply's include mask
-                # never selects them either way.
-                cat = jnp.pad(cat, [(0, short)]
-                              + [(0, 0)] * (cat.ndim - 1))
-            outs.append(cat)
-        return tuple(outs)
+        held = [len(sc.series_keys) for _i, sc in live]
+        height = _pad_size(max(held))
+        statics = dict(num_series=height, num_buckets=num_buckets,
+                       interval=interval, agg_down=dsagg, **rate_kw)
+        programs = tuple(sorted(statics.items()))
+
+        # A shard's device compiles what a request of this kind can run
+        # there before the first stage of the kind is built, whichever
+        # shard that request's own selection folds on.
+        cold = []
+        for i, _sc in live:
+            window = cols.shard_windows[i]
+            warm = (_device_id(window.device), programs,
+                    window.chunk_sizes)
+            if self._dw_shard_warm.get(warm) is None:
+                cold.append((warm, window))
+        if cold:
+            self._dw_warm_shards(cold, live[0][1].block, statics)
+        parts = []
+        for i, sc in live:
+            window = cols.shard_windows[i]
+            # The host's time in this shard's stage: its start, its fold
+            # dispatches, its finish (the device runs on behind it).
+            with obs_trace.span("resident.shard", shard=i) as sp:
+                grids = kernels.window_series_stage_chunks(
+                    sc.chunks,
+                    np.int32(min(max(start - sc.epoch, imin), imax)),
+                    np.int32(min(max(end - sc.epoch, imin), imax)),
+                    np.int32(qbase - sc.epoch),
+                    blocks=sc.blocks, block=sc.block,
+                    device=window.device, **statics)
+                if sp is not None:
+                    sp.tags.update(
+                        device=_device_id(window.device),
+                        series=len(sc.series_keys),
+                        chunks=sum(len(b) > 0 for b in sc.blocks))
+            _fold_handed(grids[5])
+            parts.append(grids[:5])
+        _C_STAGE_SHARDS.inc(len(parts))
+        # What brings the shards' grids to the combine device (the
+        # first shard's) and joins them: the copies between devices and
+        # the one program that lays the rows out.
+        with obs_trace.span("resident.gather", shards=len(parts)) as sp:
+            target = next(iter(parts[0][0].devices()))
+            moved = sum(g.nbytes for grids in parts for g in grids
+                        if target not in g.devices())
+            hit = self._dw_mask_cache.get(of + ("shard_rows",))
+            if hit is not None and hit[:2] == (cols.generation, height):
+                rows = hit[2]
+            else:
+                # Row r of the joined grids: the shard its series lives
+                # in, times the height, plus the series' row there; a
+                # padding row, one past every shard's.
+                rows = np.full(S_pad, len(live) * height, np.int32)
+                rows[:sum(held)] = np.concatenate(
+                    [n * height + np.arange(mine)
+                     for n, mine in enumerate(held)])
+                rows = jax.device_put(rows, target)
+                self._dw_mask_cache.put(
+                    of + ("shard_rows",), (cols.generation, height, rows))
+            outs = kernels.shard_combine(
+                tuple(tuple(jax.device_put(g, target) for g in grids)
+                      for grids in parts), rows)
+            _C_GATHER_BYTES.inc(moved)
+            if sp is not None:
+                sp.tags["bytes"] = moved
+        return outs
+
+    def _dw_warm_shards(self, cold, block: int, statics: dict) -> None:
+        """Compile, on the device of each window of ``cold`` ((key,
+        shard's window) pairs), every program a stage of ``statics``
+        can run there: a stage over one chunk of each shape class the
+        window holds, a block of each visited over a range nothing lies
+        in, built and thrown away. A program belongs to one device, the
+        shard a one-host panel folds on follows the host it drew and a
+        metric's chunks pad to their own classes, so without it the
+        first request to fold on a shard, or on a class, compiles
+        under that request. The shards do it side by side: the compiler
+        works outside the interpreter lock."""
+        def warm(window):
+            classes = window.chunk_classes()
+            kernels.window_series_stage_chunks(
+                classes, np.int32(1), np.int32(0), np.int32(0),
+                blocks=[(0,)] * len(classes), block=block,
+                device=window.device, **statics)
+        with concurrent.futures.ThreadPoolExecutor(len(cold)) as pool:
+            list(pool.map(warm, [window for _key, window in cold]))
+        for key, _window in cold:
+            self._dw_shard_warm.put(key, True)
 
     def _devwindow_groups(self, dw, metric_uid: bytes, cols, exact,
                           group_bys):
@@ -2611,6 +2691,12 @@ def _pad64(n: int) -> int:
     return max((n + 63) // 64 * 64, 64)
 
 
+def _device_id(device) -> int | None:
+    """A span's tag for the device a shard is pinned to (None: the
+    default placement)."""
+    return None if device is None else int(device.id)
+
+
 def _dw_chunks(cols) -> list:
     """Every device chunk of a resident window's columns, the sharded
     window's shard by shard (for a span's counts)."""
@@ -2624,12 +2710,13 @@ def _dw_fold_extent(cols) -> tuple[int, ...]:
     """DevChunks.fold_extent() of a resident window's columns, summed
     over the sharded window's shards: (blocks picked, blocks in all,
     slots picked, slots in all, chunks hit, fold calls), and after
-    them the device programs the stage build issues: the calls and,
-    for each shard, its start and its finish."""
+    them the device programs the stage build issues: the calls, for
+    each shard its start and its finish, and the join of a sharded
+    window's shards."""
     shards = getattr(cols, "shards", None)
     parts = [cols] if shards is None else list(filter(None, shards))
     sums = tuple(map(sum, zip(*(p.fold_extent() for p in parts))))
-    return sums + (sums[5] + 2 * len(parts),)
+    return sums + (sums[5] + 2 * len(parts) + (shards is not None),)
 
 
 class _GridGroups:

@@ -67,7 +67,8 @@ class ShardedDevChunks(NamedTuple):
     per-shard stage kernels. Row order of the combined result is shard
     order: combined sid = shard_starts[i] + local sid."""
     shards: list            # per-shard DevChunks | None (no series routed)
-    shard_devices: list     # per-shard device (or None = default)
+    shard_windows: list     # per-shard DeviceWindow the chunks live in
+    #                         (its ``device``: None = default placement)
     shard_starts: list      # combined-sid offset of each shard's rows
     series_keys: list       # combined directory (concat in shard order)
     generation: tuple       # (reshard_gen, per-shard generations)
@@ -164,31 +165,17 @@ class ShardedDeviceWindow:
         if len(timestamps) == 0:
             return
         with self._lock:
-            if metric_uid in self._dirty_metrics:
+            routed = self._route(metric_uid, series_keys)
+            if routed is None:
                 return
-            n_shards = len(self._shards)
-            owner = np.fromiter(
-                (series_hash(k) % n_shards for k in series_keys),
-                np.int64, len(series_keys))
-            owners = np.unique(owner).tolist()
-            self._metric_shards.setdefault(metric_uid, set()).update(
-                owners)
+            owner, owners = routed
             if self._journal is not None:
-                # Journal COPIES under the gate lock: the record must be
-                # immutable (replay happens later) and ordered with the
-                # reshard's snapshot boundary. A record a series: a
-                # reshard is rare, and its replay re-routes by series.
-                ts = np.array(timestamps, np.int64)
-                vals = np.array(values, np.float32)
-                if series_of_point is None:
-                    self._journal.append(
-                        (metric_uid, series_keys[0], ts, vals))
-                else:
-                    for j, key in enumerate(series_keys):
-                        pts = series_of_point == j
-                        if pts.any():
-                            self._journal.append(
-                                (metric_uid, key, ts[pts], vals[pts]))
+                self._journal_rows(
+                    metric_uid, series_keys,
+                    [len(timestamps)] if series_of_point is None
+                    else np.bincount(series_of_point,
+                                     minlength=len(series_keys)),
+                    timestamps, values)
             # Delegate under the fleet lock: the reshard gate's
             # quiesce+snapshot must never interleave with a half-landed
             # append (staged in neither the snapshot nor the journal).
@@ -207,6 +194,60 @@ class ShardedDeviceWindow:
                     metric_uid, [series_keys[j] for j in mine],
                     local[series_of_point[pts]], timestamps[pts],
                     values[pts])
+
+    def append_rows(self, metric_uid: bytes, series_keys, counts,
+                    timestamps: np.ndarray, values: np.ndarray) -> None:
+        """``DeviceWindow.append_rows`` over the fleet (the boot's
+        refill from columnar blocks): the run's rows go, in the run's
+        order, to the shards their series hash to, one call a shard,
+        and each shard cuts its chunks as a row at a time would."""
+        if len(timestamps) == 0:
+            return
+        counts = np.asarray(counts)
+        with self._lock:
+            routed = self._route(metric_uid, series_keys)
+            if routed is None:
+                return
+            owner, owners = routed
+            if self._journal is not None:
+                self._journal_rows(metric_uid, series_keys, counts,
+                                   timestamps, values)
+            owner_of_point = np.repeat(owner, counts)
+            for idx in owners:
+                mine = np.flatnonzero(owner == idx)
+                pts = owner_of_point == idx
+                self._shards[idx].append_rows(
+                    metric_uid, [series_keys[j] for j in mine],
+                    counts[mine], timestamps[pts], values[pts])
+
+    def _route(self, metric_uid: bytes, series_keys):
+        """(the shard of each of ``series_keys``, the shards among
+        them), noted as owners of the metric; None for a dirty metric.
+        The caller holds the fleet lock."""
+        if metric_uid in self._dirty_metrics:
+            return None
+        n_shards = len(self._shards)
+        owner = np.fromiter(
+            (series_hash(k) % n_shards for k in series_keys),
+            np.int64, len(series_keys))
+        owners = np.unique(owner).tolist()
+        self._metric_shards.setdefault(metric_uid, set()).update(owners)
+        return owner, owners
+
+    def _journal_rows(self, metric_uid: bytes, series_keys, counts,
+                      timestamps, values) -> None:
+        """Journal COPIES under the gate lock: the record must be
+        immutable (replay happens later) and ordered with the reshard's
+        snapshot boundary. A record a series (series ``i`` holds the
+        next ``counts[i]`` points): a reshard is rare, and its replay
+        re-routes by series."""
+        ts = np.array(timestamps, np.int64)
+        vals = np.array(values, np.float32)
+        ends = np.cumsum(counts)
+        for key, a, z in zip(series_keys, ends - counts, ends):
+            if z > a:
+                self._journal.append(
+                    (metric_uid, key, ts[a:z], vals[a:z]))
 
     def flush(self) -> None:
         with self._lock:
@@ -270,7 +311,7 @@ class ShardedDeviceWindow:
         self.window_hits += 1
         return ShardedDevChunks(
             shards=per,
-            shard_devices=[s.device for s in shards],
+            shard_windows=shards,
             shard_starts=starts,
             series_keys=keys,
             generation=(gen, tuple(
@@ -407,6 +448,11 @@ class ShardedDeviceWindow:
 
     # -- observability -------------------------------------------------
 
+    def shard_appended_points(self) -> list[int]:
+        """Points each shard has taken in since it was built."""
+        with self._lock:
+            return [s.appended_points for s in self._shards]
+
     def shard_resident_points(self) -> list[int]:
         with self._lock:
             shards = list(self._shards)
@@ -477,6 +523,10 @@ class ShardedDeviceWindow:
         collector.record("mesh.resident.points",
                          agg["devwindow.points.resident"])
         collector.record("mesh.resident.shards", len(shards))
+        # The window's bytes over all the devices, beside
+        # devwindow.bytes, the fullest device's: their ratio is how
+        # evenly the hash spread the series (a quarter of it over four).
+        collector.record("mesh.resident.bytes", sum(held.values()))
         collector.record("mesh.resident.reshard.count",
                          self.reshard_count)
         collector.record("mesh.resident.reshard_ms",

@@ -355,6 +355,10 @@ class DeviceWindow:
         # fleet-wide (_evict_over_budget).
         self._total_points = 0
         self._total_bytes = 0           # of the resident chunks' columns
+        # The padded sizes of every chunk uploaded so far, over all the
+        # metrics (it only grows; replaced whole, so read without the
+        # lock): the shape classes a stage's fold is compiled for.
+        self.chunk_sizes: frozenset = frozenset()
         self._seq = 0
         # Liveness signal: bumps on EVERY upload completion (success or
         # failure). Stall handling keys off this, not off elapsed time
@@ -654,6 +658,8 @@ class DeviceWindow:
             while pos > 0 and mw.chunks[pos - 1]["seq"] > seq:
                 pos -= 1
             mw.chunks.insert(pos, chunk)
+            if pad not in self.chunk_sizes:
+                self.chunk_sizes = self.chunk_sizes | {pad}
             mw.device_points += n
             mw.device_bytes += chunk["bytes"]
             self._total_points += n
@@ -970,6 +976,18 @@ class DeviceWindow:
                 generation=mw.generation, version=mw.version,
                 blocks=[c["zone"].select(start, end) for c in mw.chunks],
                 block=ZONE_BLOCK, zones=[c["zone"] for c in mw.chunks])
+
+    def chunk_classes(self) -> list:
+        """One resident chunk (its four columns) of each padded size,
+        over all the metrics: what a stage folds, with nothing to
+        visit, to have every program its kind can run compiled on this
+        window's device before a request needs it (the sharded
+        window's stages, query/executor.py)."""
+        with self._lock:
+            found = {c["pad"]: c for mw in self._metrics.values()
+                     for c in mw.chunks}
+        return [(c["ts"], c["vals"], c["sid"], c["valid"])
+                for c in found.values()]
 
     # -- observability -------------------------------------------------
 

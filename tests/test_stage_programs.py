@@ -127,10 +127,69 @@ def test_a_chunk_with_no_block_picked_is_in_no_call():
         (512, K), (512, 2), (256, K), (256, 1)]
 
 
+@pytest.mark.parametrize("agg", ["max", "avg"])
+def test_a_block_visited_over_a_range_nothing_lies_in_changes_no_grid(
+        monkeypatch, agg):
+    """What the sharded window's stages are warmed with
+    (QueryExecutor._dw_warm_shards): a block of one chunk of each shape
+    class visited over a range with its end before its start is a fold
+    call a class, which gives the grids of a stage that folded
+    nothing."""
+    classes = [chunk(90, 512), chunk(91, 256), chunk(92, 1024)]
+    kw = dict(num_series=S, num_buckets=B * 4, interval=INTERVAL,
+              agg_down=agg, block=BLOCK)
+    calls = counted(monkeypatch)
+    warm = kernels.window_series_stage_chunks(
+        classes, np.int32(1), np.int32(0), np.int32(0),
+        blocks=[(0,)] * len(classes), **kw)
+    assert calls.count("_chunk_fold") == len(classes)
+    none = np.zeros(0, np.int32)
+    del calls[:]
+    empty = kernels.window_series_stage_chunks(
+        classes, np.int32(LO), np.int32(HI), np.int32(0),
+        blocks=[none] * len(classes), **kw)
+    assert calls.count("_chunk_fold") == 0
+    assert not np.asarray(warm[1]).any()
+    for a, b in zip(warm[:5], empty[:5]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_the_join_lays_the_shards_rows_out_by_an_array():
+    """kernels.shard_combine against numpy: rows taken from the shards'
+    grids laid end to end, a row past their end zeros and False; the
+    same program for another layout of the rows."""
+    rng = np.random.default_rng(5)
+    H, held = 16, (5, 16, 0, 9)
+
+    def part():
+        return (rng.normal(size=(H, B)).astype(np.float32),
+                rng.random((H, B)) < 0.5,
+                rng.normal(size=(H, B)).astype(np.float32),
+                rng.random((H, B)) < 0.5, rng.random(H) < 0.5)
+    parts = tuple(part() for _ in held)
+    before = None
+    for order in (held, held[::-1]):
+        rows = np.full(32, len(held) * H, np.int32)
+        at = 0
+        for n, mine in enumerate(order):
+            rows[at:at + mine] = n * H + np.arange(mine)
+            at += mine
+        got = kernels.shard_combine(parts, rows)
+        for g, grids in zip(got, zip(*parts)):
+            flat = np.concatenate(grids)
+            want = np.zeros((32,) + flat.shape[1:], flat.dtype)
+            want[:at] = flat[rows[:at]]
+            np.testing.assert_array_equal(np.asarray(g), want)
+        assert before in (None, kernels.shard_combine._cache_size())
+        before = kernels.shard_combine._cache_size()
+
+
 def counted(monkeypatch):
-    """Every call of the stage's three jitted callables, by name."""
+    """Every call of the stage's jitted callables, by name: the three
+    of a window's stage and the join of a sharded window's shards."""
     calls = []
-    for name in ("_chunk_stage_start", "_chunk_fold", "_chunk_stage_finish"):
+    for name in ("_chunk_stage_start", "_chunk_fold", "_chunk_stage_finish",
+                 "shard_combine"):
         def call(*a, _fn=getattr(kernels, name), _name=name, **kw):
             calls.append(_name)
             return _fn(*a, **kw)
@@ -169,12 +228,17 @@ def test_the_counters_say_what_a_served_stage_issued(tmp_path, monkeypatch,
                      devwindow_shards=shards)
     add_refill_metric(tsdb, "refill.cpu")
     ex = QueryExecutor(tsdb, backend="tpu")
+    spec = QuerySpec("refill.cpu", {"host": "*"}, "max",
+                     downsample=(300, "max"))
+    # Before the first stage of a kind a sharded window's devices are
+    # warmed with a stage each, built and thrown away
+    # (_dw_warm_shards); the counters say what a served stage's own
+    # selection issues, so another range goes first.
+    ex.run_with_plan(spec, BASE + 1200, BASE + SPAN - 10)
     calls = counted(monkeypatch)
     names = ["devwindow.stage.programs", "devwindow.fold.dispatches",
              "devwindow.stage.miss"]
     before = [stat(n) for n in names]
-    spec = QuerySpec("refill.cpu", {"host": "*"}, "max",
-                     downsample=(300, "max"))
     _out, plan, _c = ex.run_with_plan(spec, BASE + 600, BASE + SPAN - 10)
     assert plan == "resident"
     programs, dispatches, built = (stat(n) - b
@@ -182,11 +246,13 @@ def test_the_counters_say_what_a_served_stage_issued(tmp_path, monkeypatch,
     folds = calls.count("_chunk_fold")
     assert built == 1
     assert dispatches == folds >= 2
-    # Its start and its finish a shard, and the calls.
+    # Its start and its finish a shard, the calls, and one join of a
+    # sharded window's shards.
     parts = max(shards, 1)
     assert calls.count("_chunk_stage_start") == parts
     assert calls.count("_chunk_stage_finish") == parts
-    assert programs == len(calls) == 2 * parts + folds
+    assert calls.count("shard_combine") == (shards > 0)
+    assert programs == len(calls) == 2 * parts + folds + (shards > 0)
     uid = tsdb.metrics.get_id("refill.cpu")
     chunks = sum(len(w._metrics[uid].chunks)
                  for w in getattr(tsdb.devwindow, "_shards",
