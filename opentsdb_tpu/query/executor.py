@@ -2719,6 +2719,23 @@ def _dw_fold_extent(cols) -> tuple[int, ...]:
     return sums + (sums[5] + 2 * len(parts) + (shards is not None),)
 
 
+class KeptTags(dict):
+    """The tags of a label kept with its plan (``_GridGroups.labels``),
+    with the label's ``aggregated`` list and room for the ``text`` an
+    encoder of answers made of the two (server/qjson.py fills it on the
+    label's first answer, from the event-loop thread alone), so that
+    what was formatted lives as long as the label and goes with its
+    plan."""
+
+    __slots__ = ("aggregated", "text")
+
+    def __init__(self, tags: dict[str, str],
+                 aggregated: list[str]) -> None:
+        super().__init__(tags)
+        self.aggregated = aggregated
+        self.text: str | None = None
+
+
 class _GridGroups:
     """The groups of a grid plan (resident, fused) as its answer takes
     them, kept with the plan that made the groups: the sorted group
@@ -2743,7 +2760,7 @@ class _GridGroups:
         self.members = np.fromiter(
             (sid for g in self.gkeys for sid in groups[g]), np.intp,
             int(self.offsets[-1]))
-        self.labels: list[tuple[dict[str, str], list[str]]] | None = None
+        self.labels: list[tuple[KeptTags, list[str]]] | None = None
         self.series_keys = series_keys
 
 
@@ -2787,7 +2804,10 @@ def _grid_results(metric: str, grid: _GridGroups, tags_of, has_points,
     kept = grid.labels is not None
     if not kept:
         # The plan's first answer (two at once build the same twice).
-        grid.labels = [label(gi, False) for gi in range(len(grid.gkeys))]
+        grid.labels = [(KeptTags(tags, aggregated), aggregated)
+                       for tags, aggregated in (
+                           label(gi, False)
+                           for gi in range(len(grid.gkeys)))]
     live = rows.tolist()
     labels = [lab if w else label(gi, True) for gi, w, lab
               in zip(live, whole.tolist(),

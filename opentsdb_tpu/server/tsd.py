@@ -20,6 +20,7 @@ import asyncio
 import concurrent.futures
 import functools
 import hashlib
+import itertools
 import json
 import logging
 import os
@@ -44,7 +45,7 @@ from opentsdb_tpu.obs.ring import TraceRing, log_slow, make_record
 from opentsdb_tpu.query.aggregators import Aggregators
 from opentsdb_tpu.query.executor import QueryExecutor, QuerySpec
 from opentsdb_tpu.query.grammar import parse_m
-from opentsdb_tpu.server import logbuffer
+from opentsdb_tpu.server import logbuffer, qjson
 from opentsdb_tpu.stats.collector import LatencyDigest, StatsCollector
 from opentsdb_tpu.utils import jaxenv, timeparse
 from typing import NamedTuple
@@ -1748,16 +1749,19 @@ class TSDServer:
             body = self._ascii_output(results).encode()
             ctype = "text/plain"
         elif "json" in q:
-            # On the event-loop thread and outside every span: a wide
-            # answer's encode holds the GIL against the query workers.
+            # On the event-loop thread and outside every span, written
+            # in fragments from the results' arrays (server/qjson.py):
+            # a wide answer is thousands of short calls between which
+            # the GIL can pass to the query workers, and still the
+            # loop's largest piece of work.
             with obs_trace.timed("http.q.encode"):
-                body = json.dumps(
+                body = qjson.encode(
                     self._json_output(
                         results, result_plans, result_cached,
                         result_traces if want_trace else None,
                         degraded=degraded,
                         approx=result_approx,
-                        expert=expert_label)).encode()
+                        expert=expert_label))
             _M_Q_BYTES.inc(len(body))
             ctype = "application/json"
         else:
@@ -1829,18 +1833,22 @@ class TSDServer:
     def _json_output(self, results, plans=None, cached=None,
                      traces=None, degraded=None, approx=None,
                      expert=None):
+        """One entry a result, for qjson.encode: six keys, ``dps`` a
+        view of the result's arrays (``items()`` as a dict's), and the
+        keys below only where there is something to declare."""
         out = [{
             "metric": r.metric,
             "tags": r.tags,
             "aggregateTags": r.aggregated_tags,
-            "rollup": (plans[i] if plans and i < len(plans) else "raw"),
+            "rollup": plan,
             # Fragment-cache provenance: True iff this sub-query's
             # whole range served from warm decoded fragments.
-            "cached": bool(cached[i]) if cached and i < len(cached)
-            else False,
-            "dps": {str(int(t)): float(v)
-                    for t, v in zip(r.timestamps, r.values)},
-        } for i, r in enumerate(results)]
+            "cached": bool(hit),
+            "dps": qjson.Dps(r.timestamps, r.values),
+        } for r, plan, hit in zip(
+            results,
+            itertools.chain(plans or (), itertools.repeat("raw")),
+            itertools.chain(cached or (), itertools.repeat(False)))]
         if expert:
             # Expert-path provenance, DECLARED either way: "expert"
             # when the batch served through the mesh's expert buckets,
@@ -1856,18 +1864,18 @@ class TSDServer:
             # "rollup-only" (load shedding omitted raw stitching).
             for ent in out:
                 ent["degraded"] = degraded
-        if approx:
+        if approx and any(approx):
             # The error contract: a sketch-served answer carries its
             # kind + reported bound per result ("approx": {"kind":
             # "tdigest"|"moment"|"rollup-stale", "error": ...}).
-            for i, ent in enumerate(out):
-                if i < len(approx) and approx[i]:
-                    ent["approx"] = approx[i]
+            for ent, a in zip(out, approx):
+                if a:
+                    ent["approx"] = a
         if traces is not None:
             # ?trace=1 only: the per-sub-query span tree, inline.
-            for i, ent in enumerate(out):
-                if i < len(traces) and traces[i] is not None:
-                    ent["trace"] = traces[i]
+            for ent, t in zip(out, traces):
+                if t is not None:
+                    ent["trace"] = t
         return out
 
     def _render_png(self, results, start, end, q,
