@@ -114,7 +114,7 @@ FUSED_STAGE_PLAN = ExecPlan(
               P(SERIES_AXIS), P(SERIES_AXIS), P(SERIES_AXIS),
               P(SERIES_AXIS), P(SERIES_AXIS), P(), P(), P(),
               P(), P()),
-    out_specs=(P(), P(), P(), P(), P()))
+    out_specs=(P(), P(), P(), P(), P(), P()))
 
 
 def _fused_block_stage_ops(ts_nb, ts_pay, v_nb, v_pay, first_idx,
@@ -161,7 +161,8 @@ def _fused_block_stage(ts_nb, ts_pay, v_nb, v_pay, first_idx, blk_first,
     (series_values, series_mask, filled, in_range, presence) that
     ops.kernels.window_moment_apply / window_quantile_apply consume —
     so every group aggregator, percentile and rate the resident-window
-    path serves, this path serves identically.
+    path serves, this path serves identically — and after it the
+    stage's count of scatter updates (ops.kernels._window_series_stage).
     """
     rel_ts, vals = decode_points(ts_nb, ts_pay, v_nb, v_pay,
                                  first_idx, blk_first, rel_base,
@@ -193,13 +194,20 @@ fused_block_stage = compile_with_plan(
 # Answers are bit-identical to the byte-stream fused program:
 # identical decode math (the XOR/delta chains never cross block
 # boundaries), identical point order, and padding points belong to a
-# pad record every query marks invalid.
+# pad record every query marks invalid. A row of P_BLK slots lays a
+# record over other tiles and blocks of the stage than a packed stream
+# does, which the stage's sums do not see: a run's float32 sum follows
+# from its points in their order alone (ops/kernels._run_fold).
 #
 # On the TPU a gather or a scatter costs ~10 ns an ELEMENT whatever it
 # moves (the first fill program here spent 65 ms on 8 blocks: a
 # searchsorted, eight byte gathers and three gathers by record, a
 # third of a million elements each), while a scan along a row is
-# nearly free. So nothing below gathers by point but the two payload
+# nearly free. A scatter of RUNS costs by the update: the stage's two
+# scatters took 0.19 s each over a fleet-wide gather's 22M points a
+# slot an update, and take a sixteenth of the updates since PR 46
+# (a series-hour's 360 points are one run; PERF.md §6). So nothing
+# below gathers by point but the two payload
 # reads of a fill: what a point takes from its record is spread by a
 # scatter of the RECORDS' differences at their first points and a
 # cumsum along the row (``_spread``), and the XOR chain is a

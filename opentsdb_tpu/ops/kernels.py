@@ -15,8 +15,10 @@ Design rules (SURVEY.md §7.4):
   under the HBM roofline, and all the device did in the resident cells
   until PR 39 (PERF.md §5-§6). So the resident fold (window.chunk_fold)
   reduces each run of equal segment ids on the VPU first and scatters
-  one update a run (_scatter_runs); the raw plan's _segment_moments
-  still scatters a point at a time.
+  one update a run (_scatter_runs), and since PR 46 so does the stage
+  of the plans that read past the horizon (_series_stage, through
+  _run_moments); _segment_moments, a slot an update, is what the mesh
+  legs still take (parallel/).
 - Timestamps enter as int32 *offsets from the query start*; values as
   float32. Bucket mean-timestamps are computed relative to each bucket
   start so float32 stays exact (offsets < interval <= 2^24).
@@ -57,10 +59,11 @@ _POS_INF = float("inf")
 
 # Which per-segment statistics each aggregator's _finish needs. count is
 # always computed (it doubles as the bucket-nonempty mask); the rest are
-# gated off it so e.g. a sum query issues 1-2 [N] reductions, not 5:
-# the kernel issues one flat rank-1 reduction per needed statistic and
-# nothing else (relative cost of stacked [N, K] scatters and
-# segment_min/max: not measured on a local chip).
+# gated off it so e.g. a sum query scatters 2 statistics, not 5: a
+# turn of _scatter_runs reduces and scatters each needed statistic
+# of a block's runs and nothing else (_segment_moments: one flat rank-1
+# reduction of the [N] slots a needed statistic; relative cost of
+# stacked [N, K] scatters: not measured on a local chip).
 _AGG_NEEDS = {"sum": frozenset({"sum"}), "min": frozenset({"min"}),
               "max": frozenset({"max"}), "avg": frozenset({"sum"}),
               "dev": frozenset({"sum", "m2"}),
@@ -72,7 +75,7 @@ def _needs(agg: str) -> frozenset:
 
 
 def _segment_moments(vals: jnp.ndarray, seg: jnp.ndarray, valid: jnp.ndarray,
-                     num_segments: int, extra: jnp.ndarray | None = None,
+                     num_segments: int,
                      need: frozenset = frozenset({"sum", "m2", "min",
                                                   "max"})):
     """Per-segment count, sum, centered-M2, min, max over masked points.
@@ -82,13 +85,11 @@ def _segment_moments(vals: jnp.ndarray, seg: jnp.ndarray, valid: jnp.ndarray,
     in float32 when stddev << |mean|.
 
     ``need`` gates which statistics are materialized (see _AGG_NEEDS);
-    un-needed ones return None. ``extra`` is an optional [N] feature
-    summed the same way and returned as a sixth output — downsample_group
-    passes bucket-relative timestamps through it.
+    un-needed ones return None.
     """
     count = jax.ops.segment_sum(valid.astype(jnp.float32), seg,
                                 num_segments)
-    total = m2 = mn = mx = extra_sum = None
+    total = m2 = mn = mx = None
     if "sum" in need or "m2" in need:
         total = jax.ops.segment_sum(jnp.where(valid, vals, 0.0), seg,
                                     num_segments)
@@ -102,10 +103,6 @@ def _segment_moments(vals: jnp.ndarray, seg: jnp.ndarray, valid: jnp.ndarray,
     if "max" in need:
         mx = jax.ops.segment_max(jnp.where(valid, vals, _NEG_INF), seg,
                                  num_segments)
-    if extra is not None:
-        extra_sum = jax.ops.segment_sum(jnp.where(valid, extra, 0.0), seg,
-                                        num_segments)
-        return count, total, m2, mn, mx, extra_sum
     return count, total, m2, mn, mx
 
 
@@ -314,7 +311,9 @@ def _window_series_stage(rel_ts, vals, sid, valid_in, lo, hi, shift, *,
     the quantile path proved it first, this generalizes it to moments).
 
     Returns (series_values [S, B] post-rate, series_mask [S, B]
-    post-rate, filled [S, B], in_range [S, B], presence [S] pre-rate).
+    post-rate, filled [S, B], in_range [S, B], presence [S] pre-rate)
+    and after them, as window_series_stage_chunks does, the int32
+    device scalar of the updates the stage's scatters were handed.
     ``filled``/``in_range`` carry the lerp (or, under rate, step) fill
     of the full grid: filling is ROW-LOCAL, so a series' filled row is
     identical whether or not other series are included — which makes
@@ -329,7 +328,7 @@ def _window_series_stage(rel_ts, vals, sid, valid_in, lo, hi, shift, *,
         drop_resets=drop_resets)
     return _stage_tail(out["series_values"], out["series_mask"],
                        out["presence"], num_buckets=num_buckets,
-                       rate=rate)
+                       rate=rate) + (out["handed"],)
 
 
 def _group_stage(filled, in_range, series_mask, gmap, *, num_groups,
@@ -457,11 +456,14 @@ _FOLD_TILE = 128
 _FOLD_RUNS = 8
 
 
-def _scatter_runs(part, v, ok, seg, dump):
+def _scatter_runs(part, v, ok, seg, dump, extra=None, total=None):
     """Fold one block's slots into the accumulators of ``part`` (a dict
     keyed by statistic: ``count`` the slots with ``ok``, ``sum`` /
-    ``min`` / ``max`` of ``v`` under ``ok``), one scatter update a RUN
-    of equal segment ids and not one a slot. XLA:TPU applies a
+    ``min`` / ``max`` of ``v`` under ``ok``, ``extra`` the sum of the
+    feature ``extra`` under ``ok``), one scatter update a RUN
+    of equal segment ids and not one a slot. ``total``, where given, is
+    what ``sum`` adds up in place of ``v`` (the stage's runs folded
+    beforehand, _run_fold). XLA:TPU applies a
     scatter's updates one after another (8.9 ns each on a v5e), and
     every writer stages a chunk in runs (a series-hour is 360
     consecutive slots of one series, so an hourly bucket's segment 360
@@ -495,6 +497,10 @@ def _scatter_runs(part, v, ok, seg, dump):
     # is worth 11-16 us a turn on a v5e over a tile a row (a block in
     # no order: 0.95 ms against 1.20).
     v, ok, seg = (a.reshape(tiles, tile).T for a in (v, ok, seg))
+    if "extra" in part:
+        extra = extra.reshape(tiles, tile).T[:, None, :]
+    if total is not None:
+        total = total.reshape(tiles, tile).T[:, None, :]
     start = jnp.concatenate(
         [jnp.ones((1, tiles), bool), seg[1:] != seg[:-1]], axis=0)
     run = jnp.cumsum(start.astype(jnp.int32), axis=0) - 1
@@ -513,17 +519,220 @@ def _scatter_runs(part, v, ok, seg, dump):
                 jnp.sum(live.astype(jnp.float32), axis=0).ravel())
         if "sum" in part:
             part["sum"] = part["sum"].at[at].add(
-                jnp.sum(jnp.where(live, feed, 0.0), axis=0).ravel())
+                jnp.sum(jnp.where(live, feed if total is None else total,
+                                  0.0), axis=0).ravel())
         if "min" in part:
             part["min"] = part["min"].at[at].min(
                 jnp.min(jnp.where(live, feed, _POS_INF), axis=0).ravel())
         if "max" in part:
             part["max"] = part["max"].at[at].max(
                 jnp.max(jnp.where(live, feed, _NEG_INF), axis=0).ravel())
+        if "extra" in part:
+            part["extra"] = part["extra"].at[at].add(
+                jnp.sum(jnp.where(live, extra, 0.0), axis=0).ravel())
         return part
 
     return (jax.lax.fori_loop(0, turns, turn, part),
             turns * (tiles * runs))
+
+
+# The slots of a packed stream that _run_moments hands _scatter_runs at
+# a time. _scatter_runs' trip count is its worst tile's run count, so a
+# stretch of slots in no order costs the block it lies in sixteen turns
+# and not the stream; a block's fixed cost is a pass over each
+# accumulator (the scatters' operands), so it is the fold's block and
+# not smaller.
+_STAGE_BLOCK = 1 << 16
+
+# The slots of a run that _run_fold adds up one after another before it
+# begins a new partial sum. A left fold takes a step a slot whatever
+# computes it, so a long run is cut every _STAGE_FOLD slots FROM ITS OWN
+# FIRST SLOT (the last cut left out where under _FOLD_TILE slots would
+# follow it): what a run sums to then follows from its values in their
+# order alone, and a run of up to _STAGE_FOLD + _FOLD_TILE - 1 slots (a
+# series-hour's 360) sums to the bits a slot-wise scatter gives it.
+# Whole turns of _FOLD_STEPS rows; 384 because the TPU's compiler takes
+# 3.4 s over the fleet-wide program so, 4.5 s at 512 and 10 s at 256
+# (compiled for a described v5e on this repo's CPU host, PERF.md §6).
+_STAGE_FOLD = 384
+# The lanes the columns of _run_fold's scans are laid over (a vector
+# register's), and the rows a turn of a scan takes: the scans that keep
+# only what a column ends with make a turn three device operations,
+# the one that keeps every sum one a row.
+_LANES = 128
+_FOLD_STEPS = 32
+
+
+def _varying(x, like):
+    """``x`` for the start of a loop's carry that is computed from
+    ``like``: inside a shard_map the stream varies over the mesh's
+    axes, and a carry has to vary from its start as its result will."""
+    vma = tuple(jax.typeof(like).vma)
+    return jax.lax.pcast(x, vma, to="varying") if vma else x
+
+
+def _run_fold(x, head):
+    """The sums of a stream's runs, each at its run's last slot and
+    zero at every other. A run begins at a slot with ``head`` set (slot
+    0 is one), and its sum is the left fold ((0 + x[a]) + x[a + 1]) +
+    .. of its slots, float32 additions one after another as a
+    slot-wise scatter makes them. A long run is pieces, each summed so:
+    one begins every _STAGE_FOLD slots from the run's first, but for a
+    last piece of under _FOLD_TILE slots, which stays with the one
+    before it. So a piece's last slot is the first of its run in its
+    tile of _FOLD_TILE slots or lies later, and no tile holds two: the
+    scatters are handed a long run's pieces one an update, in the
+    stream's order (_scatter_runs: run 0 of their tiles).
+
+    The stream is laid a stretch of _STAGE_FOLD slots a COLUMN, and a
+    ``scan`` goes down the rows: a step is one addition in every column
+    at once, so the stream's length is the vector's and _STAGE_FOLD the
+    steps. The columns lie over sublanes and lanes, a step a slab of
+    whole tiles (two scans of 512 rows over the 20.97M slots of a
+    fleet-wide request: 2.3 ms on a v5e so, 7.0 ms a row of the [rows,
+    columns] matrix a step, which is one sublane of every tile), and a
+    turn of a scan takes _FOLD_STEPS rows (a turn is device operations
+    of its own, which a traced run's profile pays for one by one).
+    Where a long run is cut follows from the column its first slot
+    lies in, carried forward over the columns it fills (a cummax over
+    the columns; over the slots it took the TPU 9 ms, and its compiler
+    10 s). A column then holds a piece's first slot or lies whole in a
+    piece that began in the column before, so what a column ends with
+    is right after two scans, each begun with what the columns before
+    ended the last with, and every slot after a third."""
+    size = x.shape[0]
+    rows = min(_STAGE_FOLD, -(-size // _FOLD_STEPS) * _FOLD_STEPS)
+    cols = -(-size // (rows * _LANES)) * _LANES
+    pad = cols * rows - size
+    x = jnp.pad(x, (0, pad)).reshape(cols, rows).T
+    head = jnp.pad(head, (0, pad), constant_values=True).reshape(cols, rows).T
+    # Of the run a column begins in: the slots since its first, modulo
+    # the rows (from the last head of the column before or, where that
+    # holds none, what that one began at), and the row after its last
+    # (past the rows: in the column after, or further). Column 0 begins
+    # with a head, which also ends the last column's run for the rolls.
+    row = jnp.arange(rows, dtype=jnp.int32)[:, None]
+    first = jnp.min(jnp.where(head, row, rows), axis=0)
+    latest = jnp.max(jnp.where(head, row, -1), axis=0)
+    at = jnp.arange(cols, dtype=jnp.int32) * rows
+    since = jax.lax.cummax(
+        jnp.where(latest < 0, -1, at + (rows - latest) % rows), axis=0)
+    cut = (rows - jnp.roll(since, 1) % rows) % rows
+    after = jnp.where(first < rows, first, rows + jnp.roll(first, -1))
+    head = head | ((row == cut) & (row < first)
+                   & (after - cut >= _FOLD_TILE))
+
+    def steps(acc, slots):
+        sums = []
+        for x_row, head_row in zip(*slots):
+            acc = jnp.where(head_row, 0.0, acc) + x_row
+            sums.append(acc)
+        return acc, jnp.stack(sums)
+
+    def ends(acc, slots):
+        return steps(acc, slots)[0], None
+
+    slots = tuple(a.reshape(-1, _FOLD_STEPS, cols // _LANES, _LANES)
+                  for a in (x, head))
+    acc = _varying(jnp.zeros(slots[0].shape[2:], x.dtype), x)
+    for turn in (ends, ends, steps):
+        acc, out = jax.lax.scan(
+            turn, jnp.roll(acc.reshape(-1), 1).reshape(acc.shape), slots)
+    out, head = (a.reshape(rows, cols).T.reshape(-1)[:size]
+                 for a in (out, head))
+    return jnp.where(jnp.concatenate([head[1:], jnp.ones(1, bool)]),
+                     out, 0.0)
+
+
+def _run_moments(vals, seg, valid, num_segments, extra, need):
+    """_segment_moments' statistics of a packed stream, its runs of
+    equal segment id reduced before they are scattered (_scatter_runs,
+    the routine of window.chunk_fold): what _series_stage takes them
+    by. The stream is taken a block of at most _STAGE_BLOCK slots a
+    turn of a ``fori_loop``; a length that is not whole tiles or blocks
+    (the quarter-octave ladder's 320 and 448, a dense leg's K rows, a
+    test's 16) is padded inside the program with slots of the last
+    segment, which the caller keeps for its trash.
+
+    count, min and max are the slot-wise scatters' bits. A float32 sum
+    (``sum``, and ``m2``, the second pass, centered on the segment
+    means) is a left fold of a run's valid slots in the order they lie
+    (_run_fold; a long run in pieces of _STAGE_FOLD slots), and the
+    scatters add each fold at its last slot and zero at every other: a
+    run sums to the same bits wherever in a stream, a tile or a block
+    it lies, so two plans that lay a (series, bucket) as the same run
+    of points give the same answer bit for bit, and a run of one piece
+    the slot-wise scatter's. A segment in several runs is their sums
+    added as the turns come to them.
+    ``extra`` (offsets in a bucket: whole numbers, exact while a sum
+    stays under 2**24) is summed a tile at a time.
+
+    Returns _segment_moments' tuple (un-needed statistics None), then
+    the ``extra`` sum where ``extra`` is given, and last the updates
+    each scatter of the first pass was handed (int32)."""
+    dump = num_segments - 1
+    size = max(seg.shape[0], 1)
+    tile = min(_FOLD_TILE, size)
+    # Equal blocks of whole tiles, none over _STAGE_BLOCK: the padding
+    # is under a tile a block (a tail block padded to _STAGE_BLOCK
+    # would cost a stream in no order a block's updates for nothing).
+    blocks = -(-size // _STAGE_BLOCK)
+    block = -(-size // (blocks * tile)) * tile
+    pad = blocks * block - seg.shape[0]
+    feeds = {"v": vals, "ok": valid, "seg": seg}
+    if extra is not None:
+        feeds["extra"] = extra
+    if pad:
+        feeds = {k: jnp.pad(a, (0, pad),
+                            constant_values=dump if k == "seg" else 0)
+                 for k, a in feeds.items()}
+    v, ok, seg = feeds["v"], feeds["ok"], feeds["seg"]
+    # A run for the folds: slots of one segment in a row, every invalid
+    # slot one of its own (it adds nothing, and the trash segment's
+    # stretches need no fold).
+    head = ~ok | jnp.concatenate([jnp.ones(1, bool), seg[1:] != seg[:-1]])
+
+    def folded(x):
+        return _run_fold(jnp.where(ok, x, 0.0), head)
+
+    def start(fill, shape=(num_segments,), dtype=jnp.float32):
+        return _varying(jnp.full(shape, fill, dtype), seg)
+
+    def sweep(part, total):
+        cols = dict(feeds) if total is None else dict(feeds, total=total)
+
+        def turn(i, carry):
+            part, handed = carry
+            cut = {k: jax.lax.dynamic_slice_in_dim(a, i * block, block)
+                   for k, a in cols.items()}
+            part, n = _scatter_runs(part, cut["v"], cut["ok"], cut["seg"],
+                                    dump, cut.get("extra"),
+                                    cut.get("total"))
+            return part, handed + n
+        return jax.lax.fori_loop(0, blocks, turn,
+                                 (part, start(0, (), jnp.int32)))
+
+    zeros = start(0.0)
+    part = {"count": zeros}
+    summed = "sum" in need or "m2" in need
+    if summed:
+        part["sum"] = zeros
+    if "min" in need:
+        part["min"] = start(_POS_INF)
+    if "max" in need:
+        part["max"] = start(_NEG_INF)
+    if extra is not None:
+        part["extra"] = zeros
+    part, handed = sweep(part, folded(v) if summed else None)
+    m2 = None
+    if "m2" in need:
+        d = v - (part["sum"] / jnp.maximum(part["count"], 1.0))[seg]
+        m2 = sweep({"sum": zeros}, folded(d * d))[0]["sum"]
+    out = (part["count"], part.get("sum"), m2, part.get("min"),
+           part.get("max"))
+    if extra is not None:
+        out += (part["extra"],)
+    return out + (handed,)
 
 
 # The chunks one call of window.chunk_fold takes: a stage folds its
@@ -851,42 +1060,43 @@ def _series_stage(ts, vals, sid, valid, *, num_series, num_buckets,
                   interval, agg_down, with_ts: bool):
     """Shared per-(series, bucket) downsample stage: one fused segment
     reduction producing series_values/series_mask [S, B] (and, when
-    ``with_ts``, per-bucket integer-mean member timestamps).
+    ``with_ts``, per-bucket integer-mean member timestamps), and the
+    updates its scatters were handed (an int32 device scalar:
+    tsd.query.stage.updates).
 
     Negative result from before PR 1 (its record is gone with the
     transport it was taken through): a scatter-free formulation for
     (sid, ts)-sorted columns — int32/fixed-point-int64 prefix sums +
     searchsorted of the [S*B] grid — lost to the XLA scatter on TPU and
     CPU alike, because the grid-side searchsorted costs more than the
-    scatter it replaces. The scatter path stays; a second attempt has
-    to win in the benchmark's cells. The resident fold's run reduction
-    (_scatter_runs, PR 39) is not yet in this plan: _segment_moments
-    scatters the packed stream a point at a time."""
+    scatter it replaces. The scatter path stays, and since PR 46 it is
+    handed a stream's RUNS of equal (series, bucket) and not its slots
+    (_run_moments, the resident fold's _scatter_runs): every packer
+    lays a series' points together in time order, so an hourly bucket
+    is 360 slots in a row, and the scatters of a fleet-wide 12 h
+    request (20.97M slots) get a sixteenth of the updates. A run's
+    float32 sum is its slots added one after another as before
+    (_run_fold), so where a plan lays the run moves no bit of it."""
     bucket = jnp.clip(ts // interval, 0, num_buckets - 1)
     seg = jnp.where(valid, sid * num_buckets + bucket,
                     num_series * num_buckets)
     nseg = num_series * num_buckets + 1  # +1 trash segment for padding
-    need = _needs(agg_down)
-    if with_ts:
-        # Mean member timestamp rides the same reduction pass, relative
-        # to bucket start for f32 exactness.
-        rel = (ts - bucket * interval).astype(jnp.float32)
-        count, total, sumsq, mn, mx, rel_sum = _segment_moments(
-            vals, seg, valid, nseg, extra=rel, need=need)
-    else:
-        count, total, sumsq, mn, mx = _segment_moments(
-            vals, seg, valid, nseg, need=need)
+    # Mean member timestamp rides the same reduction pass, relative
+    # to bucket start for f32 exactness.
+    rel = (ts - bucket * interval).astype(jnp.float32) if with_ts else None
+    count, total, sumsq, mn, mx, *rel_sum, handed = _run_moments(
+        vals, seg, valid, nseg, extra=rel, need=_needs(agg_down))
     per = _finish(agg_down, count, total, sumsq, mn, mx)
     shape = (num_series, num_buckets)
     series_values = per[:-1].reshape(shape)
     series_mask = count[:-1].reshape(shape) > 0
     if not with_ts:
-        return series_values, series_mask, None
-    mean_rel = jnp.floor(rel_sum / jnp.maximum(count, 1.0))
+        return series_values, series_mask, None, handed
+    mean_rel = jnp.floor(rel_sum[0] / jnp.maximum(count, 1.0))
     bucket_starts = (jnp.arange(num_buckets, dtype=jnp.int32) * interval)
     series_ts = bucket_starts[None, :] + mean_rel[:-1].reshape(shape) \
         .astype(jnp.int32)
-    return series_values, series_mask, series_ts
+    return series_values, series_mask, series_ts, handed
 
 @jit_plan(ExecPlan(
     name="downsample.group", axis="series",
@@ -914,7 +1124,9 @@ def downsample_group(ts: jnp.ndarray, vals: jnp.ndarray, sid: jnp.ndarray,
       series_ts     [S, B] int32 mean member-timestamp offset per bucket,
       series_mask   [S, B] bool bucket-nonempty mask,
       group_values  [B] cross-series aggregate (over nonempty buckets),
-      group_mask    [B] bool.
+      group_mask    [B] bool,
+      handed        int32 scalar, the updates the series stage's
+        scatters were handed (_series_stage).
 
     Semantics parity: aligned buckets + integer-mean member timestamps =
     oracle.downsample(mode='aligned', bucket_ts='avg'); cross-series
@@ -928,7 +1140,7 @@ def downsample_group(ts: jnp.ndarray, vals: jnp.ndarray, sid: jnp.ndarray,
     nonempty bucket yields none), and the group stage step-fills instead
     of lerping — all still one fused computation.
     """
-    series_values, series_mask, series_ts = _series_stage(
+    series_values, series_mask, series_ts, handed = _series_stage(
         ts, vals, sid, valid, num_series=num_series,
         num_buckets=num_buckets, interval=interval, agg_down=agg_down,
         with_ts=True)
@@ -965,6 +1177,7 @@ def downsample_group(ts: jnp.ndarray, vals: jnp.ndarray, sid: jnp.ndarray,
         # grid); filled contributions never create grid points. With rate,
         # "real" means a real rate (first points emit none).
         "group_mask": series_mask.any(axis=0),
+        "handed": handed,
     }
 
 
@@ -992,10 +1205,11 @@ def downsample_multigroup(ts: jnp.ndarray, vals: jnp.ndarray,
 
     Args as downsample_group, plus group_of_sid [S] int32 in
     [0, num_groups). Returns dict with group_values / group_mask shaped
-    [G, B]. Semantics per group are identical to calling
-    downsample_group on that group's series alone.
+    [G, B] (and ``handed``, as downsample_group). Semantics per group
+    are identical to calling downsample_group on that group's series
+    alone.
     """
-    series_values, series_mask, _ = _series_stage(
+    series_values, series_mask, _, handed = _series_stage(
         ts, vals, sid, valid, num_series=num_series,
         num_buckets=num_buckets, interval=interval, agg_down=agg_down,
         with_ts=False)
@@ -1023,6 +1237,7 @@ def downsample_multigroup(ts: jnp.ndarray, vals: jnp.ndarray,
         "series_values": series_values,
         "series_mask": series_mask,
         "presence": presence,
+        "handed": handed,
     }
 
 
@@ -1173,9 +1388,9 @@ def downsample_multigroup_quantile(
     stage, optional bucket rates, gap/step fill between each series'
     real buckets, then the quantile across member series' contributions.
     Returns dict with group_values [G, B] (quantile ``q[0]``),
-    group_mask [G, B], series_values, series_mask.
+    group_mask [G, B], series_values, series_mask, handed.
     """
-    series_values, series_mask, _ = _series_stage(
+    series_values, series_mask, _, handed = _series_stage(
         ts, vals, sid, valid, num_series=num_series,
         num_buckets=num_buckets, interval=interval, agg_down=agg_down,
         with_ts=False)
@@ -1194,6 +1409,7 @@ def downsample_multigroup_quantile(
         "group_mask": real,
         "series_values": series_values,
         "series_mask": series_mask,
+        "handed": handed,
     }
 
 

@@ -118,36 +118,54 @@ _C_GATHER_BYTES = _metrics.counter("mesh.resident.gather.bytes")
 # one a run of equal (series, bucket) and not one a slot), beside
 # devwindow.fold.slots.visited: their ratio is what the run reduction
 # left of the scatters' work. A stage's count is a device scalar its
-# folds carried; it waits in _FOLD_HANDED until the stats are read (or
-# _FOLD_HANDED_MAX have gathered), so no sub-query pays a transfer for
+# folds carried; it waits in a _Handed until the stats are read (or
+# _HANDED_MAX have gathered), so no sub-query pays a transfer for
 # it. A gauge over a running total, and not a counter, for that reason.
-_FOLD_HANDED: collections.deque = collections.deque()
-_FOLD_HANDED_MAX = 512
-_fold_updates_lock = threading.Lock()
-_fold_updates_total = 0
+_HANDED_MAX = 512
 
 
-def _fold_updates() -> int:
-    """The updates handed over since boot: the stages' counts that were
-    still on the device fetched and added to the total."""
-    global _fold_updates_total
-    with _fold_updates_lock:
-        # One drainer at a time, and the others only append.
-        handed = [_FOLD_HANDED.popleft() for _ in range(len(_FOLD_HANDED))]
-        if handed:
-            _fold_updates_total += sum(map(int, jax.device_get(handed)))
-        return _fold_updates_total
+class _Handed:
+    """The running total of the counts that stages left on the device."""
+
+    def __init__(self):
+        self._waiting: collections.deque = collections.deque()
+        self._lock = threading.Lock()
+        self._total = 0
+
+    def total(self) -> int:
+        """The updates handed over since boot: the stages' counts that
+        were still on the device fetched and added to the total."""
+        with self._lock:
+            # One drainer at a time, and the others only append.
+            handed = [self._waiting.popleft()
+                      for _ in range(len(self._waiting))]
+            if handed:
+                self._total += sum(map(int, jax.device_get(handed)))
+            return self._total
+
+    def add(self, handed) -> None:
+        """Keep one stage's count for the next reading of the stats."""
+        self._waiting.append(handed)
+        if len(self._waiting) > _HANDED_MAX:
+            self.total()
 
 
-def _fold_handed(handed) -> None:
-    """Keep one stage's count (window_series_stage_chunks' last output)
-    for the next reading of the stats."""
-    _FOLD_HANDED.append(handed)
-    if len(_FOLD_HANDED) > _FOLD_HANDED_MAX:
-        _fold_updates()
+_FOLD_HANDED = _Handed()
+_metrics.gauge("devwindow.fold.updates", _FOLD_HANDED.total)
+# The same of the plans that read past the horizon: the slots of the
+# streams their stages were given (the raw plan's packed stream, the
+# fused plan's whole blocks or matched points, padding and all) and the
+# updates kernels._series_stage's scatters were handed for them.
+_C_STAGE_SLOTS = _metrics.counter("query.stage.slots")
+_STAGE_HANDED = _Handed()
+_metrics.gauge("query.stage.updates", _STAGE_HANDED.total)
 
 
-_metrics.gauge("devwindow.fold.updates", _fold_updates)
+def _stage_handed(handed, slots: int) -> None:
+    """Count one kernels._series_stage: ``handed`` its device scalar,
+    ``slots`` the length of the stream it was given."""
+    _C_STAGE_SLOTS.inc(slots)
+    _STAGE_HANDED.add(handed)
 
 
 # What the raw plan read from storage and handed to its kernels: rows
@@ -1363,7 +1381,7 @@ class QueryExecutor:
                             interval=interval, agg_down=dsagg,
                             blocks=cols.blocks, block=cols.block,
                             **rate_kw)
-                        _fold_handed(grids[5])
+                        _FOLD_HANDED.add(grids[5])
                 except Exception as e:
                     # A near-HBM window can still OOM building the stage
                     # grids; degrade to the storage scan (the
@@ -1532,7 +1550,7 @@ class QueryExecutor:
                         device=_device_id(window.device),
                         series=len(sc.series_keys),
                         chunks=sum(len(b) > 0 for b in sc.blocks))
-            _fold_handed(grids[5])
+            _FOLD_HANDED.add(grids[5])
             parts.append(grids[:5])
         _C_STAGE_SHARDS.inc(len(parts))
         # What brings the shards' grids to the combine device (the
@@ -1904,6 +1922,12 @@ class QueryExecutor:
         scalars = (lo32, hi32, shift32,
                    np.float32(rate_kw["counter_max"]),
                    np.float32(rate_kw["reset_value"]))
+
+        def counted(out, slots):
+            *grids, handed = out
+            _stage_handed(handed, slots)
+            return grids + [None]
+
         if self.mesh is None:
             dc = self._devcache
             if dc is None:
@@ -1913,14 +1937,18 @@ class QueryExecutor:
             def run(qd, vals, slots):
                 inputs = (dc.point_inputs if selective
                           else dc.record_inputs)(src, slots, S_pad)
-                return (_ckernels.slab_stage_sel if selective
-                        else _ckernels.slab_stage_rows)(
-                    qd, vals, *inputs, *scalars, **statics)
+                # The stream: a matched point each, or every point of
+                # the rows gathered.
+                return counted(
+                    (_ckernels.slab_stage_sel if selective
+                     else _ckernels.slab_stage_rows)(
+                        qd, vals, *inputs, *scalars, **statics),
+                    len(inputs[0]) * (1 if selective else dc.P_BLK))
 
-            out = dc.stage(src, run)
-            if out is None:
+            stage = dc.stage(src, run)
+            if stage is None:
                 raise _fused.Decline("oversize")
-            return list(out) + [None], "sel" if selective else "rows"
+            return stage, "sel" if selective else "rows"
         # The plane's pjit-preferred leg: the point stream (whole
         # compressed blocks) shards over the mesh, payloads and the
         # [S, B] outputs replicate (compress/kernels.py
@@ -1958,13 +1986,13 @@ class QueryExecutor:
         if P_pad % int(self.mesh.devices.size) == 0:
             fused_fn = _ckernels.fused_block_stage_mesh(
                 self.mesh, vkind=src.kind, **statics)
-            return list(fused_fn(*args, *scalars)) + [None], "mesh"
+            return counted(fused_fn(*args, *scalars), P_pad), "mesh"
         _count_decline("mesh-indivisible")
         out = _ckernels.fused_block_stage(
             *args, *scalars[:3], **statics, vkind=src.kind,
             counter_max=rate_kw["counter_max"],
             reset_value=rate_kw["reset_value"])
-        return list(out) + [None], "bytes"
+        return counted(out, P_pad), "bytes"
 
     # -- CPU oracle backend -------------------------------------------
 
@@ -2110,6 +2138,7 @@ class QueryExecutor:
                 agg_group=(spec.aggregator if agg.kind == "moment"
                            else "count"),
                 **self._rate_kw(spec))
+            _stage_handed(out["handed"], len(rel))
             gmask, values = out["group_mask"], out["group_values"]
             if agg.kind == "percentile":
                 # series_values/series_mask are the post-rate per-bucket
@@ -2275,6 +2304,7 @@ class QueryExecutor:
                         num_buckets=num_buckets, interval=interval,
                         agg_down=dsagg, agg_group=spec.aggregator,
                         **self._rate_kw(spec))
+                _stage_handed(out["handed"], len(rel))
             gm, gv = self._aggregate_fetch(out["group_mask"],
                                            out["group_values"])
         with obs_trace.span("aggregate.results"):

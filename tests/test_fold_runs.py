@@ -229,24 +229,27 @@ def test_the_stats_count_the_updates_of_the_stages_built(tmp_path,
     assert stat(names[0]) - before[0] == updates
 
 
-def test_no_count_is_lost_between_stages_and_readers(monkeypatch):
+@pytest.mark.parametrize("tally", ["_FOLD_HANDED", "_STAGE_HANDED"])
+def test_no_count_is_lost_between_stages_and_readers(monkeypatch, tally):
     """Stages hand their counts over from several threads while others
-    read the stats: every count is added once."""
+    read the stats: every count is added once (the resident plan's
+    folds, and the stages of the plans that read past the horizon)."""
     import sys
     import threading
 
     from opentsdb_tpu.query import executor
-    monkeypatch.setattr(executor, "_FOLD_HANDED_MAX", 16)
-    before = executor._fold_updates()
+    monkeypatch.setattr(executor, "_HANDED_MAX", 16)
+    tally = getattr(executor, tally)
+    before = tally.total()
     one = np.int32(1)
 
     def stages():
         for _ in range(400):
-            executor._fold_handed(one)
+            tally.add(one)
 
     def reader():
         for _ in range(200):
-            executor._fold_updates()
+            tally.total()
 
     threads = [threading.Thread(target=stages) for _ in range(8)] + [
         threading.Thread(target=reader) for _ in range(2)]
@@ -260,4 +263,4 @@ def test_no_count_is_lost_between_stages_and_readers(monkeypatch):
     finally:
         sys.setswitchinterval(interval)
     assert not any(t.is_alive() for t in threads)
-    assert executor._fold_updates() - before == 8 * 400
+    assert tally.total() - before == 8 * 400

@@ -142,3 +142,32 @@ def test_the_dense_stage_compiles_at_the_fleet_wide_gather(one_chip):
         of((k, R_BLK), jnp.bool_), *scalars, num_series=SERIES,
         num_buckets=16, interval=3600, agg_down="avg").compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
+# The raw plan's fleet-wide program at the shape cpu4k-13h.hist-12h runs
+# it: 12 h of 4,000 hosts are 17.3M points in a stream of 20.97M slots
+# (5 << 22, the quarter-octave ladder), 320 blocks of kernels._STAGE_BLOCK.
+STREAM = 5 << 22
+
+
+@pytest.mark.parametrize("agg", ["avg", "max", "dev"])
+def test_the_raw_stage_takes_its_stream_a_block_a_turn(one_chip, agg):
+    def of(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    text = kernels.downsample_multigroup.lower(
+        of((STREAM,), jnp.int32), of((STREAM,), jnp.float32),
+        of((STREAM,), jnp.int32), of((STREAM,), jnp.bool_),
+        of((SERIES,), jnp.int32), num_series=SERIES, num_groups=SERIES,
+        num_buckets=16, interval=3600, agg_down=agg,
+        agg_group=agg).compile().as_text()
+    # As the fold's turn: nothing of [tile, runs, tiles] is written.
+    # What is as large or larger is a column of the stream or a part of
+    # one, the runs' left folds among them (kernels._run_fold, which
+    # pads the stream to whole columns of its own).
+    cube = kernels._STAGE_BLOCK * kernels._FOLD_RUNS
+    big = [(op, n) for op, n in written(text)
+           if n == cube
+           or n > STREAM + kernels._STAGE_FOLD * kernels._LANES]
+    assert not big, big
+    # Its trip counts are data: loops, and no branch.
+    assert " while(" in text and " conditional(" not in text
