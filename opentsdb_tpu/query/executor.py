@@ -28,15 +28,12 @@ Un-downsampled queries keep the exact 1.1 union-grid semantics.
 
 from __future__ import annotations
 
-import collections
-import concurrent.futures
 import itertools
 import re
 import threading
 import weakref
 from typing import NamedTuple
 
-import jax
 import numpy as np
 
 from opentsdb_tpu.core import codec
@@ -48,125 +45,14 @@ from opentsdb_tpu.compress.devcache import pad_fine as _pad_fine
 from opentsdb_tpu.obs import trace as obs_trace
 from opentsdb_tpu.obs.registry import METRICS as _metrics
 from opentsdb_tpu.ops import kernels, oracle, sketches
+from opentsdb_tpu.query import grid as qgrid
 from opentsdb_tpu.query.aggregators import Aggregators
+from opentsdb_tpu.query.fused import FusedPlan
+from opentsdb_tpu.query.grid import (QueryResult, _filter_key, _pad_size,
+                                     group_tags)
+from opentsdb_tpu.query.resident import ResidentPlan
 from opentsdb_tpu.storage.sstable import series_hash
 from opentsdb_tpu.utils.lru import LRUCache
-
-# Fused decode-plus-aggregate serving off TSST4 blocks (compress/):
-# wall time of the gather + kernel dispatch per served query.
-_M_FUSED = _metrics.timer("compress.fused_agg")
-
-# Fused coverage accounting: attempts = queries past the fused gates
-# (the fused-eligible battery), served = answered plan:"fused"; the
-# gauge is their ratio, what /stats and /metrics expose. Every decline
-# between the two increments compress.fused.decline{reason=} — the
-# no-silent-declines contract is these three instruments agreeing.
-_C_FUSED_ATTEMPT = _metrics.counter("compress.fused.attempt")
-_C_FUSED_SERVED = _metrics.counter("compress.fused.served")
-# What the gathers of the fused plan met: the points of the blocks they
-# touched, of those the points of matching in-range records, the
-# blocks' payload bytes (the larger stream of each), and the bytes the
-# byte-stream leg sent to the device (the block cache counts
-# its own fills: compress.devcache.uploaded_bytes).
-_C_FUSED_POINTS = _metrics.counter("compress.fused.points")
-_C_FUSED_MATCHED = _metrics.counter("compress.fused.matched_points")
-_C_FUSED_PAYLOAD = _metrics.counter("compress.fused.payload_bytes")
-_C_FUSED_UPLOADED = _metrics.counter("compress.fused.uploaded_bytes")
-_metrics.gauge(
-    "compress.fused.coverage",
-    lambda: (_C_FUSED_SERVED.value / _C_FUSED_ATTEMPT.value
-             if _C_FUSED_ATTEMPT.value else 0.0))
-
-# The resident plan's stage cache (_dw_stage_cache): a miss builds a
-# stage, which is the device's whole cost of a resident sub-query;
-# evicted = stages dropped by hand (a dead data version, a device OOM),
-# not the LRU's own turnover at its cap.
-_C_STAGE_HIT = _metrics.counter("devwindow.stage.hit")
-_C_STAGE_MISS = _metrics.counter("devwindow.stage.miss")
-_C_STAGE_EVICTED = _metrics.counter("devwindow.stage.evicted")
-# What the stages built were handed, in slots of the resident chunks
-# (padding included): visited = the blocks the zone maps let through to
-# window.chunk_fold, skipped = the rest. Together they are the slots
-# resident a stage built.
-_C_FOLD_VISITED = _metrics.counter("devwindow.fold.slots.visited")
-_C_FOLD_SKIPPED = _metrics.counter("devwindow.fold.slots.skipped")
-# Stages built with and without a cut by the matched series (their sum
-# is devwindow.stage.miss): how often the series dimension of the zone
-# maps engages.
-_C_FOLD_NARROWED = _metrics.counter("devwindow.fold.stages.narrowed")
-_C_FOLD_WHOLE = _metrics.counter("devwindow.fold.stages.whole")
-# window.chunk_fold calls: a stage built issues one for every group of
-# up to kernels._FOLD_GROUP chunks of one shape class its selection
-# picked a block of (kernels.fold_groups), so dispatches /
-# devwindow.stage.miss is the fold calls a stage, which grow with the
-# span of the range, a group at a time, where the slots visited need
-# not. stage.programs is every device program a stage build issued
-# from Python: the start (the accumulators), each fold call and the
-# finish, so 2 + the calls (a shard of the sharded window: its own
-# start and finish), and each one a place where the stage's thread
-# lets the interpreter lock go and has to win it back.
-_C_FOLD_DISPATCHES = _metrics.counter("devwindow.fold.dispatches")
-_C_STAGE_PROGRAMS = _metrics.counter("devwindow.stage.programs")
-# The sharded window's stages (storage/devshard.py): the shards a stage
-# built was folded on (a window_series_stage_chunks call each, so
-# shards / stage.miss is the fan-out: every shard of the metric where
-# no shard is dropped), and the bytes of the shards' grids that went
-# from their device to the combine device.
-_C_STAGE_SHARDS = _metrics.counter("devwindow.stage.shards")
-_C_GATHER_BYTES = _metrics.counter("mesh.resident.gather.bytes")
-# The updates the folds' scatters were handed (kernels._scatter_runs:
-# one a run of equal (series, bucket) and not one a slot), beside
-# devwindow.fold.slots.visited: their ratio is what the run reduction
-# left of the scatters' work. A stage's count is a device scalar its
-# folds carried; it waits in a _Handed until the stats are read (or
-# _HANDED_MAX have gathered), so no sub-query pays a transfer for
-# it. A gauge over a running total, and not a counter, for that reason.
-_HANDED_MAX = 512
-
-
-class _Handed:
-    """The running total of the counts that stages left on the device."""
-
-    def __init__(self):
-        self._waiting: collections.deque = collections.deque()
-        self._lock = threading.Lock()
-        self._total = 0
-
-    def total(self) -> int:
-        """The updates handed over since boot: the stages' counts that
-        were still on the device fetched and added to the total."""
-        with self._lock:
-            # One drainer at a time, and the others only append.
-            handed = [self._waiting.popleft()
-                      for _ in range(len(self._waiting))]
-            if handed:
-                self._total += sum(map(int, jax.device_get(handed)))
-            return self._total
-
-    def add(self, handed) -> None:
-        """Keep one stage's count for the next reading of the stats."""
-        self._waiting.append(handed)
-        if len(self._waiting) > _HANDED_MAX:
-            self.total()
-
-
-_FOLD_HANDED = _Handed()
-_metrics.gauge("devwindow.fold.updates", _FOLD_HANDED.total)
-# The same of the plans that read past the horizon: the slots of the
-# streams their stages were given (the raw plan's packed stream, the
-# fused plan's whole blocks or matched points, padding and all) and the
-# updates kernels._series_stage's scatters were handed for them.
-_C_STAGE_SLOTS = _metrics.counter("query.stage.slots")
-_STAGE_HANDED = _Handed()
-_metrics.gauge("query.stage.updates", _STAGE_HANDED.total)
-
-
-def _stage_handed(handed, slots: int) -> None:
-    """Count one kernels._series_stage: ``handed`` its device scalar,
-    ``slots`` the length of the stream it was given."""
-    _C_STAGE_SLOTS.inc(slots)
-    _STAGE_HANDED.add(handed)
-
 
 # What the raw plan read from storage and handed to its kernels: rows
 # decoded by the scans of raw sub-queries (a fragment-cache hit decodes
@@ -179,21 +65,6 @@ _C_RAW_POINTS = _metrics.counter("query.raw.points")
 # rollup planner's per-bucket records, an expert batch's groups).
 _C_PACK_FLAT = _metrics.counter("query.pack.flat_points")
 _C_PACK_SPANS = _metrics.counter("query.pack.span_points")
-# The groups a grid plan (resident, fused) answered, by where their
-# labels came from: kept = taken from the plan that made the groups
-# (_GridGroups.labels), computed = worked out on the request (the
-# first answer of a plan builds its labels, and a group with a member
-# that has no point in range is always labelled anew, over its live
-# members). kept / (kept + computed) says how often the plans are
-# still held when their groups are asked for again.
-_C_LABELS_KEPT = _metrics.counter("query.results.labels.kept")
-_C_LABELS_COMPUTED = _metrics.counter("query.results.labels.computed")
-
-
-def _count_decline(reason: str) -> None:
-    _metrics.counter("compress.fused.decline", {"reason": reason}).inc()
-
-
 # One fragment cache PER STORE, shared by every QueryExecutor over it
 # (the ROADMAP cross-executor follow-on): CLI one-shot executors, the
 # server's executor, and test harnesses all warm the same LRU, so a
@@ -235,14 +106,6 @@ class QuerySpec(NamedTuple):
     counter: bool = False           # rate rollover correction
     counter_max: float = float(2**64)
     reset_value: float | None = None
-
-
-class QueryResult(NamedTuple):
-    metric: str
-    tags: dict[str, str]
-    aggregated_tags: list[str]
-    timestamps: np.ndarray          # int64 epoch seconds
-    values: np.ndarray              # float64
 
 
 class _Span(NamedTuple):
@@ -458,41 +321,6 @@ class QueryExecutor:
         # cost-bounded in total cached series (an unfiltered hint for
         # a high-cardinality metric is a multi-MB array and key list).
         self._ident_cache = LRUCache(256, max_cost=1 << 21)
-        # Devwindow caches (previously ad-hoc dicts with wholesale
-        # clear-at-cap eviction).
-        self._dw_mask_cache = LRUCache(128)
-        self._dw_plan_cache = LRUCache(128)
-        self._dw_stage_cache = LRUCache(4)
-        # The sharded window's stages: (device, programs' statics, the
-        # window's chunk shape classes) whose programs a shard's device
-        # has compiled (_dw_warm_shards). One forgotten is warmed again,
-        # from jit's own cache.
-        self._dw_shard_warm = LRUCache(256)
-        # Fused-block stage cache (compress/): device grids keyed by
-        # the generation set + range + downsample plan. Entries pin
-        # their source SSTable objects so id() reuse can't alias a
-        # dropped generation; eligibility (dirty range, format mix) is
-        # re-checked per query — only the decode+stage compute caches.
-        self._fused_stage_cache = LRUCache(4)
-        # What a gather of the fused plan would else work out anew: the
-        # selector's verdict a series key, by (metric, filter), and a
-        # series' tags by name.
-        self._fused_sel_memo = LRUCache(64)
-        self._fused_named: dict[bytes, dict[str, str]] = {}
-        # A gather's groups as its answer takes them (_GridGroups, the
-        # labels kept), by what the groups are a function of: (metric,
-        # filter) as the key, the gather's series directory in the
-        # value, as the generation is in _dw_plan_cache's.
-        self._fused_plan_cache = LRUCache(64)
-        # Device-side decoded-block cache (compress/devcache.py):
-        # per-block query-independent columns stay resident on device,
-        # bounded by total cached points. Keyed by SSTable OBJECT +
-        # block index (entries pin their generation against id reuse).
-        dbp = int(cfg.devblock_points)
-        self._devcache = None
-        if dbp > 0 and self.backend != "cpu":
-            from opentsdb_tpu.compress.devcache import DeviceBlockCache
-            self._devcache = DeviceBlockCache(dbp)
         # Approx-serving rail cache (sketch/serving.py): per-series
         # (bucket_ts, est, lo, hi) rails for CLEAN fully-window-
         # covered percentile ranges, revalidated against the tier's
@@ -501,6 +329,14 @@ class QueryExecutor:
         self.qcache_hits = 0
         self.qcache_misses = 0
         self.qcache_bypasses = 0
+        # The plans _run_planned tries, in order, before the raw scan:
+        # each one object that owns its caches, counters and spans
+        # (the rollup step sits between them, _run_planned).
+        self.resident = ResidentPlan(tsdb, self.backend, mesh,
+                                     self._tag_filters)
+        self.fused = FusedPlan(tsdb, self.backend, mesh,
+                               self._tag_filters, self._series_hint)
+        self.plans = [self.resident, self.fused]
 
     # ------------------------------------------------------------------
     # Planning: scan + span assembly + grouping
@@ -789,24 +625,6 @@ class QueryExecutor:
             t["qcache_bypass"] = t.get("qcache_bypass", 0) + n_byp
         return blocks
 
-    @staticmethod
-    def _group_tags(members: list[dict[str, str]]):
-        """Intersection tags + aggregated (differing) tag names of a
-        group, from its series' named tags.
-
-        Parity: reference SpanGroup.computeTags (:149-173)."""
-        common = dict(members[0])
-        keys = set(members[0])
-        for tags in members[1:]:
-            keys &= set(tags)
-            for k in list(common):
-                if tags.get(k) != common[k]:
-                    del common[k]
-        common = {k: v for k, v in common.items() if k in keys}
-        aggregated = sorted(
-            {k for tags in members for k in tags} - set(common))
-        return common, aggregated
-
     # ------------------------------------------------------------------
     # Execution
     # ------------------------------------------------------------------
@@ -1011,8 +829,8 @@ class QueryExecutor:
             agg = Aggregators.get(spec.aggregator)
             for gkey in sorted(groups):
                 spans = groups[gkey]
-                rel, vals, sid, valid = self._flatten_spans(spans,
-                                                            qbase)
+                rel, vals, sid, valid = _Scan.of_spans(
+                    {(): spans}).stream(qbase)
                 qq = {"family": ("percentile"
                                  if agg.kind == "percentile"
                                  else "moment"),
@@ -1033,7 +851,7 @@ class QueryExecutor:
                 queries, self.mesh, num_series=S,
                 num_buckets=num_buckets, interval=interval)
         for (si, spans), (gv, gm) in zip(refs, got):
-            tags, aggregated = self._group_tags(
+            tags, aggregated = group_tags(
                 [sp.tags for sp in spans])
             mask = np.asarray(gm)
             grid_ts = (np.flatnonzero(mask).astype(np.int64) * interval
@@ -1064,47 +882,47 @@ class QueryExecutor:
         # the whole resolution decision INCLUDING the tier reads and
         # raw stitches it triggers (they appear as child spans), so a
         # trace's top-level children tile the query wall time.
+        def tried(plans):
+            """(Answer, label) of the first of ``plans`` that serves."""
+            for plan in plans:
+                results = plan.serve(spec, start, end, agg)
+                if results is not None:
+                    return results, plan.label
+            return None, "raw"
+
         with obs_trace.span("planner.pick") as sp:
-            dev = self._run_devwindow(spec, start, end, agg)
+            # What is materialized goes first: the plans that read no
+            # storage (the resident window), then the rollup tiers;
+            # both beat re-deriving from storage, which the plans left
+            # do (fused: exact or None) before the raw scan does.
             planned = None
-            fusedr = None
-            if dev is None:
+            results, plan = tried(p for p in self.plans if p.storage_free)
+            if results is None:
                 planned = self._plan_rollup(spec, start, end,
                                             rollup_only=rollup_only,
                                             meta_out=meta_out)
-            if dev is None and planned is None and rollup_only:
-                from opentsdb_tpu.core.errors import OverloadedError
-                raise OverloadedError(
-                    "shedding load: this query needs a raw scan "
-                    "(no eligible rollup resolution); retry shortly",
-                    retry_after=0.5, status=503)
-            if dev is None and planned is None:
-                # Fused decode-plus-aggregate off TSST4 blocks
-                # (compress/): tried after the materialized tiers
-                # (resident window, rollups beat re-deriving from
-                # storage) and before the raw scan. Exact or None.
-                fusedr = self._run_fused_blocks(spec, start, end, agg)
-            if sp is not None:
-                if dev is not None:
-                    sp.tags["plan"] = "resident"
-                elif planned is not None:
+                if planned is not None:
                     from opentsdb_tpu.rollup.tier import res_label
-                    sp.tags["plan"] = res_label(planned[2])
-                elif fusedr is not None:
-                    sp.tags["plan"] = "fused"
+                    plan = res_label(planned[2])
+                elif rollup_only:
+                    from opentsdb_tpu.core.errors import OverloadedError
+                    raise OverloadedError(
+                        "shedding load: this query needs a raw scan "
+                        "(no eligible rollup resolution); retry shortly",
+                        retry_after=0.5, status=503)
                 else:
-                    sp.tags["plan"] = "raw"
-        if dev is not None:
-            return dev, "resident", False
+                    results, plan = tried(p for p in self.plans
+                                          if not p.storage_free)
+            if sp is not None:
+                sp.tags["plan"] = plan
+        if results is not None:
+            return results, plan, False
         if planned is not None:
-            groups, spec2, res = planned
-            from opentsdb_tpu.rollup.tier import res_label
+            groups, spec2, _res = planned
             with obs_trace.span("aggregate"):
                 results = self._execute_groups(
                     spec2, _Scan.of_spans(groups), start, end)
-            return results, res_label(res), False
-        if fusedr is not None:
-            return fusedr, "fused", False
+            return results, plan, False
         import time as _time
         t0 = _time.time()
         info: dict = {}
@@ -1177,822 +995,10 @@ class QueryExecutor:
         with obs_trace.span("aggregate.results", results=len(gkeys)):
             return [QueryResult(
                         spec.metric,
-                        *self._group_tags([scan.tags[i]
+                        *group_tags([scan.tags[i]
                                            for i in scan.groups[gkey]]),
                         ts, vals)
                     for gkey, (ts, vals) in zip(gkeys, per_group)]
-
-    # -- device-resident window path ----------------------------------
-
-    def _run_devwindow(self, spec: QuerySpec, start: int, end: int,
-                       agg) -> list[QueryResult] | None:
-        """Serve the query from the device-resident hot window
-        (storage/devstore.py) when it exactly covers [start, end]: no
-        storage scan, no host->device point upload — the host only
-        filters the series directory and uploads an [S]-sized group map.
-        Returns None to fall back to the scan path (CPU backend,
-        un-downsampled queries, dirty/evicted windows, unknown UIDs,
-        out-of-int32 epochs/ranges)."""
-        dw = getattr(self.tsdb, "devwindow", None)
-        # A mesh executor serves the resident path only through the
-        # mesh-SHARDED window (devshard.py): the plain single-device
-        # window under a mesh keeps declining as before (its columns
-        # live on one device while the mesh plans expect sharding).
-        sharded = hasattr(dw, "shard_of")
-        if (dw is None or self.backend == "cpu"
-                or (self.mesh is not None and not sharded)
-                or not spec.downsample
-                or agg.kind not in ("moment", "percentile")
-                or Aggregators.get(spec.downsample[1]).kind
-                != "moment"):
-            return None
-        interval, dsagg = spec.downsample
-        qbase = start - start % interval
-        imin, imax = -(2**31), 2**31 - 1
-        # Rebased in-range timestamps span up to end - qbase; past int32
-        # they would wrap in the kernels. Checked BEFORE touching the
-        # window: dw.columns() forces a staged upload + drain, wasted on
-        # a query that can never be served from it.
-        if end - qbase > imax:
-            return None
-        from opentsdb_tpu.core.errors import NoSuchUniqueName
-        try:
-            metric_uid = self.tsdb.metrics.get_id(spec.metric)
-            exact, group_bys = self._tag_filters(spec.tags)
-        except NoSuchUniqueName:
-            return None  # scan path raises the canonical error
-        # The window serves queries from its raw chunk list (no
-        # concatenated copy — the window can approach the whole HBM);
-        # every moment family folds chunk-wise, dev included (Chan M2
-        # combination, ops/kernels._chunk_fold).
-        # From here the resident.* spans are the children of
-        # planner.pick, in order and together tiling it (README,
-        # "Observability", says what each one times).
-        with obs_trace.span("resident.columns") as sp:
-            cols = dw.chunk_columns(metric_uid, start, end)
-            if sp is not None and cols is not None:
-                chunks = _dw_chunks(cols)
-                sp.tags["chunks"] = len(chunks)
-                sp.tags["points"] = sum(int(c[0].shape[0])
-                                        for c in chunks)
-        if cols is None:
-            # On planner.pick, the span open around this call: why the
-            # window declined a request of a kind it serves.
-            sp = obs_trace.current_span()
-            if sp is not None:
-                sp.tags["miss"] = dw.last_miss()
-            return None
-        with obs_trace.span("resident.groups") as gsp:
-            groups, named, grid, plan_hit = self._devwindow_groups(
-                dw, metric_uid, cols, exact, group_bys)
-            if not groups:
-                return []
-
-            # The shift (qbase - epoch) participates in arithmetic on
-            # device (rel_ts - shift in window_series_stage) — unlike
-            # lo/hi, which are comparison-only and clamp safely. If it
-            # doesn't fit in int32 (e.g. an all-time query against a
-            # metric whose epoch is past 2^31), fall back to the scan
-            # path rather than silently mis-bucketing (devstore's
-            # exact-or-fall-back contract). Sharded windows carry one
-            # epoch PER shard; all must fit.
-            epochs = ([sc.epoch for sc in cols.shards if sc is not None]
-                      if sharded else [cols.epoch])
-            if not all(imin <= qbase - e <= imax for e in epochs):
-                return None
-            num_buckets = _pad_size(int((end - qbase) // interval + 1))
-            S_all = len(cols.series_keys)
-            S_pad = _pad_size(S_all)
-            if S_pad * num_buckets > kernels.STAGE_GRID_MAX:
-                # The largest grid the daemon kept room for beside the
-                # window at boot (tools/cli.py), and far under where the
-                # kernels' int32 per-(series, bucket) segment ids would
-                # wrap. Scan path handles it (per-group kernels, smaller
-                # grids).
-                return None
-            gkeys = grid.gkeys
-            G = _pad_size(len(gkeys))
-            # Device-resident include/gmap, cached per (window instance,
-            # plan, generation, padding): every fresh host array argument
-            # is its own transfer, so repeat dashboard queries should not
-            # re-upload masks that only change when the series directory
-            # grows (generation bump invalidates;
-            # instance_id guards against a replacement window whose counters
-            # restart at 0 — devstore's cache-keying contract).
-            mask_cache = self._dw_mask_cache
-            fk = _filter_key(exact, group_bys)
-            mkey = (dw.instance_id, metric_uid, fk)
-            hit = mask_cache.get(mkey)
-            if hit is not None and hit[0] == cols.generation:
-                include, gmap, sids = hit[1:]
-            else:
-                include = np.zeros(S_pad, bool)
-                gmap = np.full(S_pad, G - 1, np.int32)
-                for gi, gkey in enumerate(gkeys):
-                    for sid in groups[gkey]:
-                        include[sid] = True
-                        gmap[sid] = gi
-                # Sharded window: commit to the combine device (the first
-                # owning shard's) so the apply's inputs are colocated with
-                # the gathered stage grids.
-                tgt = None
-                if sharded:
-                    for sc in cols.shards:
-                        if sc is not None and sc.chunks:
-                            try:
-                                tgt = next(iter(sc.chunks[0][0].devices()))
-                            except Exception:
-                                tgt = None
-                            break
-                # The matched series ids, sorted: what the stage's block
-                # selection is narrowed by.
-                sids = np.flatnonzero(include)
-                include = jax.device_put(include, tgt)
-                gmap = jax.device_put(gmap, tgt)
-                # Generation lives in the VALUE (the _dw_plan_cache
-                # pattern): a directory growth overwrites in place, so dead
-                # generations never accumulate device arrays.
-                mask_cache.put(mkey,
-                               (cols.generation, include, gmap, sids))
-            if gsp is not None:
-                gsp.tags.update(series=S_all, groups=len(gkeys),
-                                plan_hit=plan_hit,
-                                mask_hit=hit is not None
-                                and hit[0] == cols.generation)
-        ngroups = 1 if len(gkeys) == 1 else G
-        rate_kw = self._rate_kw(spec)
-        # The heavy N-point half of a window query (range mask +
-        # per-series downsample [+ rate]) caches per (window instance,
-        # metric, data version, range, interval, downsample, rate, the
-        # filter its blocks were narrowed by) and stays device-resident.
-        # A request whose matched series cut no block out (it matched
-        # every series, or every block in range holds one of them)
-        # folds the blocks of its range whole: that stage is good for
-        # any tag filter, any group-by, moments and p50/p95/p99 alike,
-        # which then pay only the [S, B]-sized apply + one dispatch.
-        # Any other folds only the blocks its series can lie in
-        # (DevChunks.narrowed): its grids are whole for the rows its
-        # own include mask keeps and partial for the others, so its
-        # stage answers that filter alone.
-        cache = self._dw_stage_cache
-        with obs_trace.span("resident.stage") as ssp:
-            whole, cols = cols, cols.narrowed(sids, start, end)
-            narrowed = cols is not whole
-            skey = (dw.instance_id, metric_uid, cols.version, start, end,
-                    interval, dsagg, tuple(sorted(rate_kw.items())),
-                    fk if narrowed else None)
-            stage = cache.get(skey)
-            (_C_STAGE_MISS if stage is None else _C_STAGE_HIT).inc()
-            if ssp is not None:
-                ssp.tags["hit"] = stage is not None
-                ssp.tags["narrowed"] = narrowed
-                ssp.tags["series"] = len(sids)
-            if stage is None:
-                (_C_FOLD_NARROWED if narrowed else _C_FOLD_WHOLE).inc()
-                picked, of, visited, resident, folded, calls, programs = \
-                    _dw_fold_extent(cols)
-                _C_FOLD_VISITED.inc(visited)
-                _C_FOLD_SKIPPED.inc(resident - visited)
-                _C_FOLD_DISPATCHES.inc(calls)
-                _C_STAGE_PROGRAMS.inc(programs)
-                if ssp is not None:
-                    ssp.tags["chunks"] = folded
-                    ssp.tags["calls"] = calls
-                    ssp.tags["blocks"] = picked
-                    ssp.tags["blocks_total"] = of
-                try:
-                    if sharded:
-                        grids = self._dw_sharded_stage(
-                            (dw.instance_id, metric_uid), cols, start,
-                            end, qbase, num_buckets=num_buckets,
-                            S_pad=S_pad, interval=interval, dsagg=dsagg,
-                            rate_kw=rate_kw)
-                        if grids is None:
-                            return None
-                    else:
-                        lo32 = np.int32(
-                            min(max(start - cols.epoch, imin), imax))
-                        hi32 = np.int32(
-                            min(max(end - cols.epoch, imin), imax))
-                        shift32 = np.int32(qbase - cols.epoch)
-                        grids = kernels.window_series_stage_chunks(
-                            cols.chunks, lo32, hi32, shift32,
-                            num_series=S_pad, num_buckets=num_buckets,
-                            interval=interval, agg_down=dsagg,
-                            blocks=cols.blocks, block=cols.block,
-                            **rate_kw)
-                        _FOLD_HANDED.add(grids[5])
-                except Exception as e:
-                    # A near-HBM window can still OOM building the stage
-                    # grids; degrade to the storage scan (the
-                    # exact-or-fall-back contract) instead of erroring.
-                    if _is_device_oom(e):
-                        return None
-                    raise
-                # [5] fills with the host copy of presence on first fetch.
-                stage = list(grids[:5]) + [None]
-                # Stages of this metric's EARLIER data versions can never
-                # hit again (version is monotonic) but each pins [S, B]
-                # grids in HBM the devwindow's own budget can't see — drop
-                # them before the LRU cap so active ingest (a version bump
-                # per flush) doesn't strand dead grids on device.
-                for k in cache.keys():
-                    if k[:2] == (dw.instance_id, metric_uid) \
-                            and k[2] != cols.version:
-                        cache.pop(k)
-                        _C_STAGE_EVICTED.inc()
-                cache.put(skey, stage)
-        sv, sm, filled, in_range, presence_dev = stage[:5]
-        # Shrink-wrap the fetch: clip to the live group/bucket counts
-        # (64-quantized so statics don't churn recompiles) and bit-pack
-        # the mask on device, so wide group-by queries do not fetch
-        # padded [G, B] grids (what the fetch costs is the ledger's
-        # fetch_ms).
-        b_live = int((end - qbase) // interval + 1)
-        g_out = min(ngroups, _pad64(len(gkeys)))
-        b_out = min(num_buckets, _pad64(b_live))
-        shrink = dict(g_out=g_out, b_out=b_out,
-                      wire_bf16=bool(self.tsdb.config.wire_bf16))
-        # The applies allocate fresh [S,B]/[G,B] buffers on a device the
-        # resident window may have filled to within a few hundred MB of
-        # HBM — an OOM here (or in the fetch's staging buffer) must
-        # degrade to the scan path exactly like a stage-build OOM, or
-        # the exact-or-fall-back contract breaks precisely in the
-        # 1B-resident regime it exists for.
-        try:
-            with obs_trace.span("resident.apply", g_out=g_out,
-                                b_out=b_out):
-                if agg.kind == "percentile":
-                    gv, gm = kernels.window_quantile_apply(
-                        sm, filled, in_range, include, gmap,
-                        np.array([agg.quantile], np.float32),
-                        num_groups=ngroups, **shrink)
-                else:
-                    gv, gm = kernels.window_moment_apply(
-                        sv, sm, filled, in_range, include, gmap,
-                        num_groups=ngroups, agg_group=spec.aggregator,
-                        **shrink)
-            if obs_trace.current_span() is not None:
-                # Traced only: stage and apply above are dispatches
-                # (JAX returns before the device finishes), so without
-                # this sync the device's time would all land in the
-                # fetch. Untraced the path makes no such call.
-                with obs_trace.span("resident.wait"):
-                    jax.block_until_ready((gv, gm))
-            # Series with no in-range points must not shape group labels
-            # or emit empty groups — match the scan path, which never
-            # sees them. (Pre-rate presence: computed from the raw
-            # in-range mask, like the scan path's "series exists".) One
-            # batched device_get — separate np.asarray fetches would
-            # each pay a transport round trip; presence is fetched once
-            # per stage.
-            with obs_trace.span("resident.fetch") as sp:
-                if stage[5] is None:
-                    gv, gm, stage[5] = jax.device_get(
-                        (gv, gm, presence_dev))
-                else:
-                    gv, gm = jax.device_get((gv, gm))
-                if sp is not None:
-                    sp.tags["bytes"] = int(gv.nbytes + gm.nbytes)
-        except Exception as e:
-            if _is_device_oom(e):
-                # Drop the stage too: leaving it cached would pin its
-                # [S, B] grids in the very HBM that just ran out, and
-                # every later query of this panel would re-dispatch a
-                # doomed apply before falling back.
-                if cache.pop(skey, None) is not None:
-                    _C_STAGE_EVICTED.inc()
-                return None
-            raise
-        with obs_trace.span("resident.results") as sp:
-            results = _grid_results(spec.metric, grid, named.__getitem__,
-                                    stage[5], gv, gm, b_out, interval,
-                                    qbase)
-            if sp is not None:
-                sp.tags["results"] = len(results)
-        return results
-
-    def _dw_sharded_stage(self, of: tuple, cols, start: int, end: int,
-                          qbase: int, *, num_buckets: int, S_pad: int,
-                          interval: int, dsagg: str, rate_kw: dict):
-        """The stage half of a resident query over the mesh-SHARDED
-        hot set (storage/devshard.py): each shard's chunk fold runs on
-        its OWN device (async dispatch overlaps the shards), then only
-        the [S_shard, B] stage grids — never the N-point columns —
-        travel to the first shard's device, where one program
-        (kernels.shard_combine) lays their rows out in
-        combined-directory order, padded to S_pad. Row order equals
-        ``cols.series_keys`` order, so include/gmap and the apply
-        kernels are oblivious to sharding. ``of``: (window instance,
-        metric), which with ``cols.generation`` names the directory.
-
-        Nothing here compiles for a metric, a host or a range of its
-        own: every shard folds into grids of one padded height (that of
-        the fullest), on its own device whether or not a block of its
-        chunks was picked, and which rows the join takes from where is
-        an array. What a request of some kind compiles, the first
-        request of that kind has compiled, on every device: a program
-        belongs to one device, the shard a one-host panel folds on
-        follows the host it drew, and a metric's series fall to the
-        shards in their own numbers.
-
-        Numeric contract (declared, README "Serving mesh"): the
-        per-shard folds are the SAME f32 kernels as the 1-shard path
-        and a series never splits across shards, so count/min/max rows
-        are byte-identical across shard counts while sum/avg/dev rows
-        agree to f32 tolerance (bucket partial sums reassociate across
-        chunk boundaries that fall differently per shard).
-
-        Returns the window_series_stage grid tuple, or None when some
-        shard's epoch shift cannot represent in int32 (scan fallback,
-        checked again here because the caller's probe reads the shards
-        it captured — a reshard between the two is benign either way).
-        """
-        imin, imax = -(2**31), 2**31 - 1
-        live = [(i, sc) for i, sc in enumerate(cols.shards)
-                if sc is not None]
-        if not live or not all(imin <= qbase - sc.epoch <= imax
-                               for _i, sc in live):
-            return None
-        held = [len(sc.series_keys) for _i, sc in live]
-        height = _pad_size(max(held))
-        statics = dict(num_series=height, num_buckets=num_buckets,
-                       interval=interval, agg_down=dsagg, **rate_kw)
-        programs = tuple(sorted(statics.items()))
-
-        # A shard's device compiles what a request of this kind can run
-        # there before the first stage of the kind is built, whichever
-        # shard that request's own selection folds on.
-        cold = []
-        for i, _sc in live:
-            window = cols.shard_windows[i]
-            warm = (_device_id(window.device), programs,
-                    window.chunk_sizes)
-            if self._dw_shard_warm.get(warm) is None:
-                cold.append((warm, window))
-        if cold:
-            self._dw_warm_shards(cold, live[0][1].block, statics)
-        parts = []
-        for i, sc in live:
-            window = cols.shard_windows[i]
-            # The host's time in this shard's stage: its start, its fold
-            # dispatches, its finish (the device runs on behind it).
-            with obs_trace.span("resident.shard", shard=i) as sp:
-                grids = kernels.window_series_stage_chunks(
-                    sc.chunks,
-                    np.int32(min(max(start - sc.epoch, imin), imax)),
-                    np.int32(min(max(end - sc.epoch, imin), imax)),
-                    np.int32(qbase - sc.epoch),
-                    blocks=sc.blocks, block=sc.block,
-                    device=window.device, **statics)
-                if sp is not None:
-                    sp.tags.update(
-                        device=_device_id(window.device),
-                        series=len(sc.series_keys),
-                        chunks=sum(len(b) > 0 for b in sc.blocks))
-            _FOLD_HANDED.add(grids[5])
-            parts.append(grids[:5])
-        _C_STAGE_SHARDS.inc(len(parts))
-        # What brings the shards' grids to the combine device (the
-        # first shard's) and joins them: the copies between devices and
-        # the one program that lays the rows out.
-        with obs_trace.span("resident.gather", shards=len(parts)) as sp:
-            target = next(iter(parts[0][0].devices()))
-            moved = sum(g.nbytes for grids in parts for g in grids
-                        if target not in g.devices())
-            hit = self._dw_mask_cache.get(of + ("shard_rows",))
-            if hit is not None and hit[:2] == (cols.generation, height):
-                rows = hit[2]
-            else:
-                # Row r of the joined grids: the shard its series lives
-                # in, times the height, plus the series' row there; a
-                # padding row, one past every shard's.
-                rows = np.full(S_pad, len(live) * height, np.int32)
-                rows[:sum(held)] = np.concatenate(
-                    [n * height + np.arange(mine)
-                     for n, mine in enumerate(held)])
-                rows = jax.device_put(rows, target)
-                self._dw_mask_cache.put(
-                    of + ("shard_rows",), (cols.generation, height, rows))
-            outs = kernels.shard_combine(
-                tuple(tuple(jax.device_put(g, target) for g in grids)
-                      for grids in parts), rows)
-            _C_GATHER_BYTES.inc(moved)
-            if sp is not None:
-                sp.tags["bytes"] = moved
-        return outs
-
-    def _dw_warm_shards(self, cold, block: int, statics: dict) -> None:
-        """Compile, on the device of each window of ``cold`` ((key,
-        shard's window) pairs), every program a stage of ``statics``
-        can run there: a stage over one chunk of each shape class the
-        window holds, a block of each visited over a range nothing lies
-        in, built and thrown away. A program belongs to one device, the
-        shard a one-host panel folds on follows the host it drew and a
-        metric's chunks pad to their own classes, so without it the
-        first request to fold on a shard, or on a class, compiles
-        under that request. The shards do it side by side: the compiler
-        works outside the interpreter lock."""
-        def warm(window):
-            classes = window.chunk_classes()
-            kernels.window_series_stage_chunks(
-                classes, np.int32(1), np.int32(0), np.int32(0),
-                blocks=[(0,)] * len(classes), block=block,
-                device=window.device, **statics)
-        with concurrent.futures.ThreadPoolExecutor(len(cold)) as pool:
-            list(pool.map(warm, [window for _key, window in cold]))
-        for key, _window in cold:
-            self._dw_shard_warm.put(key, True)
-
-    def _devwindow_groups(self, dw, metric_uid: bytes, cols, exact,
-                          group_bys):
-        """Filter + group the window's series directory on host UIDs.
-
-        Returns ({group_key_tuple: [sid]}, {sid: named_tags}, the
-        groups as the answer takes them (_GridGroups, their labels kept
-        from the first answer on), whether the plan cache held them);
-        cached per (window instance, metric, filter) until the
-        directory grows.
-        ``dw`` is the SAME window object ``cols`` came from (passed by
-        the caller, not re-read from self.tsdb — a swap between capture
-        and here must not cache the old window's plan under the new
-        window's instance_id)."""
-        fkey = (dw.instance_id, metric_uid,
-                _filter_key(exact, group_bys))
-        cache = self._dw_plan_cache
-        hit = cache.get(fkey)
-        if hit is not None and hit[0] == cols.generation:
-            return hit[1], hit[2], hit[3], True
-        groups, named = self._series_groups(cols.series_keys, exact,
-                                            group_bys)
-        grid = _GridGroups(groups)
-        cache.put(fkey, (cols.generation, groups, named, grid))
-        return groups, named, grid, False
-
-    # -- fused decode-aggregate path (TSST4 blocks) --------------------
-
-    @staticmethod
-    def _series_selector(exact, group_bys):
-        """The ONE tag-filter/group-by predicate behind the resident-
-        window and fused plans (they must answer identically, so the
-        semantics live in one function): series_key -> group key tuple
-        when the series matches, None when filtered out. The fused
-        path pushes this down into compress/fused.gather, where it
-        runs against block keys BEFORE payload decode."""
-        group_by_keys = sorted(k for k, _ in group_bys)
-        want = dict(exact)
-        gb = {k: (set(v) if v else None) for k, v in group_bys}
-
-        def selector(skey: bytes):
-            tag_uids = codec.series_tag_uids(skey)
-            for k, v in want.items():
-                if tag_uids.get(k) != v:
-                    return None
-            for k, allowed in gb.items():
-                v = tag_uids.get(k)
-                if v is None or (allowed is not None
-                                 and v not in allowed):
-                    return None
-            return tuple(tag_uids.get(k, b"") for k in group_by_keys)
-
-        return selector
-
-    def _named_tags(self, skey: bytes) -> dict[str, str]:
-        return {self.tsdb.tagk.get_name(k): self.tsdb.tagv.get_name(v)
-                for k, v in codec.series_tag_uids(skey).items()}
-
-    def _series_groups(self, series_keys, exact, group_bys):
-        """Filter + group a series-key directory on host UIDs via
-        ``_series_selector``. sid = position in ``series_keys``.
-        Returns ({group_key_tuple: [sid]}, {sid: named_tags})."""
-        selector = self._series_selector(exact, group_bys)
-        groups: dict[tuple, list[int]] = {}
-        named: dict[int, dict[str, str]] = {}
-        for sid, skey in enumerate(series_keys):
-            g = selector(skey)
-            if g is None:
-                continue
-            groups.setdefault(g, []).append(sid)
-            named[sid] = self._named_tags(skey)
-        return groups, named
-
-    def _run_fused_blocks(self, spec: QuerySpec, start: int, end: int,
-                          agg) -> list[QueryResult] | None:
-        """Serve a downsampled query straight from TSST4 compressed
-        blocks: one fused decode-plus-aggregate XLA program produces
-        the per-(series, bucket) stage grids (the decoded columns are
-        never materialized on host), then the SAME apply kernels the
-        device-resident window uses finish grouping/percentiles.
-        Exact or None (the fall-back contract): any memtable-resident
-        data in range, non-v4 generation, non-TSF32 block, overlay
-        risk, or int32 overflow declines to the scan path."""
-        tsdb = self.tsdb
-        cfg = tsdb.config
-        if (self.backend == "cpu"
-                or not spec.downsample
-                or agg.kind not in ("moment", "percentile")
-                or Aggregators.get(spec.downsample[1]).kind != "moment"
-                or not cfg.sstable_fused_agg):
-            return None
-        store = tsdb.store
-        if getattr(store, "encoded_range", None) is None \
-                or getattr(store, "chunk_state", None) is None:
-            return None
-        interval, dsagg = spec.downsample
-        imax = 2**31 - 1
-        if start < 0 or end > 0xFFFFFFFF \
-                or end - start > imax - 4 * MAX_TIMESPAN:
-            return None
-        qbase = start - start % interval
-        if end - qbase > imax:
-            return None
-        from opentsdb_tpu.core.errors import NoSuchUniqueName
-        try:
-            metric_uid = tsdb.metrics.get_id(spec.metric)
-            exact, group_bys = self._tag_filters(spec.tags)
-        except NoSuchUniqueName:
-            return None  # scan path raises the canonical error
-        b_lo = codec.base_time(start)
-        b_hi = min(codec.base_time(end), 0xFFFFFFFF)
-        _C_FUSED_ATTEMPT.inc()
-        # Memtable-resident (dirty) data in range: decline — a frozen
-        # answer must equal the scan bit-for-bit, and overlaying live
-        # rows is the scan path's job.
-        seqs, floors, stamps, dirty = store.chunk_state(
-            tsdb.table, b_lo, b_hi + MAX_TIMESPAN)
-        if dirty:
-            _count_decline("dirty")
-            return None
-        with _M_FUSED.time():
-            res = self._run_fused_inner(
-                spec, start, end, agg, metric_uid, exact, group_bys,
-                interval, dsagg, qbase, b_lo, b_hi)
-        if res is not None:
-            _C_FUSED_SERVED.inc()
-        return res
-
-    def _run_fused_inner(self, spec, start, end, agg, metric_uid,
-                         exact, group_bys, interval, dsagg, qbase,
-                         b_lo, b_hi):
-        """The fused plan past its gates, as five spans under
-        planner.pick (README, "Observability"): fused.gather (which
-        blocks, their records, the groups), fused.dispatch (the
-        uploads and the calls of the stage and apply programs),
-        fused.wait (traced requests only, as aggregate.wait),
-        fused.fetch, fused.results."""
-        from opentsdb_tpu.compress import fused as _fused
-        tsdb = self.tsdb
-        rate_kw = self._rate_kw(spec)
-        fk = _filter_key(exact, group_bys)
-        # The tag filter is part of the stage's identity now that it's
-        # pushed into the gather (filtered-out series never reach the
-        # stage grid) — leaving it out would serve one filter's grid
-        # under another's key.
-        skey_cache = (metric_uid, b_lo, b_hi, interval, dsagg, start,
-                      end, fk, tuple(sorted(rate_kw.items())))
-        hit = self._fused_stage_cache.get(skey_cache)
-        if hit is not None:
-            gens_hit, src_keys, epoch, stage, groups = hit
-            # Validate against the CURRENT generation set: gens_hit
-            # holds the SSTable objects the cached stage was computed
-            # from (object identity — the entry pins them, so id
-            # recycling cannot alias a dropped generation). Any
-            # checkpoint/compaction swap mismatches and rebuilds.
-            spans = tsdb.store.encoded_range(
-                tsdb.table, metric_uid + b_lo.to_bytes(4, "big"),
-                metric_uid + min(b_hi + MAX_TIMESPAN,
-                                 0xFFFFFFFF).to_bytes(4, "big"))
-            if spans is None or \
-                    len(spans) != len(gens_hit) or \
-                    any(g is not h for (g, _, _), h
-                        in zip(spans, gens_hit)):
-                hit = None
-                self._fused_stage_cache.pop(skey_cache)
-        src = None
-        if hit is None:
-            with obs_trace.span("fused.gather") as sp:
-                memo = self._fused_sel_memo.get((metric_uid, fk))
-                if memo is None:
-                    memo = {}
-                    self._fused_sel_memo.put((metric_uid, fk), memo)
-                try:
-                    src = _fused.gather(
-                        tsdb.store, tsdb.table, metric_uid, b_lo, b_hi,
-                        selector=self._series_selector(exact, group_bys),
-                        series_keys=self._series_hint(
-                            metric_uid, exact, group_bys).get(
-                                "series_keys"),
-                        sel_memo=memo)
-                except _fused.Decline as d:
-                    _count_decline(d.reason)
-                    return None
-                if sp is not None:
-                    dc = self._devcache
-                    sp.tags.update(
-                        blocks=len(src.blocks), points=src.npoints,
-                        matched=src.matched,
-                        series=len(src.series_keys),
-                        payload_bytes=src.payload_bytes(),
-                        cached=dc.held(src) if dc is not None else 0)
-            if src.npoints == 0:
-                return []
-            _C_FUSED_POINTS.inc(src.npoints)
-            _C_FUSED_MATCHED.inc(src.matched)
-            _C_FUSED_PAYLOAD.inc(src.payload_bytes())
-            epoch = src.epoch
-            src_keys = src.series_keys
-            groups = src.groups
-        if not groups:
-            return []
-        S_pad = _pad_size(len(src_keys))
-        imin, imax = -(2**31), 2**31 - 1
-        if not imin <= qbase - epoch <= imax:
-            _count_decline("int32-span")
-            return None
-        num_buckets = _pad_size(int((end - qbase) // interval + 1))
-        if S_pad * num_buckets >= 2**31:
-            _count_decline("grid-too-large")
-            return None
-        grid = self._fused_plan_cache.get((metric_uid, fk))
-        if grid is None or grid.series_keys != src_keys:
-            # New to this executor, or the store has gained or lost a
-            # series of the range since: the groups' sids are positions
-            # in the gather's directory, so the kept labels go with it.
-            grid = _GridGroups(groups, src_keys)
-            self._fused_plan_cache.put((metric_uid, fk), grid)
-        gkeys = grid.gkeys
-        G = _pad_size(len(gkeys))
-        ngroups = 1 if len(gkeys) == 1 else G
-        b_live = int((end - qbase) // interval + 1)
-        g_out = min(ngroups, _pad64(len(gkeys)))
-        b_out = min(num_buckets, _pad64(b_live))
-        with obs_trace.span("fused.dispatch") as sp:
-            if src is not None:
-                try:
-                    stage, leg = self._fused_stage(
-                        src, S_pad, num_buckets, interval, dsagg,
-                        rate_kw,
-                        np.int32(min(max(start - epoch, imin), imax)),
-                        np.int32(min(max(end - epoch, imin), imax)),
-                        np.int32(qbase - epoch))
-                except _fused.Decline as d:
-                    _count_decline(d.reason)
-                    return None
-                # Key the entry on the SNAPSHOT the stage was actually
-                # computed from (src.spans — not a fresh encoded_range,
-                # which a checkpoint racing this query could have moved
-                # past the gathered data). The held objects both pin
-                # against id reuse and make hit-validation pure identity.
-                self._fused_stage_cache.put(
-                    skey_cache,
-                    (tuple(g for g, _, _ in src.spans),
-                     src_keys, epoch, stage, groups))
-            else:
-                leg = "cached"
-            sv, sm, filled, in_range, presence_dev = stage[:5]
-            include = np.zeros(S_pad, bool)
-            gmap = np.full(S_pad, G - 1, np.int32)
-            for gi, gkey in enumerate(gkeys):
-                sids = groups[gkey]
-                include[sids] = True
-                gmap[sids] = gi
-            shrink = dict(g_out=g_out, b_out=b_out,
-                          wire_bf16=bool(tsdb.config.wire_bf16))
-            if agg.kind == "percentile":
-                gv, gm = kernels.window_quantile_apply(
-                    sm, filled, in_range, include, gmap,
-                    np.array([agg.quantile], np.float32),
-                    num_groups=ngroups, **shrink)
-            else:
-                gv, gm = kernels.window_moment_apply(
-                    sv, sm, filled, in_range, include, gmap,
-                    num_groups=ngroups, agg_group=spec.aggregator,
-                    **shrink)
-            if sp is not None:
-                sp.tags["leg"] = leg
-        if obs_trace.current_span() is not None:
-            # Traced requests only, as aggregate.wait: untraced the
-            # fetch below blocks as it always did.
-            with obs_trace.span("fused.wait"):
-                jax.block_until_ready((gv, gm))
-        with obs_trace.span("fused.fetch") as sp:
-            if stage[5] is None:
-                gv, gm, stage[5] = jax.device_get((gv, gm, presence_dev))
-            else:
-                gv, gm = jax.device_get((gv, gm))
-            if sp is not None:
-                sp.tags["bytes"] = int(gv.nbytes + gm.nbytes)
-        with obs_trace.span("fused.results") as sp:
-            named = self._fused_named
-            if len(named) > 1 << 20:
-                named.clear()
-
-            def tags_of(sid: int) -> dict[str, str]:
-                sk = src_keys[sid]
-                tags = named.get(sk)
-                if tags is None:
-                    tags = named[sk] = self._named_tags(sk)
-                return tags
-
-            results = _grid_results(spec.metric, grid, tags_of, stage[5],
-                                    gv, gm, b_out, interval, qbase)
-            if sp is not None:
-                sp.tags["results"] = len(results)
-        return results
-
-    def _fused_stage(self, src, S_pad, num_buckets, interval, dsagg,
-                     rate_kw, lo32, hi32, shift32):
-        """Dispatch the window stage of one gather; returns (the stage
-        contract as a list with a slot for the fetched presence, the
-        leg that ran). On one device the gather's blocks are decoded
-        into the block cache's slabs (misses only) and the stage reads
-        them: per matched point where the selector keeps under half of
-        the points of the blocks it touches (``sel``), else per whole
-        block (``rows``); without the cache, or for a gather its slabs
-        cannot hold, the plan declines (``cache-off``, ``oversize``)
-        and the raw plan serves. Across a mesh the byte-stream leg
-        decodes and stages in one program (``mesh``)."""
-        from opentsdb_tpu.compress import fused as _fused
-        from opentsdb_tpu.compress import kernels as _ckernels
-        statics = dict(
-            num_series=S_pad, num_buckets=num_buckets,
-            interval=interval, agg_down=dsagg, rate=rate_kw["rate"],
-            counter=rate_kw["counter"],
-            drop_resets=rate_kw["drop_resets"])
-        scalars = (lo32, hi32, shift32,
-                   np.float32(rate_kw["counter_max"]),
-                   np.float32(rate_kw["reset_value"]))
-
-        def counted(out, slots):
-            *grids, handed = out
-            _stage_handed(handed, slots)
-            return grids + [None]
-
-        if self.mesh is None:
-            dc = self._devcache
-            if dc is None:
-                raise _fused.Decline("cache-off")
-            selective = 2 * src.matched <= src.npoints
-
-            def run(qd, vals, slots):
-                inputs = (dc.point_inputs if selective
-                          else dc.record_inputs)(src, slots, S_pad)
-                # The stream: a matched point each, or every point of
-                # the rows gathered.
-                return counted(
-                    (_ckernels.slab_stage_sel if selective
-                     else _ckernels.slab_stage_rows)(
-                        qd, vals, *inputs, *scalars, **statics),
-                    len(inputs[0]) * (1 if selective else dc.P_BLK))
-
-            stage = dc.stage(src, run)
-            if stage is None:
-                raise _fused.Decline("oversize")
-            return stage, "sel" if selective else "rows"
-        # The plane's pjit-preferred leg: the point stream (whole
-        # compressed blocks) shards over the mesh, payloads and the
-        # [S, B] outputs replicate (compress/kernels.py
-        # FUSED_STAGE_PLAN). Shapes that don't divide the mesh run the
-        # single-device compile — counted (mesh-indivisible) but still
-        # served fused, never a fallback to the scan.
-        ps = src.point_stream()
-        npoints = len(ps.valid)
-        _C_FUSED_UPLOADED.inc(
-            14 * npoints + len(ps.ts_pay) + len(ps.v_pay))
-        P_pad = _pad_fine(npoints)
-
-        def pad(a, dtype, fill=0):
-            out = np.full(P_pad, fill, dtype)
-            out[:len(a)] = a
-            return out
-
-        def padbuf(a):
-            # Payload bytes pad pow2: decode compute is per-POINT,
-            # byte padding costs only upload, and one compile class
-            # per octave keeps shifted windows from recompiling on
-            # byte-length wobble.
-            n = max(len(a), 1)
-            out = np.zeros(1 << (n - 1).bit_length(), np.uint8)
-            out[:len(a)] = a
-            return out
-
-        args = (pad(ps.ts_nb, np.int32), padbuf(ps.ts_pay),
-                pad(ps.v_nb, np.int32), padbuf(ps.v_pay),
-                pad(ps.first_idx, np.int32),
-                pad(ps.blk_first, np.int32),
-                pad(ps.rel_base_pt, np.int32),
-                pad(np.minimum(ps.sid_pt, S_pad - 1), np.int32),
-                pad(ps.valid, bool, False))
-        if P_pad % int(self.mesh.devices.size) == 0:
-            fused_fn = _ckernels.fused_block_stage_mesh(
-                self.mesh, vkind=src.kind, **statics)
-            return counted(fused_fn(*args, *scalars), P_pad), "mesh"
-        _count_decline("mesh-indivisible")
-        out = _ckernels.fused_block_stage(
-            *args, *scalars[:3], **statics, vkind=src.kind,
-            counter_max=rate_kw["counter_max"],
-            reset_value=rate_kw["reset_value"])
-        return counted(out, P_pad), "bytes"
 
     # -- CPU oracle backend -------------------------------------------
 
@@ -2098,15 +1104,6 @@ class QueryExecutor:
             out.append((ts[m], rates[m].astype(np.float64)))
         return out
 
-    def _rate_kw(self, spec: QuerySpec) -> dict:
-        """Static+traced rate args threaded into the fused kernels."""
-        return dict(
-            rate=spec.rate,
-            counter_max=spec.counter_max if spec.counter else 0.0,
-            reset_value=spec.reset_value or 0.0,
-            counter=spec.counter,
-            drop_resets=spec.reset_value is not None)
-
     def _tpu_downsample_group(self, spec: QuerySpec, scan: _Scan,
                               start: int, end: int):
         """The fused fast path for a scan of one group: flat downsample
@@ -2121,10 +1118,10 @@ class QueryExecutor:
         agg = Aggregators.get(spec.aggregator)
         if self.mesh is not None and agg.kind in ("moment", "percentile"):
             (spans,) = scan.spans().values()
-            sharded = self._tpu_downsample_sharded(
+            over_mesh = self._tpu_downsample_sharded(
                 spec, spans, qbase, interval, dsagg, num_buckets)
-            if sharded is not None:
-                return sharded
+            if over_mesh is not None:
+                return over_mesh
         with obs_trace.span("aggregate.pack") as sp:
             rel, vals, sid, valid = scan.stream(qbase, pad=True)
             if sp is not None:
@@ -2137,8 +1134,8 @@ class QueryExecutor:
                 agg_down=dsagg,
                 agg_group=(spec.aggregator if agg.kind == "moment"
                            else "count"),
-                **self._rate_kw(spec))
-            _stage_handed(out["handed"], len(rel))
+                **qgrid.rate_kw(spec))
+            qgrid._stage_handed(out["handed"], len(rel))
             gmask, values = out["group_mask"], out["group_values"]
             if agg.kind == "percentile":
                 # series_values/series_mask are the post-rate per-bucket
@@ -2151,29 +1148,12 @@ class QueryExecutor:
                 values = kernels.masked_quantile_axis0(
                     filled, in_range,
                     np.array([agg.quantile], np.float32))[0]
-        gmask, values = self._aggregate_fetch(gmask, values)
+        gmask, values = qgrid.fetch("aggregate", gmask, values)
         with obs_trace.span("aggregate.results"):
             # Epoch-aligned bucket-start timestamps (module docstring).
             grid_ts = (np.flatnonzero(gmask).astype(np.int64) * interval
                        + qbase)
             return grid_ts, values[gmask].astype(np.float64)
-
-    @staticmethod
-    def _aggregate_fetch(gmask, values):
-        """The device's answer brought to the host, as the two spans
-        after aggregate.dispatch. The wait is issued ONLY in a traced
-        request: the kernels above are dispatches (JAX returns before
-        the device finishes), so without it the h2d copy and the
-        device's time would all land in the fetch. Untraced the path
-        makes no such call."""
-        if obs_trace.current_span() is not None:
-            with obs_trace.span("aggregate.wait"):
-                jax.block_until_ready((gmask, values))
-        with obs_trace.span("aggregate.fetch") as sp:
-            gmask, values = jax.device_get((gmask, values))
-            if sp is not None:
-                sp.tags["bytes"] = int(gmask.nbytes + values.nbytes)
-        return gmask, values
 
     def _tpu_downsample_sharded(self, spec: QuerySpec, spans: list[_Span],
                                 qbase: int, interval: int, dsagg: str,
@@ -2201,7 +1181,7 @@ class QueryExecutor:
         )
 
         agg = Aggregators.get(spec.aggregator)
-        rate_kw = self._rate_kw(spec)
+        rate_kw = qgrid.rate_kw(spec)
         D = int(self.mesh.devices.size)
         if len(spans) >= D:
             series = [((sp.timestamps - qbase).astype(np.int64),
@@ -2224,7 +1204,8 @@ class QueryExecutor:
                     agg_group=spec.aggregator, **rate_kw)
         elif num_buckets >= 4 * D:
             bps = -(-num_buckets // D)
-            rel, vals, sid, valid = self._flatten_spans(spans, qbase)
+            rel, vals, sid, valid = _Scan.of_spans(
+                {(): spans}).stream(qbase)
             tsh = pack_time_shards(rel[valid], vals[valid], sid[valid], D,
                                    interval, bps)
             tmesh = Mesh(self.mesh.devices.reshape(-1), (TIME_AXIS,))
@@ -2240,13 +1221,6 @@ class QueryExecutor:
         gm = np.asarray(gm)
         grid_ts = np.flatnonzero(gm).astype(np.int64) * interval + qbase
         return grid_ts, np.asarray(gv)[gm].astype(np.float64)
-
-    @staticmethod
-    def _flatten_spans(spans: list[_Span], qbase: int):
-        """Spans -> one flat unpadded (rel_ts, vals, sid, valid) point
-        stream, sid = position in ``spans``: what the mesh packers of
-        one group take."""
-        return _Scan.of_spans({(): spans}).stream(qbase)
 
     def _run_tpu_multigroup(self, spec: QuerySpec, scan: _Scan,
                             gkeys: list[tuple], start: int, end: int):
@@ -2296,17 +1270,17 @@ class QueryExecutor:
                         num_series=S, num_groups=G,
                         num_buckets=num_buckets,
                         interval=interval, agg_down=dsagg,
-                        **self._rate_kw(spec))
+                        **qgrid.rate_kw(spec))
                 else:
                     out = kernels.downsample_multigroup(
                         rel, vals, sid, valid, gmap,
                         num_series=S, num_groups=G,
                         num_buckets=num_buckets, interval=interval,
                         agg_down=dsagg, agg_group=spec.aggregator,
-                        **self._rate_kw(spec))
-                _stage_handed(out["handed"], len(rel))
-            gm, gv = self._aggregate_fetch(out["group_mask"],
-                                           out["group_values"])
+                        **qgrid.rate_kw(spec))
+                qgrid._stage_handed(out["handed"], len(rel))
+            gm, gv = qgrid.fetch("aggregate", out["group_mask"],
+                                 out["group_values"])
         with obs_trace.span("aggregate.results"):
             results = []
             for gi in range(len(gkeys)):
@@ -2348,14 +1322,14 @@ class QueryExecutor:
                 np.array([agg.quantile], np.float32), mesh=self.mesh,
                 series_per_shard=sps_pad, num_groups=G,
                 num_buckets=num_buckets, interval=interval,
-                agg_down=dsagg, **self._rate_kw(spec))
+                agg_down=dsagg, **qgrid.rate_kw(spec))
         else:
             gv, gm = sharded_downsample_multigroup(
                 ts, vals, sid, valid, gmap, mesh=self.mesh,
                 series_per_shard=sps_pad, num_groups=G,
                 num_buckets=num_buckets, interval=interval,
                 agg_down=dsagg, agg_group=spec.aggregator,
-                **self._rate_kw(spec))
+                **qgrid.rate_kw(spec))
         return np.asarray(gv), np.asarray(gm)
 
     # ------------------------------------------------------------------
@@ -2368,18 +1342,8 @@ class QueryExecutor:
         selected from the sketch slot directory, not a storage scan. The
         same UID regexp as the scan path, minus the base-time bytes."""
         metric_uid = self.tsdb.metrics.get_id(metric)
-        exact, group_bys = [], []
-        for name, value in tags.items():
-            k = self.tsdb.tagk.get_id(name)
-            if value == "*":
-                group_bys.append((k, None))
-            elif "|" in value:
-                group_bys.append(
-                    (k, [self.tsdb.tagv.get_id(v)
-                         for v in value.split("|")]))
-            else:
-                exact.append((k, self.tsdb.tagv.get_id(value)))
-        regexp = self._build_regexp(exact, group_bys, prefix=UID_WIDTH)
+        regexp = self._build_regexp(*self._tag_filters(tags),
+                                    prefix=UID_WIDTH)
         pattern = re.compile(regexp, re.S) if regexp else None
         return [k for k in self.tsdb.sketches.series_keys()
                 if k.startswith(metric_uid)
@@ -2705,183 +1669,3 @@ class QueryExecutor:
 def _u32(v: int) -> bytes:
     return int(v).to_bytes(4, "big")
 
-
-def _pad_size(n: int) -> int:
-    """Round up to a power of two (min 16) to bound jit recompilations."""
-    size = 16
-    while size < n:
-        size *= 2
-    return size
-
-
-def _pad64(n: int) -> int:
-    """Round up to a multiple of 64 (min 64): fetch-slice quantization —
-    fine enough to cut padded-transfer waste, coarse enough to bound
-    the distinct static shapes the apply kernels compile for."""
-    return max((n + 63) // 64 * 64, 64)
-
-
-def _device_id(device) -> int | None:
-    """A span's tag for the device a shard is pinned to (None: the
-    default placement)."""
-    return None if device is None else int(device.id)
-
-
-def _dw_chunks(cols) -> list:
-    """Every device chunk of a resident window's columns, the sharded
-    window's shard by shard (for a span's counts)."""
-    shards = getattr(cols, "shards", None)
-    if shards is None:
-        return cols.chunks
-    return [c for sc in shards if sc is not None for c in sc.chunks]
-
-
-def _dw_fold_extent(cols) -> tuple[int, ...]:
-    """DevChunks.fold_extent() of a resident window's columns, summed
-    over the sharded window's shards: (blocks picked, blocks in all,
-    slots picked, slots in all, chunks hit, fold calls), and after
-    them the device programs the stage build issues: the calls, for
-    each shard its start and its finish, and the join of a sharded
-    window's shards."""
-    shards = getattr(cols, "shards", None)
-    parts = [cols] if shards is None else list(filter(None, shards))
-    sums = tuple(map(sum, zip(*(p.fold_extent() for p in parts))))
-    return sums + (sums[5] + 2 * len(parts) + (shards is not None),)
-
-
-class KeptTags(dict):
-    """The tags of a label kept with its plan (``_GridGroups.labels``),
-    with the label's ``aggregated`` list and room for the ``text`` an
-    encoder of answers made of the two (server/qjson.py fills it on the
-    label's first answer, from the event-loop thread alone), so that
-    what was formatted lives as long as the label and goes with its
-    plan."""
-
-    __slots__ = ("aggregated", "text")
-
-    def __init__(self, tags: dict[str, str],
-                 aggregated: list[str]) -> None:
-        super().__init__(tags)
-        self.aggregated = aggregated
-        self.text: str | None = None
-
-
-class _GridGroups:
-    """The groups of a grid plan (resident, fused) as its answer takes
-    them, kept with the plan that made the groups: the sorted group
-    keys (row ``i`` of the fetched grids is ``gkeys[i]``), the groups'
-    members as one flat array of series ids with the groups' offsets
-    into it and their sizes, and, from the first answer on, one ``(tags, aggregated)``
-    a group over its whole membership (``_grid_results`` builds them).
-    ``series_keys`` is the directory the ids are positions in, where
-    the plan is told by it (fused); the resident plan is told by its
-    window's generation."""
-
-    __slots__ = ("gkeys", "members", "offsets", "sizes", "labels",
-                 "series_keys")
-
-    def __init__(self, groups: dict[tuple, list[int]],
-                 series_keys: list[bytes] | None = None) -> None:
-        self.gkeys = sorted(groups)
-        self.sizes = np.array([len(groups[g]) for g in self.gkeys],
-                              np.intp)
-        self.offsets = np.zeros(len(self.gkeys) + 1, np.intp)
-        np.cumsum(self.sizes, out=self.offsets[1:])
-        self.members = np.fromiter(
-            (sid for g in self.gkeys for sid in groups[g]), np.intp,
-            int(self.offsets[-1]))
-        self.labels: list[tuple[KeptTags, list[str]]] | None = None
-        self.series_keys = series_keys
-
-
-def _grid_results(metric: str, grid: _GridGroups, tags_of, has_points,
-                  gv, gm, b_out: int, interval: int,
-                  qbase: int) -> list[QueryResult]:
-    """The answer of a grid plan out of its fetched grids, by whole-
-    array operations: a result a group with a live member, in the
-    order of ``grid.gkeys``.
-
-    ``gv`` / ``gm`` are the fetched ``[g_out, b_out]`` values and their
-    bit-packed mask (row ``i``: group ``i``), ``has_points`` the
-    presence of every series id (a bool array), ``tags_of(sid)`` a series' named tags.
-    A series with no point in range must not shape its group's labels
-    nor leave an empty group behind (the scan path never sees it): a
-    group with none alive is dropped, a group with all alive takes the
-    labels kept with the plan, and a group in between is labelled anew
-    over its live members.
-
-    What is handed out is shared and read-only: the tag dicts and the
-    aggregated lists between every answer of the plan (nothing under
-    opentsdb_tpu/ writes to a QueryResult's fields), the timestamps
-    between the results of one answer where their rows' masks are one
-    (hosts that report in step), the values as rows or slices of one
-    float64 array."""
-    members, offsets = grid.members, grid.offsets
-    alive = has_points[members]
-    nlive = np.add.reduceat(alive, offsets[:-1], dtype=np.intp)
-    rows = np.flatnonzero(nlive)
-    if not len(rows):
-        return []
-    whole = (nlive == grid.sizes)[rows]
-
-    def label(gi: int, live_only: bool):
-        sids = members[offsets[gi]:offsets[gi + 1]]
-        if live_only:
-            sids = sids[alive[offsets[gi]:offsets[gi + 1]]]
-        return QueryExecutor._group_tags(
-            [tags_of(sid) for sid in sids.tolist()])
-
-    kept = grid.labels is not None
-    if not kept:
-        # The plan's first answer (two at once build the same twice).
-        grid.labels = [(KeptTags(tags, aggregated), aggregated)
-                       for tags, aggregated in (
-                           label(gi, False)
-                           for gi in range(len(grid.gkeys)))]
-    live = rows.tolist()
-    labels = [lab if w else label(gi, True) for gi, w, lab
-              in zip(live, whole.tolist(),
-                     map(grid.labels.__getitem__, live))]
-    n_kept = np.count_nonzero(whole) if kept else 0
-    _C_LABELS_KEPT.inc(n_kept)
-    _C_LABELS_COMPUTED.inc(len(rows) - n_kept)
-    # The live rows: their values, and their masks as [R, b_out] bytes.
-    gv = gv[rows]
-    bits = np.unpackbits(gm[rows], axis=1, count=b_out)
-    if (bits == bits[0]).all():
-        cols = np.flatnonzero(bits[0])
-        ts = cols.astype(np.int64) * interval + qbase
-        ts.flags.writeable = False
-        values = gv[:, cols].astype(np.float64)
-        values.flags.writeable = False
-        stamps = itertools.repeat(ts)
-    else:
-        r, c = np.nonzero(bits)
-        flat_ts = c.astype(np.int64) * interval + qbase
-        flat_ts.flags.writeable = False
-        flat_vals = gv[r, c].astype(np.float64)
-        flat_vals.flags.writeable = False
-        ends = np.cumsum(bits.sum(axis=1, dtype=np.intp)).tolist()
-        cuts = list(zip([0] + ends[:-1], ends))
-        stamps = (flat_ts[lo:hi] for lo, hi in cuts)
-        values = (flat_vals[lo:hi] for lo, hi in cuts)
-    return [QueryResult(metric, tags, aggregated, ts, v)
-            for (tags, aggregated), ts, v in zip(labels, stamps, values)]
-
-
-def _is_device_oom(e: Exception) -> bool:
-    """Device allocation failure (XLA RESOURCE_EXHAUSTED) — the one
-    non-contract error the devwindow path converts into a scan-path
-    fallback rather than raising."""
-    msg = str(e)
-    return "RESOURCE_EXHAUSTED" in msg or "Out of memory" in msg
-
-
-def _filter_key(exact, group_bys):
-    """Canonical hashable form of a UID-level (exact, group_bys) tag
-    filter — the shared component of every devwindow cache key (plan,
-    mask, quantile stage). One definition so the keys can't
-    desynchronize."""
-    return (tuple(sorted(exact)),
-            tuple(sorted((k, tuple(v) if v else None)
-                         for k, v in group_bys)))

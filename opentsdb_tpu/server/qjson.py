@@ -5,7 +5,7 @@ the entries into the bytes ``json.dumps(entries).encode()`` would give,
 without making a Python object a point or formatting twice what the
 executor handed out once: a grid plan's results share one timestamps
 array a sub-query and one ``(tags, aggregated)`` a group between every
-answer of the plan (query/executor.py, ``_grid_results``).
+answer of the plan (query/grid.py, ``_grid_results``).
 """
 
 from __future__ import annotations
@@ -15,7 +15,7 @@ import json
 import numpy as np
 
 from opentsdb_tpu.obs.registry import METRICS
-from opentsdb_tpu.query.executor import KeptTags
+from opentsdb_tpu.query.grid import KeptTags
 
 # Entries written; of those with a Dps, whether the text of the keys
 # was the run's (the same timestamps as an entry before it in this

@@ -2106,7 +2106,7 @@ class TSDServer:
         def compute():
             from opentsdb_tpu.models import (anomaly_bands, ewma,
                                              hw_forecast)
-            from opentsdb_tpu.query.executor import _pad_size
+            from opentsdb_tpu.query.grid import _pad_size
 
             grid0 = start - start % interval
             T = max((end - grid0) // interval + 1, 1)

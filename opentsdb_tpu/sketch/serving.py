@@ -150,7 +150,7 @@ def plan_percentile(executor, spec, start: int, end: int, *,
     # (writer) or capture refresh (replica) invalidates. Dirty or
     # edge-stitched ranges bypass both ways (they ARE the live
     # tail).
-    from opentsdb_tpu.query.executor import _filter_key
+    from opentsdb_tpu.query.grid import _filter_key
     from opentsdb_tpu.rollup.planner import window_split
     cache = getattr(executor, "_sketch_rail_cache", None)
     w_lo, w_hi, edges = window_split(start, end, res)
@@ -408,7 +408,7 @@ def _group_stage(executor, spec, spans):
     applied to the est/lo/hi rails separately. Monotone aggregators
     only (callers gate), so the rails stay a sound enclosure.
     Returns ([QueryResult], max_abs_err, max_rel_err)."""
-    from opentsdb_tpu.query.executor import QueryResult
+    from opentsdb_tpu.query.grid import QueryResult, group_tags
 
     tsdb = executor.tsdb
     group_by_keys = sorted(
@@ -445,7 +445,7 @@ def _group_stage(executor, spec, spans):
             est_g = _agg_reduce_cols(E, agg)
             lo_g = _agg_reduce_cols(Lo, agg)
             hi_g = _agg_reduce_cols(Hi, agg)
-        tags, aggregated = executor._group_tags(
+        tags, aggregated = group_tags(
             [named_spans[s] for s in skeys])
         ts_out = grid[mask]
         est_out = est_g[mask]

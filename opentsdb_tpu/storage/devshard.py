@@ -67,8 +67,6 @@ class ShardedDevChunks(NamedTuple):
     per-shard stage kernels. Row order of the combined result is shard
     order: combined sid = shard_starts[i] + local sid."""
     shards: list            # per-shard DevChunks | None (no series routed)
-    shard_windows: list     # per-shard DeviceWindow the chunks live in
-    #                         (its ``device``: None = default placement)
     shard_starts: list      # combined-sid offset of each shard's rows
     series_keys: list       # combined directory (concat in shard order)
     generation: tuple       # (reshard_gen, per-shard generations)
@@ -311,7 +309,6 @@ class ShardedDeviceWindow:
         self.window_hits += 1
         return ShardedDevChunks(
             shards=per,
-            shard_windows=shards,
             shard_starts=starts,
             series_keys=keys,
             generation=(gen, tuple(

@@ -225,6 +225,12 @@ class DevChunks(NamedTuple):
     blocks: list            # per chunk: int32 ids of the blocks picked
     block: int              # slots a block id stands for (ZONE_BLOCK)
     zones: list             # per chunk: its ZoneMap
+    window: object = None   # the DeviceWindow the chunks live in
+
+    # A stage is built over a window's shards (query/resident.py); one
+    # window's columns are their own single shard, as devshard.py's
+    # ShardedDevChunks lists several.
+    shards = property(lambda self: [self])
 
     def narrowed(self, sids: np.ndarray, start: int,
                  end: int) -> "DevChunks":
@@ -305,6 +311,7 @@ class DeviceWindow:
     """Thread-safe store of per-metric device-resident columns."""
 
     _instances = 0
+    n_shards = 1    # (devshard.py's sharded window: as many as it has)
 
     def __init__(self, staging_points: int = 1 << 20,
                  max_points: int = 1 << 26,
@@ -975,7 +982,8 @@ class DeviceWindow:
                 epoch=mw.epoch, series_keys=list(mw.keys),
                 generation=mw.generation, version=mw.version,
                 blocks=[c["zone"].select(start, end) for c in mw.chunks],
-                block=ZONE_BLOCK, zones=[c["zone"] for c in mw.chunks])
+                block=ZONE_BLOCK, zones=[c["zone"] for c in mw.chunks],
+                window=self)
 
     def chunk_classes(self) -> list:
         """One resident chunk (its four columns) of each padded size,

@@ -239,9 +239,12 @@ def child_plane(process_id: int, coordinator: str) -> int:
         "fold_windows": int(num_windows),
         "reduction_buckets": int(B),
         "reduction_byte_identical": True,
-        "compile_cache": cache_info(),
     }
     volatile = {
+        # How many programs the plane compiled follows the program, not
+        # the run's health: a count that a PR's kernels move belongs to
+        # the half no tier-1 run leaves changed in the tree.
+        "compile_cache": cache_info(),
         "iso": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
     }
     write_artifacts("MESH_PLANE_PROC.json", out, volatile)

@@ -871,7 +871,7 @@ class TestFusedDeclineCounters:
                 t.checkpoint()
             ex = QueryExecutor(t4, backend="tpu")
             # Three devices never divide a pow2-padded point count.
-            ex.mesh = types.SimpleNamespace(devices=np.zeros(3))
+            ex.fused.mesh = types.SimpleNamespace(devices=np.zeros(3))
             before = _decline_count("mesh-indivisible")
             r_m, plan_m, _ = ex.run_with_plan(self.SPEC, BASE + 100,
                                               BASE + 5 * 3600)
@@ -907,7 +907,7 @@ class TestDeviceBlockCache:
                                300, 30 + si)
                 t.checkpoint()
             ex4 = QueryExecutor(t4, backend="tpu")
-            assert ex4._devcache is not None
+            assert ex4.fused.devcache is not None
             ex0 = QueryExecutor(t0, backend="tpu")
             spec = QuerySpec("m.d", {}, "sum", downsample=(3600, "sum"))
             h0, m0 = hit.value, miss.value
@@ -916,7 +916,7 @@ class TestDeviceBlockCache:
             assert plan1 == "fused"
             assert miss.value > m0
             m1 = miss.value
-            assert len(ex4._devcache) > 0
+            assert len(ex4.fused.devcache) > 0
             # A different window over the same blocks: the stage cache
             # misses but every block decode is already resident.
             spec2 = QuerySpec("m.d", {}, "max", downsample=(7200, "max"))
@@ -968,9 +968,9 @@ class TestDeviceBlockCache:
                           downsample=(7200, "max"))]
             for cache, plan in (("on", "fused"), ("off", "raw")):
                 if cache == "off":
-                    ex4._devcache = None
+                    ex4.fused.devcache = None
                 ex4._frag_cache.clear()
-                ex4._fused_stage_cache.clear()
+                ex4.fused.stage_cache.clear()
                 declined = _decline_count("cache-off")
                 for spec in specs:
                     r4, plan4, _ = ex4.run_with_plan(

@@ -220,11 +220,11 @@ class TestFallbacks:
         agg = Aggregators.get("sum")
         # Wide range: caught by the range-width guard before the window
         # is touched.
-        assert ex._run_devwindow(spec, 0, int(0xFFFFFFFF), agg) is None
+        assert ex.resident.serve(spec, 0, int(0xFFFFFFFF), agg) is None
         # Narrow range (fits int32) whose qbase is > 2^31 before the
         # metric's epoch: reaches the shift guard itself — the window
         # must fall back, not clamp.
-        assert ex._run_devwindow(spec, 0, 1000, agg) is None
+        assert ex.resident.serve(spec, 0, 1000, agg) is None
         assert ex.run(spec, 0, 1000) == []
         got = ex.run(spec, 0, int(0xFFFFFFFF))
         want = QueryExecutor(tsdb, backend="cpu").run(
@@ -307,7 +307,7 @@ class TestFallbacks:
     def test_mesh_executor_skips_window(self, tsdb):
         _load(tsdb, series=2)
         ex = QueryExecutor(tsdb, backend="tpu", mesh=object())
-        assert ex._run_devwindow(
+        assert ex.resident.serve(
             QuerySpec("m.cpu", {}, "sum", downsample=(600, "avg")),
             BT, BT + 7200, __import__(
                 "opentsdb_tpu.query.aggregators",

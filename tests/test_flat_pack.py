@@ -367,7 +367,7 @@ def test_span_lists_still_pack_and_are_counted_apart(tsdb):
             assert np.array_equal(a.timestamps, b.timestamps)
             assert np.array_equal(a.values, b.values)
     one = next(iter(groups.values()))
-    r1, v1, s1, ok1 = ex._flatten_spans(one, start)
+    r1, v1, s1, ok1 = _Scan.of_spans({(): one}).stream(start)
     assert len(r1) == len(one[0].timestamps) and ok1.all()
     assert (s1 == 0).all() and v1.dtype == np.float32
     assert counters()[:2] == (flat0, spans0 + n + len(r1))

@@ -229,7 +229,8 @@ def test_the_stats_count_the_updates_of_the_stages_built(tmp_path,
     assert stat(names[0]) - before[0] == updates
 
 
-@pytest.mark.parametrize("tally", ["_FOLD_HANDED", "_STAGE_HANDED"])
+@pytest.mark.parametrize("tally", ["resident._FOLD_HANDED",
+                                   "grid._STAGE_HANDED"])
 def test_no_count_is_lost_between_stages_and_readers(monkeypatch, tally):
     """Stages hand their counts over from several threads while others
     read the stats: every count is added once (the resident plan's
@@ -237,9 +238,13 @@ def test_no_count_is_lost_between_stages_and_readers(monkeypatch, tally):
     import sys
     import threading
 
-    from opentsdb_tpu.query import executor
-    monkeypatch.setattr(executor, "_HANDED_MAX", 16)
-    tally = getattr(executor, tally)
+    import importlib
+
+    from opentsdb_tpu.query import grid
+    monkeypatch.setattr(grid, "_HANDED_MAX", 16)
+    module, tally = tally.split(".")
+    tally = getattr(importlib.import_module(
+        "opentsdb_tpu.query." + module), tally)
     before = tally.total()
     one = np.int32(1)
 

@@ -11,8 +11,8 @@ import numpy as np
 import pytest
 
 from opentsdb_tpu.obs.registry import METRICS
-from opentsdb_tpu.query.executor import (QueryExecutor, QueryResult,
-                                         _GridGroups, _grid_results)
+from opentsdb_tpu.query.grid import (QueryResult, _GridGroups,
+                                     _grid_results, group_tags)
 
 QBASE = 1356998400
 METRIC = "cpu.usage_user"
@@ -22,7 +22,7 @@ COMPUTED = METRICS.counter("query.results.labels.computed")
 
 def reference(metric, groups, named, has_points, gv, gm, b_out, interval,
               qbase):
-    """The loop of ``_run_devwindow`` as PR 42 left it."""
+    """The loop of the resident plan as PR 42 left it."""
     gkeys = sorted(groups)
     gm = np.unpackbits(gm, axis=1, count=b_out).astype(bool)
     results = []
@@ -30,7 +30,7 @@ def reference(metric, groups, named, has_points, gv, gm, b_out, interval,
         live = [sid for sid in groups[gkey] if has_points[sid]]
         if not live:
             continue
-        tags, aggregated = QueryExecutor._group_tags(
+        tags, aggregated = group_tags(
             [named[sid] for sid in live])
         mask = gm[gi]
         grid_ts = (np.flatnonzero(mask).astype(np.int64) * interval

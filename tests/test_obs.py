@@ -229,8 +229,11 @@ class TestTrace:
 
 
 def _spin(seconds):
-    t0 = time.perf_counter()
-    while time.perf_counter() - t0 < seconds:
+    """Keep this thread on a core for ``seconds`` of its OWN clock, so
+    for at least as long on the wall's: a loaded host stretches the
+    wall time of a spin, never the CPU time its callers hold."""
+    t0 = time.thread_time()
+    while time.thread_time() - t0 < seconds:
         pass
 
 
@@ -264,20 +267,24 @@ class TestCpuTime:
         assert d["spans"][0]["cpu_ms"] == round(sp.cpu_ms, 3)
 
     def test_a_spinning_span_reads_its_wall_time(self):
-        # The best of five: a host that takes the core away mid-spin
-        # lengthens the wall time of that try alone.
-        best = 0.0
+        # How much of a spin's wall time its thread is on a core is the
+        # host's to say (a loaded one takes the core away mid-spin), so
+        # what is held is arithmetic: the span's CPU time is what the
+        # thread's own clock read around the same spin, between a
+        # reading taken inside the span and one taken around it.
         for _ in range(5):
             tr = obs_trace.Trace("q")
             with obs_trace.activate(tr):
+                around = time.thread_time()
                 with obs_trace.span("cpu.spins"):
+                    inside = time.thread_time()
                     _spin(0.05)
+                    inside = time.thread_time() - inside
+                around = time.thread_time() - around
             sp, = tr.root.children
             assert sp.ms >= 49 and sp.cpu_ms <= sp.ms + 1
-            best = max(best, sp.cpu_ms / sp.ms)
-            if best >= 0.8:
-                break
-        assert best >= 0.8
+            assert 0 < inside * 1e3 <= sp.cpu_ms + 1e-3
+            assert sp.cpu_ms <= around * 1e3 + 1e-3
 
     def test_timed_iter_keeps_the_cpu_of_its_pulls_alone(self):
         tr = obs_trace.Trace("q")
@@ -512,11 +519,12 @@ class TestServerTraces:
         async def drive(port):
             q = (f"/q?start={BASE}&end={BASE + 2 * 86400 + 1800}"
                  "&m=sum:1h-avg:obs.metric&json&trace=1&nocache")
-            return await http_get(port, q)
+            return [await http_get(port, q) for _ in range(5)]
 
-        st, body = run_async(server, drive)
-        assert st == 200
-        out = json.loads(body)
+        got = run_async(server, drive)
+        assert all(st == 200 for st, _body in got)
+        outs = [json.loads(body) for _st, body in got]
+        out = outs[0]
         assert out and out[0]["rollup"] in ("1h", "1d")
         tr = out[0]["trace"]
         names = _span_names(tr)
@@ -533,11 +541,17 @@ class TestServerTraces:
         assert any(any(k.startswith("qcache_")
                        for k in s.get("tags", {}))
                    for s in stitches), stitches
-        # Top-level stage durations tile the query wall time (10%);
-        # the two hops lie outside it.
-        top = sum(s["ms"] for s in tr["spans"]
-                  if not s["name"].startswith("http.q."))
-        assert top >= 0.9 * tr["ms"], (top, tr["ms"])
+        # Top-level stage durations tile the query wall time; the two
+        # hops lie outside it. Never more than it (a rounding a span),
+        # and 90% of it in the median request: where the host takes the
+        # core away between two spans of one request is its to say.
+        shares = []
+        for tr in (o[0]["trace"] for o in outs):
+            tops = [s["ms"] for s in tr["spans"]
+                    if not s["name"].startswith("http.q.")]
+            assert sum(tops) <= tr["ms"] + 1e-3 * len(tops)
+            shares.append(sum(tops) / tr["ms"])
+        assert sorted(shares)[len(shares) // 2] >= 0.9, shares
 
     def test_raw_trace_and_query_scan_delay(self, tmp_path):
         """Armed delay on the query.scan faultpoint stretches exactly

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from opentsdb_tpu.obs.registry import METRICS
-from opentsdb_tpu.query.executor import KeptTags, QueryResult
+from opentsdb_tpu.query.grid import KeptTags, QueryResult
 from opentsdb_tpu.server import qjson
 from opentsdb_tpu.server.tsd import TSDServer
 
@@ -353,7 +353,7 @@ class TestServed:
         tsdb = make_tsdb(tmp_path, hosts=8)
         end = BASE + SPAN - 10
         server = TSDServer(tsdb)
-        cache = server.executor._dw_plan_cache
+        cache = server.executor.resident.plan_cache
         held = []
 
         async def main():
@@ -369,7 +369,7 @@ class TestServed:
                 await server._server.wait_closed()
         asyncio.run(main())
         key, = cache.keys()
-        held.extend(tags for tags, _agg in cache.peek(key)[3].labels)
+        held.extend(tags for tags, _agg in cache.peek(key)[-1].labels)
         assert len(held) == 8
         assert all(type(t) is KeptTags and t.text is not None
                    for t in held)

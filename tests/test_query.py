@@ -533,7 +533,7 @@ class TestStageCacheSharing:
         # All five panels share one (metric, range, interval, agg_down)
         # -> ONE stage cache entry.
         got = [ex.run(spec, BT, BT + 7200) for spec in panels]
-        assert len(getattr(ex, "_dw_stage_cache")) == 1
+        assert len(ex.resident.stage_cache) == 1
         # Each panel must still match its own oracle run.
         ex_cpu = QueryExecutor(tsdb, backend="cpu")
         for spec, res in zip(panels, got):

@@ -18,7 +18,6 @@ from opentsdb_tpu.core.tsdb import TSDB
 from opentsdb_tpu.obs import trace as obs_trace
 from opentsdb_tpu.obs.registry import METRICS
 from opentsdb_tpu.ops import kernels
-from opentsdb_tpu.query import executor as executor_mod
 from opentsdb_tpu.query.executor import QueryExecutor, QuerySpec
 from opentsdb_tpu.server.tsd import TSDServer
 from opentsdb_tpu.stats.collector import StatsCollector
@@ -127,11 +126,13 @@ class TestResidentSpans:
         tsdb = make_tsdb(tmp_path)
         m = "max:5m-max:res.cpu{host=*}"
         end = BASE + SPAN - 10
-        # Cold (the programs compile), then a new range (a stage is
-        # built by compiled programs), then that range again (every
+        # Cold (the programs compile), then new ranges (a stage each is
+        # built by compiled programs), then the last range again (every
         # cache hits: microseconds, so only the order is held).
-        got = serve(tsdb, q(BASE, end, m), q(BASE + 600, end, m),
-                    q(BASE + 600, end, m))
+        starts = [BASE + 600 * n for n in (0, 1, 2, 3, 4, 5, 5)]
+        again = len(starts) - 1
+        got = serve(tsdb, *(q(start, end, m) for start in starts))
+        shares = []
         for i, (st, body) in enumerate(got):
             assert st == 200
             out = json.loads(body)
@@ -150,9 +151,9 @@ class TestResidentSpans:
             assert all(lo - 1e-3 <= t <= hi + 1e-3 for t in t0s)
             assert tree["t0"] <= pick["t0"]
             total = sum(s["ms"] for s in kids)
-            assert total <= pick["ms"]
-            if i < 2:
-                assert total >= 0.95 * pick["ms"], (total, pick["ms"])
+            assert total <= pick["ms"] + 1e-3 * len(kids)
+            if i < again:
+                shares.append(total / pick["ms"])
             tags = {s["name"]: s.get("tags", {}) for s in kids}
             assert tags["resident.columns"]["chunks"] >= 1
             assert tags["resident.columns"]["points"] >= HOSTS * SPAN // 10
@@ -161,9 +162,13 @@ class TestResidentSpans:
             assert tags["resident.apply"]["g_out"] >= HOSTS
             assert tags["resident.fetch"]["bytes"] > 0
             assert tags["resident.results"]["results"] == HOSTS
-            assert tags["resident.stage"]["hit"] is (i == 2)
+            assert tags["resident.stage"]["hit"] is (i == again)
             assert tags["resident.groups"]["plan_hit"] is (i > 0)
             assert tags["resident.groups"]["mask_hit"] is (i > 0)
+        # The seven tile their parent in the median request that built
+        # a stage: where the host takes the core away between two spans
+        # of one request is its to say.
+        assert sorted(shares)[len(shares) // 2] >= 0.95, shares
 
     def test_sub_queries_of_one_request_share_one_trace_id(self, tmp_path):
         tsdb = make_tsdb(tmp_path)
@@ -241,7 +246,7 @@ class TestUntracedPathUnchanged:
         calls = []
         real = jax.block_until_ready
         monkeypatch.setattr(
-            executor_mod.jax, "block_until_ready",
+            jax, "block_until_ready",
             lambda x: (calls.append(1), real(x))[1])
         assert obs_trace.span("resident.stage") is obs_trace._NOOP
         got = ex.run(spec, BASE, BASE + SPAN - 10)

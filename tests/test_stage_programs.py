@@ -131,7 +131,7 @@ def test_a_chunk_with_no_block_picked_is_in_no_call():
 def test_a_block_visited_over_a_range_nothing_lies_in_changes_no_grid(
         monkeypatch, agg):
     """What the sharded window's stages are warmed with
-    (QueryExecutor._dw_warm_shards): a block of one chunk of each shape
+    (ResidentPlan._warm_shards): a block of one chunk of each shape
     class visited over a range with its end before its start is a fold
     call a class, which gives the grids of a stage that folded
     nothing."""
@@ -232,7 +232,7 @@ def test_the_counters_say_what_a_served_stage_issued(tmp_path, monkeypatch,
                      downsample=(300, "max"))
     # Before the first stage of a kind a sharded window's devices are
     # warmed with a stage each, built and thrown away
-    # (_dw_warm_shards); the counters say what a served stage's own
+    # (ResidentPlan._warm_shards); the counters say what a served stage's own
     # selection issues, so another range goes first.
     ex.run_with_plan(spec, BASE + 1200, BASE + SPAN - 10)
     calls = counted(monkeypatch)

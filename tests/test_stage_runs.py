@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from opentsdb_tpu.ops import kernels
-from opentsdb_tpu.query import executor
+from opentsdb_tpu.query import grid as qgrid
 from opentsdb_tpu.query.executor import QueryExecutor, QuerySpec
 from tests.test_compress import BASE, _int_batch, _mk_tpu_tsdb
 from tests.test_fold_runs import AGGS, EXACT, reference
@@ -309,12 +309,12 @@ def test_the_stats_count_raw_and_fused_stages(tmp_path, monkeypatch):
     t4 = _mk_tpu_tsdb(tmp_path, "s4", "tsst4")
     t0 = _mk_tpu_tsdb(tmp_path, "s0", "none")
     seen = []
-    count = executor._stage_handed
+    count = qgrid._stage_handed
 
     def keep(handed, slots):
         seen.append((handed, slots))
         count(handed, slots)
-    monkeypatch.setattr(executor, "_stage_handed", keep)
+    monkeypatch.setattr(qgrid, "_stage_handed", keep)
     names = ["query.stage.updates", "query.stage.slots"]
     try:
         for t in (t4, t0):

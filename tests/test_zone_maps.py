@@ -21,7 +21,7 @@ FOLDS = [("sum", False), ("avg", False), ("min", False), ("max", False),
          ("count", False), ("dev", False), ("sum", True)]
 # count / min / max take no rounding, so block-wise they are the same
 # bytes; the sums reassociate, within the f32 tolerance the sharded
-# stage declares (query/executor.py, _dw_sharded_stage).
+# stage declares (query/resident.py, ResidentPlan._stage).
 EXACT = {"min", "max", "count"}
 
 
